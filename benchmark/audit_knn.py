@@ -77,11 +77,9 @@ def run_audit(n_items=200_000, d=3000, k=200, qn=8192, sample_stride=1024):
 
 
 def main():
-    import jax
+    from spark_rapids_ml_tpu.ops.precompile import ensure_compile_cache
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/srml_jax_cache")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-
+    ensure_compile_cache()
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 200_000
     d = int(sys.argv[2]) if len(sys.argv) > 2 else 3000
     k = int(sys.argv[3]) if len(sys.argv) > 3 else 200

@@ -8,9 +8,6 @@ import numpy as np
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/srml_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-
 ROWS = int(sys.argv[1]) if len(sys.argv) > 1 else 400_000
 SEED = int(sys.argv[2]) if len(sys.argv) > 2 else 0
 COLS = 3000

@@ -18,7 +18,7 @@ cd "$(dirname "$0")/.."
 #    visible as warnings (the per-PR per-module re-runs that used to ride
 #    each focused step below are consolidated here — same files, one
 #    program, no drift between the module lists and the tree).
-python -m compileall -q spark_rapids_ml_tpu benchmark tests bench.py __graft_entry__.py
+python -m compileall -q spark_rapids_ml_tpu benchmark tests bench.py chip_smoke.py __graft_entry__.py
 python -m tools.graftlint spark_rapids_ml_tpu benchmark \
     --baseline ci/graftlint-baseline.json --fail-on-new
 

@@ -57,10 +57,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import profiling
-from ..compat import shard_map
 from ..parallel.mesh import (
     DATA_AXIS,
     axis_sharding,

@@ -64,9 +64,7 @@ def _maybe_x64(dtype: Any):
     import contextlib
 
     if np.dtype(dtype) == np.float64:
-        from .compat import enable_x64
-
-        return enable_x64(True)
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 
@@ -543,7 +541,9 @@ class _TpuCaller(_TpuParams):
             )
             return results if paramMaps is not None else results[0]
         from . import profiling
+        from .ops.precompile import ensure_compile_cache
 
+        ensure_compile_cache()
         profiling.reset_phase_times()
         counters0 = profiling.counters()
         df = as_dataframe(dataset)
@@ -858,6 +858,9 @@ class _TpuModel(_TpuParams):
             from .spark.adapter import executor_transform
 
             return executor_transform(self, dataset)
+        from .ops.precompile import ensure_compile_cache
+
+        ensure_compile_cache()
         df = as_dataframe(dataset)
         if getattr(df, "_device_features", None) is not None:
             raise NotImplementedError(

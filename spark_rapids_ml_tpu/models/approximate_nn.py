@@ -246,7 +246,9 @@ class ApproximateNearestNeighbors(
 
     def _fit(self, dataset: Any) -> "ApproximateNearestNeighborsModel":
         from ..core import _use_executor_path, extract_partition_features
+        from ..ops.precompile import ensure_compile_cache
 
+        ensure_compile_cache()
         self._check_algorithm()
         if getattr(dataset, "_device_features", None) is not None:
             raise NotImplementedError(

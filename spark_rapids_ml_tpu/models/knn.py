@@ -84,7 +84,9 @@ class NearestNeighbors(_NearestNeighborsParams, _TpuEstimatorSupervised):
 
     def _fit(self, dataset: Any) -> "NearestNeighborsModel":
         from ..core import _use_executor_path
+        from ..ops.precompile import ensure_compile_cache
 
+        ensure_compile_cache()
         if getattr(dataset, "_device_features", None) is not None:
             # fitting would silently DROP the device array (the captured
             # frame only carries the placeholder column) and kneighbors

@@ -237,6 +237,7 @@ class PCAModel(_PCAParams, _TpuModel):
             n_cols=self.n_cols,
             out_cols=[out_col],
             info={"k": len(self.components_)},
+            mesh=mesh,
         )
 
     def _lane_entry(self, mesh: Any = None):

@@ -3,9 +3,11 @@
 #
 # The reference loads its native layer via JNI (JniRAPIDSML.java:26-62:
 # extract .so, System.load, declare natives); here the same role is played by
-# ctypes over a C API (no pybind11 in the image).  Everything degrades
-# gracefully: if the library is missing or SRML_NATIVE=0, `lib()` returns
-# None and callers fall back to numpy.
+# ctypes over a C API (no pybind11 in the image).  The library is OPTIONAL
+# and built outside git (native/build/ is ignored): where no .so exists, or
+# SRML_NATIVE=0, `lib()` returns None and the numpy routes ARE the path
+# (`available()` says which ran).  A .so that exists but does not load, or
+# lacks a declared symbol (a stale build), is an error — never a quiet skip.
 #
 # Build: `make -C native` or `cmake -S native -B native/build && cmake --build
 # native/build`.  Override discovery with SRML_NATIVE_LIB=/path/to/.so.
@@ -88,25 +90,27 @@ def _declare(lib: ctypes.CDLL) -> None:
 
 
 def lib() -> Optional[ctypes.CDLL]:
-    """The loaded native library, or None when unavailable/disabled."""
+    """The loaded native library, or None when not built/disabled.  Raises
+    when a library file is present but unloadable or stale."""
     global _lib, _lib_tried
     if os.environ.get("SRML_NATIVE", "1") == "0":
         return None
     with _lock:
         if _lib_tried:
             return _lib
-        _lib_tried = True
         for path in _candidate_paths():
             if os.path.exists(path):
                 try:
                     candidate = ctypes.CDLL(path)
                     _declare(candidate)
-                    _lib = candidate
-                    break
-                except (OSError, AttributeError):
-                    # unloadable or stale .so missing a symbol: fall back to
-                    # numpy rather than poisoning every caller
-                    continue
+                except (OSError, AttributeError) as exc:
+                    raise RuntimeError(
+                        f"native library {path} exists but is unusable "
+                        f"({exc}); rebuild it (`make -C native`) or remove it"
+                    ) from exc
+                _lib = candidate
+                break
+        _lib_tried = True
         return _lib
 
 

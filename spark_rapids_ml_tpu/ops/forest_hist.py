@@ -310,7 +310,7 @@ def node_histograms_sharded(
     shard's row count must stay a multiple of _ROW_TILE, i.e. N_pad must be
     a multiple of n_devices * _ROW_TILE.  Returns the REPLICATED
     (F_pad, M_SLOTS, B) histogram."""
-    from ..compat import shard_map
+    from jax import shard_map
     from ..parallel.exchange import psum_parts
     from ..parallel.mesh import DATA_AXIS
     from jax.sharding import PartitionSpec as PSpec

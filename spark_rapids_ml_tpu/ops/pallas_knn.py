@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..compat import tpu_compiler_params
 from .pallas_tpu import _round_up, pallas_enabled
 
 # tile geometry: TQ queries x TI items per grid cell, D consumed in KB-wide
@@ -442,7 +441,7 @@ def _candidates_pool(
                     (tile_i, kb) if d_blk <= kb else (8, 128), jnp.bfloat16
                 ),
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=100 << 20
             ),
             interpret=interpret,
@@ -468,7 +467,7 @@ def _candidates_pool(
             # (tq, tile_i) f32 temporaries at once; the default 16 MB
             # scoped budget caps the tile at (256, 1024) — larger query
             # tiles need the raised limit
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=96 << 20
             ),
             interpret=interpret,
@@ -526,11 +525,14 @@ def knn_candidates_pallas(
 # so the only thing left for the host is the id map: no transpose slab, no
 # XLA merge, one kernel boundary fewer.
 #
-# Selection contract: lexicographic (-d2, pos) — the pool's column order is
-# position-increasing within equal values by construction (groups are
-# position-base-ordered and _select_topm_store's argmax keeps ties in
-# first-occurrence order), so the k iterated first-occurrence argmax passes
-# return the UNIQUE lex top-k of the pool.  That makes the fused route's
+# Selection contract: lexicographic (-d2, pos).  Each of the k passes takes
+# the pool's maximum value and, among the entries that hold it, the SMALLEST
+# position — stated as a max and a min, never as an argmax: Mosaic's argmax
+# does not promise the first occurrence among equal values (on a v5e the
+# first-occurrence form returned the later duplicate of a tied pair in ~2%
+# of the entries of tests/test_pallas.py's tie test).  Positions are unique
+# in the pool, so the passes return the UNIQUE lex top-k whatever order the
+# candidates kernel left tied entries in.  That makes the fused route's
 # output deterministic under any pool partitioning — the same total-order
 # property the ANN engine's mesh-parity gate rides — and testable against a
 # plain numpy lexsort oracle (tests/test_pallas.py).
@@ -545,24 +547,26 @@ def _knn_fused_merge_kernel(
     pool_v_ref, pool_i_ref, dist_ref, pos_ref, flag_ref,
     *, k: int, m: int, m_pad: int, ng: int, tq: int, k_pad: int,
 ):
-    """Merge one query tile's pool: k iterated (argmax, max, one-hot
-    position read, mask) passes over the VMEM-resident (ng*m_pad, tq) pool
-    view — first-occurrence argmax IS the lex (-d2, pos) order (header).
+    """Merge one query tile's pool: k iterated (max value, min position
+    among its holders, mask) passes over the VMEM-resident (ng*m_pad, tq)
+    pool view — the lex (-d2, pos) order, stated without argmax (header).
     Also computes the self-verify overflow flag in-kernel: a group whose
     m-th kept value beats the margined k-th threshold might have overflowed
     (same contract as ops/knn._adaptive_merge_self)."""
     C = ng * m_pad
     v = pool_v_ref[:].reshape(C, tq)
     pidx = pool_i_ref[:].reshape(C, tq)
-    iota0 = jax.lax.broadcasted_iota(jnp.int32, (C, tq), 0)
+    no_pos = jnp.iinfo(jnp.int32).max
     vals, poss = [], []
     for _ in range(k):
-        am = jnp.argmax(v, axis=0).astype(jnp.int32)  # (tq,)
-        vals.append(jnp.max(v, axis=0))
-        sel = iota0 == am[None, :]
-        # one-hot read: exactly one pool row selected per query column
-        poss.append(jnp.where(sel, pidx, 0).sum(axis=0).astype(jnp.int32))
-        v = jnp.where(sel, -jnp.inf, v)
+        best = jnp.max(v, axis=0, keepdims=True)      # (1, tq)
+        holds = v == best
+        pos = jnp.min(jnp.where(holds, pidx, no_pos), axis=0, keepdims=True)
+        vals.append(best[0])
+        poss.append(pos[0])
+        # an exhausted pool (best == -inf) re-reads spent rows: harmless,
+        # their -inf distance is what the id map keys on
+        v = jnp.where(holds & (pidx == pos), -jnp.inf, v)
     fv = jnp.stack(vals)   # (k, tq) negated d2, descending
     fp = jnp.stack(poss)   # (k, tq)
     # margined threshold + per-group overflow flag (ops/knn._merge_pool's
@@ -651,7 +655,7 @@ def knn_fused_pallas(
             jax.ShapeDtypeStruct((q_pad, k_pad), jnp.int32),
             jax.ShapeDtypeStruct((q_pad, 1), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(vmem_limit_bytes=100 << 20),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=100 << 20),
         interpret=interpret,
     )(vals, idxs)
     zeros = jnp.zeros((Q,), jnp.int32)

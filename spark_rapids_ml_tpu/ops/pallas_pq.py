@@ -24,7 +24,7 @@
 # Layout: everything arrives pre-transposed so stores land along lanes —
 # tables  (B, ksub, m_sub): T[:, j] is a sublane column, broadcast to lanes
 # codes   (B, m_sub, R):    code row j is a lane vector
-# out     (B, R):           one (1, TILE_R) store per grid cell
+# out     (B, 1, R):        one (1, TILE_R) store per grid cell
 # Grid (B, R / TILE_R), table block resident across the R sweep.
 #
 # Accumulation ORDER is part of the contract: the j-loop is a static
@@ -34,9 +34,7 @@
 #
 # CPU / non-TPU fallback: lut_accumulate routes through an identical-math
 # XLA take_along_axis formulation (tier-1 searches ride it; the kernel
-# itself is gated in interpret mode).  Mosaic-compile validation on real
-# hardware is pending — the route keeps the SRML_DISABLE_PALLAS escape
-# hatch shared with the other TPU kernels.
+# itself is gated in interpret mode).
 #
 
 from __future__ import annotations
@@ -57,10 +55,24 @@ from .pallas_tpu import _round_up, pallas_enabled
 _LUT_TILE_R = 512
 
 
+def _lut_out_spec():
+    """Output block of both scan kernels: one (1, TILE_R) row per grid cell
+    of a (B, 1, R) array.  The unit middle axis is what Mosaic needs — the
+    last two block dims must divide by (8, 128) or equal the array's, and a
+    (1, TILE_R) block of a (B, R) array does neither once B > 1."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pl.BlockSpec(
+        (1, 1, _LUT_TILE_R), lambda qi, ri: (qi, 0, ri),
+        memory_space=pltpu.VMEM,
+    )
+
+
 def _lut_accum_kernel(t_ref, c_ref, o_ref, *, m_sub: int):
     # t_ref (1, ksub, m_sub) f32 — this query's ADC table, grid-resident
     # c_ref (1, m_sub, TILE_R) int8 — code tile, rows along lanes
-    # o_ref (1, TILE_R) f32
+    # o_ref (1, 1, TILE_R) f32
     ksub = t_ref.shape[1]
     codes = c_ref[0].astype(jnp.int32)                 # (m_sub, TILE_R)
     tile_r = codes.shape[1]
@@ -68,14 +80,16 @@ def _lut_accum_kernel(t_ref, c_ref, o_ref, *, m_sub: int):
     acc = jnp.zeros((1, tile_r), jnp.float32)
     for j in range(m_sub):
         # exactly one lane of `eq` is True per row: the masked sublane sum
-        # gathers T[j, code] bit-exactly (x + 0.0 == x)
-        eq = codes[j, :][None, :] == cls               # (ksub, TILE_R)
+        # gathers T[j, code] bit-exactly (x + 0.0 == x).  Row j of the code
+        # tile and column j of the table stay 2-D slices, so both broadcasts
+        # are plain sublane/lane splats (no 1-D relayout for Mosaic)
+        eq = codes[j : j + 1, :] == cls                # (ksub, TILE_R)
         acc = acc + jnp.sum(
-            jnp.where(eq, t_ref[0, :, j][:, None], 0.0),
+            jnp.where(eq, t_ref[0, :, j : j + 1], 0.0),
             axis=0,
             keepdims=True,
         )
-    o_ref[:] = acc
+    o_ref[0] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -109,14 +123,11 @@ def _lut_accumulate_pallas(
                 memory_space=pltpu.VMEM,
             ),
         ],
-        out_specs=pl.BlockSpec(
-            (1, _LUT_TILE_R), lambda qi, ri: (qi, ri),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, r_pad), jnp.float32),
+        out_specs=_lut_out_spec(),
+        out_shape=jax.ShapeDtypeStruct((b, 1, r_pad), jnp.float32),
         interpret=interpret,
     )(t_t, c_t)
-    return out[:, :r]
+    return out[:, 0, :r]
 
 
 def _lut_accumulate_xla(tables: jax.Array, codes: jax.Array) -> jax.Array:
@@ -170,7 +181,7 @@ def _fastscan_check(tables: jax.Array, packed: jax.Array) -> int:
 def _fastscan_kernel(t_ref, c_ref, o_ref, *, m_sub: int):
     # t_ref (1, ksub<=16, m_sub) f32 — this query's ADC table, grid-resident
     # c_ref (1, m_sub//2, TILE_R) uint8 — packed code tile, rows along lanes
-    # o_ref (1, TILE_R) f32
+    # o_ref (1, 1, TILE_R) f32
     ksub = t_ref.shape[1]
     packed = c_ref[0].astype(jnp.int32)                # (m_sub//2, TILE_R)
     lo = packed & 0xF
@@ -179,17 +190,17 @@ def _fastscan_kernel(t_ref, c_ref, o_ref, *, m_sub: int):
     cls = jax.lax.broadcasted_iota(jnp.int32, (ksub, tile_r), 0)
     acc = jnp.zeros((1, tile_r), jnp.float32)
     for j in range(m_sub):
-        nib = lo[j // 2, :] if j % 2 == 0 else hi[j // 2, :]
+        nib = (lo if j % 2 == 0 else hi)[j // 2 : j // 2 + 1, :]
         # exactly one of the 16 lanes matches per row: the masked sublane
         # sum gathers T[j, code] bit-exactly (x + 0.0 == x), same argument
         # as the 8-bit kernel with a 16x smaller compare tile
-        eq = nib[None, :] == cls                       # (ksub, TILE_R)
+        eq = nib == cls                                # (ksub, TILE_R)
         acc = acc + jnp.sum(
-            jnp.where(eq, t_ref[0, :, j][:, None], 0.0),
+            jnp.where(eq, t_ref[0, :, j : j + 1], 0.0),
             axis=0,
             keepdims=True,
         )
-    o_ref[:] = acc
+    o_ref[0] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -222,14 +233,11 @@ def _fastscan_pallas(
                 memory_space=pltpu.VMEM,
             ),
         ],
-        out_specs=pl.BlockSpec(
-            (1, _LUT_TILE_R), lambda qi, ri: (qi, ri),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, r_pad), jnp.float32),
+        out_specs=_lut_out_spec(),
+        out_shape=jax.ShapeDtypeStruct((b, 1, r_pad), jnp.float32),
         interpret=interpret,
     )(t_t, c_t)
-    return out[:, :r]
+    return out[:, 0, :r]
 
 
 def pack_codes4(codes: np.ndarray) -> np.ndarray:
@@ -279,9 +287,14 @@ def fastscan_lut_accumulate(
     routing contract at half the code bytes.  Rejects odd m_sub and
     ksub > 16 with typed errors."""
     _fastscan_check(tables, packed)
-    if interpret or pallas_enabled():
+    if lut_route(interpret) == "pallas":
         return _fastscan_pallas(tables, packed, interpret=interpret)
     return _fastscan_xla(tables, packed)
+
+
+def lut_route(interpret: bool = False) -> str:
+    """'pallas' or 'xla': the route both LUT accumulations take."""
+    return "pallas" if interpret or pallas_enabled() else "xla"
 
 
 def lut_accumulate(
@@ -295,6 +308,6 @@ def lut_accumulate(
     ops/pallas_tpu.min_dist_argmin.  Code values must lie in [0, ksub)
     (the PQ encoder guarantees it; out-of-range values contribute 0 on the
     pallas route and clamp on the XLA route — both masked upstream)."""
-    if interpret or pallas_enabled():
+    if lut_route(interpret) == "pallas":
         return _lut_accumulate_pallas(tables, codes, interpret=interpret)
     return _lut_accumulate_xla(tables, codes)

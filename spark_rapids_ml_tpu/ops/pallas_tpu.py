@@ -323,6 +323,28 @@ def _bin_features_fm_pallas(
     return out[:d, :n_pad]
 
 
+def min_dist_route(
+    n: int, d: int, k: int, itemsize: int, interpret: bool = False
+) -> str:
+    """'pallas' or 'xla': the route min_dist_argmin takes at this shape.
+
+    Routing (v5e A/B, HIGHEST precision, 2026-07-30): the fused kernel wins
+    only when the (n, k) distance matrix dominates HBM traffic — low d,
+    large k (d=32/k=16384: 27.4 ms vs XLA 34.5; d=64/k=8192: 15.3 vs 17.8).
+    When FLOPs dominate (d=3000/k=1000: 13.5 vs 10.0) or the batch pads up
+    to one row tile (single-row predict), XLA's own fusion is the better
+    program.  interpret mode bypasses the heuristic so tests always hit the
+    kernel."""
+    if not (interpret or pallas_enabled()):
+        return "xla"
+    d_pad = _round_up(d, 128)
+    tiles = _pick_tiles(d_pad, itemsize)
+    if tiles is None:
+        return "xla"
+    worthwhile = d_pad <= 256 and k >= 1024 and n >= tiles[0]
+    return "pallas" if interpret or worthwhile else "xla"
+
+
 def min_dist_argmin(
     X: jax.Array,
     centers: jax.Array,
@@ -332,30 +354,19 @@ def min_dist_argmin(
 ) -> Tuple[jax.Array, jax.Array]:
     """Fused nearest-center search: returns (min_d2 (N,), argmin (N,)).
 
-    Uses the Pallas TPU kernel when running on TPU (or when
-    interpret=True for tests); the identical-math XLA formulation otherwise.
-    min_d2 is clamped below at 0 by neither path (callers clamp if needed).
+    Uses the Pallas TPU kernel where min_dist_route says so; the
+    identical-math XLA formulation otherwise.  min_d2 is clamped below at 0
+    by neither path (callers clamp if needed).
     """
     if x_norm is None:
         x_norm = (X.astype(jnp.float32) ** 2).sum(axis=1)
     if c_norm is None:
         c_norm = (centers.astype(jnp.float32) ** 2).sum(axis=1)
-    use_pallas = interpret or pallas_enabled()
-    if use_pallas:
-        n, d = X.shape
-        k = centers.shape[0]
-        d_pad = _round_up(d, 128)
-        tiles = _pick_tiles(d_pad, X.dtype.itemsize)
-        # Routing (v5e A/B, HIGHEST precision, 2026-07-30): the fused kernel
-        # wins only when the (n, k) distance matrix dominates HBM traffic —
-        # low d, large k (d=32/k=16384: 27.4 ms vs XLA 34.5; d=64/k=8192:
-        # 15.3 vs 17.8).  When FLOPs dominate (d=3000/k=1000: 13.5 vs 10.0)
-        # or the batch pads up to one row tile (single-row predict), XLA's
-        # own fusion is the better program.  interpret mode bypasses the
-        # heuristic so tests always hit the kernel.
-        worthwhile = d_pad <= 256 and k >= 1024 and n >= tiles[0] if tiles else False
-        if tiles is not None and (interpret or worthwhile):
-            return _min_dist_argmin_pallas(
-                X, centers, x_norm, c_norm, interpret=interpret
-            )
+    route = min_dist_route(
+        X.shape[0], X.shape[1], centers.shape[0], X.dtype.itemsize, interpret
+    )
+    if route == "pallas":
+        return _min_dist_argmin_pallas(
+            X, centers, x_norm, c_norm, interpret=interpret
+        )
     return _min_dist_argmin_xla(X, centers, x_norm, c_norm)

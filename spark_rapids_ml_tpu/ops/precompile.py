@@ -3,13 +3,11 @@
 #
 # A cold estimator fit dispatches dozens of jit geometries (the MXU forest
 # builder's level/class/chunk variants are the extreme case: ~480 XLA
-# compilations, 300-500 s serialized at the 200k x 500 depth-10 shape the
-# round-2 verdict measured).  XLA compilation for this backend is serviced
-# outside the Python interpreter (measured: three concurrent 7 s compiles
-# finish in 7.9 s wall from a single-core host), so a fit that knows its
-# kernel geometries up front can turn the SUM of compile times into a MAX by
-# lowering+compiling every geometry on a thread pool and dispatching through
-# the resulting AOT executables.
+# compilations at a 200k x 500 depth-10 shape).  XLA compilation runs
+# outside the Python interpreter lock, so a fit that knows its kernel
+# geometries up front can overlap the compiles by lowering+compiling every
+# geometry on a thread pool and dispatching through the resulting AOT
+# executables.
 #
 # The reference hides the analogous cost inside cuML's precompiled fatbins
 # (its kernels ship compiled; only tiny JIT specializations happen at run
@@ -21,11 +19,10 @@
 # (shape-bucket, dtype, mesh fingerprint, donation, statics) — first call
 # compiles (counted in profiling as precompile.compile / aot_miss), repeats
 # run the cached executable (aot_hit) with zero new compilations — and
-# `initialize_persistent_cache` hooks jax's on-disk compilation cache
-# (jax.experimental.compilation_cache) so a FRESH PROCESS at a seen geometry
-# pays a disk read instead of an XLA compile.  Users: the kNN query engine
-# (ops/knn.py), the MXU forest builder (ops/forest_mxu.py), the distributed
-# fit session (parallel/runner.py), and the benchmarks.
+# `ensure_compile_cache` owns the rule for jax's on-disk compilation cache.
+# Users: the kNN query engine (ops/knn.py), the MXU forest builder
+# (ops/forest_mxu.py), the serving entries (serving/entry.py), and the
+# benchmarks.
 #
 
 from __future__ import annotations
@@ -80,51 +77,46 @@ def mesh_fingerprint(mesh: Any) -> Tuple:
 
 
 # -- persistent on-disk compilation cache ------------------------------------
-# Opt-in via SRML_COMPILE_CACHE=<dir> (or an explicit path argument): hooks
-# jax's own on-disk executable cache so a COLD PROCESS hitting a previously
-# seen kernel geometry deserializes it instead of recompiling — the lever
-# for the fleet-wide cold_sec cost (knn 4.3 s, rf_clf 50.4 s cold), which
-# in-process caches cannot touch.  Best-effort: never clobbers a cache dir
-# the embedding application already configured, and failure to initialize
-# only costs cold-compile time, never correctness.
+# ONE rule, owned here.  Where JAX_COMPILATION_CACHE_DIR is set, jax keeps
+# its cache there (it reads the variable itself) and no code sets another
+# directory.  Where it is not, the cache lives in ONE fixed directory inside
+# the checkout, <repo>/.jax_cache: the path is part of jax's cache key, so a
+# directory named from a pid, a time or mktemp would never hit.  Every fit
+# and every server start calls ensure_compile_cache(), so a FRESH PROCESS at
+# a seen kernel geometry pays a disk read instead of an XLA compile.
 
-PERSIST_CACHE_ENV = "SRML_COMPILE_CACHE"
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+# cache every executable: a compile-time floor would let timing jitter
+# decide whether a kernel is stored, so a second run of the same program
+# could still add files
+_CACHE_MIN_COMPILE_SECS = 0.0
 _persist_lock = threading.Lock()
 _persist_dir: Optional[str] = None
 
 
-def initialize_persistent_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point jax's compilation cache at `path` (default: $SRML_COMPILE_CACHE).
-    Idempotent; returns the active cache dir, or None when disabled.  An
-    already-configured jax_compilation_cache_dir (e.g. the test suite's) is
-    respected and returned as-is."""
+def ensure_compile_cache() -> str:
+    """Apply the compile-cache rule above (idempotent) and return the
+    active cache directory."""
     global _persist_dir
-    path = path or os.environ.get(PERSIST_CACHE_ENV)
     with _persist_lock:
-        if _persist_dir is not None:
-            return _persist_dir
-        existing = getattr(jax.config, "jax_compilation_cache_dir", None)
-        if existing:
-            _persist_dir = existing
-            return existing
-        if not path:
-            return None
-        try:
-            os.makedirs(path, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", path)
-            # cache small kernels too: the kNN block kernels individually
-            # compile in well under the 1 s default floor, but a cold
-            # search pays a handful of them serially
+        if _persist_dir is None:
+            path = os.environ.get(CACHE_DIR_ENV)
+            if not path:
+                path = CHECKOUT_CACHE_DIR
+                os.makedirs(path, exist_ok=True)
+                jax.config.update("jax_compilation_cache_dir", path)
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs",
-                float(os.environ.get("SRML_COMPILE_CACHE_MIN_SECS", "0.0")),
+                _CACHE_MIN_COMPILE_SECS,
             )
-        except Exception as exc:  # pragma: no cover - config drift
-            logger.warning("persistent compilation cache disabled: %s", exc)
-            return None
-        _persist_dir = path
-        profiling.incr_counter("precompile.disk_cache_enabled")
-        return path
+            _persist_dir = path
+        return _persist_dir
 
 
 class _Job:
@@ -177,8 +169,6 @@ class Precompiler:
         import contextlib
         import os
 
-        from ..compat import enable_x64
-
         trace = os.environ.get("SRML_PRECOMPILE_LOG") == "1"
         while True:
             job, fn, avals, static_kwargs = self._q.get()
@@ -195,7 +185,7 @@ class Precompiler:
                     for a in jax.tree_util.tree_leaves(avals)
                     if hasattr(a, "dtype")
                 )
-                ctx = enable_x64(True) if wide else contextlib.nullcontext()
+                ctx = jax.enable_x64(True) if wide else contextlib.nullcontext()
                 # the compile span carries the kernel name (first key
                 # element) so pool compile time is attributable per kernel
                 # in traces without string-ifying the full geometry key
@@ -238,6 +228,13 @@ class Precompiler:
                     break
                 del self._jobs[stale]
         self._q.put((job, fn, avals, static_kwargs))
+
+    def clear(self) -> None:
+        """Drop every finished executable (an in-flight job stays: its
+        waiter holds the key).  Later same-key calls compile again."""
+        with self._lock:
+            for key in [k for k, j in self._jobs.items() if j.done.is_set()]:
+                del self._jobs[key]
 
     def wait(self, keys) -> None:
         """Block until every submitted key in `keys` has finished compiling

@@ -201,7 +201,7 @@ def ell_sufficient_stats(
         )
         return LinregStats(wsum, xwsum / wsum, ywsum / wsum, G, c, y2)
 
-    from ..compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_cols = ell.n_cols

@@ -42,10 +42,10 @@ from . import faults
 ROUND_TIMEOUT_ENV = "SRML_CP_ROUND_TIMEOUT_S"
 RETRIES_ENV = "SRML_CP_RETRIES"
 BACKOFF_ENV = "SRML_CP_BACKOFF_S"
-# jax.distributed coordination-service heartbeat cadence (seconds x count):
-# bounds how long any jax-layer teardown can dangle on a dead peer
-JAX_HEARTBEAT_ENV = "SRML_JAX_HEARTBEAT_S"
-JAX_MAX_MISSING_ENV = "SRML_JAX_MAX_MISSING_HEARTBEATS"
+# jax.distributed coordination-service heartbeat timeout (seconds): bounds
+# how long any jax-layer teardown can dangle on a dead peer
+JAX_HEARTBEAT_TIMEOUT_ENV = "SRML_JAX_HEARTBEAT_TIMEOUT_S"
+_DEFAULT_JAX_HEARTBEAT_TIMEOUT_S = 10
 _DEFAULT_ROUND_TIMEOUT_S = 300.0
 _DEFAULT_RETRIES = 3
 _DEFAULT_BACKOFF_S = 0.05
@@ -244,15 +244,6 @@ class TpuContext:
     def __enter__(self) -> "TpuContext":
         faults.site("context.init", rank=self._rank)
         if self._nranks > 1:
-            # CPU pods (virtual-device CI, mc tests, CPU-only clusters)
-            # need gloo collectives armed BEFORE the backend initializes,
-            # or every cross-process GSPMD computation fails to compile.
-            # Unconditional: probing the backend kind here would itself
-            # initialize it, and the flag is inert off-CPU
-            # (compat.ensure_cpu_collectives docstring has the story)
-            from ..compat import ensure_cpu_collectives
-
-            ensure_cpu_collectives()
             # rank 0 advertises coordinator host:port; everyone gathers it.
             # A port-allocating control plane (TcpControlPlane) hands out a
             # coordinator-reserved port — no two sessions through the same
@@ -280,23 +271,24 @@ class TpuContext:
                 "rank %d/%d connecting to coordinator %s",
                 self._rank, self._nranks, coordinator,
             )
-            # Coordination-service heartbeats tightened from the 10 s x 10
-            # default: 100 s was how long a survivor's teardown dangled on
-            # a dead peer before the client's missed-heartbeat handler
+            # Coordination-service heartbeat timeout tightened from the
+            # stock 100 s: that was how long a survivor's teardown dangled
+            # on a dead peer before the client's missed-heartbeat handler
             # fired (srml-wire chaos drive).  The control plane still owns
-            # FAST detection (ms-scale markers/leases); these bound the
-            # jax-layer tail so no teardown outlives ~interval x missing.
-            from ..compat import distributed_initialize
-
-            distributed_initialize(
+            # FAST detection (ms-scale markers/leases); this bounds the
+            # jax-layer tail.
+            jax.distributed.initialize(
                 coordinator_address=coordinator,
                 num_processes=self._nranks,
                 process_id=self._rank,
-                heartbeat_interval_s=max(
-                    1, int(_env_float(JAX_HEARTBEAT_ENV, 1.0))
-                ),
-                max_missing_heartbeats=max(
-                    2, int(_env_float(JAX_MAX_MISSING_ENV, 10.0))
+                heartbeat_timeout_seconds=max(
+                    2,
+                    int(
+                        _env_float(
+                            JAX_HEARTBEAT_TIMEOUT_ENV,
+                            _DEFAULT_JAX_HEARTBEAT_TIMEOUT_S,
+                        )
+                    ),
                 ),
             )
             self._initialized_distributed = True
@@ -337,10 +329,10 @@ class TpuContext:
                 # jax.distributed.shutdown() runs a COLLECTIVE shutdown
                 # barrier.  On any abort path a peer is dead or about to
                 # be (it is unwinding this same path), so the barrier can
-                # never complete — and the 0.4.37 client LOG(FATAL)s the
-                # whole process after the ~100 s coordination heartbeat
-                # timeout, killing the typed RemoteRankError before it
-                # reaches the user (found by the srml-wire chaos drive).
+                # never complete — and the client LOG(FATAL)s the whole
+                # process after the coordination heartbeat timeout,
+                # killing the typed RemoteRankError before it reaches the
+                # user (found by the srml-wire chaos drive).
                 # Abort therefore means detach WITHOUT the barrier: skip
                 # the call, let process teardown reclaim the sockets —
                 # exactly NCCL abort() vs destroy().

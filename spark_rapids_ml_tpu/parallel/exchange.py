@@ -460,11 +460,9 @@ def _remote_dma_enabled() -> bool:
 
     import jax
 
+    from ..ops.pallas_tpu import pallas_enabled
+
     if os.environ.get(_REMOTE_DMA_ENV, "1") == "0":
-        return False
-    try:
-        from ..ops.pallas_tpu import pallas_enabled
-    except ImportError:  # pragma: no cover - circular-import guard
         return False
     return jax.default_backend() == "tpu" and pallas_enabled()
 
@@ -484,13 +482,24 @@ def _ring_shift_remote_dma(x, axis_name: str, shift: int, n_dev: int):
     def kernel(x_ref, o_ref, send_sem, recv_sem):
         my = jax.lax.axis_index(axis_name)
         dst = jax.lax.rem(my + shift + n_dev, n_dev)
+        src = jax.lax.rem(my - shift + n_dev, n_dev)
+        # handshake with both ring neighbors before any remote write: a
+        # DMA into a chip that has not ENTERED this kernel yet could land
+        # in memory its previous op still owns
+        barrier = pltpu.get_barrier_semaphore()
+        for peer in (dst, src):
+            pltpu.semaphore_signal(
+                barrier, inc=1, device_id=(peer,),
+                device_id_type=pltpu.DeviceIdType.MESH,
+            )
+        pltpu.semaphore_wait(barrier, 2)
         copy = pltpu.make_async_remote_copy(
             src_ref=x_ref,
             dst_ref=o_ref,
             send_sem=send_sem,
             recv_sem=recv_sem,
             device_id=(dst,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=pltpu.DeviceIdType.MESH,
         )
         copy.start()
         # the wait covers BOTH directions: send_sem fires when the local
@@ -501,14 +510,15 @@ def _ring_shift_remote_dma(x, axis_name: str, shift: int, n_dev: int):
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
     )
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(collective_id=0),
     )(x)
 
 

@@ -32,10 +32,16 @@ def default_num_workers() -> int:
 
 
 def get_mesh(num_workers: Optional[int] = None) -> Mesh:
-    """1-D data-parallel mesh over the first `num_workers` devices."""
+    """1-D data-parallel mesh over the first `num_workers` devices (all of
+    them when unset).  Asking for more workers than there are devices is an
+    error, never a silently narrower mesh."""
     devices = jax.devices()
     n = num_workers or len(devices)
-    n = min(n, len(devices))
+    if n > len(devices):
+        raise ValueError(
+            f"num_workers={n} exceeds the {len(devices)} "
+            f"{devices[0].platform} device(s) jax sees"
+        )
     return Mesh(np.array(devices[:n]), (DATA_AXIS,))
 
 

@@ -719,15 +719,13 @@ def distributed_session(
     rank: int, nranks: int, control_plane: Optional[ControlPlane] = None
 ) -> Iterator[DistributedFitSession]:
     cp = control_plane or LocalControlPlane()
-    # Opt-in on-disk executable cache (SRML_COMPILE_CACHE): every executor
-    # process of a barrier job — and every LATER job at the same kernel
-    # geometries — deserializes executables a sibling already compiled
-    # instead of recompiling them, the fleet-wide cold_sec lever (rf_clf
-    # was 50.4 s cold, almost all XLA compilation).  Best-effort no-op
-    # when the env var is unset or jax already has a cache configured.
-    from ..ops.precompile import initialize_persistent_cache
+    # every executor process of a barrier job — and every LATER job at the
+    # same kernel geometries — deserializes executables a sibling already
+    # compiled instead of recompiling them (the driver-local fit path makes
+    # the same call in core._call_tpu_fit_func)
+    from ..ops.precompile import ensure_compile_cache
 
-    initialize_persistent_cache()
+    ensure_compile_cache()
     try:
         with TpuContext(rank, nranks, cp):
             yield DistributedFitSession(rank, nranks, cp)

@@ -220,10 +220,13 @@ class ModelServer:
         inflight_depth: Optional[int] = None,
         warm: bool = True,
     ):
+        from ..ops.precompile import ensure_compile_cache
+        from ..utils import env_float
+
+        ensure_compile_cache()
         self.name = str(name)
         self.model = model
         self.ns = f"serving.{self.name}"
-        from ..utils import env_float
 
         self.inflight_depth = max(
             1,
@@ -379,11 +382,11 @@ class ModelServer:
         import contextlib
 
         if self._wide:
-            from ..compat import enable_x64
+            import jax
 
             # the worker thread is outside any fit's x64 scope; a float64
             # model's kernels must not silently canonicalize to f32 here
-            return enable_x64(True)
+            return jax.enable_x64(True)
         return contextlib.nullcontext()
 
     # -- client API ---------------------------------------------------------

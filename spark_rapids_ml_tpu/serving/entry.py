@@ -92,34 +92,59 @@ def kernel_entry(
     n_cols: int,
     out_cols: List[str],
     info: Dict[str, Any] = None,
+    mesh: Any = None,
 ) -> ServingEntry:
     """ServingEntry for the common single-kernel models (kmeans/pca/linreg/
     logreg/forest): `fn` is a jitted kernel (X, *consts, **statics) -> device
     outputs, dispatched through the process-wide AOT executable cache under
     `name`; `postprocess` maps the HOST-fetched outputs to output columns
-    (still at padded length — the engine slices)."""
+    (still at padded length — the engine slices).
+
+    `mesh` is the replica's device slice (serving/slicepool lease): the
+    constants and every batch are placed ON it — one program per device of
+    the slice, no collectives — so each replica of a router computes on its
+    own chip(s), and the slice rides the cache key so replicas never share
+    an executable compiled for another device.  None keeps jax's default
+    device (a standalone ModelServer)."""
     import jax
 
-    from ..ops.precompile import (
-        aval,
-        cached_kernel,
-        global_precompiler,
-        kernel_cache_key,
-    )
+    from ..ops.precompile import global_precompiler, kernel_cache_key
 
     np_dtype = np.dtype(dtype)
+    consts = tuple(consts)
+    placement = None
+    if mesh is not None:
+        from ..parallel.mesh import replicated_sharding
+
+        # a one-device slice is that device (plain single-device programs);
+        # a wider slice replicates
+        placement = (
+            jax.sharding.SingleDeviceSharding(mesh.devices.flat[0])
+            if mesh.devices.size == 1
+            else replicated_sharding(mesh)
+        )
+        consts = tuple(jax.device_put(c, placement) for c in consts)
 
     def call(batch: np.ndarray) -> Dict[str, np.ndarray]:
-        Xd = jax.device_put(np.ascontiguousarray(batch, dtype=np_dtype))
-        out = cached_kernel(name, fn, Xd, *consts, **statics)
+        args = (
+            jax.device_put(
+                np.ascontiguousarray(batch, dtype=np_dtype), placement
+            ),
+        ) + consts
+        key = kernel_cache_key(name, args, mesh, statics)
+        out = global_precompiler().cached_call(key, fn, *args, **statics)
         return postprocess(jax.device_get(out))
 
     def warm(buckets: Sequence[int]) -> list:
         pc = global_precompiler()
         keys = []
         for b in buckets:
-            args = (aval((int(b), n_cols), np_dtype),) + tuple(consts)
-            key = kernel_cache_key(name, args, None, statics)
+            args = (
+                jax.ShapeDtypeStruct(
+                    (int(b), n_cols), np_dtype, sharding=placement
+                ),
+            ) + consts
+            key = kernel_cache_key(name, args, mesh, statics)
             pc.submit(key, fn, *args, **statics)
             keys.append(key)
         return keys

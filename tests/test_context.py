@@ -64,23 +64,20 @@ class TestTpuContext:
             assert not ctx._initialized_distributed  # no jax.distributed in-process
 
     def test_multi_rank_handshake(self, monkeypatch):
-        calls = []
+        calls, heartbeats = [], []
 
-        def fake_initialize(coordinator_address, num_processes, process_id):
+        def fake_initialize(
+            coordinator_address, num_processes, process_id,
+            heartbeat_timeout_seconds,
+        ):
             calls.append((coordinator_address, num_processes, process_id))
+            heartbeats.append(heartbeat_timeout_seconds)
 
         def fake_shutdown():
             calls.append("shutdown")
 
         monkeypatch.setattr(jax.distributed, "initialize", fake_initialize)
         monkeypatch.setattr(jax.distributed, "shutdown", fake_shutdown)
-        # the real __enter__ would arm gloo collectives — with the FAKE
-        # initialize there is never a distributed client, and a gloo flag
-        # armed clientless breaks every later backend init in this process
-        # (the standalone-run landmine memory/jax-0437 documents)
-        from spark_rapids_ml_tpu import compat
-
-        monkeypatch.setattr(compat, "ensure_cpu_collectives", lambda: False)
 
         # rank 0 first (it mints the coordinator address, like the NCCL uid
         # in cuml_context.py:75-103), then rank 1 sees it via the gather
@@ -94,12 +91,29 @@ class TestTpuContext:
         assert calls[0] == (addr0, 2, 0)
         assert calls[1] == "shutdown"
         assert calls[2] == (addr0, 2, 1)
+        # the tightened coordination heartbeat reaches jax on every rank
+        assert heartbeats == [10, 10]
+
+    def test_heartbeat_timeout_env_is_forwarded(self, monkeypatch):
+        captured = {}
+        monkeypatch.setattr(
+            jax.distributed, "initialize", lambda **kw: captured.update(kw)
+        )
+        monkeypatch.setenv("SRML_JAX_HEARTBEAT_TIMEOUT_S", "37")
+        ctx = TpuContext(
+            rank=0, nranks=2, control_plane=FakeBarrierControlPlane(nranks=2)
+        )
+        ctx.__enter__()
+        ctx._initialized_distributed = False  # initialize was a stub
+        ctx.__exit__(None, None, None)
+        assert captured["heartbeat_timeout_seconds"] == 37
+        assert set(captured) == {
+            "coordinator_address", "num_processes", "process_id",
+            "heartbeat_timeout_seconds",
+        }
 
     def test_rank0_address_missing_raises(self, monkeypatch):
         monkeypatch.setattr(jax.distributed, "initialize", lambda **kw: None)
-        from spark_rapids_ml_tpu import compat
-
-        monkeypatch.setattr(compat, "ensure_cpu_collectives", lambda: False)
 
         class EmptyCp:
             def allGather(self, message):
@@ -125,7 +139,7 @@ class TestMeshCollectives:
         assert DATA_AXIS in mesh.shape
 
     def test_psum_over_mesh_matches_numpy(self):
-        from spark_rapids_ml_tpu.compat import shard_map
+        from jax import shard_map
 
         mesh = get_mesh()
         X_host = np.arange(64, dtype=np.float32).reshape(16, 4)
@@ -141,7 +155,7 @@ class TestMeshCollectives:
         np.testing.assert_allclose(np.asarray(total), X_host.sum(axis=0))
 
     def test_all_gather_roundtrip(self):
-        from spark_rapids_ml_tpu.compat import shard_map
+        from jax import shard_map
 
         mesh = get_mesh()
         n_dev = mesh.devices.size

@@ -248,7 +248,7 @@ def test_device_sections_report_static_bytes_at_trace_time():
     import jax.numpy as jnp
 
     from spark_rapids_ml_tpu import profiling
-    from spark_rapids_ml_tpu.compat import shard_map
+    from jax import shard_map
     from spark_rapids_ml_tpu.parallel.exchange import (
         allgather_rows,
         psum_merge_parts,

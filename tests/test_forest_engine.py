@@ -25,6 +25,10 @@ from spark_rapids_ml_tpu.ops.forest import (
 )
 from spark_rapids_ml_tpu.parallel.mesh import get_mesh
 
+# On a real TPU (SRML_TPU_TESTS=1) run the compiled Mosaic kernels; on the
+# CPU mesh interpret — the tests/test_pallas.py switch.
+KERNEL_INTERPRET = jax.devices()[0].platform != "tpu"
+
 
 def _cls_df(n=512, d=10, k=3, seed=1):
     from sklearn.datasets import make_classification
@@ -189,7 +193,7 @@ def test_sharded_histogram_rule_matches_oracle():
         node_histograms_sharded(
             jnp.asarray(sub), jnp.asarray(node_rel), jnp.asarray(stats),
             mesh=mesh, t_pack=T, nodes=nodes, s_dim=S, n_bins=B,
-            interpret=True,
+            interpret=KERNEL_INTERPRET,
         )
     )
     Href = node_histograms_reference(sub, node_rel, stats, T, nodes, S, B)

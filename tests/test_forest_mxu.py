@@ -1,7 +1,7 @@
 #
 # MXU forest-histogram path tests (ops/forest_hist.py + ops/forest_mxu.py).
-# The pallas kernel runs in interpret mode on the CPU test mesh; on TPU the
-# same code compiles to fused one-hot MXU matmuls (validated by bench runs).
+# The pallas kernel runs in interpret mode on the CPU test mesh; with
+# SRML_TPU_TESTS=1 on a chip the same tests compile it through Mosaic.
 #
 import numpy as np
 import pytest
@@ -26,6 +26,10 @@ from spark_rapids_ml_tpu.ops.forest_mxu import (
     grow_forest_mxu,
     mxu_depth_supported,
 )
+
+# On a real TPU (SRML_TPU_TESTS=1) run the compiled Mosaic kernels; on the
+# CPU mesh interpret — the tests/test_pallas.py switch.
+KERNEL_INTERPRET = jax.devices()[0].platform != "tpu"
 
 
 def test_gather_rows_matmul_exact():
@@ -53,7 +57,7 @@ def test_node_histograms_matches_oracle():
     H = np.asarray(
         node_histograms(
             jnp.asarray(sub), jnp.asarray(node_rel), jnp.asarray(stats),
-            t_pack=T, nodes=nodes, s_dim=S, n_bins=B, interpret=True,
+            t_pack=T, nodes=nodes, s_dim=S, n_bins=B, interpret=KERNEL_INTERPRET,
         )
     )
     Href = node_histograms_reference(sub, node_rel, stats, T, nodes, S, B)
@@ -114,7 +118,7 @@ def test_mxu_builder_matches_scatter_builder(kind, tiles):
         None if stats3 is None else jnp.asarray(stats3),
         edges, max_depth=depth, n_bins=B, kind=kind, max_features=D,
         min_samples_leaf=1.0, min_impurity_decrease=0.0, seed=7,
-        interpret=True,
+        interpret=KERNEL_INTERPRET,
     )
     stats_t = jnp.broadcast_to(st_old[None], (T, N, st_old.shape[1]))
     f2, t2, v2, ns2, imp2 = grow_forest(
@@ -171,7 +175,7 @@ def test_mxu_builder_feature_subsets_and_bootstrap_quality():
         jnp.asarray(bins_fm), jnp.asarray(base), jnp.asarray(w_trees),
         jnp.asarray(stats3), edges, max_depth=depth, n_bins=B,
         kind="regression", max_features=6, min_samples_leaf=1.0,
-        min_impurity_decrease=0.0, seed=11, interpret=True,
+        min_impurity_decrease=0.0, seed=11, interpret=KERNEL_INTERPRET,
     )
     pred = np.asarray(
         forest_predict_kernel(
@@ -206,7 +210,7 @@ def test_mxu_deep_phase_smoke_fast():
         jnp.asarray(bins_fm), jnp.asarray(base), jnp.asarray(w_trees), None,
         edges, max_depth=depth, n_bins=B, kind="gini", max_features=D,
         min_samples_leaf=1.0, min_impurity_decrease=0.0, seed=3,
-        y_vals=jnp.asarray(y), interpret=True,
+        y_vals=jnp.asarray(y), interpret=KERNEL_INTERPRET,
     )
     stats_t = jnp.broadcast_to(jnp.asarray(base.T)[None], (T, N, C))
     f2, t2, v2, _, _ = grow_forest(
@@ -263,7 +267,7 @@ def test_mxu_deep_phase_smoke_fast_regression():
         jnp.asarray(stats3), edges, max_depth=depth, n_bins=B,
         kind="regression", max_features=D, min_samples_leaf=1.0,
         min_impurity_decrease=0.0, seed=3, y_vals=jnp.asarray(y),
-        interpret=True,
+        interpret=KERNEL_INTERPRET,
     )
     st_old = jnp.stack(
         [jnp.ones(N), jnp.asarray(y), jnp.asarray(y) ** 2], axis=1
@@ -316,7 +320,7 @@ def test_mxu_deep_phase_matches_scatter_builder():
         jnp.asarray(bins_fm), jnp.asarray(base), jnp.asarray(w_trees), None,
         edges, max_depth=depth, n_bins=B, kind="gini", max_features=D,
         min_samples_leaf=1.0, min_impurity_decrease=0.0, seed=7,
-        y_vals=jnp.asarray(y), interpret=True,
+        y_vals=jnp.asarray(y), interpret=KERNEL_INTERPRET,
     )
     st_old = jnp.asarray(base.T)
     stats_t = jnp.broadcast_to(st_old[None], (T, N, 2))
@@ -372,7 +376,7 @@ def test_mxu_deep_phase_skewed_trees():
         jnp.asarray(bins_fm), jnp.asarray(base), jnp.asarray(w_trees), None,
         edges, max_depth=depth, n_bins=B, kind="gini", max_features=D,
         min_samples_leaf=1.0, min_impurity_decrease=0.0, seed=7,
-        y_vals=jnp.asarray(y), interpret=True,
+        y_vals=jnp.asarray(y), interpret=KERNEL_INTERPRET,
     )
     p1 = np.asarray(
         forest_predict_kernel(
@@ -407,7 +411,7 @@ def test_mxu_deep_phase_three_classes():
         jnp.asarray(bins_fm), jnp.asarray(base), jnp.asarray(w_trees), None,
         edges, max_depth=depth, n_bins=B, kind="gini", max_features=D,
         min_samples_leaf=1.0, min_impurity_decrease=0.0, seed=7,
-        y_vals=jnp.asarray(y), interpret=True,
+        y_vals=jnp.asarray(y), interpret=KERNEL_INTERPRET,
     )
     p = np.asarray(
         forest_predict_kernel(
@@ -444,7 +448,7 @@ def test_mxu_deep_phase_mostly_dead_rows():
         jnp.asarray(bins_fm), jnp.asarray(base), jnp.asarray(w_trees), None,
         edges, max_depth=depth, n_bins=B, kind="gini", max_features=D,
         min_samples_leaf=1.0, min_impurity_decrease=0.0, seed=3,
-        y_vals=jnp.asarray(y), interpret=True,
+        y_vals=jnp.asarray(y), interpret=KERNEL_INTERPRET,
     )
     p = np.asarray(
         forest_predict_kernel(

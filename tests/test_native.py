@@ -46,6 +46,18 @@ def test_version_and_threads():
     assert native.lib().srml_hardware_threads() >= 1
 
 
+def test_unusable_library_file_is_an_error(monkeypatch, tmp_path):
+    """A .so that exists but cannot be loaded (or is stale) raises — it is
+    never skipped in favour of the numpy routes."""
+    bad = tmp_path / "libsrml_native.so"
+    bad.write_bytes(b"not an ELF object")
+    monkeypatch.setenv("SRML_NATIVE_LIB", str(bad))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_tried", False)
+    with pytest.raises(RuntimeError, match="exists but is unusable"):
+        native.lib()
+
+
 def test_allocator_reuses_buffers():
     l = native.lib()
     p1 = l.srml_buf_alloc(1 << 20)

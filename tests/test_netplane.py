@@ -275,11 +275,6 @@ def test_tpu_context_uses_coordinator_allocated_port(monkeypatch):
         jax.distributed, "initialize",
         lambda **kw: captured.update(kw),
     )
-    # no real distributed client behind the stub: arming gloo here would
-    # break every later backend init in this process
-    from spark_rapids_ml_tpu import compat
-
-    monkeypatch.setattr(compat, "ensure_cpu_collectives", lambda: False)
     from spark_rapids_ml_tpu.parallel.context import TpuContext
 
     cp = _PortPlane()

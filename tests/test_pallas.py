@@ -1,9 +1,13 @@
 # Pallas TPU kernel correctness (interpreter mode on the CPU test mesh).
 # The same kernel compiles with Mosaic on real TPU; the hardware-exactness
 # A/B record (v5e, argmin mismatch 0 vs the XLA path) is quoted in the
-# ops/pallas_tpu.py module header.  Set SRML_TPU_TESTS=1 to re-run this file
-# against real TPU devices, where the kernel tests run the compiled Mosaic
-# path (interpret=False) instead of the interpreter.
+# ops/pallas_tpu.py module header.  SRML_TPU_TESTS=1 re-runs the kernel
+# tests compiled by Mosaic (interpret=False) on a real chip — ONE command,
+# one process:
+#   SRML_TPU_TESTS=1 python -m pytest tests/test_pallas.py \
+#     tests/test_forest_mxu.py tests/test_pq_engine.py \
+#     tests/test_knn_audit.py --runslow -q
+# (never with the tests that spawn jax children: tests/conftest.py header).
 import numpy as np
 import pytest
 

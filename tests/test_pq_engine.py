@@ -35,6 +35,10 @@ from spark_rapids_ml_tpu.ops.pallas_pq import (
 )
 from spark_rapids_ml_tpu.parallel.mesh import get_mesh
 
+# On a real TPU (SRML_TPU_TESTS=1) run the compiled Mosaic kernels; on the
+# CPU mesh interpret — the tests/test_pallas.py switch.
+KERNEL_INTERPRET = jax.devices()[0].platform != "tpu"
+
 
 def _clustered(n=2500, d=16, n_blobs=24, seed=0):
     rng = np.random.default_rng(seed)
@@ -86,7 +90,7 @@ def test_lut_kernel_matches_numpy_adc_oracle():
                 (B, R, m_sub, ksub),
                 want,
                 _lut_accumulate_pallas(
-                    jnp.asarray(T), jnp.asarray(C), interpret=True
+                    jnp.asarray(T), jnp.asarray(C), interpret=KERNEL_INTERPRET
                 ),
                 # the routed entry (XLA on this backend) computes the same
                 # sum to float tolerance — the route is per-backend, never
@@ -385,7 +389,7 @@ def test_fastscan_kernel_matches_numpy_adc_oracle():
             )
         wants.append((want, C))
         outs.append((
-            _fastscan_pallas(jnp.asarray(T), jnp.asarray(packed), interpret=True),
+            _fastscan_pallas(jnp.asarray(T), jnp.asarray(packed), interpret=KERNEL_INTERPRET),
             fastscan_lut_accumulate(jnp.asarray(T), jnp.asarray(packed)),
             unpack_codes4(jnp.asarray(packed)),
         ))

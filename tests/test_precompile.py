@@ -7,10 +7,10 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_ml_tpu import profiling
+from spark_rapids_ml_tpu.ops import precompile
 from spark_rapids_ml_tpu.ops.precompile import (
     Precompiler,
     global_precompiler,
-    initialize_persistent_cache,
     mesh_fingerprint,
     shape_bucket,
 )
@@ -126,13 +126,38 @@ def test_cached_call_falls_back_on_plain_callable_and_compile_failure():
         pc.cached_call(("boom",), boom, x)
 
 
-def test_initialize_persistent_cache_respects_existing_config():
-    """The test suite's conftest already configures jax's compilation cache
-    — initialize_persistent_cache must adopt it (not clobber it) and be
-    idempotent."""
-    existing = jax.config.jax_compilation_cache_dir
-    got = initialize_persistent_cache()
-    if existing:
-        assert got == existing
-        assert jax.config.jax_compilation_cache_dir == existing
-    assert initialize_persistent_cache() == got  # idempotent
+@pytest.fixture
+def fresh_cache_rule(monkeypatch):
+    """Run ensure_compile_cache as a fresh process would (rule not yet
+    applied, no cache directory configured), restoring the suite's
+    configuration afterwards."""
+    saved = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(precompile, "_persist_dir", None)
+    jax.config.update("jax_compilation_cache_dir", None)
+    yield
+    jax.config.update("jax_compilation_cache_dir", saved)
+
+
+def test_compile_cache_env_set_library_sets_no_directory(
+    fresh_cache_rule, monkeypatch, tmp_path
+):
+    """JAX_COMPILATION_CACHE_DIR set: jax owns the directory (it reads the
+    variable itself); the library names it and sets no other."""
+    monkeypatch.setenv(precompile.CACHE_DIR_ENV, str(tmp_path))
+    assert precompile.ensure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir is None  # untouched by code
+    assert precompile.ensure_compile_cache() == str(tmp_path)  # idempotent
+
+
+def test_compile_cache_env_unset_uses_checkout_directory(
+    fresh_cache_rule, monkeypatch
+):
+    """No environment setting: ONE fixed directory inside the checkout."""
+    import os
+
+    monkeypatch.delenv(precompile.CACHE_DIR_ENV, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert precompile.ensure_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want)

@@ -9,9 +9,9 @@ import pytest
 
 scipy_sparse = pytest.importorskip("scipy.sparse")
 
+import jax
 import jax.numpy as jnp
 
-from spark_rapids_ml_tpu.compat import enable_x64
 from spark_rapids_ml_tpu import (
     KMeans,
     LinearRegression,
@@ -48,7 +48,7 @@ def test_ell_from_csr_roundtrip():
 
 
 def test_ell_matvec_matmat():
-    with enable_x64(True):  # the fit path's f64 scope (core._maybe_x64)
+    with jax.enable_x64(True):  # the fit path's f64 scope (core._maybe_x64)
         X = _random_csr(seed=1)
         ell = ell_device_from_scipy(X, np.float64)
         b = np.random.default_rng(2).normal(size=X.shape[1])
@@ -68,7 +68,7 @@ def test_ell_sufficient_stats_parity(use_mesh):
     from spark_rapids_ml_tpu.ops.glm import linreg_sufficient_stats
     from spark_rapids_ml_tpu.parallel.mesh import get_mesh, shard_rows
 
-    with enable_x64(True):  # the fit path's f64 scope (core._maybe_x64)
+    with jax.enable_x64(True):  # the fit path's f64 scope (core._maybe_x64)
         X = _random_csr(n=256, seed=4)
         rng = np.random.default_rng(5)
         y = rng.normal(size=256)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from spark_rapids_ml_tpu import profiling
-from spark_rapids_ml_tpu.compat import shard_map
+from jax import shard_map
 from spark_rapids_ml_tpu.parallel import topology
 from spark_rapids_ml_tpu.parallel.exchange import (
     device_collective,

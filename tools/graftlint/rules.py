@@ -144,7 +144,6 @@ def _is_mesh_module(mod: str) -> bool:
         m.endswith("parallel.mesh")
         or m == "mesh"
         or m.endswith(".mesh")
-        or m.endswith("compat")
     )
 
 
