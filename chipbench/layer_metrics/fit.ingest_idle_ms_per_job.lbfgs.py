@@ -1,0 +1,4 @@
+"""fit.ingest_idle_ms_per_job under the name logreg-d3000-iter200's cells report it as."""
+from chipbench.harness import load_reader
+
+read = load_reader("fit.ingest_idle_ms_per_job").read

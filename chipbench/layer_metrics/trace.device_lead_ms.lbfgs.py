@@ -1,0 +1,4 @@
+"""trace.device_lead_ms under the name logreg-d3000-iter200's cells report it as."""
+from chipbench.harness import load_reader
+
+read = load_reader("trace.device_lead_ms").read

@@ -24,6 +24,7 @@
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -36,6 +37,7 @@ import pandas as pd
 
 import jax
 
+from . import profiling
 from .dataframe import DataFrame, FEATURE_BLOCK_ATTR, as_dataframe
 from .params import Param, Params, _TpuParams
 from .parallel.mesh import get_mesh, shard_rows, data_sharding
@@ -61,11 +63,47 @@ def _use_executor_path(dataset: Any) -> bool:
 
 def _maybe_x64(dtype: Any):
     """jax x64 scope for float64 fits; a no-op for float32."""
-    import contextlib
-
     if np.dtype(dtype) == np.float64:
         return jax.enable_x64(True)
     return contextlib.nullcontext()
+
+
+def _tree_nbytes(tree: Any) -> int:
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
+
+
+def _device_put_counted(put: Callable[[], Any]) -> Any:
+    """Every host→device copy of ingest goes through here: `put()` runs
+    inside a srml.device_put span that carries the bytes that went up, and
+    the process-wide counter ingest.h2d_bytes adds them up per fit."""
+    with profiling.span("srml.device_put") as sp:
+        out = put()
+        nbytes = _tree_nbytes(out)
+        sp.set(bytes=nbytes)
+    profiling.incr_counter("ingest.h2d_bytes", nbytes)
+    return out
+
+
+def fetch_fit_result(tree: Any) -> Any:
+    """The end of a fit function's device work, as two step spans: wait
+    (srml.fit.wait: block until the solver's outputs are ready, so the
+    device's time is not charged to the copy) and ONE batched fetch of the
+    whole result tree (srml.fit.fetch, bytes= on the span; the process-wide
+    counter fit.d2h_bytes adds them up).  Returns the host tree.
+
+    The copies are queued behind the solver before the wait, where
+    jax.device_get alone would queue them: they start when the solver ends,
+    not one host wake-up later."""
+    with profiling.span("srml.fit.wait"):
+        for leaf in jax.tree_util.tree_leaves(tree):
+            leaf.copy_to_host_async()
+        jax.block_until_ready(tree)
+    with profiling.span("srml.fit.fetch") as sp:
+        host = jax.device_get(tree)
+        nbytes = _tree_nbytes(host)
+        sp.set(bytes=nbytes)
+    profiling.incr_counter("fit.d2h_bytes", nbytes)
+    return host
 
 
 # Reserved key a fit result dict carries its TelemetrySnapshot under —
@@ -336,7 +374,6 @@ class _TpuCaller(_TpuParams):
         if not nonempty:
             raise RuntimeError("Dataset is empty; cannot fit")
         mesh = get_mesh(self.num_workers)
-        from . import profiling
 
         # Device-resident input cache (single slot).  Repeated fits over the
         # same immutable block-backed DataFrame — fitMultiple, repeated
@@ -378,8 +415,9 @@ class _TpuCaller(_TpuParams):
             # ingest.staged counts DATASET uploads: the batched sweep's
             # "one staged dataset per sweep" contract is gated on it
             profiling.incr_counter("ingest.staged")
-            with profiling.phase("srml.device_put"):
-                Xs = ell_device_from_scipy(csr, dtype=dtype, mesh=mesh)
+            Xs = _device_put_counted(
+                lambda: ell_device_from_scipy(csr, dtype=dtype, mesh=mesh)
+            )
             if cacheable:
                 _FIT_INPUT_CACHE["slot"] = (
                     cache_key,
@@ -394,8 +432,7 @@ class _TpuCaller(_TpuParams):
             X = _concat_and_free(list(nonempty), order="C")
             n_rows, n_cols = X.shape
             profiling.incr_counter("ingest.staged")
-            with profiling.phase("srml.device_put"):
-                Xs, _ = shard_rows(X, mesh)
+            Xs = _device_put_counted(lambda: shard_rows(X, mesh)[0])
             if cacheable:
                 _FIT_INPUT_CACHE["slot"] = (
                     cache_key,
@@ -412,12 +449,14 @@ class _TpuCaller(_TpuParams):
         )
         mask = np.zeros(n_pad, dtype=ldtype)
         mask[:n_rows] = w_np
-        ws = jax.device_put(mask, data_sharding(mesh))
+        ws = _device_put_counted(lambda: jax.device_put(mask, data_sharding(mesh)))
         ys = None
         if y_np is not None:
             y_pad = np.zeros(n_pad, dtype=ldtype)
             y_pad[:n_rows] = y_np
-            ys = jax.device_put(y_pad, data_sharding(mesh))
+            ys = _device_put_counted(
+                lambda: jax.device_put(y_pad, data_sharding(mesh))
+            )
         pdesc = PartitionDescriptor.build(partition_rows, n_cols)
         return FitInputs(
             X=Xs,
@@ -472,7 +511,7 @@ class _TpuCaller(_TpuParams):
             )
         mask = np.zeros(n_pad, dtype=ldtype)
         mask[:n_rows] = w_np
-        ws = jax.device_put(mask, data_sharding(mesh))
+        ws = _device_put_counted(lambda: jax.device_put(mask, data_sharding(mesh)))
         ys = None
         if label_col is not None:
             y_np = np.concatenate(
@@ -483,7 +522,9 @@ class _TpuCaller(_TpuParams):
             )
             y_pad = np.zeros(n_pad, dtype=ldtype)
             y_pad[:n_rows] = y_np
-            ys = jax.device_put(y_pad, data_sharding(mesh))
+            ys = _device_put_counted(
+                lambda: jax.device_put(y_pad, data_sharding(mesh))
+            )
         inputs = FitInputs(
             X=Xs,
             weight=ws,
@@ -504,10 +545,14 @@ class _TpuCaller(_TpuParams):
         self,
         dataset: Any,
         paramMaps: Optional[List[Dict[Param, Any]]] = None,
-    ) -> Union[Dict[str, Any], List[Dict[str, Any]]]:
+        finish: Callable[[Any], Any] = lambda result: result,
+    ) -> Any:
         """Dispatch one (or a batch of) fits on the device mesh (reference
         _call_cuml_fit_func core.py:488-640, single data load for all param
-        maps as in _fit_internal core.py:723-752).
+        maps as in _fit_internal core.py:723-752).  Returns `finish` of the
+        model-attribute dict (or the list of them, one a param map):
+        _fit_internal passes the model construction, which so runs inside
+        the driver-local path's last step span.
 
         A live pyspark DataFrame routes through the Spark barrier stage so
         training happens INSIDE the executors over a pod-wide jax.distributed
@@ -531,74 +576,79 @@ class _TpuCaller(_TpuParams):
             # the executors' merged telemetry snapshot rides the result wire
             # (parallel/runner attaches it); the driver-side phase view comes
             # from it — on live Spark the fit never ran on this thread
-            from . import profiling
-
             telem = results[0].get(TELEMETRY_ATTR) if results else None
             self._last_fit_phase_times = (
                 profiling.TelemetrySnapshot.from_dict(telem).phase_seconds()
                 if telem
                 else {}
             )
-            return results if paramMaps is not None else results[0]
-        from . import profiling
-        from .ops.precompile import ensure_compile_cache
-
-        ensure_compile_cache()
-        profiling.reset_phase_times()
-        counters0 = profiling.counters()
-        df = as_dataframe(dataset)
-        self._validate_parameters(df)
-        # float64 fits genuinely run in float64 (reference core.py:363-401
-        # keeps f64 end-to-end): without x64, jax.device_put silently
-        # canonicalizes f64 -> f32.  The x64 scope must cover BOTH ingest
-        # (device_put) and the fit (trace-time dtypes); it recompiles the
-        # kernels for f64, which TPUs execute via (slower) emulation.
-        input_col, input_cols = self._get_input_columns()
+            return finish(results if paramMaps is not None else results[0])
         from . import watch
 
+        # Driver-local path.  Four step spans tile the job on this thread,
+        # from here to the return: srml.prepare, srml.ingest, srml.fit (whose
+        # sub-spans the estimator's fit function opens: init, solve, wait,
+        # fetch, pack) and srml.finish.  A profiler trace so names what the
+        # host was doing in every gap the device idles in.
         # watch.flight_scope: an unhandled exception anywhere in the fit
         # dumps the always-on flight ring (with the innermost failing span)
         # to SRML_TRACE_DIR before propagating — the crash-time counterpart
         # of the trace session, which only exports on success
-        with watch.flight_scope(
-            f"fit-{type(self).__name__}"
-        ), profiling.trace_session(f"fit-{type(self).__name__}"), _maybe_x64(
-            self._use_dtype(df, input_col, input_cols)
-        ):
-            # srml-shield: the runner.fit injection site fires on BOTH fit
-            # paths — here (driver-local) and in parallel/runner.fit (the
-            # barrier task) — so a fault plan written against the site name
-            # covers whichever launcher ran the fit
-            from .parallel import faults
+        tag = f"fit-{type(self).__name__}"
+        with watch.flight_scope(tag), profiling.trace_session(
+            tag
+        ), contextlib.ExitStack() as scopes:
+            with profiling.span("srml.prepare"):
+                from .ops.precompile import ensure_compile_cache
+                from .parallel import faults
+                from .sanitize import sanitize_scope
 
-            faults.site("runner.fit", rank=0)
-            with profiling.phase("srml.ingest"):
+                ensure_compile_cache()
+                profiling.reset_phase_times()
+                counters0 = profiling.counters()
+                df = as_dataframe(dataset)
+                self._validate_parameters(df)
+                input_col, input_cols = self._get_input_columns()
+                extra_params = None
+                if paramMaps is not None:
+                    extra_params = [
+                        self._paramMap_to_tpu_overrides(pm) for pm in paramMaps
+                    ]
+                fit_func = self._get_tpu_fit_func(df, extra_params)
+                # float64 fits genuinely run in float64 (reference
+                # core.py:363-401 keeps f64 end-to-end): without x64,
+                # jax.device_put silently canonicalizes f64 -> f32.  The x64
+                # scope must cover BOTH ingest (device_put) and the fit
+                # (trace-time dtypes); it recompiles the kernels for f64,
+                # which TPUs execute via (slower) emulation.
+                scopes.enter_context(
+                    _maybe_x64(self._use_dtype(df, input_col, input_cols))
+                )
+                scopes.enter_context(profiling.maybe_trace(type(self).__name__))
+                # srml-shield: the runner.fit injection site fires on BOTH
+                # fit paths — here (driver-local) and in parallel/runner.fit
+                # (the barrier task) — so a fault plan written against the
+                # site name covers whichever launcher ran the fit
+                faults.site("runner.fit", rank=0)
+            with profiling.span("srml.ingest"):
                 inputs = self._build_fit_inputs(df)
-            extra_params = None
-            if paramMaps is not None:
-                extra_params = [
-                    self._paramMap_to_tpu_overrides(pm) for pm in paramMaps
-                ]
-            fit_func = self._get_tpu_fit_func(df, extra_params)
-            logger = get_logger(type(self))
-            logger.info(
-                "Invoking TPU fit: %d rows x %d cols on %d-device mesh",
-                inputs.n_rows, inputs.n_cols, inputs.mesh.devices.size,
-            )
-            from .sanitize import sanitize_scope
-
-            with profiling.maybe_trace(type(self).__name__):
-                with profiling.phase("srml.fit"), sanitize_scope():
-                    result = fit_func(inputs, dict(self._tpu_params))
-        self._last_fit_phase_times = profiling.phase_times()
-        # telemetry rides the SAME attribute dicts the executor path ships,
-        # so _fit_internal attaches model.fit_telemetry() uniformly (the
-        # snapshot is shared across a single-pass multi-model fit — one
-        # data load, one solver pass, one set of phase timers)
-        snap = profiling.TelemetrySnapshot.capture(counters0, rank=0)
-        for r in result if isinstance(result, list) else [result]:
-            r[TELEMETRY_ATTR] = snap.to_dict()
-        return result
+                get_logger(type(self)).info(
+                    "Invoking TPU fit: %d rows x %d cols on %d-device mesh",
+                    inputs.n_rows, inputs.n_cols, inputs.mesh.devices.size,
+                )
+            with profiling.span("srml.fit"), sanitize_scope():
+                result = fit_func(inputs, dict(self._tpu_params))
+            with profiling.span("srml.finish"):
+                self._last_fit_phase_times = profiling.phase_times()
+                # telemetry rides the SAME attribute dicts the executor path
+                # ships, so _fit_internal attaches model.fit_telemetry()
+                # uniformly (the snapshot is shared across a single-pass
+                # multi-model fit — one data load, one solver pass, one set
+                # of phase timers)
+                snap = profiling.TelemetrySnapshot.capture(counters0, rank=0)
+                for r in result if isinstance(result, list) else [result]:
+                    r[TELEMETRY_ATTR] = snap.to_dict()
+                return finish(result)
 
     def _paramMap_to_tpu_overrides(self, paramMap: Dict[Param, Any]) -> Dict[str, Any]:
         mapping = self._param_mapping()
@@ -703,15 +753,17 @@ class _TpuEstimator(_TpuCaller):
     def _fit_internal(
         self, dataset: Any, paramMaps: Optional[List[Dict[Param, Any]]]
     ) -> List["_TpuModel"]:
-        results = self._call_tpu_fit_func(dataset, paramMaps)
-        if paramMaps is None:
-            results = [results] if isinstance(results, dict) else list(results)
-            assert len(results) == 1
-        models = []
-        for i, attrs in enumerate(results if isinstance(results, list) else [results]):
-            pm = paramMaps[i] if paramMaps is not None and i < len(paramMaps) else None
-            models.append(self._materialize_model(attrs, pm))
-        return models
+        def models_of(results: Any) -> List["_TpuModel"]:
+            if paramMaps is None:
+                results = [results] if isinstance(results, dict) else list(results)
+                assert len(results) == 1
+            models = []
+            for i, attrs in enumerate(results if isinstance(results, list) else [results]):
+                pm = paramMaps[i] if paramMaps is not None and i < len(paramMaps) else None
+                models.append(self._materialize_model(attrs, pm))
+            return models
+
+        return self._call_tpu_fit_func(dataset, paramMaps, models_of)
 
     def _materialize_model(
         self, attrs: Dict[str, Any], paramMap: Optional[Dict[Param, Any]] = None
@@ -727,8 +779,6 @@ class _TpuEstimator(_TpuCaller):
         telem = attrs.pop(TELEMETRY_ATTR, None)
         model = self._create_model(attrs)
         if telem is not None:
-            from . import profiling
-
             model._fit_telemetry = profiling.TelemetrySnapshot.from_dict(telem)
         self._copyValues(model)
         model._tpu_params.update(self._tpu_params)

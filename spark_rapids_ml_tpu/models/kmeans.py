@@ -18,7 +18,13 @@ import numpy as np
 
 import jax
 
-from ..core import FitInputs, _TpuEstimator, _TpuModelWithPredictionCol
+from .. import profiling
+from ..core import (
+    FitInputs,
+    _TpuEstimator,
+    _TpuModelWithPredictionCol,
+    fetch_fit_result,
+)
 from ..dataframe import DataFrame
 from ..params import (
     HasFeaturesCol,
@@ -153,48 +159,53 @@ class KMeans(_KMeansParams, _TpuEstimator):
         logger = get_logger(type(self))
 
         def _fit(inputs: FitInputs, params: Dict[str, Any]):
-            k = int(params["n_clusters"])
-            seed = int(params["random_state"]) & 0x7FFFFFFF
-            chunk = min(int(params["max_samples_per_batch"]), inputs.X.shape[0])
-            if params["init"] == "random":
-                centers0 = random_init(inputs.X, inputs.weight, k, seed)
-            else:
-                oversample = float(params["oversampling_factor"])
-                round_size = max(1, min(int(oversample * k), inputs.n_rows))
-                centers0 = scalable_kmeans_pp_init(
+            # the step spans below tile srml.fit (core._call_tpu_fit_func)
+            with profiling.span("srml.fit.init"):
+                k = int(params["n_clusters"])
+                seed = int(params["random_state"]) & 0x7FFFFFFF
+                chunk = min(int(params["max_samples_per_batch"]), inputs.X.shape[0])
+                if params["init"] == "random":
+                    centers0 = random_init(inputs.X, inputs.weight, k, seed)
+                else:
+                    oversample = float(params["oversampling_factor"])
+                    round_size = max(1, min(int(oversample * k), inputs.n_rows))
+                    centers0 = scalable_kmeans_pp_init(
+                        inputs.X,
+                        inputs.weight,
+                        k,
+                        seed,
+                        oversample,
+                        rounds=4,
+                        round_size=round_size,
+                    )
+            with profiling.span("srml.fit.solve"):
+                solved = lloyd_iterations(
                     inputs.X,
                     inputs.weight,
-                    k,
-                    seed,
-                    oversample,
-                    rounds=4,
-                    round_size=round_size,
+                    centers0,
+                    inputs.mesh,
+                    int(params["max_iter"]),
+                    float(params["tol"]),
+                    chunk,
                 )
-            centers, n_iter, inertia = lloyd_iterations(
-                inputs.X,
-                inputs.weight,
-                centers0,
-                inputs.mesh,
-                int(params["max_iter"]),
-                float(params["tol"]),
-                chunk,
-            )
             # ONE batched device fetch: int()/float()/np.asarray each cost
             # a host round-trip, and centers/n_iter/inertia are ready
             # together
-            centers_h, n_iter_h, inertia_h = jax.device_get(
-                (centers, n_iter, inertia)
-            )
-            logger.info(
-                "iterations: %d, inertia: %f", int(n_iter_h), float(inertia_h)
-            )
-            return {
-                "cluster_centers_": np.asarray(centers_h, dtype=np.float64),
-                "n_cols": inputs.n_cols,
-                "dtype": str(inputs.dtype),
-                "n_iter_": int(n_iter_h),
-                "inertia_": float(inertia_h),
-            }
+            centers_h, n_iter_h, inertia_h = fetch_fit_result(solved)
+            with profiling.span("srml.fit.pack"):
+                # the device buffers go inside a step, not with the frame
+                # after the last one
+                del solved, centers0
+                logger.info(
+                    "iterations: %d, inertia: %f", int(n_iter_h), float(inertia_h)
+                )
+                return {
+                    "cluster_centers_": np.asarray(centers_h, dtype=np.float64),
+                    "n_cols": inputs.n_cols,
+                    "dtype": str(inputs.dtype),
+                    "n_iter_": int(n_iter_h),
+                    "inertia_": float(inertia_h),
+                }
 
         return _fit
 

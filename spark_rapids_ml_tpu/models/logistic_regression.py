@@ -23,7 +23,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core import FitInputs, _TpuEstimatorSupervised, _TpuModelWithPredictionCol
+from .. import profiling
+from ..core import (
+    FitInputs,
+    _TpuEstimatorSupervised,
+    _TpuModelWithPredictionCol,
+    fetch_fit_result,
+)
 from ..dataframe import DataFrame, as_dataframe
 from ..metrics.multiclass import MulticlassMetrics
 from ..params import (
@@ -54,6 +60,15 @@ from ..ops.logistic import (
     sweep_logistic_fit_kernel,
 )
 from ..utils import get_logger
+
+
+def _count_lbfgs(n_iter: Any, n_evals: Any) -> None:
+    """Add fetched L-BFGS counts (scalars, or one entry a lane) to the
+    process-wide counters lbfgs.fits / lbfgs.iters / lbfgs.evals, which a
+    fit's telemetry snapshot then carries as its own deltas."""
+    profiling.incr_counter("lbfgs.fits", int(np.size(n_iter)))
+    profiling.incr_counter("lbfgs.iters", int(np.sum(n_iter)))
+    profiling.incr_counter("lbfgs.evals", int(np.sum(n_evals)))
 
 
 class _ClassificationModelEvaluationMixIn:
@@ -284,50 +299,58 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
             num_classes = len(classes)
             k = 1 if num_classes == 2 else num_classes
             use_owlqn = reg > 0 and l1_ratio > 0
-            W, b, n_iter, converged = logistic_fit_kernel(
-                inputs.X,
-                y_enc,
-                inputs.weight,
-                k,
-                reg,
-                l1_ratio,
-                bool(params["fit_intercept"]),
-                int(params["max_iter"]),
-                float(params["tol"]),
-                use_owlqn,
-            )
+            with profiling.span("srml.fit.solve"):
+                solved = logistic_fit_kernel(
+                    inputs.X,
+                    y_enc,
+                    inputs.weight,
+                    k,
+                    reg,
+                    l1_ratio,
+                    bool(params["fit_intercept"]),
+                    int(params["max_iter"]),
+                    float(params["tol"]),
+                    use_owlqn,
+                )
             # one batched device fetch (each scalar coercion alone costs a
             # host round-trip)
-            W_h, b_h, n_iter_h, conv_h = jax.device_get(
-                (W, b, n_iter, converged)
-            )
-            logger.info(
-                "L-BFGS iters: %d converged: %s", int(n_iter_h), bool(conv_h)
-            )
-            return {
-                "coef_": np.asarray(W_h, dtype=np.float64),
-                "intercept_": np.asarray(b_h, dtype=np.float64),
-                "classes_": np.asarray(classes, dtype=np.float64),
-                "n_cols": inputs.n_cols,
-                "dtype": str(inputs.dtype),
-                "num_iters": int(n_iter_h),
-            }
+            W_h, b_h, n_iter_h, conv_h, n_evals_h = fetch_fit_result(solved)
+            with profiling.span("srml.fit.pack"):
+                # the device buffers go inside a step, not with the frame
+                # after the last one
+                del solved
+                _count_lbfgs(n_iter_h, n_evals_h)
+                logger.info(
+                    "L-BFGS iters: %d evaluations: %d converged: %s",
+                    int(n_iter_h), int(n_evals_h), bool(conv_h),
+                )
+                return {
+                    "coef_": np.asarray(W_h, dtype=np.float64),
+                    "intercept_": np.asarray(b_h, dtype=np.float64),
+                    "classes_": np.asarray(classes, dtype=np.float64),
+                    "n_cols": inputs.n_cols,
+                    "dtype": str(inputs.dtype),
+                    "num_iters": int(n_iter_h),
+                }
 
         def _fit(inputs: FitInputs, params: Dict[str, Any]):
             from ..core import discover_label_classes
             from ..ops.labels import encode_labels_kernel
 
-            assert inputs.y is not None
-            classes = discover_label_classes(inputs)
-            if len(classes) < 2:
-                raise RuntimeError(
-                    "LogisticRegression requires at least two distinct labels"
+            # the step spans here and in _single_fit tile srml.fit
+            # (core._call_tpu_fit_func)
+            with profiling.span("srml.fit.init"):
+                assert inputs.y is not None
+                classes = discover_label_classes(inputs)
+                if len(classes) < 2:
+                    raise RuntimeError(
+                        "LogisticRegression requires at least two distinct labels"
+                    )
+                # encode labels as class indices on device, preserving the
+                # row sharding (padded rows clamp into range; masked by w)
+                y_enc = encode_labels_kernel(
+                    inputs.y, jnp.asarray(classes.astype(inputs.y.dtype))
                 )
-            # encode labels as class indices on device, preserving the row
-            # sharding (padded rows clamp into range; masked by w)
-            y_enc = encode_labels_kernel(
-                inputs.y, jnp.asarray(classes.astype(inputs.y.dtype))
-            )
             if extra_params:
                 results = []
                 for override in extra_params:
@@ -376,7 +399,6 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
         folds as fold-id weight masks, candidates as traced reg/l1 lanes
         with per-lane convergence masks (ops/logistic.py,
         ops/lbfgs.minimize_lbfgs_batched)."""
-        from .. import profiling
         from ..core import discover_label_classes
         from ..ops import sweep as sweep_ops
         from ..ops.labels import encode_labels_kernel
@@ -459,7 +481,7 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                     folds=n_folds,
                     owlqn=owlqn,
                 ):
-                    W, b, n_iter, conv = sweep_ops.dispatch(
+                    W, b, n_iter, conv, n_evals = sweep_ops.dispatch(
                         "sweep.logreg.fit",
                         sweep_logistic_fit_kernel,
                         inputs.X,
@@ -477,9 +499,13 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                         use_owlqn=owlqn,
                     )
                     # graftlint: disable=R1 (one batched fetch per penalty FAMILY — at most two iterations, each a distinct compiled sweep whose results ship home together)
-                    W_h, b_h, n_iter_h, conv_h = jax.device_get(
-                        (W, b, n_iter, conv)
+                    W_h, b_h, n_iter_h, conv_h, n_evals_h = jax.device_get(
+                        (W, b, n_iter, conv, n_evals)
                     )
+                # the lanes of the candidate bucket beyond the grid are not fits
+                _count_lbfgs(
+                    n_iter_h[:, : len(idxs)], n_evals_h[:, : len(idxs)]
+                )
                 logger.info(
                     "sweep L-BFGS iters (fold x candidate): %s converged: %s",
                     n_iter_h[:, : len(idxs)].tolist(),

@@ -15,6 +15,11 @@
 #     round_size candidates per round with Gumbel top-k sampling
 #     (prob ∝ cost), then runs weighted k-means++ on the small replicated
 #     candidate set.
+#   - jax.named_scope marks the parts lloyd_iterations' device time divides
+#     into: lloyd.norms (row norms, once a fit), lloyd.assign (distances +
+#     argmin), lloyd.update (one-hot sums, psum, division) and lloyd.inertia
+#     (the last, exact pass).  Metadata on the operations, read in xprof's
+#     trace viewer and op profile; free at run time.
 #
 
 from __future__ import annotations
@@ -57,16 +62,19 @@ def _chunked_assign_stats(X_loc, w_loc, centers, chunk, x_norm_loc, exact_inerti
     Xc = Xp.reshape(n_chunks, chunk, d)
     wc = wp.reshape(n_chunks, chunk)
     xnc = jnp.pad(x_norm_loc, (0, pad)).reshape(n_chunks, chunk)
-    c_norm = (centers * centers).sum(axis=1)
+    with jax.named_scope("lloyd.assign"):
+        c_norm = (centers * centers).sum(axis=1)
 
     def body(carry, xw):
         sums, counts, inertia = carry
         xb, wb, x_norm = xw
-        d2 = x_norm[:, None] - 2.0 * (xb @ centers.T) + c_norm[None, :]
-        assign = jnp.argmin(d2, axis=1)
-        onehot = jax.nn.one_hot(assign, k, dtype=xb.dtype) * wb[:, None]
-        sums = sums + onehot.T @ xb
-        counts = counts + onehot.sum(axis=0)
+        with jax.named_scope("lloyd.assign"):
+            d2 = x_norm[:, None] - 2.0 * (xb @ centers.T) + c_norm[None, :]
+            assign = jnp.argmin(d2, axis=1)
+        with jax.named_scope("lloyd.update"):
+            onehot = jax.nn.one_hot(assign, k, dtype=xb.dtype) * wb[:, None]
+            sums = sums + onehot.T @ xb
+            counts = counts + onehot.sum(axis=0)
         if exact_inertia:
             diff = xb - centers[assign]
             inertia = inertia + ((diff * diff).sum(axis=1) * wb).sum()
@@ -101,7 +109,8 @@ def lloyd_iterations(
     """
 
     def per_device(X_loc, w_loc, centers0):
-        x_norm_loc = (X_loc * X_loc).sum(axis=1)  # hoisted out of the loop
+        with jax.named_scope("lloyd.norms"):
+            x_norm_loc = (X_loc * X_loc).sum(axis=1)  # hoisted out of the loop
 
         def cond(state):
             _, prev_shift, it = state
@@ -112,13 +121,14 @@ def lloyd_iterations(
             sums, counts, _ = _chunked_assign_stats(
                 X_loc, w_loc, centers, chunk, x_norm_loc
             )
-            sums = jax.lax.psum(sums, DATA_AXIS)
-            counts = jax.lax.psum(counts, DATA_AXIS)
-            nonempty = counts > 0
-            new_centers = jnp.where(
-                nonempty[:, None], sums / jnp.maximum(counts, 1.0)[:, None], centers
-            )
-            shift = ((new_centers - centers) ** 2).sum()
+            with jax.named_scope("lloyd.update"):
+                sums = jax.lax.psum(sums, DATA_AXIS)
+                counts = jax.lax.psum(counts, DATA_AXIS)
+                nonempty = counts > 0
+                new_centers = jnp.where(
+                    nonempty[:, None], sums / jnp.maximum(counts, 1.0)[:, None], centers
+                )
+                shift = ((new_centers - centers) ** 2).sum()
             return (new_centers, shift, it + 1)
 
         init = (centers0, jnp.array(jnp.inf, X_loc.dtype), jnp.array(0, jnp.int32))
@@ -126,10 +136,11 @@ def lloyd_iterations(
         # one final stats pass so inertia reflects the returned centers
         # (exact difference-form cost: the reported inertia must not carry
         # the training loop's fast-matmul cancellation error)
-        _, _, final_inertia = _chunked_assign_stats(
-            X_loc, w_loc, centers, chunk, x_norm_loc, exact_inertia=True
-        )
-        final_inertia = jax.lax.psum(final_inertia, DATA_AXIS)
+        with jax.named_scope("lloyd.inertia"):
+            _, _, final_inertia = _chunked_assign_stats(
+                X_loc, w_loc, centers, chunk, x_norm_loc, exact_inertia=True
+            )
+            final_inertia = jax.lax.psum(final_inertia, DATA_AXIS)
         return centers, n_iter, final_inertia
 
     return shard_map(

@@ -13,6 +13,11 @@
 # projection inside the backtracking line search.  l1_weight is a
 # per-coordinate vector so intercepts stay unregularized (Spark semantics).
 #
+# jax.named_scope marks the two parts an iteration's device time divides
+# into, lbfgs.eval (value and gradient) and lbfgs.direction (two-loop
+# recursion): metadata on the operations, read in xprof's trace viewer and op
+# profile, free at run time.
+#
 
 from __future__ import annotations
 
@@ -28,6 +33,11 @@ class LbfgsResult(NamedTuple):
     f: jax.Array
     n_iter: jax.Array
     converged: jax.Array
+    # evaluations of the objective (value and gradient): the one at x0 and
+    # every line-search trial.  An evaluation is what a fit's time is made
+    # of (two passes over X in the logistic kernels), and the line search
+    # takes another number of them on every dataset; n_iter does not say.
+    n_evals: jax.Array
 
 
 def _pseudo_gradient(x, g, l1w):
@@ -104,7 +114,8 @@ def minimize_lbfgs_batched(
     l1w = l1_weight.astype(dtype)
 
     def full_objective(x):
-        f, g = value_and_grad(x)
+        with jax.named_scope("lbfgs.eval"):
+            f, g = value_and_grad(x)
         if use_owlqn:
             f = f + (l1w * jnp.abs(x)).sum(axis=-1)
         return f, g
@@ -120,18 +131,20 @@ def minimize_lbfgs_batched(
         jnp.zeros((L,), jnp.int32),         # memory count
         jnp.zeros((L,), jnp.int32),         # per-lane iteration
         jnp.zeros((L,), bool),              # converged
+        jnp.ones((L,), jnp.int32),          # per-lane evaluations (f0 is one)
     )
     two_loop_lanes = jax.vmap(_two_loop, in_axes=(0, 0, 0, 0, 0, None))
 
     def cond(state):
-        _, _, _, _, _, _, _, it, converged = state
+        _, _, _, _, _, _, _, it, converged, _ = state
         return jnp.any((it < max_iter) & (~converged))
 
     def body(state):
-        x, f, g, S, Y, rho, count, it, converged = state
+        x, f, g, S, Y, rho, count, it, converged, n_evals = state
         active = (it < max_iter) & (~converged)
         pg = _pseudo_gradient(x, g, l1w) if use_owlqn else g
-        d = -two_loop_lanes(pg, S, Y, rho, count, history)
+        with jax.named_scope("lbfgs.direction"):
+            d = -two_loop_lanes(pg, S, Y, rho, count, history)
         if use_owlqn:
             d = jnp.where(d * -pg > 0, d, 0.0)
         xi = jnp.sign(x)
@@ -168,7 +181,9 @@ def minimize_lbfgs_batched(
             _, _, _, _, n_ls, ok = ls_state
             return jnp.any(active & (~ok) & (n_ls < max_ls))
 
-        _, x_new, f_new, g_new, _, ls_ok = jax.lax.while_loop(
+        # n_ls counts a lane's own trials (a frozen lane rides along
+        # uncounted), so a lane's n_evals is its solo run's
+        _, x_new, f_new, g_new, n_ls, ls_ok = jax.lax.while_loop(
             ls_cond,
             ls_body,
             (t0, x, f, g, jnp.zeros((L,), jnp.int32), jnp.zeros((L,), bool)),
@@ -212,12 +227,13 @@ def minimize_lbfgs_batched(
             count,
             it + active.astype(jnp.int32),
             jnp.where(active, converged_new, converged),
+            n_evals + n_ls,
         )
 
-    x, f, g, S, Y, rho, count, it, converged = jax.lax.while_loop(
+    x, f, g, S, Y, rho, count, it, converged, n_evals = jax.lax.while_loop(
         cond, body, state
     )
-    return LbfgsResult(x=x, f=f, n_iter=it, converged=converged)
+    return LbfgsResult(x=x, f=f, n_iter=it, converged=converged, n_evals=n_evals)
 
 
 @partial(jax.jit, static_argnames=("value_and_grad", "max_iter", "history", "use_owlqn", "max_ls"))
@@ -243,7 +259,8 @@ def minimize_lbfgs(
     l1w = l1_weight.astype(dtype)
 
     def full_objective(x):
-        f, g = value_and_grad(x)
+        with jax.named_scope("lbfgs.eval"):
+            f, g = value_and_grad(x)
         if use_owlqn:
             f = f + (l1w * jnp.abs(x)).sum()
         return f, g
@@ -260,16 +277,18 @@ def minimize_lbfgs(
         jnp.array(0, jnp.int32),         # memory count
         jnp.array(0, jnp.int32),         # iteration
         jnp.array(False),                # converged
+        jnp.array(1, jnp.int32),         # evaluations (f0 is one)
     )
 
     def cond(state):
-        _, _, _, _, _, _, _, it, converged = state
+        _, _, _, _, _, _, _, it, converged, _ = state
         return (it < max_iter) & (~converged)
 
     def body(state):
-        x, f, g, S, Y, rho, count, it, _ = state
+        x, f, g, S, Y, rho, count, it, _, n_evals = state
         pg = _pseudo_gradient(x, g, l1w) if use_owlqn else g
-        d = -_two_loop(pg, S, Y, rho, count, history)
+        with jax.named_scope("lbfgs.direction"):
+            d = -_two_loop(pg, S, Y, rho, count, history)
         if use_owlqn:
             # align the direction against the pseudo-gradient's orthant
             d = jnp.where(d * -pg > 0, d, 0.0)
@@ -298,7 +317,7 @@ def minimize_lbfgs(
             _, _, _, _, n_ls, ok = ls_state
             return (~ok) & (n_ls < max_ls)
 
-        _, x_new, f_new, g_new, _, ls_ok = jax.lax.while_loop(
+        _, x_new, f_new, g_new, n_ls, ls_ok = jax.lax.while_loop(
             ls_cond, ls_body, (t0, x, f, g, jnp.array(0, jnp.int32), jnp.array(False))
         )
         # on line-search exhaustion keep the current iterate (the last trial
@@ -323,9 +342,12 @@ def minimize_lbfgs(
             | (jnp.max(jnp.abs(pg_new)) <= tol)
             | (~ls_ok)
         )
-        return (x_new, f_new, g_new, S, Y, rho, count, it + 1, converged)
+        return (
+            x_new, f_new, g_new, S, Y, rho, count, it + 1, converged,
+            n_evals + n_ls,
+        )
 
-    x, f, g, S, Y, rho, count, it, converged = jax.lax.while_loop(
+    x, f, g, S, Y, rho, count, it, converged, n_evals = jax.lax.while_loop(
         cond, body, class_state
     )
-    return LbfgsResult(x=x, f=f, n_iter=it, converged=converged)
+    return LbfgsResult(x=x, f=f, n_iter=it, converged=converged, n_evals=n_evals)

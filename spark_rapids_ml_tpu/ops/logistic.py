@@ -101,7 +101,7 @@ def _solve_from(
         use_owlqn=use_owlqn,
     )
     W, b = _unpack(result.x, k, d, fit_intercept)
-    return W, b, result.n_iter, result.converged
+    return W, b, result.n_iter, result.converged, result.n_evals
 
 
 @partial(
@@ -119,10 +119,11 @@ def logistic_fit_kernel(
     max_iter: int,
     tol: float,
     use_owlqn: bool,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fit one logistic model; k == 1 -> binary sigmoid (y_enc in {0,1}),
     k >= 2 -> multinomial softmax (y_enc = class index).  Returns
-    (W (k, D), b (k,), n_iter, converged)."""
+    (W (k, D), b (k,), n_iter, converged, n_evals): n_evals counts the
+    objective's evaluations (ops/lbfgs.LbfgsResult)."""
     d = X.shape[1]
     n_params = k * d + (k if fit_intercept else 0)
     return _solve_from(
@@ -148,7 +149,7 @@ def logistic_warm_fit_kernel(
     fit_intercept: bool,
     max_iter: int,
     use_owlqn: bool,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """logistic_fit_kernel warm-started from (W0 (k, D), b0 (k,)) — the
     srml-stream partial_fit kernel: each device-staged chunk resumes the
     solve from the running streamed coefficients instead of zeros, so a
@@ -189,7 +190,7 @@ def sweep_logistic_fit_kernel(
     max_iter: int = 100,
     use_owlqn: bool = False,
     mesh=None,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fit a whole regularization sweep — m candidates x k folds — as ONE
     jitted L-BFGS/OWL-QN run over the one staged dataset.
 
@@ -202,7 +203,8 @@ def sweep_logistic_fit_kernel(
     (the (N, D) x (D, k*m*kcls) product XLA builds from the lane einsum);
     per-lane convergence masks in minimize_lbfgs_batched freeze finished
     lanes.  Returns (W (k, m, kcls, D), b (k, m, kcls), n_iter (k, m),
-    converged (k, m)).  `mesh` only keys the AOT executable cache — the
+    converged (k, m), n_evals (k, m)).  `mesh` only keys the AOT executable
+    cache — the
     row-sharded reductions compile to psums via GSPMD exactly like the
     single-fit kernel's."""
     n, d = X.shape
@@ -273,6 +275,7 @@ def sweep_logistic_fit_kernel(
         b,
         result.n_iter.reshape(k_folds, mb),
         result.converged.reshape(k_folds, mb),
+        result.n_evals.reshape(k_folds, mb),
     )
 
 

@@ -450,6 +450,23 @@ def _span_stack() -> list:
     return stack
 
 
+# jax.profiler.TraceAnnotation, resolved on the first span (profiling itself
+# never pulls jax in at import): the import machinery is not on every span's
+# path
+_annotation: Optional[Callable[[str], contextlib.AbstractContextManager]] = None
+
+
+def _resolve_annotation() -> Callable[[str], contextlib.AbstractContextManager]:
+    global _annotation
+    try:
+        import jax.profiler
+
+        _annotation = jax.profiler.TraceAnnotation
+    except Exception:  # pragma: no cover - profiler always importable with jax
+        _annotation = lambda _name: contextlib.nullcontext()  # noqa: E731
+    return _annotation
+
+
 @contextlib.contextmanager
 def span(name: str, **attrs: Any) -> Iterator[_SpanHandle]:
     """Named range: xprof TraceAnnotation + wall-clock accounting + (while a
@@ -461,53 +478,48 @@ def span(name: str, **attrs: Any) -> Iterator[_SpanHandle]:
     rollups are built from.  `attrs` become the trace event's args
     (bytes=, rows=, block=...); they are ignored — never allocated — when
     no session is collecting."""
-    try:
-        import jax.profiler
-
-        annotation: contextlib.AbstractContextManager = jax.profiler.TraceAnnotation(
-            name
-        )
-    except Exception:  # pragma: no cover - profiler always importable with jax
-        annotation = contextlib.nullcontext()
-    collecting = _collect_depth > 0
-    if collecting:
-        sid = next(_span_ids)
-        stack = _span_stack()
-        parent = stack[-1] if stack else 0
-        stack.append(sid)
-        handle = _SpanHandle(dict(attrs))
-    else:
-        handle = _NULL_SPAN
-    # flight recorder (srml-watch): ALWAYS on when installed — one bounded
-    # ring event per span close plus the open-span stack a hang dump and
-    # the stall watchdog read.  Overhead is gated <2% of a warm fit by
-    # tests/test_watch.py.
-    fr = _flight
-    if fr is not None:
-        fr.on_span_open(name)
-    t0 = time.perf_counter()
-    try:
-        with annotation:
-            yield handle
-    finally:
-        t1 = time.perf_counter()
-        dt = t1 - t0
-        reg = _registry()
-        reg[name] = reg.get(name, 0.0) + dt
-        cnt = _count_registry()
-        cnt[name] = cnt.get(name, 0) + 1
+    # the annotation opens first and closes last: the span's own bookkeeping
+    # lies inside it, so spans that tile a thread leave only the context
+    # managers' plumbing between their ranges in a profiler trace
+    with (_annotation or _resolve_annotation())(name):
+        collecting = _collect_depth > 0
         if collecting:
-            stack.pop()
-            th = threading.current_thread()
-            with _trace_lock:
-                if len(_trace_records) < _TRACE_CAP:
-                    _trace_records.append(
-                        (name, t0, t1, th.ident, th.name, sid, parent,
-                         handle.attrs)
-                    )
+            sid = next(_span_ids)
+            stack = _span_stack()
+            parent = stack[-1] if stack else 0
+            stack.append(sid)
+            handle = _SpanHandle(dict(attrs))
+        else:
+            handle = _NULL_SPAN
+        # flight recorder (srml-watch): ALWAYS on when installed — one
+        # bounded ring event per span close plus the open-span stack a hang
+        # dump and the stall watchdog read.  Overhead is gated <2% of a warm
+        # fit by tests/test_watch.py.
+        fr = _flight
         if fr is not None:
-            fr.on_span_close(name, t0, t1, sys.exc_info()[0] is not None)
-        _log.debug("phase %s: %.3fs", name, dt)
+            fr.on_span_open(name)
+        t0 = time.perf_counter()
+        try:
+            yield handle
+        finally:
+            t1 = time.perf_counter()
+            dt = t1 - t0
+            reg = _registry()
+            reg[name] = reg.get(name, 0.0) + dt
+            cnt = _count_registry()
+            cnt[name] = cnt.get(name, 0) + 1
+            if collecting:
+                stack.pop()
+                th = threading.current_thread()
+                with _trace_lock:
+                    if len(_trace_records) < _TRACE_CAP:
+                        _trace_records.append(
+                            (name, t0, t1, th.ident, th.name, sid, parent,
+                             handle.attrs)
+                        )
+            if fr is not None:
+                fr.on_span_close(name, t0, t1, sys.exc_info()[0] is not None)
+            _log.debug("phase %s: %.3fs", name, dt)
 
 
 # API-compatible shim: every existing phase site is a span site
@@ -543,11 +555,23 @@ def _safe_tag(tag: str) -> str:
     return "".join(c if (c.isalnum() or c in "-_.") else "-" for c in tag)
 
 
-def _write_chrome_trace(path: str, records: List[tuple]) -> None:
+def _clock_pair() -> Dict[str, float]:
+    """The span clock beside the wall clock, read back to back."""
+    return {"perf_counter_s": time.perf_counter(), "unix_s": time.time()}
+
+
+def _write_chrome_trace(
+    path: str, records: List[tuple], clock: Optional[Dict[str, Any]] = None
+) -> None:
     """Write span records as Chrome trace-event JSON (the `traceEvents`
     array format Perfetto and chrome://tracing load): one complete ("X")
     event per span with microsecond ts/dur relative to the process epoch,
-    plus thread_name metadata events so worker threads are labeled."""
+    plus thread_name metadata events so worker threads are labeled.
+    `clock` lands under the document's `metadata` key (trace viewers keep
+    unknown top-level keys as metadata): the span clock's epoch and its
+    reading beside the wall clock at the session's start and end, so the
+    file can be laid over an xprof capture of the same run, whose host
+    events carry wall-clock times."""
     pid = os.getpid()
     tid_of: Dict[int, int] = {}
     names: Dict[int, str] = {}
@@ -582,7 +606,9 @@ def _write_chrome_trace(path: str, records: List[tuple]) -> None:
         }
         for tid, tname in sorted(names.items())
     ]
-    doc = {"traceEvents": meta + events_out, "displayTimeUnit": "ms"}
+    doc: Dict[str, Any] = {"traceEvents": meta + events_out, "displayTimeUnit": "ms"}
+    if clock is not None:
+        doc["metadata"] = {"clock": {"epoch_perf_counter_s": _EPOCH, **clock}}
     tmp = f"{path}.tmp{pid}"
     try:
         with open(tmp, "w") as f:
@@ -627,17 +653,19 @@ def trace_session(tag: str = "session") -> Iterator[Optional[str]]:
     global _collect_depth
     with _trace_lock:
         _collect_depth += 1
-    t_start = time.perf_counter()
+    clock = {"start": _clock_pair()}
+    t_start = clock["start"]["perf_counter_s"]
     try:
         yield path
     finally:
+        clock["end"] = _clock_pair()
         with _trace_lock:
             records = [r for r in _trace_records if r[1] >= t_start]
             _collect_depth -= 1
             if _collect_depth == 0:
                 _trace_records.clear()
         try:
-            _write_chrome_trace(path, records)
+            _write_chrome_trace(path, records, clock)
             _log.info(
                 "srml-scope trace for %r: %d span(s) -> %s",
                 tag, len(records), path,
@@ -1037,17 +1065,6 @@ def with_benchmark(name: str, fn: Callable[[], Any]) -> Tuple[Any, float]:
     _log.info("-" * 100)
     _log.info("%s took: %s sec", name, dt)
     return result, dt
-
-
-def device_step_annotation(step: int) -> contextlib.AbstractContextManager:
-    """StepTraceAnnotation for iteration-granular traces (opt-in use in
-    benchmark loops)."""
-    try:
-        import jax.profiler
-
-        return jax.profiler.StepTraceAnnotation("step", step_num=step)
-    except Exception:  # pragma: no cover
-        return contextlib.nullcontext()
 
 
 # -- srml-watch bootstrap ------------------------------------------------------

@@ -621,7 +621,7 @@ class StreamingLogisticRegression(StreamingEngine):
         wd = _stage(w, bucket, self._dtype)
         W0 = jax.device_put(np.asarray(self._W, self._dtype))
         b0 = jax.device_put(np.asarray(self._b, self._dtype))
-        W, b, _n_iter, _conv = jax.device_get(
+        W, b, _n_iter, _conv, _n_evals = jax.device_get(
             cached_kernel(
                 "stream.logreg_update",
                 logistic_warm_fit_kernel,

@@ -29,8 +29,9 @@
 #      watched FIT thread's span-close count, not the publisher thread's
 #      clock: a wedged fit with a healthy publisher still trips the dog.
 #   3. DEVICE-MEMORY ACCOUNTING — HBM/host watermarks sampled via jax
-#      device memory stats at span boundaries (free when the backend has
-#      no stats, as XLA:CPU does not), per-phase peak-delta attribution
+#      device memory stats at the open and close of a thread's OUTERMOST
+#      span (nested spans never sample; free when the backend has no
+#      stats, as XLA:CPU does not), per-phase peak-delta attribution
 #      merged into TelemetrySnapshot.memory, and executable-cache
 #      introspection from ops/precompile (entry count, bucket geometries,
 #      estimated bytes).
@@ -67,6 +68,11 @@ HEARTBEAT_ENV = "SRML_WATCH_HEARTBEAT_S"    # per-rank heartbeat period
 STALL_ENV = "SRML_WATCH_STALL_S"            # stall threshold (0 = off)
 
 _DEFAULT_RING = 4096
+# an outermost span that opens within this long of its thread's last memory
+# sample shares it: spans that tile a thread (the fit's step spans) read
+# device memory once at each boundary, and the fit's telemetry snapshot rides
+# the srml.fit close it follows
+_MEM_SHARE_S = 1e-3
 _DEFAULT_MAX_DUMPS = 32
 _DEFAULT_HEARTBEAT_S = 1.0
 
@@ -120,7 +126,8 @@ class FlightRecorder:
         self._idx = 0
         self._total = 0
         self._lock = sanitize.lockdep_lock("watch.ring")
-        # ident -> [thread_obj, open_stack(list of (name, t_open)), closes]
+        # ident -> [thread_obj, open_stack(list of (name, t_open, mem)),
+        #           closes, last memory sample (t, mem) or None]
         self._threads: Dict[int, list] = {}
         self._mem_lock = sanitize.lockdep_lock("watch.mem")
         self._phase_mem: Dict[str, list] = {}  # name -> [count, peak, sum_delta]
@@ -136,7 +143,7 @@ class FlightRecorder:
         if getattr(_wtls, "rec", None) is self:
             return _wtls.slot
         th = threading.current_thread()
-        slot = [th, [], 0]
+        slot = [th, [], 0, None]
         _wtls.slot = slot
         _wtls.rec = self
         _wtls.err_span = None
@@ -156,15 +163,16 @@ class FlightRecorder:
     # -- event intake (called from profiling hooks) --------------------------
     def on_span_open(self, name: str) -> None:
         slot = self._thread_slot()
+        stack = slot[1]
         mem = None
-        if self._mem_sampler is not None:
-            try:
-                mem = self._mem_sampler()
-            except Exception:
-                mem = None
-        elif not self._mem_probed:
-            self._probe_memory()
-        slot[1].append((name, profiling.now(), mem))
+        if not stack:
+            # device memory is read at the open and close of a thread's
+            # OUTERMOST span only: a nested span costs no memory_stats() call
+            if self._mem_sampler is not None:
+                mem = self._take_mem(slot, share=True)
+            elif not self._mem_probed:
+                self._probe_memory()
+        stack.append((name, profiling.now(), mem))
 
     def on_span_close(self, name: str, t0: float, t1: float, error: bool) -> None:
         slot = self._thread_slot()
@@ -180,10 +188,7 @@ class FlightRecorder:
         else:
             _wtls.err_span = None
         if mem_open is not None and self._mem_sampler is not None:
-            try:
-                now_mem = self._mem_sampler()
-            except Exception:
-                now_mem = None
+            now_mem = self._take_mem(slot, share=False)
             if now_mem is not None:
                 in_use0, _peak0 = mem_open
                 _in_use1, peak1 = now_mem
@@ -275,6 +280,21 @@ class FlightRecorder:
         self._mem_sampler = fn
         self._mem_probed = True
 
+    def _take_mem(self, slot: list, share: bool) -> Optional[Tuple[float, float]]:
+        """One reading of the sampler, counted (`watch.mem_samples`) and kept
+        on the thread's slot with its time; with `share`, the kept reading
+        is handed back instead while it is younger than _MEM_SHARE_S."""
+        last = slot[3]
+        if share and last is not None and profiling.now() - last[0] < _MEM_SHARE_S:
+            return last[1]
+        try:
+            mem = self._mem_sampler()
+        except Exception:
+            mem = None
+        profiling.incr_counter("watch.mem_samples")
+        slot[3] = (profiling.now(), mem)
+        return mem
+
     def _probe_memory(self) -> None:
         """One-time capability probe: XLA:CPU exposes no memory_stats, so
         the sampler stays None (zero per-span cost) off-TPU.  Deferred
@@ -306,9 +326,12 @@ class FlightRecorder:
         out: Dict[str, Dict[str, float]] = {}
         for name, d in self.phase_memory().items():
             out[f"mem.phase.{name}"] = d
-        dev = None
         try:
-            dev = _device_mem()
+            dev = (
+                self._take_mem(self._thread_slot(), share=True)
+                if self._mem_sampler is not None
+                else _device_mem()
+            )
         except Exception:
             dev = None
         if dev is not None:

@@ -1,0 +1,285 @@
+"""The step spans of a fit and the counts only the program can give (PR 24).
+
+A public fit on the driver-local path is tiled by four spans on the calling
+thread (srml.prepare, srml.ingest, srml.fit, srml.finish), srml.fit by the
+fit function's five (init, solve, wait, fetch, pack); ingest.h2d_bytes and
+fit.d2h_bytes count what crossed the host link; LbfgsResult.n_evals counts
+the objective's evaluations; jax.named_scope names the solver loops' parts.
+"""
+import threading
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from spark_rapids_ml_tpu import KMeans, LogisticRegression, profiling
+from spark_rapids_ml_tpu.dataframe import DataFrame
+from spark_rapids_ml_tpu.ops import lbfgs
+from spark_rapids_ml_tpu.ops.kmeans import lloyd_iterations
+from spark_rapids_ml_tpu.ops.logistic import logistic_fit_kernel
+from spark_rapids_ml_tpu.parallel.mesh import get_mesh
+
+N, D, K = 512, 16, 4
+TOP = ["srml.prepare", "srml.ingest", "srml.fit", "srml.finish"]
+STEPS = ["srml.fit.init", "srml.fit.solve", "srml.fit.wait", "srml.fit.fetch", "srml.fit.pack"]
+# between two spans of a tiling lie a context manager's exit and the next one's
+# entry, microseconds; the allowance is for a collector pause on a busy runner
+STRETCH_S = 1e-3
+
+
+def _table():
+    rng = np.random.default_rng(7)
+    X = rng.standard_normal((N, D)).astype(np.float32)
+    y = (X @ rng.standard_normal(D) + 0.3 * rng.standard_normal(N) > 0).astype(np.float32)
+    return X, y
+
+
+def _case(family):
+    X, y = _table()
+    if family == "kmeans":
+        est = KMeans(k=K, maxIter=5, tol=0.0, initMode="random", seed=3, num_workers=2)
+        return est, (lambda: DataFrame.from_numpy(X, num_partitions=2)), X, None
+    est = LogisticRegression(maxIter=8, tol=1e-30, regParam=1e-3, num_workers=2)
+    return est, (lambda: DataFrame.from_numpy(X, y=y, num_partitions=2)), X, y
+
+
+def _traced_fit(est, df):
+    """One public fit under collect_spans: (entry, return, this thread's records by start)."""
+    me = threading.get_ident()
+    with profiling.collect_spans():
+        t0 = profiling.now()
+        est.fit(df)
+        t1 = profiling.now()
+        mine = sorted((r for r in profiling.span_records() if r[3] == me), key=lambda r: r[1])
+    return t0, t1, mine
+
+
+@pytest.mark.parametrize("family", ["kmeans", "logreg"])
+def test_step_spans_tile_the_public_fit(family):
+    est, frame, _X, _y = _case(family)
+    est.fit(frame())                    # compiles; the tiling is of a warm fit
+    for _attempt in range(3):           # a fit the scheduler cut into is taken again
+        t0, t1, mine = _traced_fit(est, frame())
+        top = [r for r in mine if r[6] == 0]
+        assert [r[0] for r in top] == TOP
+        # every instant of the public fit lies in exactly one top-level span
+        edges = [t0] + [t for r in top for t in (r[1], r[2])] + [t1]
+        assert all(a <= b for a, b in zip(edges, edges[1:]))
+        # the fit function's step spans tile srml.fit
+        fit = top[2]
+        steps = [r for r in mine if r[6] == fit[5]]
+        assert [r[0] for r in steps] == STEPS
+        inner = [fit[1]] + [t for r in steps for t in (r[1], r[2])] + [fit[2]]
+        assert all(a <= b for a, b in zip(inner, inner[1:]))
+        outside = sum(b - a for a, b in zip(edges[0::2], edges[1::2]))
+        bare = max(b - a for a, b in zip(inner[0::2], inner[1::2]))
+        if outside < STRETCH_S and bare < STRETCH_S:
+            break
+    assert outside < STRETCH_S and bare < STRETCH_S
+    # what crossed the host link rides the spans that carried it
+    by_name = {}
+    for r in mine:
+        by_name.setdefault(r[0], []).append(r)
+    assert by_name["srml.fit.fetch"][0][7]["bytes"] > 0
+    puts = by_name["srml.device_put"]
+    assert puts and all(r[6] == top[1][5] and r[7]["bytes"] > 0 for r in puts)
+
+
+@pytest.mark.parametrize("family", ["kmeans", "logreg"])
+def test_h2d_and_d2h_bytes_equal_the_arrays_nbytes(family):
+    est, frame, X, y = _case(family)
+    model = est.fit(frame())
+    moved = model.fit_telemetry().counters
+    vectors = 1 if y is None else 2          # the weight mask; the labels
+    assert moved["ingest.h2d_bytes"] == X.nbytes + vectors * N * 4
+    if family == "kmeans":
+        fetched = K * D * 4 + 4 + 4          # centres, n_iter, inertia
+    else:
+        fetched = D * 4 + 4 + 4 + 1 + 4      # W, b, n_iter, converged, n_evals
+        assert moved["lbfgs.fits"] == 1 and moved["lbfgs.iters"] == model.num_iters
+        assert moved["lbfgs.evals"] >= model.num_iters + 1
+    assert moved["fit.d2h_bytes"] == fetched
+
+
+# -- LbfgsResult.n_evals -------------------------------------------------------
+
+_SCALES = np.logspace(0, 3, 12)
+
+
+def _quartic(x):
+    """Badly scaled and not quadratic: unit steps overshoot, the search backtracks."""
+    s = jnp.asarray(_SCALES, x.dtype)
+    f = 0.5 * (s * x * x).sum() + 0.25 * ((x - 1.0) ** 4).sum()
+    return f, s * x + (x - 1.0) ** 3
+
+
+def test_n_evals_is_the_number_of_times_the_objective_ran():
+    ran = []
+
+    def counted(x):
+        jax.debug.callback(lambda: ran.append(1))
+        return _quartic(x)
+
+    x0 = jnp.full((12,), 2.0, jnp.float32)
+    out = lbfgs.minimize_lbfgs(counted, x0, jnp.zeros_like(x0), max_iter=40, tol=1e-9)
+    jax.effects_barrier()
+    assert int(out.n_evals) == len(ran)
+    assert int(out.n_evals) > int(out.n_iter) + 1      # some step was halved
+
+
+def _reference_lbfgs(vg, x0, max_iter, tol, history=10, max_ls=20):
+    """minimize_lbfgs's smooth path in numpy float64 with a Python line
+    search; returns (x, iterations, evaluations)."""
+    x = np.asarray(x0, np.float64)
+    f, g = (np.asarray(v, np.float64) for v in vg(x))
+    pairs, evals, it = [], 1, 0
+    while it < max_iter:
+        q, alphas = g.copy(), []
+        for s, yv in reversed(pairs):
+            a = (s @ q) / (s @ yv)
+            alphas.append(a)
+            q = q - a * yv
+        if pairs:
+            s, yv = pairs[-1]
+            q = q * ((s @ yv) / (yv @ yv))
+        for (s, yv), a in zip(pairs, reversed(alphas)):
+            q = q + (a - (yv @ q) / (s @ yv)) * s
+        d, deriv = -q, g @ -q
+        if deriv >= 0:
+            d, deriv = -g, -(g @ g)
+        t = 1.0 / max(np.linalg.norm(g), 1.0) if not pairs else 1.0
+        ok = False
+        for _ in range(max_ls):
+            x_new = x + t * d
+            f_new, g_new = (np.asarray(v, np.float64) for v in vg(x_new))
+            evals += 1
+            ok = f_new <= f + 1e-4 * t * deriv
+            t *= 0.5
+            if ok:
+                break
+        it += 1
+        if not ok:
+            break
+        s, yv = x_new - x, g_new - g
+        if s @ yv > 1e-10:
+            pairs = (pairs + [(s, yv)])[-history:]
+        done = abs(f - f_new) <= tol * max(abs(f_new), 1.0) or np.max(np.abs(g_new)) <= tol
+        x, f, g = x_new, f_new, g_new
+        if done:
+            break
+    return x, it, evals
+
+
+def test_n_evals_equals_a_python_side_line_searchs_count():
+    def vg64(x):
+        f = 0.5 * (_SCALES * x * x).sum() + 0.25 * ((x - 1.0) ** 4).sum()
+        return f, _SCALES * x + (x - 1.0) ** 3
+
+    with jax.enable_x64(True):
+        x0 = jnp.full((12,), 2.0, jnp.float64)
+        out = lbfgs.minimize_lbfgs(_quartic, x0, jnp.zeros_like(x0), max_iter=40, tol=1e-12)
+        x, n_iter, n_evals = np.asarray(out.x), int(out.n_iter), int(out.n_evals)
+    x_ref, it_ref, evals_ref = _reference_lbfgs(vg64, np.full(12, 2.0), 40, 1e-12)
+    assert (n_iter, n_evals) == (it_ref, evals_ref)
+    assert n_evals >= n_iter + 1
+    np.testing.assert_allclose(x, x_ref, rtol=1e-9, atol=1e-12)
+
+
+def _lbfgs_without_the_count(vg, x0, max_iter, tol, history=10, max_ls=20):
+    """The loop of minimize_lbfgs (smooth path) as it was before n_evals rode
+    its state: the twin the carry is compared with, bit for bit."""
+    P, dtype = x0.shape[0], x0.dtype
+    f0, g0 = vg(x0)
+    state = (
+        x0, f0, g0, jnp.zeros((history, P), dtype), jnp.zeros((history, P), dtype),
+        jnp.zeros((history,), dtype), jnp.array(0, jnp.int32), jnp.array(0, jnp.int32), jnp.array(False),
+    )
+
+    def body(state):
+        x, f, g, S, Y, rho, count, it, _ = state
+        d = -lbfgs._two_loop(g, S, Y, rho, count, history)
+        deriv = g @ d
+        bad = deriv >= 0
+        d = jnp.where(bad, -g, d)
+        deriv = jnp.where(bad, -(g @ g), deriv)
+        t0 = jnp.where(count == 0, 1.0 / jnp.maximum(jnp.linalg.norm(g), 1.0), 1.0).astype(dtype)
+
+        def ls_body(ls):
+            t, _, _, _, n_ls, _ = ls
+            x_new = x + t * d
+            f_new, g_new = vg(x_new)
+            return (t * 0.5, x_new, f_new, g_new, n_ls + 1, f_new <= f + 1e-4 * t * deriv)
+
+        _, x_new, f_new, g_new, _, ok = jax.lax.while_loop(
+            lambda ls: (~ls[5]) & (ls[4] < max_ls), ls_body,
+            (t0, x, f, g, jnp.array(0, jnp.int32), jnp.array(False)),
+        )
+        x_new, f_new, g_new = jnp.where(ok, x_new, x), jnp.where(ok, f_new, f), jnp.where(ok, g_new, g)
+        s, y = x_new - x, g_new - g
+        sy = s @ y
+        store = sy > 1e-10
+        slot = jnp.mod(count, history)
+        S = jnp.where(store, S.at[slot].set(s), S)
+        Y = jnp.where(store, Y.at[slot].set(y), Y)
+        rho = jnp.where(store, rho.at[slot].set(1.0 / jnp.where(sy != 0, sy, 1.0)), rho)
+        converged = (
+            (jnp.abs(f - f_new) <= tol * jnp.maximum(jnp.abs(f_new), 1.0))
+            | (jnp.max(jnp.abs(g_new)) <= tol) | (~ok)
+        )
+        return (x_new, f_new, g_new, S, Y, rho, count + store.astype(jnp.int32), it + 1, converged)
+
+    out = jax.lax.while_loop(lambda st: (st[7] < max_iter) & (~st[8]), body, state)
+    return out[0], out[1], out[7]
+
+
+def test_theta_is_identical_with_and_without_the_carry():
+    X, y = _table()
+    X, y = jnp.asarray(X), jnp.asarray(y)
+
+    def vg(theta):
+        def loss(t):
+            z = X @ t
+            return (jnp.logaddexp(0.0, z) - y * z).mean() + 5e-4 * (t * t).sum()
+
+        return jax.value_and_grad(loss)(theta)
+
+    x0 = jnp.zeros((D,), jnp.float32)
+    with_count = lbfgs.minimize_lbfgs(vg, x0, jnp.zeros_like(x0), max_iter=25, tol=1e-30)
+    x, f, n_iter = jax.jit(_lbfgs_without_the_count, static_argnums=(0, 2))(vg, x0, 25, 1e-30)
+    assert int(with_count.n_iter) == int(n_iter)
+    assert np.asarray(with_count.x).tobytes() == np.asarray(x).tobytes()
+    assert np.asarray(with_count.f).tobytes() == np.asarray(f).tobytes()
+
+
+def test_batched_lanes_count_their_own_evaluations():
+    """A lane's n_evals is its solo run's: frozen lanes ride along uncounted."""
+    def lanes_vg(xs):
+        return jax.vmap(_quartic)(xs)
+
+    x0 = jnp.stack([jnp.full((12,), 2.0, jnp.float32), jnp.full((12,), 0.05, jnp.float32)])
+    both = lbfgs.minimize_lbfgs_batched(lanes_vg, x0, jnp.zeros_like(x0), max_iter=40, tol=1e-6)
+    solos = [
+        lbfgs.minimize_lbfgs(_quartic, x0[lane], jnp.zeros((12,), jnp.float32), max_iter=40, tol=1e-6)
+        for lane in range(2)
+    ]
+    lanes, solos = jax.device_get(((both.n_iter, both.n_evals), [(s.n_iter, s.n_evals) for s in solos]))
+    assert [(int(i), int(e)) for i, e in zip(*lanes)] == [(int(i), int(e)) for i, e in solos]
+    assert all(e >= i + 1 for i, e in solos)
+
+
+# -- named scopes --------------------------------------------------------------
+
+
+def test_lowered_solvers_carry_the_scope_names():
+    X = jnp.ones((64, 8), jnp.float32)
+    w = jnp.ones((64,), jnp.float32)
+    lloyd = lloyd_iterations.lower(X, w, jnp.ones((4, 8), jnp.float32), get_mesh(2), 3, 0.0, 16)
+    text = lloyd.as_text(debug_info=True)
+    for scope in ("lloyd.norms", "lloyd.assign", "lloyd.update", "lloyd.inertia"):
+        assert scope in text, scope
+    logistic = logistic_fit_kernel.lower(X, w, w, 1, 0.0, 0.0, True, 5, 1e-6, False)
+    text = logistic.as_text(debug_info=True)
+    for scope in ("lbfgs.eval", "lbfgs.direction"):
+        assert scope in text, scope

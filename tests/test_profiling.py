@@ -6,6 +6,7 @@
 import json
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -277,6 +278,28 @@ def test_trace_session_writes_valid_chrome_trace(tmp_path, monkeypatch):
     assert by["t.a"]["args"]["rows"] == 4
     # session tag is sanitized into the filename
     assert os.path.basename(path).startswith("unit-sess-")
+
+
+def test_trace_session_records_its_clock_beside_the_wall_clock(tmp_path, monkeypatch):
+    """What lays a span file over an xprof capture of the same run: the span
+    clock's epoch, and its reading beside the wall clock at both ends."""
+    monkeypatch.setenv(profiling.TRACE_ENV, str(tmp_path))
+    wall0 = time.time()
+    with profiling.trace_session("clock") as path:
+        with profiling.span("t.clock"):
+            time.sleep(0.002)
+    with open(path) as f:
+        doc = json.load(f)
+    clock = doc["metadata"]["clock"]
+    start, end = clock["start"], clock["end"]
+    assert wall0 <= start["unix_s"] <= end["unix_s"] <= time.time()
+    # one offset between the two clocks, read twice
+    assert (end["unix_s"] - end["perf_counter_s"]) == pytest.approx(
+        start["unix_s"] - start["perf_counter_s"], abs=5e-3
+    )
+    (event,) = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+    at = clock["epoch_perf_counter_s"] + event["ts"] * 1e-6    # back on the span clock
+    assert start["perf_counter_s"] <= at <= end["perf_counter_s"]
 
 
 # -- telemetry snapshots ------------------------------------------------------

@@ -365,6 +365,52 @@ def test_phase_memory_attribution_with_injected_sampler(fresh_recorder):
     assert "mem.host" in telem  # RSS watermark always available
 
 
+def _counting_sampler(rec):
+    calls = []
+
+    def sampler():
+        calls.append(profiling.now())
+        return (100.0 + len(calls), 200.0 + len(calls))
+
+    rec.set_memory_sampler(sampler)
+    return calls
+
+
+def test_nested_spans_take_no_memory_sample_and_the_outermost_takes_two(
+    fresh_recorder,
+):
+    calls = _counting_sampler(fresh_recorder)
+    before = profiling.counter("watch.mem_samples")
+    with profiling.span("w.mem.outer"):
+        for _ in range(3):
+            with profiling.span("w.mem.inner"):
+                with profiling.span("w.mem.innermost"):
+                    pass
+    assert len(calls) == 2
+    assert profiling.counter("watch.mem_samples") - before == 2
+    assert set(fresh_recorder.phase_memory()) == {"w.mem.outer"}
+
+
+def test_spans_that_tile_a_thread_share_the_sample_at_their_boundary(
+    fresh_recorder, monkeypatch
+):
+    calls = _counting_sampler(fresh_recorder)
+    for name in ("w.mem.a", "w.mem.b", "w.mem.c"):
+        with profiling.span(name):
+            pass
+    # a's open and one close each: b and c open on their neighbour's close
+    assert len(calls) == 4
+    # the snapshot a fit takes right after a close rides that close's sample
+    telem = fresh_recorder.telemetry_memory()
+    assert len(calls) == 4 and telem["mem.hbm"]["peak_bytes"] == 204.0
+    assert set(fresh_recorder.phase_memory()) == {"w.mem.a", "w.mem.b", "w.mem.c"}
+    # an opening further than _MEM_SHARE_S from the last sample reads anew
+    monkeypatch.setattr(watch, "_MEM_SHARE_S", 0.0)
+    with profiling.span("w.mem.d"):
+        pass
+    assert len(calls) == 6
+
+
 def test_telemetry_snapshot_carries_and_merges_memory(fresh_recorder):
     rec = fresh_recorder
     rec.set_memory_sampler(lambda: (10.0, 20.0))
