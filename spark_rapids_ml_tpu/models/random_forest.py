@@ -30,7 +30,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..core import FitInputs, _TpuEstimatorSupervised, _TpuModelWithPredictionCol
+from ..core import (
+    FitInputs,
+    _TpuEstimatorSupervised,
+    _TpuModelWithPredictionCol,
+    fetch_fit_result,
+)
 from ..dataframe import DataFrame
 from .linear_regression import _RegressionModelEvaluationMixIn
 from .logistic_regression import _ClassificationModelEvaluationMixIn
@@ -53,8 +58,11 @@ from .. import profiling
 from ..ops.forest import (
     bin_features,
     bin_features_feature_major,
+    bootstrap_chunks,
+    bootstrap_counts,
+    bootstrap_weights,
+    _bin_edges_device_kernel,
     compute_bin_edges,
-    compute_bin_edges_device,
     forest_predict_cached,
     grow_forest,
     warm_forest_kernels,
@@ -166,7 +174,7 @@ def _binning_sample(inputs: FitInputs) -> np.ndarray:
 def _binning_sample_device(inputs: FitInputs):
     """Single-rank TPU path: the strided binning sample STAYS ON DEVICE
     (same row selection as _binning_sample) so the quantile edges can be
-    computed there (ops/forest.compute_bin_edges_device) and only the
+    computed there (ops/forest._bin_edges_device_kernel) and only the
     (D, B-1) edge matrix crosses the host link.  Returns None when the
     fit is multi-rank/multi-shard or non-f32 — those keep the host
     gather path."""
@@ -180,8 +188,14 @@ def _binning_sample_device(inputs: FitInputs):
     shard_pairs = list(_aligned_shard_objs(X, w))
     if len(shard_pairs) != 1:
         return None
-    sx, sw = shard_pairs[0]
-    idx = _binning_rows(sw.data, _binning_quota(X, 1))
+    sx, _ = shard_pairs[0]
+    # the valid rows from the ingest's HOST weights (none given: the frame's
+    # n_rows rows): reading the device's mask back would block every fit
+    host_w = (
+        inputs.host_w if inputs.host_w is not None
+        else np.ones(inputs.n_rows, np.float32)
+    )
+    idx = _binning_rows(host_w, _binning_quota(X, 1))
     if idx.size == 0:
         return None
     return sx.data[jnp.asarray(idx)]
@@ -195,9 +209,7 @@ def _per_tree_stats(stats, weight, key, n_trees, bootstrap):
     on every device — and is not expressible at all on a multi-process
     mesh)."""
     if bootstrap:
-        bw = jax.random.poisson(key, 1.0, (n_trees, weight.shape[0])).astype(
-            weight.dtype
-        )
+        bw = bootstrap_counts(key, n_trees, weight.shape[0]).astype(weight.dtype)
         w_t = weight[None, :] * bw
     else:
         w_t = jnp.broadcast_to(weight[None, :], (n_trees, weight.shape[0]))
@@ -236,10 +248,9 @@ def _mxu_eligible(inputs, n_bins, max_features, max_depth, s_split) -> bool:
     )
 
 
-def _maybe_grow_mxu(
+def _grow_mxu_device(
     inputs,
     bins_fm,        # (D, n_pad) int8 feature-major (bin_features_feature_major)
-    edges,
     stats,
     n_trees,
     bootstrap,
@@ -253,10 +264,12 @@ def _maybe_grow_mxu(
     min_samples_leaf,
     min_impurity_decrease,
 ):
-    """Grow on the MXU histogram builder.  Caller has already checked
-    _mxu_eligible and binned feature-major — the row-major int bin matrix
-    this path used to re-lay-out was a redundant 1.2-4.8 GB resident copy
-    that tipped the depth-13 benchmark fit over HBM."""
+    """Grow on the MXU histogram builder: every dispatch of the growth, and
+    nothing read back.  Returns (tree_buf, plan) for core.fetch_fit_result
+    and forest_mxu.pack_forest.  Caller has already checked _mxu_eligible
+    and binned feature-major — the row-major int bin matrix this path used
+    to re-lay-out was a redundant 1.2-4.8 GB resident copy that tipped the
+    depth-13 benchmark fit over HBM."""
     from ..ops import forest_mxu
 
     n_pad = bins_fm.shape[1]
@@ -277,20 +290,35 @@ def _maybe_grow_mxu(
         # stats rows are (1, y, y^2)*mask; split search needs only (w, wy)
         base_stats, stats3 = st_fm[:2], st_fm
         y_vals = st_fm[1]
-    key = jax.random.PRNGKey((seed + 104729) & 0x7FFFFFFF)
     if bootstrap:
-        bw = jax.random.poisson(key, 1.0, (n_trees, n_pad)).astype(w_pad.dtype)
-        w_trees = w_pad[None, :] * bw
+        w_trees = w_pad[None, :] * bootstrap_weights(
+            _bootstrap_draw(seed, n_trees, n_pad, 0)
+        )
     else:
         w_trees = jnp.broadcast_to(w_pad[None, :], (n_trees, n_pad))
-    return forest_mxu.grow_forest_mxu(
-        bins_fm, base_stats, w_trees, stats3, edges,
+    return forest_mxu.grow_forest_mxu_device(
+        bins_fm, base_stats, w_trees, stats3,
         max_depth=max_depth, n_bins=n_bins, kind=kind,
         max_features=int(max_features),
         min_samples_leaf=min_samples_leaf,
         min_impurity_decrease=min_impurity_decrease,
-        seed=seed, y_vals=y_vals,
+        seed=seed, y_vals=y_vals, n_rows=inputs.n_rows,
+        # the kernels run through the interpreter anywhere but on the chip
+        interpret=jax.default_backend() != "tpu",
     )
+
+
+def _bootstrap_draw(seed: int, n_trees: int, n_rows: int, tree_chunk: int) -> np.ndarray:
+    """A model's bootstrap_draw_: what ops/forest.bootstrap_weights needs to
+    draw the fit's (tree, row) bootstrap counts again."""
+    return np.array([seed, n_trees, n_rows, tree_chunk], np.int64)
+
+
+def _draw_attr(draw) -> Dict[str, np.ndarray]:
+    """The model attribute a bootstrapped fit keeps (32 bytes: what
+    ops/forest.bootstrap_weights draws the (tree, row) counts again from);
+    nothing for a fit without bootstrap, or a model built from arrays."""
+    return {} if draw is None else {"bootstrap_draw_": np.asarray(draw, np.int64)}
 
 
 class _RandomForestClass(_TpuParams):
@@ -476,117 +504,8 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
         logger = get_logger(type(self))
         is_classification = self._is_classification
 
-        def _single_fit(
-            inputs: FitInputs, params: Dict[str, Any], get_bins, edges, stats, extra_attrs
-        ) -> Dict[str, Any]:
-            max_depth = int(params["max_depth"])
-            if max_depth > _MAX_SUPPORTED_DEPTH:
-                raise ValueError(
-                    f"maxDepth > {_MAX_SUPPORTED_DEPTH} is not supported by the dense "
-                    f"TPU tree layout (got {max_depth})"
-                )
-            n_trees = int(params["n_estimators"])
-            n_bins = int(params["n_bins"])
-            criterion = params.get("split_criterion")
-            kind = (
-                "regression"
-                if not is_classification
-                else ("entropy" if criterion == "entropy" else "gini")
-            )
-            max_features = _resolve_max_features(
-                params.get("max_features", "auto"),
-                inputs.n_cols,
-                is_classification,
-                n_trees,
-            )
-            seed = params.get("random_state")
-            seed = int(seed) & 0x7FFFFFFF if seed is not None else 42
-            bootstrap = bool(params.get("bootstrap", True))
-            grow_kwargs = dict(
-                max_depth=max_depth,
-                n_bins=n_bins,
-                kind=kind,
-                max_features=max_features,
-                min_samples_leaf=float(params.get("min_samples_leaf", 1)),
-                min_impurity_decrease=float(
-                    params.get("min_impurity_decrease", 0.0)
-                ),
-            )
-            key = jax.random.PRNGKey(seed)
-            s_split = 2 if not is_classification else stats.shape[1]
-            if _mxu_eligible(inputs, n_bins, max_features, max_depth, s_split):
-                mxu = _maybe_grow_mxu(
-                    inputs, get_bins("fm", edges), edges, stats, n_trees,
-                    bootstrap, seed, is_classification, **grow_kwargs,
-                )
-                features, thresholds, leaf_values, node_counts, impurities = mxu
-                logger.info(
-                    "grew %d trees on the MXU histogram path (depth<=%d, "
-                    "bins=%d)", n_trees, max_depth, n_bins,
-                )
-                attrs = {
-                    "features_": features,
-                    "thresholds_": thresholds,
-                    "leaf_values_": leaf_values,
-                    "node_counts_": node_counts,
-                    "impurities_": impurities,
-                    "max_depth": max_depth,
-                    "n_cols": inputs.n_cols,
-                    "dtype": str(inputs.dtype),
-                }
-                attrs.update(extra_attrs)
-                return attrs
-            # Mesh-parallel engine growth (ops/forest.grow_forest): trees
-            # ride the scan-batched level-block kernels in CHUNKS sized so
-            # the (combined, D) per-node feature-subset scores at the
-            # deepest level and the (Tc, N, S) per-tree stats tensor each
-            # stay within budget.  The old per-tree grow_tree fallback —
-            # one host level-loop per tree plus five np.asarray device
-            # fetches per tree when stacking — is gone: a chunk of ONE
-            # tree still runs the batched engine with its single fetch.
-            n_pad = inputs.X.shape[0]
-            t_sub = (
-                max(1, (512 << 20) // max(1, (2**max_depth) * inputs.n_cols * 4))
-                if max_features < inputs.n_cols
-                else n_trees
-            )
-            t_stats = max(1, (2 << 30) // max(1, n_pad * stats.shape[1] * 4))
-            t_chunk = max(1, min(n_trees, t_sub, t_stats))
-            # stage the level-block kernel compiles on the precompile pool
-            # BEFORE binning runs, so XLA compiles while rows are binned.
-            # The tree count rides every kernel aval shape, so a partial
-            # final chunk is its own geometry — warm it too, or its blocks
-            # cold-compile serially at the end of the fit
-            warm_forest_kernels(
-                n_pad, inputs.n_cols, t_chunk, stats.shape[1],
-                mesh=inputs.mesh, dtype=stats.dtype, **grow_kwargs,
-            )
-            t_rem = n_trees % t_chunk
-            if t_rem:
-                warm_forest_kernels(
-                    n_pad, inputs.n_cols, t_rem, stats.shape[1],
-                    mesh=inputs.mesh, dtype=stats.dtype, **grow_kwargs,
-                )
-            Xb = get_bins("rm", edges)
-            parts = []
-            for t0 in range(0, n_trees, t_chunk):
-                tc = min(t_chunk, n_trees - t0)
-                key, kt = jax.random.split(key)
-                stats_t = _per_tree_stats(stats, inputs.weight, kt, tc, bootstrap)
-                parts.append(
-                    grow_forest(
-                        Xb, stats_t, edges,
-                        seed=(seed + 7919 * t0) & 0x7FFFFFFF,
-                        mesh=inputs.mesh, **grow_kwargs,
-                    )
-                )
-            if len(parts) == 1:
-                features, thresholds, leaf_values, node_counts, impurities = parts[0]
-            else:
-                features, thresholds, leaf_values, node_counts, impurities = (
-                    np.concatenate([p[i] for p in parts]) for i in range(5)
-                )
-            logger.info("grew %d trees (depth<=%d, bins=%d)", n_trees, max_depth, n_bins)
+        def _attrs(forest, inputs, max_depth, extra_attrs, draw):
+            features, thresholds, leaf_values, node_counts, impurities = forest
             attrs = {
                 "features_": features,
                 "thresholds_": thresholds,
@@ -596,9 +515,141 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                 "max_depth": max_depth,
                 "n_cols": inputs.n_cols,
                 "dtype": str(inputs.dtype),
+                "bootstrap_draw_": draw,
             }
             attrs.update(extra_attrs)
             return attrs
+
+        # The step spans of _fit and _single_fit tile srml.fit
+        # (core._call_tpu_fit_func): init (edges, label stats, binning,
+        # bootstrap draw), solve (growth: dispatches only on the MXU
+        # builder), wait + fetch (core.fetch_fit_result: ONE batched fetch
+        # of the forest), pack.
+        def _single_fit(
+            inputs: FitInputs, params: Dict[str, Any], get_bins, edges, stats, extra_attrs
+        ) -> Dict[str, Any]:
+            with profiling.span("srml.fit.init"):
+                max_depth = int(params["max_depth"])
+                if max_depth > _MAX_SUPPORTED_DEPTH:
+                    raise ValueError(
+                        f"maxDepth > {_MAX_SUPPORTED_DEPTH} is not supported by the dense "
+                        f"TPU tree layout (got {max_depth})"
+                    )
+                n_trees = int(params["n_estimators"])
+                n_bins = int(params["n_bins"])
+                criterion = params.get("split_criterion")
+                kind = (
+                    "regression"
+                    if not is_classification
+                    else ("entropy" if criterion == "entropy" else "gini")
+                )
+                max_features = _resolve_max_features(
+                    params.get("max_features", "auto"),
+                    inputs.n_cols,
+                    is_classification,
+                    n_trees,
+                )
+                seed = params.get("random_state")
+                seed = int(seed) & 0x7FFFFFFF if seed is not None else 42
+                bootstrap = bool(params.get("bootstrap", True))
+                grow_kwargs = dict(
+                    max_depth=max_depth,
+                    n_bins=n_bins,
+                    kind=kind,
+                    max_features=max_features,
+                    min_samples_leaf=float(params.get("min_samples_leaf", 1)),
+                    min_impurity_decrease=float(
+                        params.get("min_impurity_decrease", 0.0)
+                    ),
+                )
+                s_split = 2 if not is_classification else stats.shape[1]
+                mxu = _mxu_eligible(inputs, n_bins, max_features, max_depth, s_split)
+                profiling.incr_counter("forest.fits")
+                if mxu:
+                    bins_fm = get_bins("fm", edges)
+                    n_pad_fm = bins_fm.shape[1]
+            if mxu:
+                with profiling.span("srml.fit.solve"):
+                    buf, plan = _grow_mxu_device(
+                        inputs, bins_fm, stats, n_trees, bootstrap, seed,
+                        is_classification, **grow_kwargs,
+                    )
+                # the edges go with the forest: one read of the device a fit
+                profiling.incr_counter("forest.host_syncs")
+                buf_h, edges_h = fetch_fit_result((buf, jnp.asarray(edges)))
+                with profiling.span("srml.fit.pack"):
+                    from ..ops.forest_mxu import pack_forest
+
+                    del buf, bins_fm
+                    forest = pack_forest(buf_h, plan, edges_h)
+                    logger.info(
+                        "grew %d trees on the MXU histogram path (depth<=%d, "
+                        "bins=%d)", n_trees, max_depth, n_bins,
+                    )
+                    draw = (
+                        _bootstrap_draw(seed, n_trees, n_pad_fm, 0)
+                        if bootstrap else None
+                    )
+                    return _attrs(forest, inputs, max_depth, extra_attrs, draw)
+            # Mesh-parallel engine growth (ops/forest.grow_forest): trees
+            # ride the scan-batched level-block kernels in CHUNKS sized so
+            # the (combined, D) per-node feature-subset scores at the
+            # deepest level and the (Tc, N, S) per-tree stats tensor each
+            # stay within budget; a chunk of ONE tree still runs the batched
+            # engine with its single fetch.
+            with profiling.span("srml.fit.init"):
+                if not isinstance(edges, np.ndarray):
+                    profiling.incr_counter("forest.host_syncs")
+                    edges = np.asarray(edges)
+                n_pad = inputs.X.shape[0]
+                t_sub = (
+                    max(1, (512 << 20) // max(1, (2**max_depth) * inputs.n_cols * 4))
+                    if max_features < inputs.n_cols
+                    else n_trees
+                )
+                t_stats = max(1, (2 << 30) // max(1, n_pad * stats.shape[1] * 4))
+                t_chunk = max(1, min(n_trees, t_sub, t_stats))
+                # stage the level-block kernel compiles on the precompile pool
+                # BEFORE binning runs, so XLA compiles while rows are binned.
+                # The tree count rides every kernel aval shape, so a partial
+                # final chunk is its own geometry — warm it too, or its blocks
+                # cold-compile serially at the end of the fit
+                warm_forest_kernels(
+                    n_pad, inputs.n_cols, t_chunk, stats.shape[1],
+                    mesh=inputs.mesh, dtype=stats.dtype, **grow_kwargs,
+                )
+                t_rem = n_trees % t_chunk
+                if t_rem:
+                    warm_forest_kernels(
+                        n_pad, inputs.n_cols, t_rem, stats.shape[1],
+                        mesh=inputs.mesh, dtype=stats.dtype, **grow_kwargs,
+                    )
+                Xb = get_bins("rm", edges)
+            with profiling.span("srml.fit.solve"):
+                draw = _bootstrap_draw(seed, n_trees, n_pad, t_chunk)
+                parts = []
+                for t0, tc, kt in bootstrap_chunks(draw):
+                    stats_t = _per_tree_stats(stats, inputs.weight, kt, tc, bootstrap)
+                    parts.append(
+                        grow_forest(
+                            Xb, stats_t, edges,
+                            seed=(seed + 7919 * t0) & 0x7FFFFFFF,
+                            mesh=inputs.mesh, **grow_kwargs,
+                        )
+                    )
+            with profiling.span("srml.fit.pack"):
+                if len(parts) == 1:
+                    forest = parts[0]
+                else:
+                    forest = tuple(
+                        np.concatenate([p[i] for p in parts]) for i in range(5)
+                    )
+                profiling.incr_counter(
+                    "forest.nodes",
+                    int(2 * (np.asarray(forest[0]) >= 0).sum() + n_trees),
+                )
+                logger.info("grew %d trees (depth<=%d, bins=%d)", n_trees, max_depth, n_bins)
+                return _attrs(forest, inputs, max_depth, extra_attrs, draw if bootstrap else None)
 
         def _fit(inputs: FitInputs, params: Dict[str, Any]):
             assert inputs.y is not None
@@ -607,17 +658,25 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
             # np.asarray(inputs.X) round-trips the whole dataset over the
             # host link — 4.8 GB at the benchmark shape — and raises
             # outright multi-process).  Single-rank TPU fits keep the
-            # sample on device and sort there (only the 1.5 MB edge
-            # matrix crosses the link); multi-rank/CPU fits take the host
-            # gather path.
+            # sample AND the edges on the device (the 1.5 MB edge matrix
+            # crosses the link with the finished forest); multi-rank/CPU
+            # fits take the host gather path.
             X_host = None
-            with profiling.phase("forest.bin"):
-                sample_dev = _binning_sample_device(inputs)
+
+            def edges_for(bins: int):
                 if sample_dev is not None:
-                    edges = compute_bin_edges_device(sample_dev, n_bins)
-                else:
-                    X_host = _binning_sample(inputs)
-                    edges = compute_bin_edges(X_host, n_bins)
+                    return _bin_edges_device_kernel(
+                        sample_dev, n_bins=bins, n_cols=sample_dev.shape[1]
+                    )
+                return compute_bin_edges(X_host, bins)
+
+            with profiling.span("srml.fit.init"):
+                with profiling.span("forest.edges"):
+                    sample_dev = _binning_sample_device(inputs)
+                    if sample_dev is None:
+                        profiling.incr_counter("forest.host_syncs")
+                        X_host = _binning_sample(inputs)
+                    edges = edges_for(n_bins)
 
             # Lazy per-route binning: the MXU route bins straight into the
             # feature-major int8 layout (bin_features_feature_major), the
@@ -636,7 +695,7 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                     return cached[1]
                 if any(held[0] is not e for held in bins_cache.values()):
                     bins_cache.clear()  # new edges: old matrices are dead
-                with profiling.phase("forest.bin"):
+                with profiling.span("forest.bin"):
                     if layout == "fm":
                         from ..ops.forest_hist import _ROW_TILE
 
@@ -650,27 +709,20 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                 bins_cache[layout] = (e, out)
                 return out
 
-            stats, extra_attrs = self._label_stats(inputs)
+            with profiling.span("srml.fit.init"):
+                stats, extra_attrs = self._label_stats(inputs)
             if extra_params:
                 results = []
                 for override in extra_params:
                     p = dict(params)
                     p.update(override)
+                    e = edges
                     if int(p["n_bins"]) != n_bins:
-                        e2 = (
-                            compute_bin_edges_device(
-                                sample_dev, int(p["n_bins"])
-                            )
-                            if sample_dev is not None
-                            else compute_bin_edges(X_host, int(p["n_bins"]))
-                        )
-                        results.append(
-                            _single_fit(inputs, p, get_bins, e2, stats, extra_attrs)
-                        )
-                    else:
-                        results.append(
-                            _single_fit(inputs, p, get_bins, edges, stats, extra_attrs)
-                        )
+                        with profiling.span("srml.fit.init"):
+                            e = edges_for(int(p["n_bins"]))
+                    results.append(
+                        _single_fit(inputs, p, get_bins, e, stats, extra_attrs)
+                    )
                 return results
             return _single_fit(inputs, params, get_bins, edges, stats, extra_attrs)
 
@@ -935,6 +987,7 @@ class RandomForestClassificationModel(
         dtype: str,
         classes_: np.ndarray,
         num_classes: int,
+        bootstrap_draw_: Optional[np.ndarray] = None,
     ) -> None:
         super().__init__(
             features_=np.asarray(features_),
@@ -947,7 +1000,9 @@ class RandomForestClassificationModel(
             dtype=str(dtype),
             classes_=np.asarray(classes_),
             num_classes=int(num_classes),
+            **_draw_attr(bootstrap_draw_),
         )
+        self.bootstrap_draw_ = self._model_attributes.get("bootstrap_draw_")
         self.features_ = np.asarray(features_)
         self.thresholds_ = np.asarray(thresholds_)
         self.leaf_values_ = np.asarray(leaf_values_)
@@ -1086,6 +1141,7 @@ class RandomForestRegressionModel(
         max_depth: int,
         n_cols: int,
         dtype: str,
+        bootstrap_draw_: Optional[np.ndarray] = None,
     ) -> None:
         super().__init__(
             features_=np.asarray(features_),
@@ -1096,7 +1152,9 @@ class RandomForestRegressionModel(
             max_depth=int(max_depth),
             n_cols=int(n_cols),
             dtype=str(dtype),
+            **_draw_attr(bootstrap_draw_),
         )
+        self.bootstrap_draw_ = self._model_attributes.get("bootstrap_draw_")
         self.features_ = np.asarray(features_)
         self.thresholds_ = np.asarray(thresholds_)
         self.leaf_values_ = np.asarray(leaf_values_)

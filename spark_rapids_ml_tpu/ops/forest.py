@@ -55,7 +55,7 @@ from __future__ import annotations
 
 import os
 from functools import partial
-from typing import NamedTuple, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -144,6 +144,37 @@ def compute_bin_edges_device(sample_dev: jax.Array, n_bins: int) -> np.ndarray:
             sample_dev, n_bins=n_bins, n_cols=sample_dev.shape[1]
         )
     )
+
+
+def bootstrap_chunks(draw) -> List[Tuple[int, int, jax.Array]]:
+    """[(first tree, trees, key)]: the key chain of a fit's bootstrap draw, from
+    the model's `bootstrap_draw_` = (seed, n_trees, n_rows, tree_chunk).
+    tree_chunk 0 is the MXU builder's single draw of all trees; otherwise one
+    split of the fit's key for every chunk of trees the mesh engine grows
+    together (the chunking shapes the stream, so the model keeps it)."""
+    seed, n_trees, _n_rows, tree_chunk = (int(v) for v in np.asarray(draw))
+    if tree_chunk == 0:
+        return [(0, n_trees, jax.random.PRNGKey((seed + 104729) & 0x7FFFFFFF))]
+    key, chunks = jax.random.PRNGKey(seed), []
+    for t0 in range(0, n_trees, tree_chunk):
+        key, kt = jax.random.split(key)
+        chunks.append((t0, min(tree_chunk, n_trees - t0), kt))
+    return chunks
+
+
+def bootstrap_counts(key: jax.Array, n_trees: int, n_rows: int) -> jax.Array:
+    """(n_trees, n_rows) float32 Poisson(1) counts of one key."""
+    return jax.random.poisson(key, 1.0, (n_trees, n_rows)).astype(jnp.float32)
+
+
+def bootstrap_weights(draw) -> jax.Array:
+    """(n_trees, n_rows) float32 Poisson(1) bootstrap counts of a forest fit,
+    drawn again from the model's `bootstrap_draw_`.  bootstrap_chunks and
+    bootstrap_counts are the ONE rule of the draw: both builders draw through
+    them (the mesh engine chunk by chunk, sharded inside its jit), and so does
+    a check.  n_rows counts the padding rows too; their mask is the caller's."""
+    n_rows = int(np.asarray(draw)[2])
+    return jnp.concatenate([bootstrap_counts(k, tc, n_rows) for _t0, tc, k in bootstrap_chunks(draw)])
 
 
 @jax.jit

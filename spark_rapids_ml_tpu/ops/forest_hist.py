@@ -171,29 +171,41 @@ def node_histograms(
             (_F_BLOCK, M_SLOTS, n_bins), lambda f, k: (f, 0, 0)
         ),
         interpret=interpret,
+        name="forest_hist_shallow",
     )(bins_sub, node_rel, stats_s)
 
 
-# deep-phase row tile: buckets are padded to a multiple of this, so a finer
+# deep-phase row tile: segments are padded to a multiple of this, so a finer
 # tile keeps the padding overhead low (~6% at 1M rows / 128 buckets)
 _ROW_TILE_DEEP = 512
 
 
-def _hist_kernel_bucketed(
-    bins_ref,       # (_F_BLOCK, Kt) int8 — subset rows tile (bucket-sorted)
-    node_ref,       # (1, Kt) int32 bucket-LOCAL node ids (>= nodes -> masked)
+def _hist_kernel_segmented(
+    t0_ref,         # (1,) int32 scalar prefetch: first tree of this dispatch
+    seg_ref,        # (t_chunk * n_tiles,) int32 scalar prefetch: tile -> segment
+    bins_ref,       # (_F_BLOCK, Kt) int8 — subset rows tile (segment-sorted)
+    node_ref,       # (1, Kt) int32 segment-LOCAL node ids (>= nodes -> masked)
     stats_ref,      # (S, Kt) f32 stat rows
-    out_ref,        # (1, _F_BLOCK, slots_pad, B) f32
+    out_ref,        # (_F_BLOCK, slots_pad, B) f32: the tile's segment
     *,
     nodes: int,
     s_dim: int,
     slots_pad: int,
     n_bins: int,
     row_tile: int,
+    n_tiles: int,
 ):
+    del t0_ref  # read by the index maps only
+    t = pl.program_id(0)
     k = pl.program_id(2)
+    at = t * n_tiles + k
+    # a segment's tiles are contiguous: its output block stays resident
+    # while they stream, and is zeroed at the first of them
+    first = jnp.logical_or(
+        k == 0, seg_ref[at] != seg_ref[jnp.maximum(at - 1, 0)]
+    )
 
-    @pl.when(k == 0)
+    @pl.when(first)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -221,69 +233,86 @@ def _hist_kernel_bucketed(
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        out_ref[0, j, :, :] += acc
+        out_ref[j, :, :] += acc
 
 
 @partial(
     jax.jit,
-    static_argnames=("n_buckets", "nodes", "s_dim", "n_bins", "interpret"),
+    static_argnames=(
+        "t_chunk", "n_segs", "nodes", "s_dim", "n_bins", "f_pad", "interpret",
+    ),
 )
-def node_histograms_bucketed(
-    bins_sub: jax.Array,  # (F_pad, n_buckets * cap) int8, bucket-sorted rows
-    node_rel: jax.Array,  # (1, n_buckets * cap) int32 bucket-LOCAL node ids
-    stats_s: jax.Array,   # (S, n_buckets * cap) f32
-    n_buckets: int,
-    nodes: int,           # local nodes per bucket at this level
+def node_histograms_segmented(
+    bins_s: jax.Array,    # (T, F_all, n2) int8, rows sorted by segment
+    node_loc: jax.Array,  # (T, 1, n2) int32 segment-LOCAL node ids
+    stats_s: jax.Array,   # (T, S, n2) f32
+    tile_seg: jax.Array,  # (t_chunk * n_tiles,) int32 segment of each row tile
+    t0: jax.Array,        # () int32 first tree of the t_chunk window
+    t_chunk: int,
+    n_segs: int,          # segments a tree (the last one takes stray tiles)
+    nodes: int,           # local nodes per segment at this level
     s_dim: int,
     n_bins: int,
+    f_pad: int,           # leading feature rows to scan (multiple of _F_BLOCK)
     interpret: bool = False,
 ) -> jax.Array:
-    """Deep-phase histograms: rows grouped into `n_buckets` equal-length
-    contiguous buckets (one level-L_s subtree each); every bucket only pays
-    for its own <= 128 (local node, stat) slots.  Returns
-    (n_buckets, F_pad, slots_pad, B) f32."""
-    f_pad, n_tot = bins_sub.shape
-    assert n_tot % n_buckets == 0
-    cap = n_tot // n_buckets
-    assert cap % _ROW_TILE_DEEP == 0, "pad buckets to _ROW_TILE_DEEP"
-    assert f_pad % _F_BLOCK == 0
+    """Deep-phase histograms over a STATIC layout: every tree's rows lie
+    sorted by segment (one level-L_s subtree each), each segment padded to
+    whole _ROW_TILE_DEEP tiles, and `tile_seg` names each tile's segment.
+    The kernel walks a tree's tiles in order and accumulates into the
+    output block of the tile's segment, so segments of any length share one
+    executable: its geometry follows from (T, n2, f_pad, nodes) alone.
+    A segment that owns no tile is never written (garbage): the layout gives
+    every kept segment at least one.  Returns
+    (t_chunk * n_segs, f_pad, slots_pad, B) f32."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    T, f_all, n2 = bins_s.shape
+    assert n2 % _ROW_TILE_DEEP == 0, "pad rows to _ROW_TILE_DEEP"
+    assert f_pad % _F_BLOCK == 0 and f_pad <= f_all
+    n_tiles = n2 // _ROW_TILE_DEEP
+    assert tile_seg.shape == (t_chunk * n_tiles,)
     slots = nodes * s_dim
     assert slots <= M_SLOTS
     slots_pad = max(8, -(-slots // 8) * 8)
-    cap_k = cap // _ROW_TILE_DEEP
 
     kernel = partial(
-        _hist_kernel_bucketed,
+        _hist_kernel_segmented,
         nodes=nodes,
         s_dim=s_dim,
         slots_pad=slots_pad,
         n_bins=n_bins,
         row_tile=_ROW_TILE_DEEP,
+        n_tiles=n_tiles,
+    )
+    rows = lambda t, f, k, t0_ref, seg_ref: (t0_ref[0] + t, 0, k)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(t_chunk, f_pad // _F_BLOCK, n_tiles),
+        in_specs=[
+            pl.BlockSpec(
+                (None, _F_BLOCK, _ROW_TILE_DEEP),
+                lambda t, f, k, t0_ref, seg_ref: (t0_ref[0] + t, f, k),
+            ),
+            pl.BlockSpec((None, 1, _ROW_TILE_DEEP), rows),
+            pl.BlockSpec((None, stats_s.shape[1], _ROW_TILE_DEEP), rows),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, _F_BLOCK, slots_pad, n_bins),
+            lambda t, f, k, t0_ref, seg_ref: (
+                t * n_segs + seg_ref[t * n_tiles + k], f, 0, 0
+            ),
+        ),
     )
     return pl.pallas_call(
         kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (n_buckets, f_pad, slots_pad, n_bins), jnp.float32
-        ),
-        grid=(n_buckets, f_pad // _F_BLOCK, cap_k),
-        in_specs=[
-            pl.BlockSpec(
-                (_F_BLOCK, _ROW_TILE_DEEP),
-                lambda b, f, k: (f, b * cap_k + k),
-            ),
-            pl.BlockSpec(
-                (1, _ROW_TILE_DEEP), lambda b, f, k: (0, b * cap_k + k)
-            ),
-            pl.BlockSpec(
-                (stats_s.shape[0], _ROW_TILE_DEEP),
-                lambda b, f, k: (0, b * cap_k + k),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, _F_BLOCK, slots_pad, n_bins), lambda b, f, k: (b, f, 0, 0)
+            (t_chunk * n_segs, f_pad, slots_pad, n_bins), jnp.float32
         ),
         interpret=interpret,
-    )(bins_sub, node_rel, stats_s)
+        name="forest_hist_deep",
+    )(t0.reshape(1).astype(jnp.int32), tile_seg, bins_s, node_loc, stats_s)
 
 
 @partial(

@@ -1,40 +1,48 @@
 #
 # MXU forest builder: lock-step level-wise growth driven by the pallas
-# histogram kernel (ops/forest_hist.py).
+# histogram kernels (ops/forest_hist.py).
 #
 # Replaces the scatter-bound grow_forest path (ops/forest.py) on TPU for the
 # depths where every level's (node, stat) slots fit one 128-slot matmul
-# (2^level * s_dim <= 128).  Design notes:
+# (2^level * s_dim <= 128), and twice that depth plus one through the
+# segmented deep phase.  Design notes:
 #
 #   - Trees grow LOCK-STEP; at shallow levels several trees pack into one
 #     128-slot scan and share the streamed one-hot operand.
 #   - Feature subsets (featureSubsetStrategy) are sampled per (tree-group,
-#     level) — one subset shared by the <= 64 trees packed into a scan.
-#     cuML/Spark sample per node; per-(group, level) sampling keeps the
-#     de-correlation role (random-subspace forests, Ho 1998) while letting
-#     histogram work ride a single MXU operand.  Groups shrink to one tree
-#     by the depth where per-node sampling would matter most.
+#     level) in the shallow phase — one subset shared by the <= 64 trees
+#     packed into a scan — and ONCE PER TREE for all of its deep levels (the
+#     subset's rows ride the deep phase's payload sort).  cuML/Spark sample
+#     per node; this keeps the de-correlation role (random-subspace forests,
+#     Ho 1998) while letting histogram work ride a single MXU operand.
 #   - Regression split search uses only (w, w*y) histograms: the w*y^2 term
 #     cancels in the weighted variance gain (sum_c (wy_c)^2/w_c is monotone
 #     in it), halving slot usage; node impurities come from a per-node
-#     3-stat mini-scan.
-#   - Row routing is scatter-free: per level, the <= n_nodes chosen feature
-#     rows are selected by a tiny one-hot matmul and compared against each
+#     3-stat total.
+#   - Row routing is scatter- and gather-free: a node's split feature is
+#     selected by a one-hot against the subset rows and compared against the
 #     node's split bin under the node mask.
 #
-# Cold-fit compile protocol (round-2 verdict, weak item 3): every phase is
-# ONE fused jit per geometry — level steps carry a TRACED group/chunk offset
-# with a clamped window, so remainder groups reuse the same executable
-# instead of compiling their own — and every geometry the fit will dispatch
-# is enumerated up front and compiled in parallel through ops/precompile
-# (compilation for this backend is serviced outside the Python process, so
-# the wall cost is the slowest single kernel, not the sum of ~480 of them).
-# The deep phase's payload-sort width is a static bound derived from
-# (n_pad, n_buckets) alone so its ~45 s compile starts at fit entry and
-# overlaps the whole shallow phase.
+# EVERY EXECUTABLE FOLLOWS FROM THE FIT'S STATIC GEOMETRY
+# (n_pad, D, T, max_depth, n_bins, max_features, s_dim, kind): no shape, no
+# trip count and no dispatch depends on what the data did.  The deep phase
+# sorts each tree's rows ONCE by their ancestor at the bucket level (one
+# "segment" per (tree, bucket)), pads every segment to whole row tiles with
+# weight-0 filler rows that ride the sort, and hands the histogram kernel a
+# per-tile segment id (forest_hist.node_histograms_segmented): segments of
+# any length accumulate inside one kernel, so there are no size classes, no
+# per-class executables and no host round-trip for the segment lengths.  All
+# of it — counts, filler keys, tile map — is computed on the device; the
+# host's only read is ONE batched fetch of the finished forest.
 #
-# The returned dense tree arrays are identical in layout to grow_forest's,
-# so models/random_forest.py consumes either builder interchangeably.
+# Level steps carry a TRACED tree offset with a clamped window, so the last
+# (partial) tree group reuses the same executable, and every geometry is
+# submitted to ops/precompile at entry and compiled in parallel.
+#
+# Split records are written by the level steps into one device buffer of
+# (channel, tree, node) float32 (`tree_buf`); pack_forest turns its host
+# copy into grow_forest's dense arrays, so models/random_forest.py consumes
+# either builder interchangeably.
 #
 # Sharding: the histogram kernel's mesh rule lives in
 # forest_hist.node_histograms_sharded (per-shard pallas pass + one psum);
@@ -45,18 +53,15 @@
 
 from __future__ import annotations
 
-from functools import partial
-from typing import Tuple
+from functools import lru_cache, partial
+from typing import Any, List, NamedTuple, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-# _p2floor: deep-phase window sizes come from the engine's shared
-# power-of-two bucketing so kernel-geometry keys draw from a small,
-# dataset-independent universe the persistent compile cache can accumulate
-from .forest import _p2floor
+from .. import profiling
 from .forest_hist import (
     M_SLOTS,
     _F_BLOCK,
@@ -64,15 +69,20 @@ from .forest_hist import (
     _ROW_TILE_DEEP,
     gather_rows_matmul,
     node_histograms,
-    node_histograms_bucketed,
+    node_histograms_segmented,
 )
 from .precompile import aval, global_precompiler
 
-import logging
+# channels of tree_buf, the (channel, tree, node) float32 record the level
+# steps write: local split feature, split bin, split taken, node weight,
+# node impurity, then the node's V values
+_CH_F, _CH_B, _CH_OK, _CH_W, _CH_IMP, _CH_VAL = range(6)
 
-logger = logging.getLogger("spark_rapids_ml_tpu.forest_mxu")
-
-_LANE = _ROW_TILE
+# the deep phase's histogram budget a dispatch: sets how many trees a deep
+# level step takes (one at the last level of a 128-bucket, 128-bin fit)
+_DEEP_HIST_BYTES = 768 << 20
+# split-search intermediate (segments, S, local, f_pad, B) per lax.map step
+_SPLIT_CHUNK_BYTES = 64 << 20
 
 
 def _shallow_levels(s_dim: int) -> int:
@@ -84,11 +94,26 @@ def _shallow_levels(s_dim: int) -> int:
 
 
 def mxu_depth_supported(max_depth: int, s_dim: int) -> bool:
-    """Shallow phase hosts levels up to L_s; the bucketed deep phase covers
-    another L_s + 1 levels (one bucket per level-(L_s+1) node, each again
+    """Shallow phase hosts levels up to L_s; the segmented deep phase covers
+    another L_s + 1 levels (one segment per level-(L_s+1) node, each again
     bounded by the slot budget)."""
     l_s = _shallow_levels(s_dim)
     return max_depth <= 2 * l_s + 1
+
+
+@lru_cache(maxsize=1024)
+def _offset(value: int) -> jax.Array:
+    """A tree window's start as a device scalar, put there once a process: a
+    host-to-device copy in the level loop would wait for the steps queued
+    before it, and hold the host in step with the device."""
+    return jnp.asarray(np.int32(value))
+
+
+def _even_chunk(total: int, cap: int) -> int:
+    """Trees a step takes: the fewest windows of at most `cap` trees, of
+    even size, so the clamped last window overlaps as little as it can."""
+    cap = max(1, min(cap, total))
+    return -(-total // -(-total // cap))
 
 
 @partial(jax.jit, static_argnames=("tpack", "s_dim"))
@@ -147,30 +172,31 @@ def _split_from_hist(
         l_w = left.sum(axis=1)
         r_w = right.sum(axis=1)
         eps = 1e-12
-        pl_ = left / jnp.maximum(l_w, eps)[:, None]
-        pr_ = right / jnp.maximum(r_w, eps)[:, None]
-        if kind == "entropy":
-            l_imp = -(pl_ * jnp.log2(jnp.maximum(pl_, eps))).sum(axis=1)
-            r_imp = -(pr_ * jnp.log2(jnp.maximum(pr_, eps))).sum(axis=1)
-        else:  # gini
-            l_imp = 1.0 - (pl_ * pl_).sum(axis=1)
-            r_imp = 1.0 - (pr_ * pr_).sum(axis=1)
-        # parent impurity/weight from the per-node class totals folded into
-        # H: total over any feature == node class counts (feature 0 here)
+        # parent class counts from the per-node class totals folded into H:
+        # total over any feature == node class counts (feature 0 here)
         node_cls = hist[:, :, :, 0, :].sum(axis=-1)  # (tpack, S, nodes)
         node_cls = jnp.moveaxis(node_cls, 1, 2)      # (tpack, nodes, S)
         p_w = node_cls.sum(axis=2)
         pw_safe = jnp.maximum(p_w, eps)
         pp = node_cls / pw_safe[:, :, None]
         if kind == "entropy":
+            pl_ = left / jnp.maximum(l_w, eps)[:, None]
+            pr_ = right / jnp.maximum(r_w, eps)[:, None]
+            l_wimp = -(pl_ * jnp.log2(jnp.maximum(pl_, eps))).sum(axis=1) * l_w
+            r_wimp = -(pr_ * jnp.log2(jnp.maximum(pr_, eps))).sum(axis=1) * r_w
             p_imp = -(pp * jnp.log2(jnp.maximum(pp, eps))).sum(axis=2)
-        else:
-            p_imp = 1.0 - (pp * pp).sum(axis=2)
+            p_wimp = p_imp * p_w
+        else:  # gini
+            # weight x gini as sum_k c_k (w - c_k) / w: EXACTLY 0 for a pure
+            # node.  1 - sum p^2 is not, where the division is not exactly
+            # rounded (this chip's x / x can read 0.99999994), and a pure
+            # node would then "gain" rounding noise and split
+            l_wimp = (left * (l_w[:, None] - left)).sum(axis=1) / jnp.maximum(l_w, eps)
+            r_wimp = (right * (r_w[:, None] - right)).sum(axis=1) / jnp.maximum(r_w, eps)
+            p_wimp = (node_cls * (p_w[:, :, None] - node_cls)).sum(axis=2) / pw_safe
+            p_imp = p_wimp / pw_safe
         p_val = pp
-        gain = (
-            p_imp[:, :, None, None] * p_w[:, :, None, None]
-            - (l_imp * l_w + r_imp * r_w)
-        )
+        gain = p_wimp[:, :, None, None] - (l_wimp + r_wimp)
 
     ok_lr = (l_w >= min_samples_leaf) & (r_w >= min_samples_leaf)
     gain = jnp.where(ok_lr, gain, -jnp.inf)
@@ -247,30 +273,60 @@ def _pack_rows(sub: jax.Array, f_pad: int) -> jax.Array:
     return v[:, 0] | (v[:, 1] << 8) | (v[:, 2] << 16) | (v[:, 3] << 24)
 
 
-@partial(jax.jit, static_argnames=())
-def _unpack_rows(packed: jax.Array) -> jax.Array:
-    """(P, N) int32 -> (4P, N) int8 inverse of _pack_rows."""
-    p = packed[:, None, :]
-    parts = jnp.concatenate(
-        [(p >> (8 * i)) & 0xFF for i in range(4)], axis=1
-    )
-    return parts.reshape(-1, packed.shape[1]).astype(jnp.int8)
-
-
-# stray-slot sentinel for bucket-local node ids: large enough that 2*x+1
-# growth across every deep level stays far outside any local node range and
-# far below int32 overflow (local <= 64, <= 7 deep levels -> < 2^27)
-_STRAY = 1 << 18
-
 
 # ---------------------------------------------------------------------------
 # Fused per-geometry steps.  Each is ONE jit: the level loops dispatch these
-# (through the precompiler) and nothing else, so a cold fit compiles one
-# executable per geometry instead of one per op per chunk.  Group/chunk
-# offsets are TRACED with a clamped window: the last (partial) group shifts
-# its window back in-bounds and blends the overlap back unchanged, so
-# remainders reuse the same executable.
+# (through the precompiler) and nothing else.  Tree offsets are TRACED with a
+# clamped window: the last (partial) group shifts its window back in-bounds
+# and blends the overlap back unchanged, so remainders reuse the executable.
 # ---------------------------------------------------------------------------
+
+
+def _record(buf, vals, s0, g0, base: int):
+    """Write a window's (C, trees, nodes) split records into tree_buf at
+    trees s0.., nodes base..; trees below g0 (the clamp overlap) keep theirs."""
+    c, trees, nodes = vals.shape
+    old = jax.lax.dynamic_slice(buf, (0, s0, base), (c, trees, nodes))
+    fresh = (s0 + jnp.arange(trees)) >= g0
+    merged = jnp.where(fresh[None, :, None], vals, old)
+    return jax.lax.dynamic_update_slice(buf, merged, (0, s0, base))
+
+
+def _split_channels(bf, bb, ok, p_w, p_imp, p_val):
+    """(5 + V, trees, nodes) float32 from a split search's outputs."""
+    f32 = jnp.float32
+    return jnp.concatenate(
+        [
+            jnp.stack(
+                [bf.astype(f32), bb.astype(f32), ok.astype(f32), p_w, p_imp]
+            ),
+            jnp.moveaxis(p_val, -1, 0),
+        ]
+    )
+
+
+def _leaf_channels(tot, kind: str):
+    """(5 + V, trees, nodes) float32 from leaf-level per-node totals:
+    (trees, nodes, 3) regression (w, wy, wy2) or (trees, nodes, S) class
+    counts."""
+    if kind == "regression":
+        w = tot[..., 0]
+        w_n = jnp.maximum(w, 1e-12)
+        mean = tot[..., 1] / w_n
+        imp = jnp.maximum(tot[..., 2] / w_n - mean * mean, 0.0)
+        val = mean[..., None]
+    else:
+        w = tot.sum(axis=-1)
+        val = tot / jnp.maximum(w, 1e-12)[..., None]
+        if kind == "entropy":
+            imp = -(val * jnp.log2(jnp.maximum(val, 1e-12))).sum(axis=-1)
+        else:  # as _split_from_hist: exactly 0 for a pure node
+            w_n = jnp.maximum(w, 1e-12)
+            imp = (tot * (w[..., None] - tot)).sum(axis=-1) / w_n / w_n
+    zero = jnp.zeros_like(w)
+    return jnp.concatenate(
+        [jnp.stack([zero, zero, zero, w, imp]), jnp.moveaxis(val, -1, 0)]
+    )
 
 
 @partial(
@@ -282,6 +338,7 @@ _STRAY = 1 << 18
 )
 def _shallow_step(
     rel: jax.Array,        # (T, n_pad) int32 — full routing state
+    buf: jax.Array,        # (C, T, M) f32 tree_buf
     w_trees: jax.Array,    # (T, n_pad)
     stat_rows: jax.Array,  # (3, n_pad) reg (1,y,y2)*mask | (S, n_pad) clf
     sub: jax.Array,        # (f_pad, n_pad) int8 this group's subset rows
@@ -297,8 +354,8 @@ def _shallow_step(
     interpret: bool,
 ):
     """One shallow (level, tree-group) step: totals + histogram + split +
-    route, updating rel in place.  Window rows below g0 (clamp overlap) keep
-    their routing; their split outputs are garbage the host writer skips."""
+    route, updating rel and tree_buf.  Window trees below g0 (clamp overlap)
+    keep their routing and their records."""
     T, n_pad = rel.shape
     f_pad = sub.shape[0]
     s0 = jnp.minimum(g0, T - tpack)
@@ -310,50 +367,43 @@ def _shallow_step(
     else:
         base = stat_rows
         tot = None
-    stats_s = _stats_rows(base, w_g, tpack, s_dim)
-    H = node_histograms(
-        sub, rel_g, stats_s, t_pack=tpack, nodes=nodes, s_dim=s_dim,
-        n_bins=n_bins, interpret=interpret,
-    )
-    feat_valid = jnp.arange(f_pad) < F
-    bf, bb, ok, p_w, p_imp, p_val = _split_from_hist(
-        H, tot, feat_valid, tpack, nodes, s_dim, kind, msl, mid
-    )
-    new_rel = _route(sub, rel_g, bf, bb, ok)
-    fresh = (s0 + jnp.arange(tpack)) >= g0
-    new_rel = jnp.where(fresh[:, None], new_rel, rel_g)
-    rel = jax.lax.dynamic_update_slice(rel, new_rel, (s0, 0))
-    return rel, (bf, bb, ok, p_w, p_imp, p_val)
+    with jax.named_scope("forest.hist"):
+        stats_s = _stats_rows(base, w_g, tpack, s_dim)
+        H = node_histograms(
+            sub, rel_g, stats_s, t_pack=tpack, nodes=nodes, s_dim=s_dim,
+            n_bins=n_bins, interpret=interpret,
+        )
+    with jax.named_scope("forest.split"):
+        feat_valid = jnp.arange(f_pad) < F
+        out = _split_from_hist(
+            H, tot, feat_valid, tpack, nodes, s_dim, kind, msl, mid
+        )
+    with jax.named_scope("forest.route"):
+        new_rel = _route(sub, rel_g, out[0], out[1], out[2])
+        fresh = (s0 + jnp.arange(tpack)) >= g0
+        new_rel = jnp.where(fresh[:, None], new_rel, rel_g)
+        rel = jax.lax.dynamic_update_slice(rel, new_rel, (s0, 0))
+    return rel, _record(buf, _split_channels(*out), s0, g0, nodes - 1)
 
 
-@partial(jax.jit, static_argnames=("tpack", "nodes"))
+@partial(jax.jit, static_argnames=("tpack", "nodes", "kind"))
 def _shallow_leaf(
     rel: jax.Array,
+    buf: jax.Array,
     w_trees: jax.Array,
     stat_rows: jax.Array,
     g0: jax.Array,
     tpack: int,
     nodes: int,
+    kind: str,
 ):
-    """Leaf-level totals for one tree group: (tpack, nodes, 3) regression
-    (w, wy, wy2) or (tpack, nodes, S) class counts."""
+    """Leaf-level totals for one tree group, recorded in tree_buf."""
     T, n_pad = rel.shape
     s0 = jnp.minimum(g0, T - tpack)
     rel_g = jax.lax.dynamic_slice(rel, (s0, 0), (tpack, n_pad))
     w_g = jax.lax.dynamic_slice(w_trees, (s0, 0), (tpack, n_pad))
-    return _node_totals(rel_g, stat_rows[None, :, :] * w_g[:, None, :], nodes)
-
-
-@partial(jax.jit, static_argnames=("n_buckets",))
-def _keys_bounds(rel: jax.Array, n_buckets: int):
-    """Per-(tree, bucket) row counts via one batched key sort +
-    searchsorted — the only host round-trip the deep phase needs before its
-    geometry is known."""
-    keys = jnp.minimum(rel, n_buckets).astype(jnp.int32)
-    sk = jnp.sort(keys, axis=1)
-    return jax.vmap(
-        lambda s: jnp.searchsorted(s, jnp.arange(n_buckets + 1))
-    )(sk)
+    tot = _node_totals(rel_g, stat_rows[None, :, :] * w_g[:, None, :], nodes)
+    return _record(buf, _leaf_channels(tot, kind), s0, g0, nodes - 1)
 
 
 @partial(jax.jit, static_argnames=("f_pad", "P", "chunk"))
@@ -362,7 +412,7 @@ def _pack_all(
 ) -> jax.Array:
     """(T, P, n_pad) int32 packed per-tree deep-subset rows (4 bins/word).
     Only ceil(F/4) words are packed — feature PADDING rows never ride the
-    payload sort; _build_class re-pads to f_pad after the unpack."""
+    payload sort; _deep_state re-pads to f_pad after the unpack."""
 
     def one(feats):
         sub = gather_rows_matmul(bins_fm, feats, f_pad=f_pad, chunk=chunk)
@@ -374,7 +424,7 @@ def _pack_all(
 @partial(jax.jit, static_argnames=("n_buckets", "n2"))
 def _sort_part(
     rel: jax.Array,      # (T, n_pad) node ids AT the bucket level
-    dkeys: jax.Array,    # (T, n2 - n_pad) int32 host-built filler keys
+    dkeys: jax.Array,    # (T, n2 - n_pad) int32 filler keys (_deep_layout)
     payload: jax.Array,  # (T, n_pad) or (n_pad,) — ONE payload array
     n_buckets: int,
     n2: int,
@@ -388,9 +438,8 @@ def _sort_part(
     that the precompiler runs concurrently.  All parts sort by the same
     UNIQUE combined key (bucket_key * n2 + column), so every part computes
     the identical permutation with no reliance on sort stability.  n2 is a
-    STATIC bound (n_pad + worst-case alignment filler + largest class
-    window), so these lower at fit entry and compile while the shallow
-    phase runs.  Uniqueness needs (n_buckets + 1) * n2 < 2^31 — 16.6 M rows
+    STATIC bound (_deep_width: n_pad + one tile of filler a bucket), so
+    these lower at fit entry and compile while the shallow phase runs.  Uniqueness needs (n_buckets + 1) * n2 < 2^31 — 16.6 M rows
     at 128 buckets, far beyond a single chip's forest capacity."""
     T, n_pad = rel.shape
     assert (n_buckets + 1) * n2 < 2**31, "combined sort key overflows int32"
@@ -406,119 +455,123 @@ def _sort_part(
     return out
 
 
-@partial(jax.jit, static_argnames=("cap", "n_seg", "f_pad"))
-def _build_class(
-    packed_sorted,             # tuple of P (T, n2) int32 sorted word parts
-    w_sorted: jax.Array,       # (T, n2)
-    y_sorted: jax.Array,       # (T, n2)
-    seg_t: jax.Array,          # (n_seg,) int32 tree of each segment
-    sl_start: jax.Array,       # (n_seg,) int32 clamped window starts
-    off: jax.Array,            # (n_seg,) int32 in-window segment offset
-    seg_len: jax.Array,        # (n_seg,) int32 padded segment length
-    cap: int,
-    n_seg: int,
-    f_pad: int,
-):
-    """One size class's concatenated layout: per-segment cap-wide windows
-    sliced out of the sorted arrays (batched dynamic_slice — XLA lowers the
-    vmap to contiguous block copies, near-memcpy, unlike scalar gathers on
-    this backend), unpacked to int8 subset rows, weights masked to the
-    segment's own rows, bucket-local node ids initialized."""
-    P = len(packed_sorted)
-    j = jnp.arange(cap)
-    in_seg = (j[None, :] >= off[:, None]) & (j[None, :] < (off + seg_len)[:, None])
-
-    # Slice each segment's cap-wide window as a 2-D dynamic_slice block:
-    # indexing arr[t] first and slicing second would materialize an
-    # (n_seg, n2)-per-payload row gather before the slice — 67 GB at the
-    # 200k x 500 regression geometry (P=42).  The word parts arrive as a
-    # TUPLE (not one stacked (P, T, n2) array): stacking would transiently
-    # double the deep phase's largest HBM buffer; here only the cap-wide
-    # slices are ever stacked.
-    def slice_row(arr2d):
-        return jax.vmap(
-            lambda t, s: jax.lax.dynamic_slice(arr2d, (t, s), (1, cap))[0]
-        )(seg_t, sl_start)
-
-    pk = jnp.stack([slice_row(wp) for wp in packed_sorted])  # (P, n_seg, cap)
-    sub4 = _unpack_rows(pk.reshape(P, -1))           # (4P, n_seg*cap)
-    sub_c = jnp.pad(sub4, ((0, f_pad - 4 * P), (0, 0)))
-    w_c = (slice_row(w_sorted) * in_seg).reshape(-1)
-    y_c = slice_row(y_sorted).reshape(-1)
-    rel_c = jnp.where(in_seg, 0, _STRAY).astype(jnp.int32).reshape(-1)
-    return sub_c, w_c, y_c, rel_c
+# ---------------------------------------------------------------------------
+# The deep phase: levels past the 128-slot budget.
+#
+# 1. Rows are grouped ONCE per tree by their ancestor at the bucket level via
+#    a batched payload sort (the only fast data-movement primitive on this
+#    backend — XLA gather/scatter scalarize).  Weight-0 filler rows ride the
+#    sort so every (tree, bucket) SEGMENT is a whole number of
+#    _ROW_TILE_DEEP tiles and owns at least one.
+# 2. The sorted width n2 = n_pad + n_buckets * _ROW_TILE_DEEP is a static
+#    bound (a segment takes less than one tile of filler, an empty one a
+#    whole tile); what the segments do not use lies behind them, with the
+#    rows whose node stopped in the shallow phase, in tiles of the stray
+#    segment, which the kernel skips.
+# 3. Segments never move again: routing keeps rows inside their subtree, so
+#    the layout is built once and reused by every deeper level, which runs
+#    ONE histogram / split / route step per tree window.
+#
+# The per-tree deep feature subset rides the sort as packed int32 payload
+# (4 bins/word).
+# ---------------------------------------------------------------------------
 
 
-def _nseg_chunk(n_seg: int, local: int, s_dim: int, f_pad: int, n_bins: int) -> int:
-    """Segments per deep dispatch window: the VMEM-budget bound
-    (_seg_chunk), floored to a power of two and clamped under the class's
-    segment count (also pow2-floored, so windows never exceed the array
-    and the remainder rides the clamped-overlap machinery)."""
-    return min(
-        _p2floor(_seg_chunk(local, s_dim, f_pad, n_bins)), _p2floor(n_seg)
+def _deep_width(n_pad: int, n_buckets: int) -> int:
+    return n_pad + n_buckets * _ROW_TILE_DEEP
+
+
+@partial(jax.jit, static_argnames=("n_buckets", "n2"))
+def _deep_layout(rel: jax.Array, n_buckets: int, n2: int):
+    """From the routing state at the bucket level: the filler rows' sort keys
+    (T, n2 - n_pad) and each row tile's segment (T, n2 / tile), segment
+    n_buckets being the stray one.  All on the device: the segments' lengths
+    never reach the host."""
+    T, n_pad = rel.shape
+    tile = _ROW_TILE_DEEP
+    keys = jnp.minimum(rel, n_buckets)
+    ids = jnp.arange(n_buckets, dtype=keys.dtype)
+    counts = (keys[:, :, None] == ids[None, None, :]).sum(
+        axis=1, dtype=jnp.int32
+    )  # (T, n_buckets)
+    aligned = -(-jnp.maximum(counts, 1) // tile) * tile
+    seg_end = jnp.cumsum(aligned, axis=1)
+    fill_end = jnp.cumsum(aligned - counts, axis=1)
+    at = jnp.arange(n2 - n_pad, dtype=jnp.int32)
+    dkeys = (fill_end[:, None, :] <= at[None, :, None]).sum(
+        axis=-1, dtype=jnp.int32
     )
-
-
-@partial(jax.jit, static_argnames=("cap", "nrows"))
-def _deep_window(sub_c, rel_c, w_c, y_c, c0, cap: int, nrows: int):
-    """Slice one clamped (nseg_chunk*cap)-row window out of a class's
-    state arrays.  A TRIVIAL jit (near-memcpy) keyed by the class's full
-    size — split out so the EXPENSIVE kernels (_deep_step/_deep_leaf) see
-    only the fixed-size window and their jit keys carry no n_seg: the
-    data-dependent segment count used to put every fresh dataset on the
-    compile path (60 x ~6 s per cold fit); window-shape keys come from a
-    small power-of-two universe the persistent cache accumulates once."""
-    s = jnp.minimum(c0, rel_c.shape[0] // cap - nrows // cap)
-    rs = s * cap
-    return (
-        jax.lax.dynamic_slice(sub_c, (0, rs), (sub_c.shape[0], nrows)),
-        jax.lax.dynamic_slice(rel_c, (rs,), (nrows,)),
-        jax.lax.dynamic_slice(w_c, (rs,), (nrows,)),
-        jax.lax.dynamic_slice(y_c, (rs,), (nrows,)),
+    tile0 = jnp.arange(n2 // tile, dtype=jnp.int32) * tile
+    tile_seg = (seg_end[:, None, :] <= tile0[None, :, None]).sum(
+        axis=-1, dtype=jnp.int32
     )
+    return dkeys, tile_seg
 
 
-@partial(jax.jit, static_argnames=("cap", "nrows"))
-def _deep_window3(rel_c, w_c, y_c, c0, cap: int, nrows: int):
-    """Leaf-level variant of _deep_window (no subset rows needed)."""
-    s = jnp.minimum(c0, rel_c.shape[0] // cap - nrows // cap)
-    rs = s * cap
-    return (
-        jax.lax.dynamic_slice(rel_c, (rs,), (nrows,)),
-        jax.lax.dynamic_slice(w_c, (rs,), (nrows,)),
-        jax.lax.dynamic_slice(y_c, (rs,), (nrows,)),
-    )
+@partial(jax.jit, static_argnames=("f_pad", "s_dim", "kind"))
+def _deep_state(packed_sorted, w_sorted, y_sorted, f_pad: int, s_dim: int, kind: str):
+    """The sorted payloads as the level steps read them: subset rows
+    (T, f_pad, n2) int8, the histogram's stat rows (T, S, n2), the 3-stat
+    rows of regression's node totals (T, 3, n2; classification: the stat
+    rows again), and segment-local node ids (T, 1, n2), all zero: every row
+    starts at its segment's root; filler rows weigh nothing."""
+    words = jnp.stack(packed_sorted, axis=1)                 # (T, P, n2)
+    T, P, n2 = words.shape
+    parts = jnp.stack(
+        [(words >> (8 * i)) & 0xFF for i in range(4)], axis=2
+    )  # (T, P, 4, n2)
+    bins_s = parts.reshape(T, 4 * P, n2).astype(jnp.int8)
+    bins_s = jnp.pad(bins_s, ((0, 0), (0, f_pad - 4 * P), (0, 0)))
+    if kind == "regression":
+        wy = w_sorted * y_sorted
+        stats_s = jnp.stack([w_sorted, wy], axis=1)
+        stats3 = jnp.stack([w_sorted, wy, wy * y_sorted], axis=1)
+    else:
+        cls = jnp.arange(s_dim, dtype=jnp.float32)
+        stats_s = w_sorted[:, None, :] * (
+            y_sorted[:, None, :] == cls[None, :, None]
+        ).astype(jnp.float32)
+        stats3 = stats_s
+    return bins_s, stats_s, stats3, jnp.zeros((T, 1, n2), jnp.int32)
 
 
-@partial(jax.jit, static_argnames=("cap",))
-def _deep_update(rel_c, new_rel_win, c0, cap: int):
-    """Write a window's routing back, keeping OLD routing for the clamp
-    overlap rows (segments below c0 were already routed by the previous
-    window; routing is not idempotent — 2*rel+go applied twice would leap
-    a level)."""
-    nseg_chunk = new_rel_win.shape[0] // cap
-    s = jnp.minimum(c0, rel_c.shape[0] // cap - nseg_chunk)
-    fresh = jnp.repeat((s + jnp.arange(nseg_chunk)) >= c0, cap)
-    old = jax.lax.dynamic_slice(rel_c, (s * cap,), (new_rel_win.shape[0],))
-    merged = jnp.where(fresh, new_rel_win, old)
-    return jax.lax.dynamic_update_slice(rel_c, merged, (s * cap,))
+def _seg_totals(rl, st, seg_oh, local: int):
+    """(trees, n_buckets, local, S) per-node stat sums of a tree window.
+    rl (trees, tiles, tile) local node ids, st (trees, S, tiles, tile),
+    seg_oh (trees, tiles, n_buckets) one-hot of each tile's segment.  Tile
+    by tile (a tile lies in one segment), one tree at a time so the node
+    one-hot stays a tile-sized temporary; exact for integer weights."""
+    hi = jax.lax.Precision.HIGHEST
+
+    def one(args):
+        rl_t, st_t, oh_t = args
+        on = (
+            rl_t[:, None, :] == jnp.arange(local, dtype=rl_t.dtype)[None, :, None]
+        ).astype(jnp.float32)  # (tiles, local, tile)
+        per_tile = jnp.einsum("klc,skc->kls", on, st_t, precision=hi)
+        return jnp.einsum("kb,kls->bls", oh_t, per_tile, precision=hi)
+
+    return jax.lax.map(one, (rl, st, seg_oh))
 
 
 @partial(
     jax.jit,
     static_argnames=(
-        "cap", "nseg_chunk", "local", "s_dim", "kind", "n_bins",
-        "F", "msl", "mid", "interpret",
+        "t_chunk", "level", "bucket_level", "s_dim", "kind", "n_bins", "F",
+        "msl", "mid", "interpret",
     ),
 )
 def _deep_step(
-    sub_k: jax.Array,   # (f_pad, nseg_chunk*cap) int8 window
-    rel_k: jax.Array,   # (nseg_chunk*cap,) int32 bucket-local node ids
-    w_k: jax.Array,
-    y_k: jax.Array,
-    cap: int,
-    nseg_chunk: int,
-    local: int,
+    bins_s: jax.Array,    # (T, f_pad, n2) int8
+    rel_loc: jax.Array,   # (T, 1, n2) int32 segment-local node ids
+    stats_s: jax.Array,   # (T, S, n2)
+    stats3: jax.Array,    # (T, 3, n2) regression totals' rows
+    tile_seg: jax.Array,  # (T, n_tiles) int32
+    buf: jax.Array,       # (C, T, M) tree_buf
+    t0: jax.Array,        # () int32 traced window start
+    t_chunk: int,
+    level: int,
+    bucket_level: int,
     s_dim: int,
     kind: str,
     n_bins: int,
@@ -527,459 +580,147 @@ def _deep_step(
     mid: float,
     interpret: bool,
 ):
-    """One deep (class, level, chunk) step over a pre-sliced window of
-    `nseg_chunk` segments: stats + bucketed histogram + split + route.
-    Returns (new_rel window, split outputs); the caller merges the window
-    back with _deep_update (overlap masking lives there)."""
-    f_pad = sub_k.shape[0]
-    if kind == "regression":
-        tot3 = jnp.stack([w_k, w_k * y_k, w_k * y_k * y_k])
-        node_tot = _node_totals_bucketed(rel_k, tot3, nseg_chunk, local, cap)
-        stats_k = jnp.stack([w_k, w_k * y_k])
-    else:
-        cls_iota = jnp.arange(s_dim, dtype=jnp.float32)
-        stats_k = w_k[None, :] * (
-            y_k[None, :] == cls_iota[:, None]
-        ).astype(jnp.float32)
-        node_tot = None
-    H = node_histograms_bucketed(
-        sub_k, rel_k[None, :], stats_k,
-        n_buckets=nseg_chunk, nodes=local, s_dim=s_dim, n_bins=n_bins,
-        interpret=interpret,
-    )  # (nseg_chunk, f_pad, slots_pad, B)
-    Hf = jnp.transpose(
-        H[:, :, : local * s_dim, :], (1, 0, 2, 3)
-    ).reshape(f_pad, nseg_chunk * local * s_dim, n_bins)
-    feat_valid = jnp.arange(f_pad) < F
-    bf, bb, ok, p_w, p_imp, p_val = _split_from_hist(
-        Hf, node_tot, feat_valid, nseg_chunk, local, s_dim, kind, msl, mid
-    )  # leading (nseg_chunk, local)
-    new_rel = _route_bucketed(sub_k, rel_k, bf, bb, ok, cap)
-    return new_rel, (bf, bb, ok, p_w, p_imp, p_val)
+    """One deep (level, tree window) step: segmented histogram + split +
+    route, updating rel_loc and tree_buf."""
+    T, f_pad, n2 = bins_s.shape
+    tile = _ROW_TILE_DEEP
+    n_tiles = n2 // tile
+    nb = 2**bucket_level
+    local = 2 ** (level - bucket_level)
+    s0 = jnp.minimum(t0, T - t_chunk)
+    seg = jax.lax.dynamic_slice(tile_seg, (s0, 0), (t_chunk, n_tiles))
+    rl = jax.lax.dynamic_slice(rel_loc, (s0, 0, 0), (t_chunk, 1, n2))
+    rl = rl.reshape(t_chunk, n_tiles, tile)
+    seg_oh = (
+        seg[:, :, None] == jnp.arange(nb, dtype=seg.dtype)[None, None, :]
+    ).astype(jnp.float32)  # stray tiles: all zero
 
+    with jax.named_scope("forest.hist"):
+        H = node_histograms_segmented(
+            bins_s, rel_loc, stats_s, seg.reshape(-1), s0,
+            t_chunk=t_chunk, n_segs=nb + 1, nodes=local, s_dim=s_dim,
+            n_bins=n_bins, f_pad=f_pad, interpret=interpret,
+        )  # (t_chunk * (nb + 1), f_pad, slots_pad, B)
+        slots = local * s_dim
+        H = H.reshape((t_chunk, nb + 1) + H.shape[1:])[:, :nb, :, :slots, :]
 
-@partial(
-    jax.jit,
-    static_argnames=("cap", "nseg_chunk", "local", "s_dim", "kind"),
-)
-def _deep_leaf(
-    rel_k: jax.Array,
-    w_k: jax.Array,
-    y_k: jax.Array,
-    cap: int,
-    nseg_chunk: int,
-    local: int,
-    s_dim: int,
-    kind: str,
-):
-    """Leaf-level per-node totals for one pre-sliced (class, chunk)
-    window: (nseg_chunk, local, 3) regression or (nseg_chunk, local, S)
-    class counts."""
-    if kind == "regression":
-        stats = jnp.stack([w_k, w_k * y_k, w_k * y_k * y_k])
-    else:
-        cls_iota = jnp.arange(s_dim, dtype=jnp.float32)
-        stats = w_k[None, :] * (
-            y_k[None, :] == cls_iota[:, None]
-        ).astype(jnp.float32)
-    return _node_totals_bucketed(rel_k, stats, nseg_chunk, local, cap)
-
-
-@partial(jax.jit, static_argnames=("n_buckets", "local", "cap"))
-def _node_totals_bucketed(
-    rel_loc: jax.Array,   # (n2,)
-    stats3: jax.Array,    # (S, n2)
-    n_buckets: int,
-    local: int,
-    cap: int,
-):
-    """(n_buckets, local, S) per-node stat sums via bucket-blocked one-hot
-    contraction (cap rows per bucket are contiguous); S = stats3.shape[0]
-    (3 impurity stats for regression, n_classes for classification leaf
-    totals)."""
-    st = stats3.reshape(stats3.shape[0], n_buckets, cap)
-    rl = rel_loc.reshape(n_buckets, cap)
-    on = (
-        rl[:, None, :] == jnp.arange(local, dtype=rl.dtype)[None, :, None]
-    ).astype(stats3.dtype)  # (n_buckets, local, cap)
-    return jnp.einsum(
-        "blc,sbc->bls", on, st, preferred_element_type=jnp.float32
-    )
-
-
-@partial(jax.jit, static_argnames=("cap",))
-def _route_bucketed(
-    sub: jax.Array,       # (f_pad, n2)
-    rel_loc: jax.Array,   # (n2,)
-    bf: jax.Array,        # (n_buckets, local)
-    bb: jax.Array,
-    ok: jax.Array,
-    cap: int,
-):
-    n_buckets, local = bf.shape
-    f_pad = sub.shape[0]
-    sel = (
-        bf[:, :, None] == jnp.arange(f_pad, dtype=bf.dtype)[None, None, :]
-    ).astype(jnp.float32)
-    sub_b = sub.reshape(f_pad, n_buckets, cap).astype(jnp.float32)
-    sel_bins = jnp.einsum(
-        "blf,fbc->blc", sel, sub_b, preferred_element_type=jnp.float32
-    ).astype(jnp.int32)  # (n_buckets, local, cap)
-    rl = rel_loc.reshape(n_buckets, cap)
-    on = rl[:, None, :] == jnp.arange(local, dtype=rl.dtype)[None, :, None]
-    act = on & ok[:, :, None]
-    go = (act & (sel_bins > bb[:, :, None])).any(axis=1)
-    stays = act.any(axis=1)
-    new = jnp.where(stays, 2 * rl + go.astype(jnp.int32), 2 * local)
-    return new.reshape(-1)
-
-
-def _deep_geometry(n_pad: int, n_buckets: int) -> int:
-    """Static payload-sort width: real rows + worst-case per-bucket
-    alignment filler + headroom for the largest possible class window
-    (a clamped window must never run off the end)."""
-    TILE = _ROW_TILE_DEEP
-    cap_max = TILE
-    while cap_max < n_pad:
-        cap_max *= 2
-    return max(n_pad + n_buckets * TILE + TILE, cap_max + TILE)
-
-
-def _seg_chunk(local: int, s_dim: int, f_pad: int, n_bins: int) -> int:
-    """Segments per deep dispatch: the split-search intermediate
-    (chunk, S, local, f_pad, B) stays ~<=64 MB."""
-    return max(1, (64 << 20) // max(1, local * s_dim * f_pad * n_bins * 4))
-
-
-def _deep_phase(
-    rel: jax.Array,          # (T, n_pad) node ids AT the bucket level
-    bins_fm: jax.Array,
-    w_trees: jax.Array,
-    y_vals: jax.Array,       # (n_pad,) label/target values (f32)
-    edges: np.ndarray,
-    outputs,                 # (feature, threshold, leaf_value, n_samples, impurity)
-    rng: np.random.Generator,
-    *,
-    bucket_level: int,
-    max_depth: int,
-    n_bins: int,
-    kind: str,
-    s_dim: int,
-    max_features: int,
-    min_samples_leaf: float,
-    min_impurity_decrease: float,
-    interpret: bool = False,
-) -> None:
-    """Levels past the 128-slot budget, data-proportional in compute AND
-    memory regardless of tree skew:
-
-    1. Rows are grouped ONCE per tree by their bucket-level ancestor via a
-       batched payload sort (the only fast data-movement primitive on this
-       backend — XLA gather/scatter scalarize).  Tile-aligned filler rows
-       (weight 0) ride the sort so every bucket's region is a multiple of
-       _ROW_TILE_DEEP.
-    2. Every non-empty (tree, bucket) segment is assigned to a geometric
-       SIZE CLASS (capacity = next power-of-two tile multiple >= its padded
-       length, so padding overhead <= 2x).  A class batches segments from
-       ALL trees: each level then runs ONE histogram / split / route
-       dispatch per (class, segment-chunk) per level — a skewed forest
-       (few giant buckets + many dead ones) costs what its rows cost, where
-       an equal-capacity layout would pad every bucket to the largest (the
-       round-1 design's HBM blow-up) and per-bucket windows would stream
-       the full row set once per live window.
-    3. Buckets never move again: routing keeps rows inside their subtree,
-       so the class layout is built once and reused by every deeper level.
-
-    The per-tree deep feature subset rides the sort as packed int32
-    payload (4 bins/word)."""
-    feature, threshold, leaf_value, n_samples, impurity = outputs
-    T, n_pad = rel.shape
-    D = bins_fm.shape[0]
-    n_buckets = 2**bucket_level
-    F = int(max_features)
-    P = -(-F // 4)
-    f_pad = -(-max(F, 4) // _F_BLOCK) * _F_BLOCK
-    TILE = _ROW_TILE_DEEP
-    n2 = _deep_geometry(n_pad, n_buckets)
-    msl = float(min_samples_leaf)
-    mid = float(min_impurity_decrease)
-    pc = global_precompiler()
-
-    # one deep subset per tree, shared by its levels >= bucket_level (the
-    # random-subspace compromise documented in the module header)
-    feats_all = np.stack(
-        [rng.choice(D, F, replace=False).astype(np.int32) for _ in range(T)]
-    )
-
-    # --- per-(tree, bucket) counts (host round-trip; geometry source) -----
-    bounds = pc.call(
-        ("keys_bounds", T, n_pad, n_buckets),
-        _keys_bounds, rel, n_buckets=n_buckets,
-    )
-    g_chunk = 16384 if n_pad % 16384 == 0 else _ROW_TILE
-    packed = pc.call(
-        ("pack_all", D, n_pad, T, F, f_pad, P, g_chunk),
-        _pack_all, bins_fm, jnp.asarray(feats_all),
-        f_pad=f_pad, P=P, chunk=g_chunk,
-    )
-    counts = np.asarray(bounds)
-    counts = counts[:, 1:] - counts[:, :-1]              # (T, n_buckets)
-    aligned = -(-counts // TILE) * TILE                  # 0 stays 0
-    starts = np.concatenate(
-        [np.zeros((T, 1), np.int64), np.cumsum(aligned, axis=1)], axis=1
-    )[:, :n_buckets]
-
-    # size classes are decided from the counts BEFORE the sort so clamped
-    # windows are guaranteed in-bounds by the static n2 headroom
-    classes: dict = {}
-    for t in range(T):
-        for b in range(n_buckets):
-            seg_cap = int(aligned[t, b])
-            if seg_cap == 0:
-                continue
-            cls_cap = TILE
-            while cls_cap < seg_cap:
-                cls_cap *= 2
-            classes.setdefault(cls_cap, []).append(
-                (t, b, int(starts[t, b]), seg_cap)
+    with jax.named_scope("forest.split"):
+        n_seg = t_chunk * nb
+        per = max(1, min(n_seg, _SPLIT_CHUNK_BYTES // (slots * f_pad * n_bins * 4)))
+        while n_seg % per:
+            per -= 1
+        Hc = H.reshape(n_seg // per, per, f_pad, slots, n_bins)
+        if kind == "regression":
+            st3 = jax.lax.dynamic_slice(
+                stats3, (s0, 0, 0), (t_chunk, 3, n2)
+            ).reshape(t_chunk, 3, n_tiles, tile)
+            tot = _seg_totals(rl, st3, seg_oh, local).reshape(
+                n_seg // per, per, local, 3
             )
-    if logger.isEnabledFor(logging.DEBUG):
-        real = int(counts.sum())
-        tile_rows = int(aligned.sum())
-        class_rows = sum(cap * len(segs) for cap, segs in classes.items())
-        logger.debug(
-            "deep geometry: %d real rows -> %d tile-aligned (%.2fx) -> "
-            "%d class-padded (%.2fx) across %d classes / %d segments",
-            real, tile_rows, tile_rows / max(real, 1),
-            class_rows, class_rows / max(real, 1),
-            len(classes), sum(len(s) for s in classes.values()),
-        )
-
-    # --- submit every remaining geometry for parallel compilation ---------
-    # The heavy kernels (_deep_step/_deep_leaf) are keyed ONLY by their
-    # pow2 window geometry — no n_seg — so their keys repeat across fits
-    # and datasets and the persistent compile cache turns a foreign-data
-    # cold fit into deserialize-only.  The n_seg-shaped helpers
-    # (window/update/build) are near-memcpy jits submitted alongside.
-    f32, i32, i8 = jnp.float32, jnp.int32, jnp.int8
-    for cls_cap, segs in classes.items():
-        n_seg = len(segs)
-        nr = n_seg * cls_cap
-        pc.submit(
-            ("build_class", T, n2, P, cls_cap, n_seg, f_pad),
-            _build_class,
-            tuple(aval((T, n2), i32) for _ in range(P)),
-            aval((T, n2), f32), aval((T, n2), f32),
-            aval((n_seg,), i32), aval((n_seg,), i32), aval((n_seg,), i32),
-            aval((n_seg,), i32),
-            cap=cls_cap, n_seg=n_seg, f_pad=f_pad,
-        )
-        seen_nrw = set()
-        for level in range(bucket_level, max_depth + 1):
-            local = 2 ** (level - bucket_level)
-            nseg_chunk = _nseg_chunk(n_seg, local, s_dim, f_pad, n_bins)
-            nr_w = nseg_chunk * cls_cap
-            if level == max_depth:
-                pc.submit(
-                    ("deep_win3", nr, nr_w, cls_cap),
-                    _deep_window3,
-                    aval((nr,), i32), aval((nr,), f32), aval((nr,), f32),
-                    aval((), i32),
-                    cap=cls_cap, nrows=nr_w,
-                )
-                pc.submit(
-                    ("deep_leaf", cls_cap, nseg_chunk, local, s_dim, kind),
-                    _deep_leaf,
-                    aval((nr_w,), i32), aval((nr_w,), f32), aval((nr_w,), f32),
-                    cap=cls_cap, nseg_chunk=nseg_chunk,
-                    local=local, s_dim=s_dim, kind=kind,
-                )
-            else:
-                if nr_w not in seen_nrw:
-                    seen_nrw.add(nr_w)
-                    pc.submit(
-                        ("deep_win", nr, nr_w, cls_cap, f_pad),
-                        _deep_window,
-                        aval((f_pad, nr), i8), aval((nr,), i32),
-                        aval((nr,), f32), aval((nr,), f32), aval((), i32),
-                        cap=cls_cap, nrows=nr_w,
-                    )
-                    pc.submit(
-                        ("deep_upd", nr, nr_w, cls_cap),
-                        _deep_update,
-                        aval((nr,), i32), aval((nr_w,), i32), aval((), i32),
-                        cap=cls_cap,
-                    )
-                pc.submit(
-                    ("deep_step", cls_cap, nseg_chunk, local, s_dim,
-                     kind, n_bins, F, msl, mid, interpret),
-                    _deep_step,
-                    aval((f_pad, nr_w), i8), aval((nr_w,), i32),
-                    aval((nr_w,), f32), aval((nr_w,), f32),
-                    cap=cls_cap, nseg_chunk=nseg_chunk,
-                    local=local, s_dim=s_dim, kind=kind, n_bins=n_bins, F=F,
-                    msl=msl, mid=mid, interpret=interpret,
-                )
-
-    # --- the batched bucket sort (compiling since fit entry) ---------------
-    dkeys = np.full((T, n2 - n_pad), n_buckets, np.int32)
-    for t in range(T):
-        dk = np.repeat(
-            np.arange(n_buckets, dtype=np.int32), aligned[t] - counts[t]
-        )
-        dkeys[t, : dk.size] = dk
-    dkeys_dev = jnp.asarray(dkeys)
-    word_key = ("sort_part_i32", T, n_pad, n_buckets, n2)
-    packed_sorted = tuple(
-        pc.call(
-            word_key, _sort_part, rel, dkeys_dev, packed[:, p, :],
-            n_buckets=n_buckets, n2=n2,
-        )
-        for p in range(P)
-    )
-    w_sorted = pc.call(
-        ("sort_part_f32", T, n_pad, n_buckets, n2),
-        _sort_part, rel, dkeys_dev, w_trees, n_buckets=n_buckets, n2=n2,
-    )
-    y_sorted = pc.call(
-        ("sort_part_f32_1d", T, n_pad, n_buckets, n2),
-        _sort_part, rel, dkeys_dev, y_vals, n_buckets=n_buckets, n2=n2,
-    )
-    del packed
-
-    # --- build each class's concatenated layout ONCE ----------------------
-    class_state: dict = {}
-    for cls_cap, segs in sorted(classes.items()):
-        n_seg = len(segs)
-        # clamp so the cap-wide window stays in bounds; the in-segment mask
-        # recovers the true segment rows
-        sl_start = np.array(
-            [min(s[2], n2 - cls_cap) for s in segs], np.int64
-        )
-        off = np.array([s[2] for s in segs], np.int64) - sl_start
-        seg_len = np.array([s[3] for s in segs], np.int64)
-        sub_c, w_c, y_c, rel_c = pc.call(
-            ("build_class", T, n2, P, cls_cap, n_seg, f_pad),
-            _build_class,
-            packed_sorted, w_sorted, y_sorted,
-            jnp.asarray([s[0] for s in segs], jnp.int32),
-            jnp.asarray(sl_start, jnp.int32),
-            jnp.asarray(off, jnp.int32),
-            jnp.asarray(seg_len, jnp.int32),
-            cap=cls_cap, n_seg=n_seg, f_pad=f_pad,
-        )
-        class_state[cls_cap] = {
-            "segs": segs, "sub": sub_c, "w": w_c, "y": y_c, "rel": rel_c,
-        }
-    del packed_sorted, w_sorted, y_sorted
-
-    # --- levels: one fused dispatch per (class, chunk) --------------------
-    # deferred host fetches: one device_get at the end (a sync per
-    # dispatch would serialize hundreds of host round-trips)
-    pending = []  # (tag, seg_sublist, level, window_offset, device_arrays)
-
-    for level in range(bucket_level, max_depth + 1):
-        local = 2 ** (level - bucket_level)
-        is_last = level == max_depth
-        for cls_cap, st in class_state.items():
-            segs = st["segs"]
-            n_seg = len(segs)
-            nr = n_seg * cls_cap
-            nseg_chunk = _nseg_chunk(n_seg, local, s_dim, f_pad, n_bins)
-            nr_w = nseg_chunk * cls_cap
-            for c0 in range(0, n_seg, nseg_chunk):
-                c1 = min(c0 + nseg_chunk, n_seg)
-                o = max(0, c0 - (n_seg - nseg_chunk))  # window clamp offset
-                c0_dev = jnp.asarray(np.int32(c0))
-                if is_last:
-                    rel_w, w_w, y_w = pc.call(
-                        ("deep_win3", nr, nr_w, cls_cap),
-                        _deep_window3, st["rel"], st["w"], st["y"], c0_dev,
-                        cap=cls_cap, nrows=nr_w,
-                    )
-                    tot = pc.call(
-                        ("deep_leaf", cls_cap, nseg_chunk, local, s_dim,
-                         kind),
-                        _deep_leaf, rel_w, w_w, y_w,
-                        cap=cls_cap, nseg_chunk=nseg_chunk,
-                        local=local, s_dim=s_dim, kind=kind,
-                    )
-                    tag = "leaf_reg" if kind == "regression" else "leaf_cls"
-                    pending.append((tag, segs[c0:c1], level, o, tot))
-                    continue
-                sub_w, rel_w, w_w, y_w = pc.call(
-                    ("deep_win", nr, nr_w, cls_cap, f_pad),
-                    _deep_window, st["sub"], st["rel"], st["w"], st["y"],
-                    c0_dev, cap=cls_cap, nrows=nr_w,
-                )
-                new_rel_w, out = pc.call(
-                    ("deep_step", cls_cap, nseg_chunk, local, s_dim,
-                     kind, n_bins, F, msl, mid, interpret),
-                    _deep_step, sub_w, rel_w, w_w, y_w,
-                    cap=cls_cap, nseg_chunk=nseg_chunk,
-                    local=local, s_dim=s_dim, kind=kind, n_bins=n_bins, F=F,
-                    msl=msl, mid=mid, interpret=interpret,
-                )
-                st["rel"] = pc.call(
-                    ("deep_upd", nr, nr_w, cls_cap),
-                    _deep_update, st["rel"], new_rel_w, c0_dev, cap=cls_cap,
-                )
-                pending.append(("split", segs[c0:c1], level, o, out))
-
-    # --- single host fetch + per-segment numpy writes ----------------------
-    fetched = jax.device_get([p[4] for p in pending])
-    for (tag, segs_c, level, o, _), got in zip(pending, fetched):
-        local = 2 ** (level - bucket_level)
-        base = 2**level - 1
-        if tag == "leaf_reg":
-            th = np.asarray(got)[o : o + len(segs_c)]  # (nseg, local, 3)
-            w_n = np.maximum(th[:, :, 0], 1e-12)
-            val = (th[:, :, 1] / w_n)[:, :, None]
-            imp = np.maximum(th[:, :, 2] / w_n - (th[:, :, 1] / w_n) ** 2, 0.0)
-            cnt = th[:, :, 0]
-            for i, (t, b, _, _) in enumerate(segs_c):
-                sl = slice(base + b * local, base + (b + 1) * local)
-                n_samples[t, sl] = cnt[i]
-                impurity[t, sl] = imp[i]
-                leaf_value[t, sl] = val[i]
-        elif tag == "leaf_cls":
-            tot_h = np.asarray(got)[o : o + len(segs_c)]  # (nseg, local, S)
-            w_n = np.maximum(tot_h.sum(2), 1e-12)
-            val = tot_h / w_n[:, :, None]
-            if kind == "entropy":
-                imp = -(val * np.log2(np.maximum(val, 1e-12))).sum(2)
-            else:
-                imp = 1.0 - (val * val).sum(2)
-            cnt = tot_h.sum(2)
-            for i, (t, b, _, _) in enumerate(segs_c):
-                sl = slice(base + b * local, base + (b + 1) * local)
-                n_samples[t, sl] = cnt[i]
-                impurity[t, sl] = imp[i]
-                leaf_value[t, sl] = val[i]
         else:
-            bf_h, bb_h, ok_h, pw_h, pi_h, pv_h = (
-                np.asarray(a)[o : o + len(segs_c)] for a in got
-            )  # leading (nseg, local)
-            for i, (t, b, _, _) in enumerate(segs_c):
-                sl = slice(base + b * local, base + (b + 1) * local)
-                gf = feats_all[t][np.minimum(bf_h[i], F - 1)]
-                n_samples[t, sl] = pw_h[i]
-                impurity[t, sl] = pi_h[i]
-                leaf_value[t, sl] = pv_h[i]
-                feature[t, sl] = np.where(ok_h[i], gf, -1)
-                threshold[t, sl] = np.where(
-                    ok_h[i],
-                    edges[gf, np.minimum(bb_h[i], edges.shape[1] - 1)],
-                    0.0,
-                )
+            tot = jnp.zeros((n_seg // per, 0), jnp.float32)  # unused
+        feat_valid = jnp.arange(f_pad) < F
+
+        def search(args):
+            h, t = args
+            hf = jnp.transpose(h, (1, 0, 2, 3)).reshape(f_pad, per * slots, n_bins)
+            return _split_from_hist(
+                hf, t if kind == "regression" else None, feat_valid,
+                per, local, s_dim, kind, msl, mid,
+            )
+
+        out = jax.lax.map(search, (Hc, tot))
+        bf, bb, ok, p_w, p_imp, p_val = (
+            a.reshape((t_chunk, nb, local) + a.shape[3:]) for a in out
+        )
+
+    with jax.named_scope("forest.route"):
+        # a tile's split table, by a one-hot over its tree's segments
+        tab = jnp.stack(
+            [bf.astype(jnp.float32), bb.astype(jnp.float32), ok.astype(jnp.float32)]
+        )  # (3, t_chunk, nb, local)
+        tile_tab = jnp.einsum(
+            "tkb,ctbl->ctkl", seg_oh, tab, precision=jax.lax.Precision.HIGHEST
+        )  # (3, t_chunk, n_tiles, local)
+        on = rl[:, :, None, :] == jnp.arange(local, dtype=rl.dtype)[None, None, :, None]
+        # a row's own node's (feature, bin, taken): one pass over the node one-hot
+        mine = jnp.where(on[None], tile_tab[..., None], 0.0).sum(axis=3)
+        f_row = mine[0].astype(jnp.int32).reshape(t_chunk, 1, n2)
+        b_row = mine[1].astype(jnp.int32).reshape(t_chunk, n2)
+        stays = (mine[2] > 0.5).reshape(t_chunk, n2)
+        bins_c = jax.lax.dynamic_slice(bins_s, (s0, 0, 0), (t_chunk, f_pad, n2))
+        own = f_row == jnp.arange(f_pad, dtype=jnp.int32)[None, :, None]
+        v_row = jnp.where(own, bins_c.astype(jnp.int32), 0).sum(axis=1)
+        old = rl.reshape(t_chunk, n2)
+        new = jnp.where(stays, 2 * old + (v_row > b_row).astype(jnp.int32), 2 * local)
+        fresh = (s0 + jnp.arange(t_chunk)) >= t0
+        new = jnp.where(fresh[:, None], new, old)
+        rel_loc = jax.lax.dynamic_update_slice(
+            rel_loc, new.reshape(t_chunk, 1, n2), (s0, 0, 0)
+        )
+
+    vals = _split_channels(bf, bb, ok, p_w, p_imp, p_val)
+    vals = vals.reshape(vals.shape[0], t_chunk, nb * local)
+    return rel_loc, _record(buf, vals, s0, t0, 2**level - 1)
 
 
-def grow_forest_mxu(
+@partial(jax.jit, static_argnames=("level", "bucket_level", "kind"))
+def _deep_leaf(rel_loc, stats3, tile_seg, buf, level: int, bucket_level: int, kind: str):
+    """Leaf-level per-node totals of every tree, recorded in tree_buf."""
+    T, _, n2 = rel_loc.shape
+    tile = _ROW_TILE_DEEP
+    n_tiles = n2 // tile
+    nb = 2**bucket_level
+    local = 2 ** (level - bucket_level)
+    seg_oh = (
+        tile_seg[:, :, None] == jnp.arange(nb, dtype=tile_seg.dtype)[None, None, :]
+    ).astype(jnp.float32)
+    tot = _seg_totals(
+        rel_loc.reshape(T, n_tiles, tile),
+        stats3.reshape(T, stats3.shape[1], n_tiles, tile),
+        seg_oh, local,
+    )  # (T, nb, local, S)
+    vals = _leaf_channels(tot.reshape(T, nb * local, tot.shape[-1]), kind)
+    zero = jnp.zeros((), jnp.int32)
+    return _record(buf, vals, zero, zero, 2**level - 1)
+
+
+class ForestPlan(NamedTuple):
+    """What pack_forest needs beside tree_buf's host copy: the fit's static
+    geometry and the feature subsets the host drew (the device records a
+    split's feature by its index in the subset that was searched)."""
+
+    max_depth: int
+    n_values: int
+    max_features: int
+    shallow: List[Tuple[int, int, int, np.ndarray]]  # (level, g0, g1, feats)
+    deep_level: int                                  # first deep level, or -1
+    deep_feats: Any                                  # (T, F) int32 or None
+
+
+class _Dispatcher:
+    """The fit's dispatches, counted: every executable goes through the
+    process's precompiler under a key of its static geometry."""
+
+    def __init__(self):
+        self.pc = global_precompiler()
+        self.keys: set = set()
+        self.n = 0
+
+    def submit(self, key, fn, *avals, **statics):
+        self.pc.submit(key, fn, *avals, **statics)
+
+    def call(self, key, fn, *args, **statics):
+        self.n += 1
+        self.keys.add(key)
+        return self.pc.call(key, fn, *args, **statics)
+
+
+def grow_forest_mxu_device(
     bins_fm: jax.Array,     # (D, N_pad) int8 feature-major binned features
     base_stats: jax.Array,  # (S, N_pad) f32 unweighted stat rows (see below)
     w_trees: jax.Array,     # (T, N_pad) f32 per-tree bootstrap*mask weights
     stats3: jax.Array,      # (3, N_pad) f32 (1, y, y^2)*mask rows (reg) or None
-    edges: np.ndarray,      # (D, B-1) raw-space bin edges
     max_depth: int,
     n_bins: int,
     kind: str,              # "gini" | "entropy" | "regression"
@@ -988,18 +729,20 @@ def grow_forest_mxu(
     min_impurity_decrease: float,
     seed: int,
     y_vals: jax.Array = None,
+    n_rows: int = None,
     interpret: bool = False,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Grow T trees; returns grow_forest's host-array contract:
-    (features (T, M), thresholds, leaf_values (T, M, V), n_samples,
-    impurities).
+) -> Tuple[jax.Array, ForestPlan]:
+    """Grow T trees on the device; returns (tree_buf, plan) with nothing read
+    back: the caller fetches tree_buf (core.fetch_fit_result) and hands its
+    host copy to pack_forest.
 
     base_stats rows: regression -> (1*mask, y*mask); classification ->
     per-class one-hot rows (S = n_classes).  stats3 supplies the per-node
     impurity stats for regression (ignored for classification).  y_vals
     (raw target / class index per row) is required when max_depth exceeds
     the shallow slot budget — the deep phase rebuilds stats from it after
-    the bucket sort."""
+    the segment sort.  n_rows (the frame's rows, without padding) only feeds
+    the forest.hist_rows_needed counter."""
     T, n_pad = w_trees.shape
     D = bins_fm.shape[0]
     S = base_stats.shape[0]
@@ -1008,190 +751,276 @@ def grow_forest_mxu(
     assert mxu_depth_supported(max_depth, S), "depth exceeds MXU slot budget"
     l_s = _shallow_levels(S)
     shallow_top = min(max_depth, l_s)
-    if max_depth > l_s:
+    deep = max_depth > l_s
+    if deep:
         assert y_vals is not None, "deep growth needs y_vals"
+    n_rows = n_pad if n_rows is None else int(n_rows)
 
     M = 2 ** (max_depth + 1) - 1
-    feature = np.full((T, M), -1, np.int32)
-    threshold = np.zeros((T, M), np.float32)
-    leaf_value = np.zeros((T, M, V), np.float32)
-    n_samples = np.zeros((T, M), np.float32)
-    impurity = np.zeros((T, M), np.float32)
-
+    C = 5 + V
     rng = np.random.default_rng(seed)
     F = int(max_features)
     f_pad = -(-max(F, 1) // _F_BLOCK) * _F_BLOCK
     msl = float(min_samples_leaf)
     mid = float(min_impurity_decrease)
-    rel = jnp.zeros((T, n_pad), jnp.int32)
     stat_rows = stats3 if kind == "regression" else base_stats
     s_rows = int(stat_rows.shape[0])
-    pc = global_precompiler()
+    run = _Dispatcher()
     f32, i32, i8 = jnp.float32, jnp.int32, jnp.int8
-
-    # --- submit every geometry known at entry for parallel compilation ----
+    a_rel, a_buf = aval((T, n_pad), i32), aval((C, T, M), f32)
+    a_w, a_t0 = aval((T, n_pad), f32), aval((), i32)
     chunk = 16384 if n_pad % 16384 == 0 else _ROW_TILE
-    pc.submit(
-        ("gather_rows", D, n_pad, F, f_pad, chunk),
-        gather_rows_matmul, aval((D, n_pad), i8), aval((F,), i32),
+
+    def tpack_at(level: int) -> int:
+        return _even_chunk(T, M_SLOTS // (2**level * S))
+
+    def draw_subset() -> np.ndarray:
+        # every feature: in the table's order, so ties go to the lowest feature
+        if F >= D:
+            return np.arange(D, dtype=np.int32)
+        return rng.choice(D, F, replace=False).astype(np.int32)
+
+    # --- every geometry of the fit, submitted for parallel compilation ----
+    k_gather = ("gather_rows", D, n_pad, F, f_pad, chunk)
+    run.submit(
+        k_gather, gather_rows_matmul, aval((D, n_pad), i8), aval((F,), i32),
         f_pad=f_pad, chunk=chunk,
     )
+    shallow_keys = {}
     for level in range(shallow_top + 1):
-        nodes = 2**level
-        tpack = max(1, min(T, M_SLOTS // (nodes * S)))
+        nodes, tpack = 2**level, tpack_at(level)
         if level == max_depth:
-            pc.submit(
-                ("shallow_leaf", T, n_pad, s_rows, tpack, nodes),
-                _shallow_leaf,
-                aval((T, n_pad), i32), aval((T, n_pad), f32),
-                aval((s_rows, n_pad), f32), aval((), i32),
-                tpack=tpack, nodes=nodes,
+            key = ("shallow_leaf", T, n_pad, M, s_rows, tpack, nodes, kind)
+            run.submit(
+                key, _shallow_leaf, a_rel, a_buf, a_w,
+                aval((s_rows, n_pad), f32), a_t0,
+                tpack=tpack, nodes=nodes, kind=kind,
             )
         else:
-            pc.submit(
-                ("shallow_step", T, n_pad, s_rows, f_pad, tpack, nodes, S,
-                 kind, n_bins, F, msl, mid, interpret),
-                _shallow_step,
-                aval((T, n_pad), i32), aval((T, n_pad), f32),
-                aval((s_rows, n_pad), f32), aval((f_pad, n_pad), i8),
-                aval((), i32),
+            key = ("shallow_step", T, n_pad, M, s_rows, f_pad, tpack, nodes, S,
+                   kind, n_bins, F, msl, mid, interpret)
+            run.submit(
+                key, _shallow_step, a_rel, a_buf, a_w,
+                aval((s_rows, n_pad), f32), aval((f_pad, n_pad), i8), a_t0,
                 tpack=tpack, nodes=nodes, s_dim=S, kind=kind, n_bins=n_bins,
                 F=F, msl=msl, mid=mid, interpret=interpret,
             )
-    if max_depth > l_s:
-        # the deep phase's entry-known geometries: the count round-trip, the
-        # packed subset build and — critically — the payload sort, whose
-        # static width bound lets its compile overlap the shallow phase
-        n_buckets_d = 2 ** (l_s + 1)
-        F_d = F
-        P_d = -(-F_d // 4)
-        f_pad_d = -(-max(F_d, 4) // _F_BLOCK) * _F_BLOCK
-        n2_d = _deep_geometry(n_pad, n_buckets_d)
-        pc.submit(
-            ("keys_bounds", T, n_pad, n_buckets_d),
-            _keys_bounds, aval((T, n_pad), i32), n_buckets=n_buckets_d,
+        shallow_keys[level] = key
+    if deep:
+        bucket_level = l_s + 1
+        nb = 2**bucket_level
+        P = -(-F // 4)
+        f_pad_d = -(-max(F, 4) // _F_BLOCK) * _F_BLOCK
+        n2 = _deep_width(n_pad, nb)
+        n_tiles = n2 // _ROW_TILE_DEEP
+        a_keys = aval((T, n2 - n_pad), i32)
+        a_bins = aval((T, f_pad_d, n2), i8)
+        a_loc = aval((T, 1, n2), i32)
+        a_st = aval((T, S, n2), f32)
+        a_st3 = aval((T, 3 if kind == "regression" else S, n2), f32)
+        a_seg = aval((T, n_tiles), i32)
+        k_layout = ("deep_layout", T, n_pad, nb, n2)
+        run.submit(k_layout, _deep_layout, a_rel, n_buckets=nb, n2=n2)
+        k_pack = ("pack_all", D, n_pad, T, F, f_pad_d, P, chunk)
+        run.submit(
+            k_pack, _pack_all, aval((D, n_pad), i8), aval((T, F), i32),
+            f_pad=f_pad_d, P=P, chunk=chunk,
         )
-        pc.submit(
-            ("pack_all", D, n_pad, T, F_d, f_pad_d, P_d, chunk),
-            _pack_all, aval((D, n_pad), i8), aval((T, F_d), i32),
-            f_pad=f_pad_d, P=P_d, chunk=chunk,
+        k_sort = {
+            name: ("sort_part_" + name, T, n_pad, nb, n2)
+            for name in ("i32", "f32", "f32_1d")
+        }
+        for name, a_pay in (
+            ("i32", aval((T, n_pad), i32)), ("f32", a_w),
+            ("f32_1d", aval((n_pad,), f32)),
+        ):
+            run.submit(
+                k_sort[name], _sort_part, a_rel, a_keys, a_pay,
+                n_buckets=nb, n2=n2,
+            )
+        k_state = ("deep_state", T, n2, P, f_pad_d, S, kind)
+        run.submit(
+            k_state, _deep_state,
+            tuple(aval((T, n2), i32) for _ in range(P)),
+            aval((T, n2), f32), aval((T, n2), f32),
+            f_pad=f_pad_d, s_dim=S, kind=kind,
         )
-        pc.submit(
-            ("sort_part_i32", T, n_pad, n_buckets_d, n2_d),
-            _sort_part,
-            aval((T, n_pad), i32), aval((T, n2_d - n_pad), i32),
-            aval((T, n_pad), i32),
-            n_buckets=n_buckets_d, n2=n2_d,
-        )
-        pc.submit(
-            ("sort_part_f32", T, n_pad, n_buckets_d, n2_d),
-            _sort_part,
-            aval((T, n_pad), i32), aval((T, n2_d - n_pad), i32),
-            aval((T, n_pad), f32),
-            n_buckets=n_buckets_d, n2=n2_d,
-        )
-        pc.submit(
-            ("sort_part_f32_1d", T, n_pad, n_buckets_d, n2_d),
-            _sort_part,
-            aval((T, n_pad), i32), aval((T, n2_d - n_pad), i32),
-            aval((n_pad,), f32),
-            n_buckets=n_buckets_d, n2=n2_d,
+        deep_keys, deep_chunk = {}, {}
+        for level in range(bucket_level, max_depth):
+            slots_pad = max(8, -(-(2 ** (level - bucket_level) * S) // 8) * 8)
+            per_tree = (nb + 1) * f_pad_d * slots_pad * n_bins * 4
+            # at most 16 trees a step: their tile map is the kernel's
+            # scalar-prefetch operand
+            tc = _even_chunk(T, min(16, max(1, _DEEP_HIST_BYTES // per_tree)))
+            key = ("deep_step", T, n2, M, f_pad_d, tc, level, bucket_level, S,
+                   kind, n_bins, F, msl, mid, interpret)
+            run.submit(
+                key, _deep_step, a_bins, a_loc, a_st, a_st3, a_seg, a_buf, a_t0,
+                t_chunk=tc, level=level, bucket_level=bucket_level, s_dim=S,
+                kind=kind, n_bins=n_bins, F=F, msl=msl, mid=mid,
+                interpret=interpret,
+            )
+            deep_keys[level], deep_chunk[level] = key, tc
+        k_leaf = ("deep_leaf", T, n2, M, max_depth, bucket_level, S, kind)
+        run.submit(
+            k_leaf, _deep_leaf, a_loc, a_st3, a_seg, a_buf,
+            level=max_depth, bucket_level=bucket_level, kind=kind,
         )
 
-    # Host fetches are DEFERRED: every (level, group) appends its small
-    # result arrays here and one jax.device_get at the end of the phase
-    # collects them all.  A per-iteration device_get would block dispatch on
-    # a host<->device round-trip per group per level (hundreds of syncs for
-    # a deep forest); nothing on the host is needed inside the loop, since routing (rel)
-    # stays on device.
-    pending = []  # (tag, g0, g1, level_slice, feats_np, offset, arrays)
+    # every subset of the fit, drawn and put on the device before the first
+    # dispatch (the device idles then anyway): the level loops below enqueue
+    # executables only, so the host runs ahead of the device and a late host
+    # costs the device nothing
+    shallow: List[Tuple[int, int, int, np.ndarray]] = [
+        (level, g0, min(g0 + tpack_at(level), T), draw_subset())
+        for level in range(min(shallow_top + 1, max_depth))
+        for g0 in range(0, T, tpack_at(level))
+    ]
+    # one deep subset per tree, shared by its levels >= bucket_level
+    deep_feats = np.stack([draw_subset() for _ in range(T)]) if deep else None
+    staged = jax.device_put([f for _lv, _g0, _g1, f in shallow] + ([deep_feats] if deep else []))
+    subsets = iter(staged)
 
-    for level in range(shallow_top + 1):
-        nodes = 2**level
-        is_last = level == max_depth
-        tpack = max(1, min(T, M_SLOTS // (nodes * S)))
-        base = 2**level - 1
-        for g0 in range(0, T, tpack):
-            g1 = min(g0 + tpack, T)
-            o = max(0, g0 - (T - tpack))  # window clamp offset
-            g0_dev = jnp.asarray(np.int32(g0))
-            sl = slice(base, base + nodes)
-            if is_last:
-                tot = pc.call(
-                    ("shallow_leaf", T, n_pad, s_rows, tpack, nodes),
-                    _shallow_leaf, rel, w_trees, stat_rows, g0_dev,
-                    tpack=tpack, nodes=nodes,
-                )
-                pending.append(
-                    (
-                        "leaf_reg" if kind == "regression" else "leaf_cls",
-                        g0, g1, sl, None, o, tot,
+    rel = jnp.zeros((T, n_pad), jnp.int32)
+    buf = jnp.zeros((C, T, M), jnp.float32)
+    hist_rows = hist_needed = 0
+
+    with profiling.span("forest.shallow"):
+        for level in range(shallow_top + 1):
+            nodes, tpack = 2**level, tpack_at(level)
+            with profiling.span("forest.level", level=level) as sp:
+                before = run.n
+                for g0 in range(0, T, tpack):
+                    g1 = min(g0 + tpack, T)
+                    g0_dev = _offset(g0)
+                    if level == max_depth:
+                        buf = run.call(
+                            shallow_keys[level], _shallow_leaf, rel, buf,
+                            w_trees, stat_rows, g0_dev,
+                            tpack=tpack, nodes=nodes, kind=kind,
+                        )
+                        continue
+                    sub = run.call(
+                        k_gather, gather_rows_matmul, bins_fm, next(subsets),
+                        f_pad=f_pad, chunk=chunk,
                     )
+                    rel, buf = run.call(
+                        shallow_keys[level], _shallow_step, rel, buf, w_trees,
+                        stat_rows, sub, g0_dev,
+                        tpack=tpack, nodes=nodes, s_dim=S, kind=kind,
+                        n_bins=n_bins, F=F, msl=msl, mid=mid,
+                        interpret=interpret,
+                    )
+                    hist_rows += tpack * n_pad
+                    hist_needed += (g1 - g0) * n_rows
+                sp.set(dispatches=run.n - before)
+
+    if deep:
+        with profiling.span("forest.sort") as sp:
+            before = run.n
+            dkeys, tile_seg = run.call(
+                k_layout, _deep_layout, rel, n_buckets=nb, n2=n2
+            )
+            packed = run.call(
+                k_pack, _pack_all, bins_fm, next(subsets),
+                f_pad=f_pad_d, P=P, chunk=chunk,
+            )
+            sort = lambda name, payload: run.call(
+                k_sort[name], _sort_part, rel, dkeys, payload,
+                n_buckets=nb, n2=n2,
+            )
+            packed_sorted = tuple(sort("i32", packed[:, p, :]) for p in range(P))
+            w_sorted = sort("f32", w_trees)
+            y_sorted = sort("f32_1d", y_vals)
+            del packed
+            bins_s, stats_s, st3, rel_loc = run.call(
+                k_state, _deep_state, packed_sorted, w_sorted, y_sorted,
+                f_pad=f_pad_d, s_dim=S, kind=kind,
+            )
+            del packed_sorted, w_sorted, y_sorted, rel
+            sp.set(dispatches=run.n - before)
+        with profiling.span("forest.deep"):
+            for level in range(bucket_level, max_depth):
+                tc = deep_chunk[level]
+                with profiling.span("forest.level", level=level) as sp:
+                    before = run.n
+                    for t0 in range(0, T, tc):
+                        rel_loc, buf = run.call(
+                            deep_keys[level], _deep_step, bins_s, rel_loc,
+                            stats_s, st3, tile_seg, buf, _offset(t0),
+                            t_chunk=tc, level=level, bucket_level=bucket_level,
+                            s_dim=S, kind=kind, n_bins=n_bins, F=F, msl=msl,
+                            mid=mid, interpret=interpret,
+                        )
+                        hist_rows += tc * n2
+                        hist_needed += (min(t0 + tc, T) - t0) * n_rows
+                    sp.set(dispatches=run.n - before)
+            with profiling.span("forest.level", level=max_depth, dispatches=1):
+                buf = run.call(
+                    k_leaf, _deep_leaf, rel_loc, st3, tile_seg, buf,
+                    level=max_depth, bucket_level=bucket_level, kind=kind,
                 )
-                continue
 
-            feats_np = rng.choice(D, F, replace=False).astype(np.int32)
-            sub = pc.call(
-                ("gather_rows", D, n_pad, F, f_pad, chunk),
-                gather_rows_matmul, bins_fm, jnp.asarray(feats_np),
-                f_pad=f_pad, chunk=chunk,
-            )
-            rel, out = pc.call(
-                ("shallow_step", T, n_pad, s_rows, f_pad, tpack, nodes, S,
-                 kind, n_bins, F, msl, mid, interpret),
-                _shallow_step, rel, w_trees, stat_rows, sub, g0_dev,
-                tpack=tpack, nodes=nodes, s_dim=S, kind=kind, n_bins=n_bins,
-                F=F, msl=msl, mid=mid, interpret=interpret,
-            )
-            pending.append(("split", g0, g1, sl, feats_np, o, out))
+    profiling.incr_counter("forest.levels", max_depth + 1)
+    profiling.incr_counter("forest.dispatches", run.n)
+    profiling.incr_counter("forest.geometries", len(run.keys))
+    profiling.incr_counter("forest.hist_rows", hist_rows)
+    profiling.incr_counter("forest.hist_rows_needed", hist_needed)
+    plan = ForestPlan(
+        max_depth, V, F, shallow, bucket_level if deep else -1, deep_feats
+    )
+    return buf, plan
 
-    # single host fetch for the whole shallow phase
-    fetched = jax.device_get([p[6] for p in pending])
-    for (tag, g0, g1, sl, feats_np, o, _), got in zip(pending, fetched):
-        tp = g1 - g0
-        if tag == "leaf_reg":
-            tot_h = np.asarray(got)[o : o + tp]
-            w_n = np.maximum(tot_h[:, :, 0], 1e-12)
-            val = (tot_h[:, :, 1] / w_n)[:, :, None]
-            imp = np.maximum(
-                tot_h[:, :, 2] / w_n - (tot_h[:, :, 1] / w_n) ** 2, 0.0
-            )
-            n_samples[g0:g1, sl] = tot_h[:, :, 0]
-            impurity[g0:g1, sl] = imp
-            leaf_value[g0:g1, sl] = val
-        elif tag == "leaf_cls":
-            cls_h = np.asarray(got)[o : o + tp]
-            w_n = np.maximum(cls_h.sum(axis=2), 1e-12)
-            val = cls_h / w_n[:, :, None]
-            if kind == "entropy":
-                imp = -(val * np.log2(np.maximum(val, 1e-12))).sum(2)
-            else:
-                imp = 1.0 - (val * val).sum(axis=2)
-            n_samples[g0:g1, sl] = cls_h.sum(2)
-            impurity[g0:g1, sl] = imp
-            leaf_value[g0:g1, sl] = val
-        else:
-            bf_h, bb_h, ok_h, pw_h, pi_h, pv_h = (
-                np.asarray(a)[o : o + tp] for a in got
-            )
-            gf = feats_np[np.minimum(bf_h, F - 1)]
-            n_samples[g0:g1, sl] = pw_h
-            impurity[g0:g1, sl] = pi_h
-            leaf_value[g0:g1, sl] = pv_h
-            feature[g0:g1, sl] = np.where(ok_h, gf, -1)
-            threshold[g0:g1, sl] = np.where(
-                ok_h,
-                edges[gf, np.minimum(bb_h, edges.shape[1] - 1)],
-                0.0,
-            )
-    if max_depth > l_s:
-        _deep_phase(
-            rel, bins_fm, w_trees, y_vals, edges,
-            (feature, threshold, leaf_value, n_samples, impurity), rng,
-            bucket_level=l_s + 1, max_depth=max_depth, n_bins=n_bins,
-            kind=kind, s_dim=S, max_features=F,
-            min_samples_leaf=msl,
-            min_impurity_decrease=mid,
-            interpret=interpret,
-        )
-    return feature, threshold, leaf_value, n_samples, impurity
+
+def pack_forest(
+    buf: np.ndarray, plan: ForestPlan, edges: np.ndarray
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """tree_buf's host copy -> grow_forest's dense arrays: (features (T, M),
+    thresholds, leaf_values (T, M, V), n_samples, impurities).  A split's
+    feature was recorded by its index in the subset searched; the subset is
+    the host's, and the threshold is that feature's edge at the split bin."""
+    buf = np.asarray(buf)
+    T, M = buf.shape[1:]
+    edges = np.asarray(edges)
+    # only the nodes that split have a feature and a threshold to look up
+    ok = buf[_CH_OK] > 0.5
+    at_t, at_n = np.nonzero(ok)
+    local_f = np.minimum(buf[_CH_F][at_t, at_n].astype(np.int32), plan.max_features - 1)
+    bb = np.minimum(buf[_CH_B][at_t, at_n].astype(np.int32), edges.shape[1] - 1)
+    gf = np.empty(at_t.shape, np.int32)
+    level = np.floor(np.log2(at_n + 1)).astype(np.int32)
+    deep = level >= plan.deep_level if plan.deep_level >= 0 else np.zeros(at_t.shape, bool)
+    if deep.any():
+        gf[deep] = plan.deep_feats[at_t[deep], local_f[deep]]
+    # shallow: the subset of the node's (level, tree group), from a table of them
+    if plan.shallow:
+        subset_of = np.full((plan.max_depth + 1, T), -1, np.int32)
+        for k, (lv, g0, g1, _feats) in enumerate(plan.shallow):
+            subset_of[lv, g0:g1] = k
+        table = np.stack([f for _lv, _g0, _g1, f in plan.shallow])
+        sh = ~deep
+        gf[sh] = table[subset_of[level[sh], at_t[sh]], local_f[sh]]
+    feature = np.full((T, M), -1, np.int32)
+    feature[at_t, at_n] = gf
+    threshold = np.zeros((T, M), np.float32)
+    threshold[at_t, at_n] = edges[gf, bb]
+    leaf_value = np.ascontiguousarray(np.moveaxis(buf[_CH_VAL:], 0, -1))
+    profiling.incr_counter("forest.nodes", int(2 * at_t.size + T))
+    return feature, threshold, leaf_value, buf[_CH_W].copy(), buf[_CH_IMP].copy()
+
+
+def grow_forest_mxu(
+    bins_fm: jax.Array,
+    base_stats: jax.Array,
+    w_trees: jax.Array,
+    stats3: jax.Array,
+    edges: np.ndarray,      # (D, B-1) raw-space bin edges
+    **kwargs,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """grow_forest_mxu_device + a plain fetch + pack_forest: grow_forest's
+    host-array contract in one call (tests; the estimator fetches through
+    core.fetch_fit_result between the two)."""
+    buf, plan = grow_forest_mxu_device(
+        bins_fm, base_stats, w_trees, stats3, **kwargs
+    )
+    return pack_forest(np.asarray(buf), plan, np.asarray(edges))

@@ -319,6 +319,7 @@ def _bin_features_fm_pallas(
             (d_x, _round_up(n_pad, tn)), jnp.int8
         ),
         interpret=interpret,
+        name="forest_bin",
     )(Xp, e_pad)
     return out[:d, :n_pad]
 
