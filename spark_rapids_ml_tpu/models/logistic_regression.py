@@ -55,6 +55,7 @@ from ..params import (
 from ..ops.logistic import (
     logistic_decision_kernel,
     logistic_fit_kernel,
+    one_pass_objective,
     scores_to_labels,
     scores_to_probs,
     sweep_logistic_fit_kernel,
@@ -62,11 +63,15 @@ from ..ops.logistic import (
 from ..utils import get_logger
 
 
-def _count_lbfgs(n_iter: Any, n_evals: Any) -> None:
+def _count_lbfgs(n_iter: Any, n_evals: Any, one_pass: bool = False) -> None:
     """Add fetched L-BFGS counts (scalars, or one entry a lane) to the
     process-wide counters lbfgs.fits / lbfgs.iters / lbfgs.evals, which a
-    fit's telemetry snapshot then carries as its own deltas."""
+    fit's telemetry snapshot then carries as its own deltas.  one_pass: the
+    fits read X once an evaluation (ops/logistic.one_pass_objective), and
+    count in lbfgs.one_pass_fits too."""
     profiling.incr_counter("lbfgs.fits", int(np.size(n_iter)))
+    if one_pass:
+        profiling.incr_counter("lbfgs.one_pass_fits", int(np.size(n_iter)))
     profiling.incr_counter("lbfgs.iters", int(np.sum(n_iter)))
     profiling.incr_counter("lbfgs.evals", int(np.sum(n_evals)))
 
@@ -311,6 +316,7 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                     int(params["max_iter"]),
                     float(params["tol"]),
                     use_owlqn,
+                    inputs.mesh,
                 )
             # one batched device fetch (each scalar coercion alone costs a
             # host round-trip)
@@ -319,7 +325,9 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                 # the device buffers go inside a step, not with the frame
                 # after the last one
                 del solved
-                _count_lbfgs(n_iter_h, n_evals_h)
+                _count_lbfgs(
+                    n_iter_h, n_evals_h, one_pass_objective(inputs.X, k, inputs.mesh)
+                )
                 logger.info(
                     "L-BFGS iters: %d evaluations: %d converged: %s",
                     int(n_iter_h), int(n_evals_h), bool(conv_h),

@@ -5,8 +5,9 @@
 # LogisticRegressionMG (the reference configures it at
 # classification.py:955-961: lbfgs_memory=10, penalty_normalized=False).
 # The smooth objective's value+grad closure is evaluated over row-sharded
-# arrays, so its reductions compile to psums — every optimizer iteration is
-# one fused device program with one all-reduce, no host round trips.
+# arrays: its reductions compile to psums, or it brings one psum of its own
+# (the one-pass logistic data term) — every optimizer iteration is one fused
+# device program with one all-reduce an evaluation, no host round trips.
 #
 # OWL-QN (Andrew & Gao 2007) handles the L1 term: pseudo-gradient at the
 # current orthant, direction aligned against the pseudo-gradient, orthant
@@ -35,8 +36,10 @@ class LbfgsResult(NamedTuple):
     converged: jax.Array
     # evaluations of the objective (value and gradient): the one at x0 and
     # every line-search trial.  An evaluation is what a fit's time is made
-    # of (two passes over X in the logistic kernels), and the line search
-    # takes another number of them on every dataset; n_iter does not say.
+    # of (in the logistic kernels one pass over X for a dense binary fit on
+    # the TPU, ops/logistic_pass.py, and two wherever the gradient comes
+    # from autodiff), and the line search takes another number of them on
+    # every dataset; n_iter does not say.
     n_evals: jax.Array
 
 

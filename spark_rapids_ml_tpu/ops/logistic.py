@@ -10,9 +10,16 @@
 #           + reg * ( l1r * |W|_1  +  (1 - l1r)/2 * |W|_2^2 )
 #
 # with reg = regParam (C = 1/reg in the param surface), intercepts never
-# regularized.  The data term is evaluated over the row-sharded (X, y, w), so
-# jax.grad's reductions become psums; L1 is handled by OWL-QN in
-# ops/lbfgs.py.
+# regularized.  L1 is handled by OWL-QN in ops/lbfgs.py.
+#
+# The data term and its gradient, over the row-sharded (X, y, w):
+#   - dense binary: closed form (ops/logistic_pass.py), no autodiff.  On the
+#     TPU the Pallas call `logistic_pass` reads each row ONCE an evaluation
+#     (one_pass_objective says when); on a mesh every device walks its own
+#     rows under shard_map and one psum of (g, gb, loss) joins them.  Anywhere
+#     else the same sums in plain jnp, whose reductions GSPMD turns into psums.
+#   - ELL sparse X and multinomial: jax.value_and_grad of the loss, whose
+#     reductions become psums the same way (two reads of X an evaluation).
 #
 
 from __future__ import annotations
@@ -23,8 +30,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from ..parallel.mesh import DATA_AXIS
 from .lbfgs import minimize_lbfgs, minimize_lbfgs_batched
 from .linalg import exact_matmul
+from .logistic_pass import binary_block_sums, one_pass_sums, takes_table
+from .pallas_tpu import pallas_enabled
+from .sparse import EllMatrix, ell_matmat
 
 
 def _unpack(theta: jax.Array, k: int, d: int, fit_intercept: bool):
@@ -39,8 +50,6 @@ def _model_scores(X, W, b):
     scatter-add X.T @ r — one code path for both L-BFGS objectives, no
     densification of sparse inputs (reference sparse qn fit,
     classification.py:1206-1218)."""
-    from .sparse import EllMatrix, ell_matmat
-
     if isinstance(X, EllMatrix):
         return ell_matmat(X, W.T) + b
     return X @ W.T + b
@@ -62,9 +71,42 @@ def _softmax_data_loss(theta, X, yidx, w, k, d, fit_intercept):
     return (ll * w).sum() / w.sum()
 
 
+def one_pass_objective(X, k: int, mesh=None) -> bool:
+    """Whether a fit of (X, k classes) takes the one-pass data term: a dense
+    float32 table, binary, where Pallas kernels run, of more rows a device
+    than XLA keeps in VMEM (ops/logistic_pass.takes_table).  Static at
+    dispatch, so the host knows it too (the lbfgs.one_pass_fits counter)."""
+    if isinstance(X, EllMatrix) or k != 1 or X.dtype != jnp.float32:
+        return False
+    shards = 1 if mesh is None else mesh.shape[DATA_AXIS]
+    return pallas_enabled() and takes_table(X.shape[0] // shards, X.shape[1])
+
+
+def _binary_value_and_grad(X, y01, w, d, fit_intercept, mesh):
+    """theta -> (_binary_data_loss, its gradient) of a dense X in closed
+    form.  Built once a solve, outside the optimizer's loop; so is 1 / sum w,
+    which the sums are multiplied by (autodiff's gradient was: a division
+    in the loop costs a streamed chunk's fit 1%, v5e, 8192 x 256)."""
+    inv_wsum = 1.0 / w.sum()
+    if one_pass_objective(X, 1, mesh):
+        sums = one_pass_sums(X, y01, w, mesh)
+    else:
+        def sums(W, b):
+            return binary_block_sums(X, y01, w, W, b)
+
+    def value_and_grad(theta):
+        W, b = _unpack(theta, 1, d, fit_intercept)
+        loss, g, gb = sums(W[0], b[0])
+        if fit_intercept:
+            g = jnp.concatenate([g, gb[None]])
+        return loss * inv_wsum, g * inv_wsum
+
+    return value_and_grad
+
+
 def _solve_from(
     X, y_enc, w, theta0, k, reg, l1_ratio, fit_intercept, max_iter, tol,
-    use_owlqn,
+    use_owlqn, mesh=None,
 ):
     """Shared L-BFGS/OWL-QN solve from an explicit starting point — the ONE
     objective construction behind the batch kernel (zero init) and the
@@ -79,17 +121,33 @@ def _solve_from(
         [jnp.ones(k * d, dtype), jnp.zeros(n_params - k * d, dtype)]
     )
 
-    def value_and_grad(theta):
-        def smooth(t):
-            if k == 1:
-                data = _binary_data_loss(t, X, y_enc.astype(dtype), w, d, fit_intercept)
-            else:
-                data = _softmax_data_loss(
-                    t, X, y_enc.astype(jnp.int32), w, k, d, fit_intercept
-                )
-            return data + 0.5 * l2 * ((t * reg_mask) ** 2).sum()
+    def penalty(t):
+        return 0.5 * l2 * ((t * reg_mask) ** 2).sum()
 
-        return jax.value_and_grad(smooth)(theta)
+    if k == 1 and not isinstance(X, EllMatrix):
+        data_value_and_grad = _binary_value_and_grad(
+            X, y_enc.astype(dtype), w, d, fit_intercept, mesh
+        )
+
+        def value_and_grad(theta):
+            f, g = data_value_and_grad(theta)
+            return f + penalty(theta), g + l2 * reg_mask * theta
+
+    else:
+
+        def value_and_grad(theta):
+            def smooth(t):
+                if k == 1:
+                    data = _binary_data_loss(
+                        t, X, y_enc.astype(dtype), w, d, fit_intercept
+                    )
+                else:
+                    data = _softmax_data_loss(
+                        t, X, y_enc.astype(jnp.int32), w, k, d, fit_intercept
+                    )
+                return data + penalty(t)
+
+            return jax.value_and_grad(smooth)(theta)
 
     result = minimize_lbfgs(
         value_and_grad,
@@ -106,7 +164,7 @@ def _solve_from(
 
 @partial(
     jax.jit,
-    static_argnames=("k", "fit_intercept", "max_iter", "use_owlqn"),
+    static_argnames=("k", "fit_intercept", "max_iter", "use_owlqn", "mesh"),
 )
 def logistic_fit_kernel(
     X: jax.Array,
@@ -119,16 +177,20 @@ def logistic_fit_kernel(
     max_iter: int,
     tol: float,
     use_owlqn: bool,
+    mesh=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fit one logistic model; k == 1 -> binary sigmoid (y_enc in {0,1}),
     k >= 2 -> multinomial softmax (y_enc = class index).  Returns
     (W (k, D), b (k,), n_iter, converged, n_evals): n_evals counts the
-    objective's evaluations (ops/lbfgs.LbfgsResult)."""
+    objective's evaluations (ops/lbfgs.LbfgsResult).  `mesh` is the mesh a
+    row-sharded X lies on: the one-pass data term runs per device under its
+    shard_map (a Pallas call is no program GSPMD can partition); None for a
+    table on one device."""
     d = X.shape[1]
     n_params = k * d + (k if fit_intercept else 0)
     return _solve_from(
         X, y_enc, w, jnp.zeros((n_params,), X.dtype), k, reg, l1_ratio,
-        fit_intercept, max_iter, tol, use_owlqn,
+        fit_intercept, max_iter, tol, use_owlqn, mesh,
     )
 
 
@@ -287,8 +349,6 @@ def logistic_decision_kernel(X: jax.Array, W: jax.Array, b: jax.Array) -> jax.Ar
     multinomial (matches cuML decision_function semantics used by the
     reference transform, classification.py:1236-1262).  Accepts dense or
     ELL sparse feature blocks."""
-    from .sparse import EllMatrix
-
     if isinstance(X, EllMatrix):
         return _model_scores(X, W, b)
     return exact_matmul(X, W.T) + b

@@ -182,3 +182,98 @@ def test_float64_warns_and_ignores():
     assert lr._float32_inputs is True
     model = lr.fit(_df(X, y))
     assert model.dtype == "float32"
+
+
+# -- the one-pass data term (ops/logistic_pass.py) through the public fit -----
+# Off the chip the rule `one_pass_objective` is False; a test steers it (the
+# program has no option for it) and the kernel then runs interpreted.
+
+
+def _force_one_pass(monkeypatch, on=True):
+    from spark_rapids_ml_tpu.ops import logistic, logistic_pass
+
+    monkeypatch.setattr(logistic, "pallas_enabled", lambda: on)
+    # a test's table is one XLA would keep in VMEM: the rule leaves those alone
+    monkeypatch.setattr(logistic_pass, "_RESIDENT_BYTES", 0)
+    # the jitted entry keeps what it traced: the rule is read at trace time
+    logistic.logistic_fit_kernel.clear_cache()
+
+
+@pytest.mark.parametrize("num_workers", [1, 8], ids=["one_device", "mesh8"])
+def test_one_pass_fit_matches_autodiff_fit_and_sklearn(monkeypatch, num_workers):
+    import jax
+    from sklearn.linear_model import LogisticRegression as SkLR
+
+    from spark_rapids_ml_tpu.ops import logistic
+
+    X, y = _cls_data(n=2300, d=8, seed=3, sep=0.7)   # 287-288 rows a device of 8
+    reg = 0.05
+    est = LogisticRegression(
+        regParam=reg, maxIter=200, tol=1e-10, num_workers=num_workers
+    )
+
+    def fit():
+        model = est.fit(_df(X, y))
+        return np.append(model.coefficients, model.intercept), model.fit_telemetry().counters
+
+    _force_one_pass(monkeypatch)
+    one_pass, counted = fit()
+    assert counted["lbfgs.fits"] == 1 and counted["lbfgs.one_pass_fits"] == 1
+
+    # the objective L-BFGS had before: autodiff of the data loss
+    _force_one_pass(monkeypatch, on=False)
+
+    def autodiff(X, y01, w, d, fit_intercept, mesh):
+        return jax.value_and_grad(
+            lambda t: logistic._binary_data_loss(t, X, y01, w, d, fit_intercept)
+        )
+
+    monkeypatch.setattr(logistic, "_binary_value_and_grad", autodiff)
+    before, counted = fit()
+    assert counted["lbfgs.fits"] == 1 and "lbfgs.one_pass_fits" not in counted
+    monkeypatch.undo()
+    logistic.logistic_fit_kernel.clear_cache()
+    plain, _ = fit()                                  # the closed form in plain jnp
+
+    np.testing.assert_allclose(one_pass, before, atol=2e-4)
+    np.testing.assert_allclose(plain, before, atol=2e-4)
+    sk = SkLR(C=1.0 / (reg * len(y)), max_iter=5000, tol=1e-12).fit(X, y)
+    np.testing.assert_allclose(one_pass[:-1], sk.coef_[0], atol=2e-3)
+    assert abs(one_pass[-1] - sk.intercept_[0]) < 2e-3
+
+
+@pytest.mark.parametrize(
+    "case,takes_it",
+    [("dense_binary", True), ("multinomial", False), ("sparse_binary", False),
+     ("dense_binary_disabled", False), ("dense_binary_that_fits_vmem", False)],
+)
+def test_one_pass_fits_counts_exactly_the_fits_that_took_it(monkeypatch, case, takes_it):
+    from spark_rapids_ml_tpu.ops import logistic, logistic_pass
+
+    calls = []
+    real = logistic_pass.one_pass_sums
+    monkeypatch.setattr(
+        logistic, "one_pass_sums", lambda *a, **k: calls.append(1) or real(*a, **k)
+    )
+    _force_one_pass(monkeypatch, on=case != "dense_binary_disabled")
+    if case == "dense_binary_that_fits_vmem":
+        monkeypatch.setattr(logistic_pass, "_RESIDENT_BYTES", 112 << 20)
+    extra = {}
+    if case == "multinomial":
+        X, y = _cls_data(n=600, k=3, seed=5)
+    elif case == "sparse_binary":
+        scipy_sparse = pytest.importorskip("scipy.sparse")
+        X, y = _cls_data(n=600, seed=6)
+        X = scipy_sparse.csr_matrix(np.where(np.abs(X) > 1.0, X, 0.0))
+        extra = {"float32_inputs": False}
+    else:
+        X, y = _cls_data(n=600, seed=7)
+    model = LogisticRegression(regParam=0.1, maxIter=30, num_workers=1, **extra).fit(
+        DataFrame.from_numpy(X, y=y, num_partitions=2)
+    )
+    counted = model.fit_telemetry().counters
+    assert counted["lbfgs.fits"] == 1
+    assert counted.get("lbfgs.one_pass_fits", 0) == int(takes_it)
+    assert bool(calls) == takes_it
+    monkeypatch.undo()
+    logistic.logistic_fit_kernel.clear_cache()

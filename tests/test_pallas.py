@@ -487,3 +487,120 @@ def test_knn_candidates_qres_multi_kblock_ragged_tail():
     order = np.argsort(d2, axis=1, kind="stable")[:, :k]
     want = np.sqrt(np.take_along_axis(d2, order, axis=1))
     np.testing.assert_allclose(np.asarray(fv), want, rtol=1e-3, atol=1e-3)
+
+
+# -- the one-pass logistic data term (ops/logistic_pass.py) -------------------
+# The fused value and gradient against jax.value_and_grad of the loss L-BFGS
+# had (ops/logistic._binary_data_loss) at HIGHEST, the kernel interpreted.
+
+
+def _logistic_case(n, d, seed, uniform=False, pad=0):
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, d)).astype(np.float32)
+    y = (rng.random(n) > 0.4).astype(np.float32)
+    w = np.ones(n, np.float32) if uniform else (0.25 + rng.random(n)).astype(np.float32)
+    if pad:
+        w[-pad:] = 0.0           # padding rows: weight 0, and rows that must not count
+        X[-pad:] = 1e3 * rng.standard_normal((pad, d)).astype(np.float32)
+    theta = (rng.standard_normal(d + 1) / np.sqrt(d)).astype(np.float32)
+    return tuple(jnp.asarray(a) for a in (X, y, w, theta))
+
+
+def _autodiff_reference(X, y, w, theta, fit_intercept):
+    from spark_rapids_ml_tpu.ops.logistic import _binary_data_loss
+
+    d = X.shape[1]
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(_binary_data_loss)(
+            theta if fit_intercept else theta[:d], X, y, w, d, fit_intercept
+        )
+
+
+def _assert_sums_match(sums, X, y, w, theta, fit_intercept):
+    d = X.shape[1]
+    b = theta[d] if fit_intercept else jnp.zeros((), jnp.float32)
+    loss, g, gb = sums(theta[:d], b)
+    f_ref, g_ref = _autodiff_reference(X, y, w, theta, fit_intercept)
+    got = jnp.concatenate([g, gb[None]]) if fit_intercept else g
+    scale = float(jnp.abs(g_ref).max())
+    np.testing.assert_allclose(float(loss / w.sum()), float(f_ref), rtol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(got / w.sum()), np.asarray(g_ref), atol=5e-6 * scale
+    )
+
+
+@pytest.mark.parametrize("fit_intercept", [True, False], ids=["intercept", "no_intercept"])
+@pytest.mark.parametrize(
+    "n,d,tile",
+    [
+        (512, 7, 256),      # whole tiles, a width under one sublane group
+        (700, 7, 256),      # whole tiles and a tail through the plain form
+        (100, 7, None),     # under one tile: the plain form alone
+        (768, 128, 256),
+        (900, 128, 256),
+        (90, 128, None),
+        (1024, 3000, None), # the benchmark's width: the tile follows from it (512)
+        (1100, 3000, None),
+        (300, 3000, None),  # under the width's tile: 256 rows in the kernel, 44 past it
+    ],
+)
+def test_logistic_one_pass_matches_autodiff(n, d, tile, fit_intercept):
+    from spark_rapids_ml_tpu.ops.logistic_pass import one_pass_sums
+
+    X, y, w, theta = _logistic_case(n, d, seed=n + d)
+    sums = one_pass_sums(X, y, w, tile=tile, interpret=KERNEL_INTERPRET)
+    _assert_sums_match(sums, X, y, w, theta, fit_intercept)
+
+
+@pytest.mark.parametrize(
+    "uniform,pad",
+    [(True, 0), (False, 0), (True, 37), (False, 300)],
+    ids=["uniform", "weighted", "uniform_padded", "weighted_padded_past_a_tile"],
+)
+def test_logistic_one_pass_weights_and_padding_rows(uniform, pad):
+    from spark_rapids_ml_tpu.ops.logistic_pass import one_pass_sums
+
+    X, y, w, theta = _logistic_case(840, 40, seed=pad, uniform=uniform, pad=pad)
+    sums = one_pass_sums(X, y, w, tile=256, interpret=KERNEL_INTERPRET)
+    _assert_sums_match(sums, X, y, w, theta, True)
+    if pad:  # the same sums as the table without its padding rows
+        keep = 840 - pad
+        short = one_pass_sums(X[:keep], y[:keep], w[:keep], tile=256, interpret=KERNEL_INTERPRET)
+        for a, b in zip(sums(theta[:40], theta[40]), short(theta[:40], theta[40])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+
+
+def test_logistic_row_tile_follows_the_width():
+    from spark_rapids_ml_tpu.ops.logistic_pass import row_tile, takes_table
+
+    assert takes_table(400_000, 3000)           # 4.8 GB a device: streamed from HBM
+    assert not takes_table(8192, 3000)          # 98 MB: XLA holds it in VMEM
+    assert not takes_table(400_000, 20_000)     # too wide for the kernel
+
+    assert row_tile(400_000, 3000) == 512       # 6 MB a buffer of the (3000, tile) block
+    assert row_tile(8192, 256) == 2048          # narrow tables: the cap
+    assert row_tile(300, 3000) == 256           # never more than the table
+    assert row_tile(100, 3000) == 0             # under 128 rows: no kernel
+    assert row_tile(400_000, 20_000) == 0       # too wide for the (D, 128) residents
+
+
+@pytest.mark.skipif(ON_TPU, reason="the 8-device mesh is the CPU suite's")
+@pytest.mark.parametrize("n_loc", [256, 300, 96], ids=["whole_tiles", "tail", "plain_only"])
+def test_logistic_one_pass_on_the_mesh_is_one_psum(n_loc):
+    """Row-sharded over the 8-device mesh: every device walks its own rows, and
+    ONE psum (of g, gb and the loss together) joins them."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.ops.logistic_pass import one_pass_sums
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, get_mesh
+
+    mesh = get_mesh(8)
+    X, y, w, theta = _logistic_case(8 * n_loc, 24, seed=n_loc, pad=50)
+    rows = NamedSharding(mesh, P(DATA_AXIS))
+    Xs, ys, ws = (jax.device_put(a, rows) for a in (X, y, w))
+
+    def run(theta):
+        return one_pass_sums(Xs, ys, ws, mesh, tile=128, interpret=True)(theta[:24], theta[24])
+
+    _assert_sums_match(lambda W, b: jax.jit(run)(theta), X, y, w, theta, True)
+    assert str(jax.make_jaxpr(run)(theta)).count("psum") == 1
