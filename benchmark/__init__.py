@@ -1,8 +1,6 @@
 #
-# TPU-native benchmark harness (counterpart of the reference's
-# /root/reference/python/benchmark/: benchmark_runner.py, benchmark/base.py,
-# gen_data.py).  The harness times estimator fit/transform on parquet (or
-# synthetic in-memory) datasets and scores model quality per algorithm, with
-# an optional sklearn CPU baseline mode standing in for the reference's
-# Spark-CPU comparison runs.
+# What is left of the port of the reference's python/benchmark/: gen_data.py,
+# its data generators, and audit_knn.py, the float64 ground-truth audit of
+# the kNN kernels on the chip.  The harness that timed estimators from here
+# was removed by PR 28: the repo's benchmark is chipbench/ (BENCHMARK.json).
 #

@@ -3,7 +3,7 @@
 # chip_smoke.py — the quickest proof that the system still starts on the chip.
 #
 # Drives the main path once through the entry points a user calls, at the
-# reference's published width (run_benchmark.sh:45-55, quoted in BASELINE.md:
+# reference's published width (upstream's run_benchmark.sh:45-55:
 # KMeans k=1000, initMode="random", tol=0.0 on 3000 float32 columns):
 #
 #   stage 0  device: what jax found; anything but a TPU stops the run

@@ -1,885 +1,66 @@
 #!/bin/bash
-# CI entry point (counterpart of the reference's ci/test.sh: lint -> unit
-# tests -> benchmark smoke on tiny data).
+# CI entry point: each gate once.
+#   1. byte-compile + graftlint     2. native build
+#   3. the Tier-1 tests, as the driver runs them (SRML_CI_FULL=1: the slow pass too)
+#   4. the benchmark's own CPU tests (chipbench/tests)
+# Speed is not a CI matter: it is measured on the chip, by BENCHMARK.json's cells
+# (docs/benchmarking.md).  The focused re-runs and the CPU smokes of the
+# pre-round harness that stood here were removed by PR 28; what each asserted
+# is a test under tests/ (CHANGES.md, PR 28, names them).
 set -ex
 
 cd "$(dirname "$0")/.."
 
-# 1. lint / static checks: byte-compile everything (mypy/black optional in
-#    this image), then graftlint — the JAX/TPU invariant checker (R1-R12:
-#    hidden host syncs, recompile risk, unbound collective axis names,
-#    nondeterministic RNG/set-order, float64 in solver kernels, raw clocks
-#    outside srml-scope, unnamed threads, remote-DMA confinement, unbounded
-#    waits, raw-socket confinement, lock-order/blocking-under-lock,
-#    shared-state write discipline; see docs/graftlint.md).  This is the
-#    ONE whole-package gate: R11/R12 need every module parsed together for
-#    the package-wide lock graph, and --fail-on-new vs the committed
-#    baseline makes any NEW finding a build error while audited debt stays
-#    visible as warnings (the per-PR per-module re-runs that used to ride
-#    each focused step below are consolidated here — same files, one
-#    program, no drift between the module lists and the tree).
-python -m compileall -q spark_rapids_ml_tpu benchmark tests bench.py chip_smoke.py __graft_entry__.py
+# 1. static checks: byte-compile everything, then graftlint, the JAX/TPU
+#    invariant checker (R1-R12, docs/graftlint.md).  ONE whole-package run: R11
+#    and R12 need every module parsed together for the package-wide lock graph,
+#    and --fail-on-new against the committed baseline makes any NEW finding a
+#    build error while audited debt stays visible as warnings.
+python -m compileall -q spark_rapids_ml_tpu benchmark chipbench tools tests \
+    chip_smoke.py __graft_entry__.py
 python -m tools.graftlint spark_rapids_ml_tpu benchmark \
     --baseline ci/graftlint-baseline.json --fail-on-new
 
-# 2. native runtime build
+# 2. native runtime build (optional at run time: without it the numpy routes
+#    are the path)
 make -C native
 
-# 3. unit tests on the virtual 8-device CPU mesh.  Default budget: the fast
-#    suite (heavy multi-process / deep-forest paths are @slow-tagged, like the
-#    reference's --runslow gate, conftest.py:96-116).  SRML_CI_FULL=1 adds the
-#    full --runslow pass (nightly budget).  Both wall-clocks are printed so the
-#    two CI budgets stay measured.
-#    --durations keeps the top time sinks visible so the default budget
-#    cannot quietly creep (round-4 judge: 338 s -> 492 s unnoticed).
+# 3. Tier-1: the fast suite on the virtual 8-device CPU mesh (tests/conftest.py
+#    pins the platform and the device count), with the driver's own options
+#    (/root/TESTS_LAST_RUN.json: six workers, a file to a worker, no cache
+#    provider).  Heavy multi-process and deep-forest paths are @slow-tagged.
+#    --durations keeps the top time sinks visible so the budget cannot creep
+#    unnoticed; both wall-clocks are printed.
 t0=$SECONDS
-python -m pytest tests/ -x -q --durations=10
-echo "CI budget: default suite took $((SECONDS - t0))s"
+env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
+    --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 \
+    --dist loadfile -p no:randomly --durations=10
+echo "CI budget: Tier-1 took $((SECONDS - t0))s"
 if [ "${SRML_CI_FULL:-0}" = "1" ]; then
+    # the nightly budget: everything @slow (3- and 4-process multicontroller
+    # fits and kneighbors on both control planes, bitwise across planes; the
+    # hardware kNN audit, which skips off the chip), in one process: these
+    # tests start jax children of their own
     t1=$SECONDS
     python -m pytest tests/ -x -q --runslow -m slow
-    echo "CI budget: slow-marked remainder took $((SECONDS - t1))s"
-    # srml-shield slow gates, re-asserted by name: the 3- and 4-process
-    # multicontroller fit + kneighbors parity variants (uneven partitions,
-    # one empty rank — rank-indexing bugs cannot hide at nranks=2) and the
-    # hardware kNN audit (TPU-gated; skips cleanly on CPU)
-    python -m pytest tests/test_multicontroller.py -q --runslow \
-        -k "three_plus or multirank"
-    python -m pytest tests/test_knn_audit.py -q --runslow
-    # srml-wire slow gates by name: the FULL fit matrix rerun on the TCP
-    # plane must be BITWISE-equal to the file plane, and the 2-process
-    # kneighbors exchange must pass over sockets
-    python -m pytest tests/test_multicontroller.py -q --runslow \
-        -k "bitwise_equal_across_planes or (kneighbors_across and tcp)"
+    # the runtime half of graftlint-cc: the chaos matrix and the serving and
+    # elastic recovery gates with the lock-order validator armed and nothing
+    # else (debug_nans and the transfer guard stay off, so timings hold).  A
+    # violation raises out of the acquiring thread, so a green run IS the
+    # zero-violations assertion; no other gate sees the alias and cross-module
+    # edges the static pass cannot follow (docs/graftlint.md#r11).
+    SRML_SANITIZE=lockdep python -m pytest -q \
+        tests/test_faults.py tests/test_netplane.py
+    SRML_SANITIZE=lockdep python -m pytest -q \
+        tests/test_serving.py tests/test_autoscale.py \
+        -k "shield or worker_death or wedge_then or drain_during or budget \
+            or rolls_up or concurrent_allocate_release or preemption_storm"
+    echo "CI budget: slow pass took $((SECONDS - t1))s"
 fi
 
-# 3b. focused gates for the kNN query-engine contracts (cheap; both files
-#     also run inside the full suite above — re-asserted here by name so a
-#     selective run or marker drift can never silently drop them):
-#     - interpret-mode Pallas kNN kernels, incl. the multi-K-block
-#       query-resident grid (revisited output dim must be innermost)
-#     - precompile executable cache hit/miss: a repeat same-shape search
-#       performs ZERO new compilations (profiling counters)
-python -m pytest tests/test_pallas.py -q -k knn
-python -m pytest tests/test_precompile.py -q
-
-# 3c. focused gates for the sharded UMAP engine (also inside the full suite;
-#     re-asserted by name so marker drift can never silently drop them).
-#     Runs on the multi-device CPU mesh — conftest injects the 8-device
-#     flag, forced explicitly here so a stripped environment still gets it:
-#     - mesh-shape parity: fixed seed => same embedding on a 1-device and
-#       an 8-device mesh, and k=15 neighbor preservation within 1% of the
-#       single-device reference layout
-#     - epoch loop issues ceil(n_epochs / SRML_UMAP_EPOCH_BLOCK) dispatches
-#       and repeat same-shape fits perform ZERO new compilations
-#     - graph assembly stays on device (single-upload transfer counters)
-#     (graftlint re-check rides the step-1 whole-package gate.)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_umap_engine.py -q
-
-# 3d. focused gates for the device-resident forest engine (also inside the
-#     full suite; re-asserted by name so marker drift can never silently
-#     drop them).  Runs on the 8-device CPU mesh, forced explicitly:
-#     - mesh parity: fixed seed => IDENTICAL forest (features, thresholds,
-#       leaf values) on a 1-device and an 8-device mesh fit
-#     - dispatch counting: ceil(levels / SRML_FOREST_LEVEL_BLOCK) engine
-#       dispatches, one early-stop flag sync per block, ONE forest fetch
-#       (forest.levels.dispatches / forest.level_syncs / forest.d2h_transfers)
-#     - zero-recompile repeat fit + repeat transform (precompile counters)
-#     - interpret-mode sharded+psum MXU histogram rule vs the numpy oracle
-#     (graftlint re-check rides the step-1 whole-package gate.)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_forest_engine.py -q
-
-# 3e. focused gates for the srml-serve subsystem (also inside the full
-#     suite; re-asserted by name so marker drift can never silently drop
-#     them).  Runs on the 8-device CPU mesh, forced explicitly:
-#     - concurrent single-row clients coalesce into >1-request device
-#       batches (occupancy histogram + coalesced_batches counters)
-#     - steady state after bucket warmup performs ZERO new executable
-#       compilations (precompile compile/fallback counters frozen)
-#     - overload rejects fast with ServerOverloaded instead of blocking;
-#       queued-request deadlines expire with RequestTimeout
-#     - registry serves core.load'ed models with transform-equal outputs
-#     plus the save->load->transform persistence matrix the registry
-#     builds on, and an open-loop bench_serving smoke over two model types
-#     (throughput + p50/p95/p99 columns present, steady-state assertion
-#     on).  (graftlint re-check rides the step-1 whole-package gate.)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_serving.py tests/test_persistence_matrix.py -q
-SERVE_SMOKE=$(mktemp -d)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.bench_serving --models kmeans,linreg --rates 50,200 \
-    --duration 1.5 --fit_rows 1024 --num_cols 8 \
-    --report_path "$SERVE_SMOKE/serving.jsonl"
-test "$(wc -l < "$SERVE_SMOKE/serving.jsonl")" -eq 4
-python - "$SERVE_SMOKE/serving.jsonl" <<'EOF'
-import json, sys
-recs = [json.loads(l) for l in open(sys.argv[1])]
-assert {r["model"] for r in recs} == {"kmeans", "linreg"}
-for r in recs:
-    assert r["steady_compiles"] == 0, r
-    assert all(k in r for k in ("throughput_rps", "p50_ms", "p95_ms", "p99_ms")), r
-EOF
-rm -rf "$SERVE_SMOKE"
-
-# 3f. focused gates for the srml-ann IVF-Flat subsystem (also inside the
-#     full suite; re-asserted by name so marker drift can never silently
-#     drop them).  Runs on the 8-device CPU mesh, forced explicitly:
-#     - recall@10 >= 0.95 vs the exact kneighbors path at the documented
-#       nprobe on clustered data (the acceptance gate)
-#     - BITWISE 1-device-vs-8-device mesh parity of probed results
-#       (lexicographic (d2, pos) selection — extends the UMAP/RF matrix)
-#     - repeat same-shape probed search performs ZERO new compilations,
-#       and the warm path covers the exact dispatch key
-#     - the SRML_UMAP_ANN=ivfflat knob keeps k=15 neighbor preservation
-#       within the established 1% of the exact-graph layout
-#     plus a bench_approximate_nn smoke asserting recall/qps columns +
-#     zero steady-state compiles on tiny clustered data.  (graftlint
-#     re-check rides the step-1 whole-package gate.)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_ann_engine.py -q
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_umap_engine.py -q -k ann_graph
-ANN_SMOKE=$(mktemp -d)
-python -m benchmark.gen_data blobs --num_rows 2000 --num_cols 16 --n_clusters 8 \
-    --output_dir "$ANN_SMOKE/blobs" --output_num_files 2
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.benchmark_runner approximate_nearest_neighbors \
-    --train_path "$ANN_SMOKE/blobs" --k 10 --nlist 8 --nprobe 4 \
-    --report_path "$ANN_SMOKE/ann.jsonl"
-python - "$ANN_SMOKE/ann.jsonl" <<'EOF'
-import json, sys
-rec = json.loads(open(sys.argv[1]).readline())
-assert rec["recall_at_k"] >= 0.95, rec
-assert rec["qps"] > 0 and "speedup_vs_exact" in rec, rec
-assert rec["steady_compiles"] == 0, rec
-EOF
-rm -rf "$ANN_SMOKE"
-
-# 3g. focused gates for srml-scope observability (also inside the full
-#     suite; re-asserted by name so marker drift can never silently drop
-#     them), then an end-to-end trace/export smoke: a kmeans fit + a
-#     serving session run with SRML_TRACE_DIR set, and the emitted files
-#     must parse as valid Chrome trace-event JSON with >0 complete ("X")
-#     span events; the fit must surface fit_telemetry() on the model; and
-#     export_metrics() must round-trip through json.loads with the stable
-#     schema (docs/observability.md).
-python -m pytest tests/test_profiling.py -q
-TRACE_SMOKE=$(mktemp -d)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    SRML_TRACE_DIR="$TRACE_SMOKE/traces" python - "$TRACE_SMOKE/traces" <<'EOF'
-import glob, json, sys
-import numpy as np
-from spark_rapids_ml_tpu import KMeans, profiling
-from spark_rapids_ml_tpu.dataframe import DataFrame
-from spark_rapids_ml_tpu.serving import ModelRegistry
-
-X = np.random.default_rng(0).standard_normal((512, 16)).astype(np.float32)
-model = KMeans(k=4, maxIter=5, seed=1).fit(DataFrame.from_numpy(X))
-telem = model.fit_telemetry()
-assert telem is not None and telem.phases["srml.fit"]["count"] == 1, telem
-with ModelRegistry(max_batch=32, max_wait_ms=2) as reg:
-    reg.register("km", model)
-    for i in range(8):
-        reg.get("km").predict(X[i])
-    snap = reg.telemetry()
-    assert snap.counters.get("serving.km.requests", 0) >= 8, snap.counters
-
-traces = glob.glob(sys.argv[1] + "/*.trace.json")
-tags = {p.rsplit("/", 1)[-1].split("-")[0] for p in traces}
-assert {"fit", "serve"} <= tags, traces
-for p in traces:
-    doc = json.load(open(p))
-    complete = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
-    assert complete, f"{p}: no complete span events"
-    for e in complete:
-        assert set(e) >= {"name", "ts", "dur", "pid", "tid", "args"}, e
-
-exported = profiling.export_metrics()
-rt = json.loads(json.dumps(exported))
-assert rt == exported and rt["schema"] == "srml-scope/v1"
-assert "srml_counter{" in profiling.render_prometheus(exported)
-print(f"observability smoke OK: {len(traces)} trace file(s), "
-      f"{len(exported['counters'])} counters exported")
-EOF
-rm -rf "$TRACE_SMOKE"
-
-# 3h. focused gates for the srml-watch health plane (also inside the full
-#     suite; re-asserted by name so marker drift can never silently drop
-#     them), then a serving health smoke:
-#     - induced-hang: a fit task blocking one mocked rank produces a
-#       watchdog report naming the stalled rank AND its innermost open span
-#     - induced-exception: a failing fit dumps a Perfetto-loadable flight
-#       recording with the failing span as the final event
-#     - overhead: always-on flight recording stays under 2% of a warm
-#       kmeans fit
-#     - ModelRegistry.health() reports READY with SLO attainment >= 0 and
-#       the health/memory gauge families render through export_metrics()/
-#       render_prometheus()
-#     (graftlint re-check, incl. R7, rides the step-1 whole-package gate.)
-python -m pytest tests/test_watch.py -q
-python -m pytest tests/test_watch.py -q -k "induced_hang or induced_exception or overhead"
-WATCH_SMOKE=$(mktemp -d)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    SRML_TRACE_DIR="$WATCH_SMOKE/traces" SRML_SERVE_SLO_MS=500 python - <<'EOF'
-import numpy as np
-from spark_rapids_ml_tpu import KMeans, profiling, watch
-from spark_rapids_ml_tpu.dataframe import DataFrame
-from spark_rapids_ml_tpu.serving import ModelRegistry
-
-X = np.random.default_rng(0).standard_normal((512, 16)).astype(np.float32)
-model = KMeans(k=4, maxIter=5, seed=1).fit(DataFrame.from_numpy(X))
-telem = model.fit_telemetry()
-assert telem is not None and "mem.host" in telem.memory, telem.memory
-with ModelRegistry(max_batch=32, max_wait_ms=2) as reg:
-    reg.register("km", model)
-    for i in range(16):
-        reg.get("km").predict(X[i])
-    h = reg.health()
-    assert h["state"] == "READY", h
-    km = h["models"]["km"]
-    assert km["attainment"] >= 0 and 0 <= km["burn"] <= 1, km
-    m = profiling.export_metrics()
-    g = m["gauges"]
-    assert g.get("health.km.state_code") == 1.0, g
-    assert any(k.startswith("mem.host.") for k in g), g
-    txt = profiling.render_prometheus(m)
-    assert "# TYPE srml_health gauge" in txt, txt[-500:]
-    assert "# TYPE srml_memory_bytes gauge" in txt
-assert watch.ring_stats()["events"] > 0
-print("watch smoke OK:", km["state"], f"attainment={km['attainment']}")
-EOF
-rm -rf "$WATCH_SMOKE"
-
-# 3i. focused gates for the kNN exchange + fused epilogue (also inside the
-#     full suite; re-asserted here by name so marker drift can never
-#     silently drop them).  Runs on the 8-device CPU mesh, forced
-#     explicitly:
-#     - BITWISE parity matrix: ring-permute exchange == all-gather
-#       exchange == single-device reference on 1/2/8-device meshes
-#       (lex (d2, pos) total order + fixed-tile scans)
-#     - distributed_kneighbors ring route == allgather route == sklearn,
-#       including the collective fallback when a rank's items overflow
-#     - repeat same-shape ring search performs ZERO new compilations
-#     - fused merge epilogue in interpret mode: nb>1 K-block geometry,
-#       the lex tie contract vs the numpy oracle, and the forced
-#       self-verify fallback through the fused path
-#     plus a bench_nearest_neighbors smoke asserting zero new compiles on
-#     repeat search and the bytes-moved fields present.  (graftlint
-#     re-check, incl. R8, rides the step-1 whole-package gate.)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_knn_exchange.py -q
-python -m pytest tests/test_pallas.py -q -k "fused"
-KNN_SMOKE=$(mktemp -d)
-python -m benchmark.gen_data blobs --num_rows 2000 --num_cols 16 --n_clusters 8 \
-    --output_dir "$KNN_SMOKE/blobs" --output_num_files 2
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.benchmark_runner knn \
-    --train_path "$KNN_SMOKE/blobs" --k 10 \
-    --report_path "$KNN_SMOKE/knn.jsonl"
-python - "$KNN_SMOKE/knn.jsonl" <<'EOF'
-import json, sys
-rec = json.loads(open(sys.argv[1]).readline())
-assert rec["repeat_new_compiles"] == 0, rec
-# 8-device mesh: the ring exchange must have moved (and counted) bytes
-assert rec["exchange_bytes"] > 0, rec
-assert any(s.startswith("knn.ring") for s in rec["exchange_sections"]), rec
-EOF
-rm -rf "$KNN_SMOKE"
-
-# 3j. srml-shield chaos gates (also inside the full suite; re-asserted by
-#     name so marker drift can never silently drop them —
-#     docs/robustness.md):
-#     - CHAOS MATRIX on 3 real OS processes: a rank killed mid-collective
-#       (SRML_FAULTS cp.gather action=die) makes every survivor raise
-#       RemoteRankError NAMING the dead rank in < 10 s (vs the 300 s round
-#       timeout), with clean teardown and no orphan alive/heartbeat files;
-#       the orderly-abort variant carries exception type + failing span
-#       through the abort marker
-#     - unarmed-path overhead: SRML_FAULTS unset adds no measurable work at
-#       injection sites (structural gate, test_watch style)
-#     - serving recovery: injected worker death and watchdog-confirmed
-#       wedge each return the server to READY via supervised restart, with
-#       queued/in-flight requests failed by the typed retryable
-#       ServerRecovering (never a hang) and ZERO new compiles across the
-#       recovery (buckets re-warm from the retained AOT cache)
-#     (graftlint re-check, incl. R9, rides the step-1 whole-package gate.)
-# the explicit full-file run IS the by-name gate: nothing in it is
-# marker-filtered, so no subset re-run is needed (the chaos matrix is the
-# most expensive piece of 3j — run it once)
-python -m pytest tests/test_faults.py -q
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_serving.py -q \
-    -k "shield or worker_death or wedge_then or drain_during or budget or rolls_up"
-
-# 3k. srml-router gates (also inside the full suite; re-asserted by name
-#     so marker drift can never silently drop them — docs/serving.md
-#     §srml-router):
-#     - replica CHAOS: with 2 replicas under a request stream, killing one
-#       (SRML_FAULTS serving.dispatch, tag = replica name) produces ZERO
-#       client-visible errors — the routed future re-routes the typed
-#       retryable failure to the survivor — and the killed replica
-#       re-admits warm (zero new compiles, retained AOT cache)
-#     - zero-downtime SWAP: rolling router.swap() under load with zero
-#       errors and zero new compiles at cut-over; registry swap()
-#       persistence semantics (save -> load -> swap -> serve bit-equal,
-#       swap-during-drain, incompatible-signature rejection)
-#     - depth-2 continuous batching: the serve.<n>.inflight_depth series
-#       reaches 2 (assembly overlapped device execution) and the
-#       zero-new-compiles steady gate holds per replica
-#     - admission/shedding: batch class sheds first at the configured
-#       fill ceilings while interactive traffic is still admitted
-#     - the srml_router / srml_health exposition round-trip incl.
-#       per-replica restart counts
-#     plus a bench_serving router smoke asserting the
-#     max-sustained-QPS-at-p99-SLO headline per depth, the PAIRED goodput
-#     confirm with depth-2 >= depth-1 at the COMMON SUSTAINED offered
-#     load (min of the two search maxima) and equal SLO, and a zero-error
-#     swap blip.  The paired rate is min, not max: at the stronger arm's
-#     maximum the first thing to fail on a 2-core host is the CLIENT
-#     pacing thread (late-arrival bursts into an ~8-request queue), which
-#     scores scheduler contention, not the pipeline.  The structural
-#     depth-2 > depth-1 admission-capacity dominance is gated
-#     deterministically by test_router's goodput test (device leg = GIL-
-#     releasing sleep); the smoke gates live-XLA parity at the common
-#     load with zero sheds/errors plus the zero-new-compiles steady
-#     state.  Trials are best-of-3 and interleaved across the depth arms
-#     so one machine-weather phase cannot land entirely on one arm.
-#     The depth comparison runs at ONE replica: inflight depth is
-#     per-replica pipeline machinery, and 2 replicas x depth-2 is 6
-#     serving threads — on a 2-core CI box that oversubscription measures
-#     context-switching, not the pipeline.  The multi-replica behaviours
-#     (chaos re-route, rolling swap) keep their 2-replica gates.
-# the explicit full-file run IS the by-name gate (nothing marker-filtered)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_router.py -q
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_persistence_matrix.py -q -k "swap"
-ROUTER_SMOKE=$(mktemp -d)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.bench_serving --models kmeans \
-    --headline --headline_trials 3 --duration 1 --slo_ms 500 \
-    --replicas 1 \
-    --fit_rows 8192 --num_cols 512 --max_batch 4096 --rows_per_request 512 \
-    --report_path "$ROUTER_SMOKE/router.jsonl"
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.bench_serving --models kmeans \
-    --swap_blip --duration 1 --slo_ms 500 \
-    --replicas 2 \
-    --fit_rows 8192 --num_cols 512 --max_batch 4096 --rows_per_request 512 \
-    --swap_rate 30 --report_path "$ROUTER_SMOKE/router.jsonl"
-python - "$ROUTER_SMOKE/router.jsonl" <<'EOF'
-import json, sys
-recs = [json.loads(l) for l in open(sys.argv[1])]
-head = {r["inflight_depth"]: r for r in recs
-        if r.get("metric") == "max_sustained_qps_at_p99_slo"}
-assert set(head) == {1, 2}, sorted(head)
-for r in head.values():
-    assert r["max_sustained_qps"] > 0, r
-# the continuous-batching acceptance bar, measured PAIRED (equal offered
-# load, equal SLO, seconds apart): depth-2 delivers >= depth-1
-paired = [r for r in recs if r.get("metric") == "paired_goodput_at_slo"]
-assert paired, recs
-gp = paired[0]["goodput_rps"]
-assert gp["2"] >= gp["1"] > 0, paired[0]
-swap = [r for r in recs if r.get("metric") == "swap_blip"]
-assert swap and swap[0]["errors"] == 0, swap          # zero-downtime
-assert swap[0]["replica_swaps"] == 2, swap            # every slot rolled
-assert swap[0]["completed"] == swap[0]["requests"], swap
-EOF
-rm -rf "$ROUTER_SMOKE"
-
-# 3l. srml-sweep batched-tuning gates (also inside the full suite;
-#     re-asserted by name so marker drift can never silently drop them —
-#     docs/tuning_engine.md).  Runs on the 8-device CPU mesh, forced
-#     explicitly:
-#     - EXACT batched-vs-sequential equality: avgMetrics/stdMetrics/
-#       best_index and sub-model coefficients on 1/2/8-device meshes
-#       (linreg bitwise; logreg exact metrics + trajectory-tolerance
-#       coefficients), incl. the m=1 grid, the k>rows-per-fold edge, and
-#       the cluster-side sequential CV vs the local batched sweep
-#     - ONE staged dataset per sweep (ingest.staged transfer counter) and
-#       ZERO new compiles on a repeat same-shape sweep with different grid
-#       values (the candidate-bucket AOT key: lanes are traced, not baked)
-#     - kill switch + fallbacks: SRML_SWEEP_BATCH=0, non-lane-batchable
-#       grid params, and sparse CSR input all keep the legacy fold loop
-#     plus a bench_tuning smoke at the default CI shape asserting the batched
-#     route beats the sequential one in candidates/sec on BOTH solver
-#     families and repeats with zero new kernel compilations.
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_tuning.py -q -k "batched_sweep or cv_copy"
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_spark_cv.py -q -k "batched"
-TUNE_SMOKE=$(mktemp -d)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.bench_tuning --algos linreg,logreg \
-    --rows 20000 --cols 64 --num_folds 3 --grid_size 8 --num_runs 1 \
-    --report_path "$TUNE_SMOKE/tuning.jsonl"
-python - "$TUNE_SMOKE/tuning.jsonl" <<'EOF'
-import json, sys
-recs = [json.loads(l) for l in open(sys.argv[1])]
-assert {r["algo"] for r in recs} == {"linreg", "logreg"}, recs
-for r in recs:
-    assert r["batched_cps"] > r["sequential_cps"], r   # the perf acceptance bar
-    assert r["repeat_new_compiles"] == 0, r            # candidate-bucket AOT key
-    assert r["phase_times"].get("tuning.sweep.solve", 0) > 0, r
-    # cumulative across the arm's warm-up + timed batched sweeps
-    assert r["counters"].get("tuning.candidates", 0) >= r["grid_size"], r
-EOF
-rm -rf "$TUNE_SMOKE"
-
-# 3m. srml-wire gates (also inside the full suite; re-asserted by name so
-#     marker drift can never silently drop them — docs/robustness.md §wire):
-#     - control-plane CONFORMANCE: one contract module over the file, TCP,
-#       and local planes (rank-indexed gathers, binary round-trip, abort
-#       marker shape, typed ControlPlaneTimeout naming round + missing
-#       ranks, health surface, close idempotence)
-#     - the multi-host-style CHAOS MATRIX on real OS processes over real
-#       sockets: SIGKILL'd rank, partitioned rank (injected cp.net sticky
-#       drop), and killed coordinator each surface as a TYPED error naming
-#       the culprit within 2 heartbeat intervals (wall-clock asserted),
-#       with zero orphaned sockets/threads/files; a stale-epoch zombie
-#       rejoin is fenced (StaleEpochError), never readmitted
-#     plus a bench_control_plane smoke asserting
-#     the pushed abort beats one 50 ms file-plane poll interval.
-#     (graftlint re-check, incl. R10, rides the step-1 whole-package gate.)
-#     (SRML_CI_FULL additionally reruns the full multicontroller fit +
-#     kneighbors matrix on SRML_CP=tcp with the bitwise cross-plane gate —
-#     see the slow-suite block in step 3.)
-python -m pytest tests/test_control_plane_contract.py tests/test_netplane.py -q
-WIRE_SMOKE=$(mktemp -d)
-python -m benchmark.bench_control_plane --planes file,tcp \
-    --gather_rounds 60 --abort_trials 3 \
-    --report_path "$WIRE_SMOKE/cp.jsonl"
-python - "$WIRE_SMOKE/cp.jsonl" <<'EOF'
-import json, sys
-recs = [json.loads(l) for l in open(sys.argv[1])]
-abort = {r["plane"]: r for r in recs if r["metric"] == "cp_abort_propagation"}
-gather = {r["plane"]: r for r in recs if r["metric"] == "cp_gather_round"}
-assert set(abort) == {"file", "tcp"} and set(gather) == {"file", "tcp"}, recs
-# THE srml-wire bar: a coordinator-pushed abort must land inside one
-# file-plane poll interval (50 ms) — measured ~1-3 ms on localhost
-assert abort["tcp"]["max_ms"] < 50.0, abort["tcp"]
-assert abort["tcp"]["survivors"] == 2 * abort["tcp"]["trials"], abort["tcp"]
-assert gather["tcp"]["p50_ms"] > 0 and gather["file"]["p50_ms"] > 0
-assert abort["tcp"]["wire_counters"].get("cp.net.pushed_aborts", 0) > 0
-EOF
-rm -rf "$WIRE_SMOKE"
-
-# 3n. srml-pq IVF-PQ gates (also inside the full suite; re-asserted by
-#     name so marker drift can never silently drop them —
-#     docs/ann_engine.md §IVF-PQ).  Runs on the 8-device CPU mesh, forced
-#     explicitly:
-#     - the ADC LUT-accumulation kernel EXACT vs the numpy oracle in
-#       interpret mode (sequential-j accumulation contract, ragged rows,
-#       sub-256 table widths)
-#     - BITWISE 1-device-vs-8-device parity of probed AND refined ivfpq
-#       results (the flat kernel's lex/merge helpers reused verbatim)
-#     - refined recall@10 >= 0.9 at the documented defaults on clustered
-#       data, and zero-new-compile repeat/warmed searches
-#     plus a paired bench_approximate_nn smoke (flat + pq arms on ONE
-#     dataset) asserting refined recall@10 >= 0.9, zero new compiles in
-#     the timed repeat window, and the compression headline:
-#     pq index_bytes_per_item < 1/8 of the flat arm's.  (graftlint
-#     re-check rides the step-1 whole-package gate.)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_pq_engine.py -q
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_pq_engine.py -q \
-    -k "lut_kernel or mesh_parity or refined_recall or zero_new_compiles"
-PQ_SMOKE=$(mktemp -d)
-python -m benchmark.gen_data blobs --num_rows 2000 --num_cols 32 --n_clusters 8 \
-    --output_dir "$PQ_SMOKE/blobs" --output_num_files 2
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.benchmark_runner approximate_nearest_neighbors \
-    --train_path "$PQ_SMOKE/blobs" --k 10 --nlist 8 --nprobe 4 \
-    --report_path "$PQ_SMOKE/ann.jsonl"
-# pq operating point for the tiny smoke: every list probed + x8 refine
-# (raw ADC recall at 2k rows x 32 dims is ~0.54 — the refine recovery is
-# exactly what the gate exercises), n_bits=6 so the fixed codebook bytes
-# do not swamp the per-item ratio at this tiny item count
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.benchmark_runner approximate_nearest_neighbors \
-    --train_path "$PQ_SMOKE/blobs" --k 10 --nlist 8 --nprobe 8 \
-    --algorithm ivfpq --pq_m 8 --pq_bits 6 --refine_ratio 8 \
-    --report_path "$PQ_SMOKE/ann.jsonl"
-python - "$PQ_SMOKE/ann.jsonl" <<'EOF'
-import json, sys
-recs = [json.loads(l) for l in open(sys.argv[1])]
-arms = {r.get("algorithm", "ivfflat"): r for r in recs}
-assert set(arms) == {"ivfflat", "ivfpq"}, sorted(arms)
-pq, flat = arms["ivfpq"], arms["ivfflat"]
-assert pq["recall_at_k"] >= 0.9, pq              # refined recall@10
-assert "recall_at_k_raw" in pq and pq["qps"] > 0, pq
-assert pq["steady_compiles"] == 0, pq            # repeat_new_compiles == 0
-# the compression headline, measured on one dataset: pq < flat / 8
-ratio = flat["index_bytes_per_item"] / pq["index_bytes_per_item"]
-assert ratio >= 8.0, (flat["index_bytes_per_item"], pq["index_bytes_per_item"])
-EOF
-rm -rf "$PQ_SMOKE"
-
-# 3o. srml-stream gates (also inside the full suite; re-asserted by name
-#     so marker drift can never silently drop them — docs/streaming.md):
-#     - streamed==batch EQUALITY: partial_fit over chunks vs batch fit on
-#       the union — BITWISE for linreg coefficients and sign-canonicalized
-#       PCA components on the exact-arithmetic data family, inertia-/
-#       accuracy-gated for the online kmeans/logreg approximations,
-#       against 1/2/8-device batch meshes
-#     - ZERO-COMPILE steady ingest (same-bucket chunks after the first
-#       move aot_hit, never precompile.compile)
-#     - live IVF mutation: recall@10 >= 0.95 across an add/delete/repack
-#       sequence (incl. through serve.ann and a warm-covered overflow
-#       repack with zero steady-state compiles)
-#     - train-while-serve: StreamingSession.refresh() through the router
-#       under concurrent load — zero client-visible errors, zero new
-#       compiles at the same-shape cut-over
-#     plus a bench_streaming smoke asserting steady ingest with zero new
-#     compiles and a zero-error refresh blip.  (graftlint re-check rides
-#     the step-1 whole-package gate.)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_streaming.py -q
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_streaming.py -q \
-    -k "bitwise_equals_batch or inertia_quality or metric_quality or steady_ingest_zero or add_delete_repack_recall or overflow_repack or served_ann_absorbs or refresh_under_router_load"
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_persistence_matrix.py -q -k "streamed"
-STREAM_SMOKE=$(mktemp -d)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.bench_streaming --algos linreg,kmeans \
-    --rows 8000 --cols 32 --chunk_rows 1024 --blip_requests 20 \
-    --report_path "$STREAM_SMOKE/stream.jsonl"
-python - "$STREAM_SMOKE/stream.jsonl" <<'EOF'
-import json, sys
-recs = [json.loads(l) for l in open(sys.argv[1])]
-assert {r["algo"] for r in recs} == {"linreg", "kmeans"}, recs
-for r in recs:
-    assert r["rows_per_sec"] > 0, r
-    assert r["repeat_new_compiles"] == 0, r      # zero-compile steady ingest
-    assert r["refresh_errors"] == 0, r           # zero-error refresh blip
-    assert r["refreshes"] == 2 and r["p99_before_ms"] > 0, r
-    assert r["counters"].get("stream.rows", 0) == r["rows"], r
-EOF
-rm -rf "$STREAM_SMOKE"
-
-# 3p. graftlint-cc gates: the concurrency pass (R11 lock-order, R12
-#     shared-state) and its runtime half (also inside the full suite;
-#     re-asserted by name so marker drift can never silently drop them):
-#     - fixture suites: a crafted lock-order inversion fires both directly
-#       nested and through a one-call interprocedural edge, every
-#       blocking-op class under a held lock fires, the condition-wait
-#       idiom stays exempt, guarded-vs-unguarded shared-state writes
-#       separate (incl. the _locked helper convention), stable finding
-#       ids survive line shifts, and --fail-on-new gates fresh findings
-#       against a v2 baseline
-#     - runtime lockdep: a crafted two-thread inversion raises the typed
-#       LockOrderViolation carrying both lock names and both stacks; the
-#       disabled path hands back raw threading primitives (zero overhead)
-#     then the chaos matrix + serving-recovery gates re-run ONCE with the
-#     lockdep sanitizer armed (SRML_SANITIZE=lockdep arms ONLY the
-#     lock-order validator — debug_nans/transfer-guard stay off so
-#     timings hold).  A violation raises out of the acquiring thread, so
-#     a green rerun IS the zero-violations assertion — and the runtime
-#     half covers the alias/cross-module edges the static pass documents
-#     as invisible (docs/graftlint.md#r11).
-python -m pytest tests/test_graftlint_concurrency.py tests/test_lockdep.py -q
-SRML_SANITIZE=lockdep python -m pytest tests/test_faults.py tests/test_netplane.py -q
-SRML_SANITIZE=lockdep XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_serving.py -q \
-    -k "shield or worker_death or wedge_then or drain_during or budget or rolls_up"
-
-# 3q. srml-lanes gates (also inside the full suite; re-asserted by name
-#     so marker drift can never silently drop them — docs/serving.md
-#     §multiplex):
-#     - lane engine: pow2 bucket edges (K=1, non-pow2 K), duplicate-lane
-#       padding, and the compile-count gate — growing K across a pow2
-#       bucket boundary compiles exactly once, zero within a bucket
-#     - multiplex: per-tenant outputs bitwise-equal to dedicated servers
-#       for every lane-served model family, paging parity with zero new
-#       compiles across page-in/eviction churn, per-tenant counters
-#     then the fast multiplex smoke: 8 linreg variants on a 2-LANE HBM
-#     budget under a mixed-tenant stream — per-tenant outputs must be
-#     BITWISE-equal to 8 dedicated servers (integer-exact data) while
-#     every variant pages through the 2 resident lanes, with zero
-#     steady-state compiles; plus a bench_multiplex --headline smoke
-#     (K=1,8 QPS-at-SLO curve + paging record, backend-tagged).
-#     (graftlint re-check rides the step-1 whole-package gate.)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_lanes.py tests/test_multiplex.py -q
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_lanes.py tests/test_multiplex.py -q \
-    -k "growing_k or bitwise or paging_parity or interleaved or per_tenant"
-python - <<'EOF'
-import numpy as np
-from spark_rapids_ml_tpu import profiling
-from spark_rapids_ml_tpu.models.linear_regression import LinearRegressionModel
-from spark_rapids_ml_tpu.serving import ModelServer, MultiplexServer
-
-rng = np.random.RandomState(0)
-D = 8
-models = {
-    f"m{i}": LinearRegressionModel(
-        coef_=rng.randint(-3, 4, size=D).astype(np.float64),
-        intercept_=float(i % 3), n_cols=D, dtype="float32",
-    )
-    for i in range(8)
-}
-X = rng.randint(-4, 5, size=(6, D)).astype(np.float32)
-expected = {}
-for mid, m in models.items():
-    with ModelServer(f"ci-ded-{mid}", m) as srv:
-        expected[mid] = srv.predict(X)["prediction"]
-with MultiplexServer("ci_mux", models, resident_lanes=2,
-                     max_batch=64, max_wait_ms=5) as mux:
-    assert mux.lanes()["n_lanes"] == 2
-    before = profiling.counters("precompile.")
-    futs = [(mid, mux.submit(X, model_id=mid))
-            for _ in range(3) for mid in models]  # mixed-tenant stream
-    for mid, f in futs:
-        got = f.result(timeout=60)["prediction"]
-        assert np.array_equal(got, expected[mid]), mid  # bitwise per tenant
-    delta = profiling.counter_deltas(before, "precompile.")
-    assert delta.get("precompile.compile", 0) == 0, delta
-    assert delta.get("precompile.fallback", 0) == 0, delta
-    snap = mux.lanes()
-    assert snap["page_in"] > 0 and snap["evictions"] > 0, snap
-    mux.drain()
-    mux.assert_steady_state()   # zero steady-state compiles
-print("multiplex smoke: 8 tenants on 2 lanes, bitwise parity, "
-      f"{snap['page_in']} page-ins, zero new compiles")
-EOF
-MUX_SMOKE=$(mktemp -d)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.bench_multiplex --headline --ks 1,8 \
-    --duration 0.4 --slo_ms 500 --report_path "$MUX_SMOKE/mux.jsonl"
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.bench_multiplex --paging --registered 16 \
-    --resident 2 --rate 100 --duration 1 \
-    --report_path "$MUX_SMOKE/mux.jsonl"
-python - "$MUX_SMOKE/mux.jsonl" <<'EOF'
-import json, sys
-recs = [json.loads(l) for l in open(sys.argv[1])]
-heads = [r for r in recs if r["metric"] == "multiplex_max_sustained_qps_at_p99_slo"]
-assert {r["k_variants"] for r in heads} == {1, 8}, heads
-for r in heads:
-    assert r["max_sustained_qps"] > 0 and r["backend"], r
-page = [r for r in recs if r["metric"] == "multiplex_paging"]
-assert len(page) == 1, recs
-p = page[0]
-assert p["errors"] == 0 and p["page_ins"] > 0, p
-assert 0.0 <= p["lane_hit_rate"] <= 1.0 and p["page_in_p99_ms"] > 0, p
-EOF
-rm -rf "$MUX_SMOKE"
-
-# 3r. srml-tier capacity gates (also inside the full suite; re-asserted
-#     by name so marker drift can never silently drop them —
-#     docs/ann_engine.md §OPQ / §4-bit fast-scan / §Tiered residency):
-#     - the 4-bit fast-scan LUT kernel EXACT vs the numpy sequential-ADC
-#       oracle in interpret mode, pack/unpack round-trip, typed packer
-#       rejections (odd m_sub silently falls back to the unpacked route)
-#     - OPQ: refined 4-bit+OPQ recall >= the raw 8-bit arm at half M
-#       (equal index bytes), rotation orthonormal, reconstruction error
-#       never worse than unrotated; persistence round-trips the rotation
-#       bit-identically across meshes
-#     - tiered residency BITWISE == all-resident, zero new compiles
-#       across a cold->warm probe sweep, ann.tier.* counters move;
-#       tombstoned ids never resurface from paged-in cold lists
-#     - refine_ratio edge semantics (0 -> typed error, 1 = ADC only) and
-#       the hot_fraction param surface (validated at fit)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_pq_engine.py tests/test_persistence_matrix.py -q \
-    -k "fastscan or opq or tiered or tombstone or refine_ratio_edge or hot_fraction"
-# the tiered pager must stay graftlint-clean (R1: per-group result fetch
-# is deferred to ONE batched device_get, never a sync inside the loop)
-python -m tools.graftlint \
-    spark_rapids_ml_tpu/ann/pq.py spark_rapids_ml_tpu/ann/ivfflat.py \
-    spark_rapids_ml_tpu/ann/tier.py spark_rapids_ml_tpu/ann/mutable.py \
-    spark_rapids_ml_tpu/ops/pallas_pq.py \
-    spark_rapids_ml_tpu/models/approximate_nn.py
-# paired bench smoke on ONE dataset: the capacity headline measured at
-# like-for-like residency (8-bit vs 4-bit+OPQ, both resident), plus a
-# tiered arm exercising the pager end-to-end through the estimator
-TIER_SMOKE=$(mktemp -d)
-python -m benchmark.gen_data blobs --num_rows 2048 --num_cols 32 --n_clusters 16 \
-    --output_dir "$TIER_SMOKE/blobs" --output_num_files 2
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.benchmark_runner approximate_nearest_neighbors \
-    --train_path "$TIER_SMOKE/blobs" --k 10 --nlist 16 --nprobe 16 \
-    --algorithm ivfpq --pq_m 16 --pq_bits 8 --refine_ratio 8 \
-    --report_path "$TIER_SMOKE/ann.jsonl"
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.benchmark_runner approximate_nearest_neighbors \
-    --train_path "$TIER_SMOKE/blobs" --k 10 --nlist 16 --nprobe 16 \
-    --algorithm ivfpq --pq_m 16 --pq_bits 4 --opq --refine_ratio 8 \
-    --report_path "$TIER_SMOKE/ann.jsonl"
-# tiered arm at nprobe=4: with hot_fraction 0.5 over 16 lists the pager
-# actually pages (8 hot pinned, cold lists LRU-cycle through the pool)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.benchmark_runner approximate_nearest_neighbors \
-    --train_path "$TIER_SMOKE/blobs" --k 10 --nlist 16 --nprobe 4 \
-    --algorithm ivfpq --pq_m 16 --pq_bits 4 --opq --hot_fraction 0.5 \
-    --refine_ratio 8 --report_path "$TIER_SMOKE/ann.jsonl"
-python - "$TIER_SMOKE/ann.jsonl" <<'EOF'
-import json, sys
-recs = [json.loads(l) for l in open(sys.argv[1])]
-assert len(recs) == 3, len(recs)
-b8, b4, tiered = recs
-assert b8["pq_bits"] == 8 and b4["pq_bits"] == 4 and b4["pq_opq"], recs
-assert tiered["hot_fraction"] == 0.5, tiered
-for r in recs:
-    assert r["recall_at_k"] >= 0.9, r       # refined recall@10, every arm
-    assert r["steady_compiles"] == 0, r     # repeat_new_compiles == 0
-# THE capacity headline, at like-for-like (all-resident) residency:
-# 4-bit+OPQ HBM bytes/item <= 0.6x the 8-bit arm's (measured ~0.46 at
-# this geometry: packed codes halve, codebook tables shrink 16x)
-assert b4["hbm_bytes_per_item"] <= 0.6 * b8["hbm_bytes_per_item"], \
-    (b4["hbm_bytes_per_item"], b8["hbm_bytes_per_item"])
-# the tiered arm really paged: cold lists live in host RAM, the LRU
-# counters moved, and the estimator surfaced the residency split
-tc = tiered["metrics_export"]["counters"]
-assert tc.get("ann.tier.hits", 0) > 0 and tc.get("ann.tier.misses", 0) > 0, tc
-assert tc.get("ann.tier.page_bytes", 0) > 0, tc
-assert tiered["host_bytes_per_item"] > 0, tiered
-EOF
-rm -rf "$TIER_SMOKE"
-
-# 3s. srml-topo gates: topology-aware hierarchical collectives (also
-#     inside the full suite; re-asserted by name so marker drift can
-#     never silently drop them — docs/knn_pipeline.md §topology,
-#     docs/observability.md §5):
-#     - BITWISE parity: hierarchical device collectives (allgather_rows /
-#       gather_stack / psum_merge) == flat on contiguous and interleaved
-#       group shapes; the kNN ring+gather kernels == the single-device
-#       reference on 1/2/8-device meshes across simulated topologies
-#       1x8 / 2x4 / 4x2, with and without the SRML_EXCHANGE_TOPO=flat pin
-#     - per-link counter split matches the byte model exactly, and on a
-#       simulated 2x4 the hierarchical schedule's DCN bytes <=
-#       flat DCN / n_hosts (+10% slack) — the headline collapse
-#     - TopologyMap is a compile-cache static (flat / hier / pinned key
-#       differently; equal-by-value maps key identically) and the hier
-#       route performs ZERO new compilations on repeat search
-#     - the host-plane ring adopts the same cycle (CRC-agreed) bitwise
-#       vs flat, with ici/dcn attribution only under SRML_TOPO
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_topology.py -q
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_topology.py tests/test_router.py -q \
-    -k "test_knn_topology_parity_matrix_bitwise \
-        or test_hier_collectives_bitwise_match_flat \
-        or test_knn_hier_dcn_bytes_bound_on_2x4 \
-        or test_hier_route_zero_new_compiles_on_repeat_search \
-        or test_slice_meshes_topology_aware_never_straddles_host_group"
-# the exchange plane + its consumers must stay graftlint-clean (R8: only
-# exchange.py touches the remote-DMA API; R1/R6 on the new topology path)
-python -m tools.graftlint \
-    spark_rapids_ml_tpu/parallel/topology.py \
-    spark_rapids_ml_tpu/parallel/exchange.py \
-    spark_rapids_ml_tpu/parallel/mesh.py \
-    spark_rapids_ml_tpu/ops/knn.py
-# paired bench smoke on ONE dataset: hierarchical 2x4 vs flat-pinned 2x4;
-# the DCN collapse and zero steady-state compiles are captured artifacts
-TOPO_SMOKE=$(mktemp -d)
-python -m benchmark.gen_data blobs --num_rows 2000 --num_cols 16 --n_clusters 8 \
-    --output_dir "$TOPO_SMOKE/blobs" --output_num_files 2
-XLA_FLAGS="--xla_force_host_platform_device_count=8" SRML_TOPO=2:4 \
-    python -m benchmark.benchmark_runner knn \
-    --train_path "$TOPO_SMOKE/blobs" --k 10 \
-    --report_path "$TOPO_SMOKE/knn_topo.jsonl"
-XLA_FLAGS="--xla_force_host_platform_device_count=8" SRML_TOPO=2:4 \
-    SRML_EXCHANGE_TOPO=flat \
-    python -m benchmark.benchmark_runner knn \
-    --train_path "$TOPO_SMOKE/blobs" --k 10 \
-    --report_path "$TOPO_SMOKE/knn_topo.jsonl"
-python - "$TOPO_SMOKE/knn_topo.jsonl" <<'EOF'
-import json, sys
-hier, flat = [json.loads(l) for l in open(sys.argv[1])]
-assert hier["topology"] == "2x4/hier", hier["topology"]
-assert flat["topology"] == "2x4/flat-pinned", flat["topology"]
-for r in (hier, flat):
-    assert r["repeat_new_compiles"] == 0, r
-    assert r["exchange_route"] != "none", r
-hd, fd = hier["exchange_link_bytes"]["dcn"], flat["exchange_link_bytes"]["dcn"]
-# flat on a multi-group topology accounts everything as DCN; the
-# hierarchical schedule must collapse cross-host traffic by >= n_hosts
-assert hier["exchange_link_bytes"]["ici"] > 0, hier
-assert fd > 0 and hd <= fd / 2 * 1.10, (hd, fd)
-EOF
-rm -rf "$TOPO_SMOKE"
-
-# 3t. srml-elastic gates (also inside the full suite; re-asserted by name
-#     so marker drift can never silently drop them — docs/serving.md
-#     §srml-elastic):
-#     - the shared-pool invariant: two models on ONE SlicePool can never
-#       be handed overlapping devices; group-major carve under
-#       SRML_TOPO=2:4 never straddles a host group; exhaustion is the
-#       typed retryable CapacityExhausted (never a silent round-robin),
-#       and shared single-device leases exist only under the explicit
-#       allow_oversubscribe policy
-#     - warm scale-up: deploy-at-max / trim / regrow performs ZERO new
-#       compiles (AOT cache keys include slice device ids — the bill is
-#       paid once at deploy) with predictions bitwise-identical to a
-#       fixed-replica comparator throughout
-#     - the preemption storm: replicas killed under a zero restart budget
-#       (SRML_FAULTS serving.dispatch kills) are re-sliced + re-warmed
-#       through Router.replace_replica with zero client-visible errors
-#     then the concurrency-sensitive pair re-run ONCE under the lockdep
-#     sanitizer (a violation raises out of the acquiring thread, so a
-#     green rerun IS the zero-violations assertion), a focused graftlint
-#     pass over the elastic plane + the modules this layer touched, and
-#     the bench --autoscale step-load smoke asserting the two required
-#     zeros: scale_up_new_compiles and storm_client_errors.
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_autoscale.py -q
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_autoscale.py -q \
-    -k "shared_pool_keeps_models_disjoint or never_straddles \
-        or scale_up_is_warm or preemption_storm \
-        or oversubscription_is_typed"
-SRML_SANITIZE=lockdep XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m pytest tests/test_autoscale.py -q \
-    -k "concurrent_allocate_release or preemption_storm"
-python -m tools.graftlint \
-    spark_rapids_ml_tpu/serving/slicepool.py \
-    spark_rapids_ml_tpu/serving/autoscale.py \
-    spark_rapids_ml_tpu/serving/router.py \
-    spark_rapids_ml_tpu/serving/engine.py \
-    spark_rapids_ml_tpu/serving/scheduler.py \
-    spark_rapids_ml_tpu/parallel/mesh.py
-# rows_per_request is sized to the full batch so one replica saturates
-# below the paced client's ceiling on the 2-core image (the burst must
-# build REAL queue pressure for the signal-driven scale-up to fire)
-ELASTIC_SMOKE=$(mktemp -d)
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python -m benchmark.bench_serving --models kmeans --autoscale \
-    --duration 1 --fit_rows 4096 --num_cols 16 \
-    --rows_per_request 256 --max_batch 256 \
-    --report_path "$ELASTIC_SMOKE/elastic.jsonl"
-python - "$ELASTIC_SMOKE/elastic.jsonl" <<'EOF'
-import json, sys
-rec = json.loads(open(sys.argv[1]).readline())
-assert rec["metric"] == "autoscale_step_load", rec
-# THE srml-elastic bars: warm scale-up (the deploy-at-max discipline) and
-# preemption repair with zero client-visible errors
-assert rec["scale_up_new_compiles"] == 0, rec
-assert rec["storm_client_errors"] == 0 and rec["errors_total"] == 0, rec
-assert rec["storm_restored"] and rec["repairs"] >= 1, rec
-assert rec["scale_ups"] >= 1, rec   # the burst really forced a scale event
-assert max(p["replicas"] for p in rec["replica_trajectory"]) \
-    > rec["min_replicas"], rec
-EOF
-rm -rf "$ELASTIC_SMOKE"
-
-# 4. benchmark smoke on tiny data (reference ci/test.sh:38-45)
-SMOKE_DIR=$(mktemp -d)
-trap 'rm -rf "$SMOKE_DIR"' EXIT
-python -m benchmark.gen_data blobs --num_rows 1000 --num_cols 8 --n_clusters 4 \
-    --output_dir "$SMOKE_DIR/blobs" --output_num_files 2
-python -m benchmark.gen_data regression --num_rows 1000 --num_cols 8 \
-    --output_dir "$SMOKE_DIR/reg" --output_num_files 2
-python -m benchmark.benchmark_runner kmeans --train_path "$SMOKE_DIR/blobs" \
-    --k 4 --maxIter 5 --report_path "$SMOKE_DIR/report.jsonl"
-python -m benchmark.benchmark_runner linear_regression --train_path "$SMOKE_DIR/reg" \
-    --report_path "$SMOKE_DIR/report.jsonl"
-test "$(wc -l < "$SMOKE_DIR/report.jsonl")" -eq 2
+# 4. the benchmark the driver runs on the chip, checked here on the CPU: its
+#    drivers at a test's size, the trace reduction on recorded traces, the
+#    controls and planted faults that must turn `correct` false
+python -m pytest chipbench/tests -q -p no:cacheprovider
 
 echo "CI OK"

@@ -42,8 +42,7 @@
 # Exactness knob: probing all lists (nprobe >= nlist) visits every item
 # exactly once, so the probed result EQUALS the exact kneighbors result up
 # to f32 distance formulation differences — the recall harness
-# (recall_at_k) gates probed results against ops/knn's exact path in tests
-# and in benchmark/bench_approximate_nn.py.
+# (recall_at_k) gates probed results against ops/knn's exact path in tests.
 #
 
 from __future__ import annotations
@@ -863,7 +862,7 @@ def ivfflat_search_prepared(
     buckets driven through the kNN engine's dispatch/collect pipeline;
     every kernel dispatch rides the AOT executable cache — a repeat search
     at a seen geometry performs zero new compilations."""
-    from ..ops.knn import _pipeline_window, _query_block_bucket, _run_block_pipeline
+    from ..ops.knn import _PIPELINE_WINDOW, _query_block_bucket, _run_block_pipeline
 
     if isinstance(queries, jax.Array):
         q = queries if queries.dtype == dtype else queries.astype(dtype)
@@ -933,7 +932,7 @@ def ivfflat_search_prepared(
         out_i.append(ids_host)
 
     _run_block_pipeline(
-        len(starts), _dispatch, _collect, _pipeline_window(2),
+        len(starts), _dispatch, _collect, _PIPELINE_WINDOW,
         phase_prefix="ann",
     )
     profiling.incr_counter("ann.searches")
@@ -996,7 +995,7 @@ def warm_probe_kernels(
 def recall_at_k(approx_ids, exact_ids) -> float:
     """Mean fraction of each row's exact k-nearest ids recovered by the
     probed result — the gate every probed result set is scored with
-    (tests/test_ann_engine.py, benchmark/bench_approximate_nn.py).  The -1
+    (tests/test_ann_engine.py, tests/test_pq_engine.py).  The -1
     unfillable sentinel never counts as a hit."""
     a = np.asarray(approx_ids)
     e = np.asarray(exact_ids)

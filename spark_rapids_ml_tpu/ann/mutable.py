@@ -237,7 +237,7 @@ class MutableIVFIndex:
                 # from the FINAL staged buffers before the swap, so the
                 # first post-swap search dispatches a ready executable
                 # (probes keep serving the old snapshot meanwhile)
-                # graftlint: disable=R11 (compile wait holds only the mutator lock, by design: probes are lock-free on the snapshot, and releasing mid-mutation would tear the staged swap — NOTES.md)
+                # graftlint: disable=R11 (compile wait holds only the mutator lock, by design: probes are lock-free on the snapshot, and releasing mid-mutation would tear the staged swap)
                 self._warm_for(staged)
             self._index = staged
             profiling.incr_counter("ann.mutate.adds", items.shape[0])
@@ -280,7 +280,7 @@ class MutableIVFIndex:
             self._repack_locked(l_pad)
             staged = self._stage()
             if staged.l_pad != self._index.l_pad:
-                # graftlint: disable=R11 (compile wait holds only the mutator lock, by design: probes are lock-free on the snapshot, and releasing mid-repack would tear the staged swap — NOTES.md)
+                # graftlint: disable=R11 (compile wait holds only the mutator lock, by design: probes are lock-free on the snapshot, and releasing mid-repack would tear the staged swap)
                 self._warm_for(staged)
             self._index = staged
 

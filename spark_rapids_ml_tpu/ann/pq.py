@@ -951,7 +951,7 @@ def _resident_probe_all(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """All-resident probe sweep: query blocks ride the kNN engine's
     dispatch/collect pipeline, every dispatch rides the AOT cache."""
-    from ..ops.knn import _pipeline_window, _run_block_pipeline
+    from ..ops.knn import _PIPELINE_WINDOW, _run_block_pipeline
 
     n = qp.shape[0]
     starts = list(range(0, n, block))
@@ -986,7 +986,7 @@ def _resident_probe_all(
         out_d.append(d_host[:n_q])
         out_p.append(pos_host[:n_q])
     _run_block_pipeline(
-        len(starts), _dispatch, _collect, _pipeline_window(2),
+        len(starts), _dispatch, _collect, _PIPELINE_WINDOW,
         phase_prefix="ann",
     )
     return np.concatenate(out_d), np.concatenate(out_p)

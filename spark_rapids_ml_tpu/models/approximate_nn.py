@@ -206,16 +206,9 @@ class _ApproximateNearestNeighborsParams(
     def _resolved_hot_fraction(self) -> float:
         """The tiered-residency knob for BOTH tiers: the fraction of each
         shard's lists pinned HBM-resident (ann/tier.py pages the rest from
-        host RAM on probe demand).  algoParams['hot_fraction'] wins; the
-        SRML_ANN_HOT_FRACTION env var is the fleet-wide default; 1.0
-        (everything resident — the pre-tier behavior) otherwise."""
-        import os
-
-        ap = self._validated_algo_params()
-        if "hot_fraction" in ap:
-            hf = float(ap["hot_fraction"])
-        else:
-            hf = float(os.environ.get("SRML_ANN_HOT_FRACTION", "1.0"))
+        host RAM on probe demand).  algoParams['hot_fraction'], or 1.0
+        (everything resident) where it is unset."""
+        hf = float(self._validated_algo_params().get("hot_fraction", 1.0))
         if not 0.0 <= hf <= 1.0:
             raise ValueError(
                 f"hot_fraction ({hf}) must be in [0, 1] (1 = fully "
@@ -753,10 +746,9 @@ class ApproximateNearestNeighborsModel(
 
     def index_bytes_per_item(self, mesh: Any = None) -> float:
         """Device-resident index bytes per indexed item on this mesh — the
-        flat-vs-PQ compression headline benchmark/bench_approximate_nn.py
-        reports (host-side payloads — ids, the PQ refine f32 vectors — are
-        deliberately excluded: device HBM is the capacity constraint the
-        PQ tier exists to lift)."""
+        flat-vs-PQ compression headline (host-side payloads — ids, the PQ
+        refine f32 vectors — are deliberately excluded: device HBM is the
+        capacity constraint the PQ tier exists to lift)."""
         self._check_algorithm()
         mesh = mesh or get_mesh(self.num_workers)
         if self.getAlgorithm() == "ivfpq":

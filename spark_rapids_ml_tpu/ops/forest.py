@@ -653,17 +653,15 @@ def _level_block() -> int:
     return max(1, int(os.environ.get("SRML_FOREST_LEVEL_BLOCK", "4")))
 
 
-def _hist_budget_bytes() -> int:
-    """Per-chunk histogram buffer budget (MB) for the feature-chunked
-    split search."""
-    return int(os.environ.get("SRML_FOREST_HIST_MB", "256")) << 20
+# per-chunk histogram buffer budget of the feature-chunked split search
+_HIST_BUDGET_BYTES = 256 << 20
 
 
 def _feat_chunk(n_cols: int, combined: int, n_bins: int, s_dim: int) -> int:
     """Power-of-two feature-chunk width keeping one (fc, S, combined*B)
     histogram under the budget — bucketed (like the node counts) so the
     executable-cache key universe stays small."""
-    fc = max(1, _hist_budget_bytes() // max(1, combined * n_bins * s_dim * 4))
+    fc = max(1, _HIST_BUDGET_BYTES // max(1, combined * n_bins * s_dim * 4))
     return max(1, min(_p2floor(fc), _p2floor(n_cols)))
 
 

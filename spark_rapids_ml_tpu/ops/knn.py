@@ -157,17 +157,10 @@ _COLLECT_MERGE_BUDGET = 1 << 30
 # log instead of on wall-clock timing.
 # ---------------------------------------------------------------------------
 
-_PIPELINE_WINDOW_ENV = "SRML_KNN_PIPELINE_WINDOW"
+# blocks dispatched ahead of the one being collected
+_PIPELINE_WINDOW = 2
+_PIPELINE_WINDOW_ADAPTIVE = 4
 _FORCE_ADAPTIVE_ENV = "SRML_KNN_FORCE_ADAPTIVE"
-
-
-def _pipeline_window(default: int) -> int:
-    import os
-
-    try:
-        return max(1, int(os.environ.get(_PIPELINE_WINDOW_ENV, default)))
-    except ValueError:
-        return default
 
 
 def _force_adaptive() -> bool:
@@ -410,7 +403,6 @@ def knn_block_kernel(
 # ---------------------------------------------------------------------------
 
 _EXCHANGE_ENV = "SRML_KNN_EXCHANGE"
-_RING_CHUNK_ENV = "SRML_KNN_RING_CHUNK"
 _RING_CHUNK = 16384
 _RING_QT = 64
 
@@ -456,13 +448,8 @@ def _exchange_geometry(n_loc: int, q_rows: int, n_dev: int, route: str):
     tile is the same (qt, chunk) shape on every mesh, so per-candidate d2
     bits are mesh-independent and the lex merges make the rest exact."""
     import math
-    import os
 
-    try:
-        cap = int(os.environ.get(_RING_CHUNK_ENV, _RING_CHUNK))
-    except ValueError:
-        cap = _RING_CHUNK
-    chunk = max(1, min(cap, n_loc))
+    chunk = max(1, min(_RING_CHUNK, n_loc))
     rows = q_rows // n_dev if route == "ring" else q_rows
     qt = max(1, math.gcd(max(rows, 1), _RING_QT))
     return chunk, qt
@@ -976,8 +963,9 @@ def _adaptive_pallas_phases(items, item_norm, valid, qd, k, m, n_items,
     kernel's pool feeds a second Pallas kernel that emits the final
     per-block (distance, position, flag) arrays in one pass over the
     VMEM-resident pool — no XLA transpose slab, no sort-shaped merge, the
-    structural fix for the knn.collect spread named by BENCH_r05's
-    attribution.  `fused=False` keeps the XLA merge (_adaptive_merge_self),
+    structural fix for the knn.collect spread of the pre-round capture
+    (PERF.md section 7).  `fused=False` keeps the XLA merge
+    (_adaptive_merge_self),
     which is also the fallback for pools past the fused VMEM budget.
     Verification reads the pool's per-group m-th kept values either way;
     SRML_KNN_AUDIT_COUNT=1 restores the global count scan
@@ -1968,7 +1956,7 @@ def knn_search_prepared(
             out_i.append(ids_host)
 
         _run_block_pipeline(
-            len(starts), _dispatch_a, _collect_a, _pipeline_window(4)
+            len(starts), _dispatch_a, _collect_a, _PIPELINE_WINDOW_ADAPTIVE
         )
 
         if fallback_q:
@@ -2039,7 +2027,7 @@ def knn_search_prepared(
         out_d.append(d_host)
         out_i.append(ids_host)
 
-    _run_block_pipeline(len(starts), _dispatch, _collect, _pipeline_window(2))
+    _run_block_pipeline(len(starts), _dispatch, _collect, _PIPELINE_WINDOW)
     with profiling.phase("knn.merge"):
         return (
             np.concatenate(out_d)[:, :k_eff],

@@ -37,7 +37,6 @@
 from __future__ import annotations
 
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -49,11 +48,8 @@ from .pallas_tpu import _round_up, pallas_enabled
 # tile geometry: TQ queries x TI items per grid cell, D consumed in KB-wide
 # blocks.  VMEM at (256, 1024, 512): 2x double-buffered q/item blocks
 # (2*(256+1024)*512*4 = 5.2 MB) + the f32 accumulator tile (1 MB) + norm
-# slivers — comfortably inside the ~15 MB scoped budget.  TQ and the
-# query-resident K-block cap are hardware-tuning knobs (SRML_KNN_TILE_Q /
-# SRML_KNN_TILE_D, read once at import) so TPU generations with different
-# VMEM/MXU balances can be swept without code edits.
-_TILE_Q = int(os.environ.get("SRML_KNN_TILE_Q", "256"))
+# slivers — comfortably inside the ~15 MB scoped budget.
+_TILE_Q = 256
 _TILE_I = 1024
 _TILE_D = 512
 
@@ -67,7 +63,7 @@ _MIN_ALIGN_ROWS = 1 << 15
 # ~(4 + 4 + 2 + 2) bytes x tile_i x kb = 36 MB at (1024, 3072), which stays
 # inside the raised 100 MB scoped budget alongside the (TQ, TI) accumulator
 # tile and the epilogue temporaries.
-_TILE_D_QRES = int(os.environ.get("SRML_KNN_TILE_D", "3072"))
+_TILE_D_QRES = 3072
 
 
 def pallas_align_dims(n_rows: int, d: int, n_dev: int):
@@ -518,8 +514,9 @@ def knn_candidates_pallas(
 # The candidates kernel's (ng, m_pad, q_pad) pool used to flow through an
 # XLA transpose + grouped top-k + flag pass (_adaptive_merge_self): a second
 # full HBM materialization of the pool, a sort-shaped selection, and the
-# epilogue BENCH_r05's spread attribution pinned as the kNN arm's 26%
-# "knn.collect" culprit.  The fused merge kernel below consumes the pool in
+# epilogue the pre-round capture pinned as the kNN arm's "knn.collect"
+# spread (PERF.md section 7).  The fused merge kernel below consumes the
+# pool in
 # its NATIVE layout — one (ng, m_pad, tq) VMEM block per query tile — and
 # emits the FINAL per-block (distance, position, self-verify flag) arrays,
 # so the only thing left for the host is the id map: no transpose slab, no

@@ -335,7 +335,6 @@ def spectral_init(
 #     degree graphs (test_umap.test_hub_heavy_graph_layout_quality)
 #   SRML_UMAP_EPOCH_BLOCK — epochs fused per jitted layout step (lax.scan);
 #     the epoch loop issues ceil(n_epochs / block) dispatches total
-#   SRML_UMAP_TABLE — negative-sample table size per epoch
 def _layout_cap() -> int:
     return int(os.environ.get("SRML_UMAP_DEGREE_CAP", 36))
 
@@ -348,8 +347,8 @@ def _epoch_block() -> int:
     return max(1, int(os.environ.get("SRML_UMAP_EPOCH_BLOCK", 50)))
 
 
-def _neg_table() -> int:
-    return int(os.environ.get("SRML_UMAP_TABLE", 256))
+# negative-sample table size per epoch, where `table_size=` is left at 0
+_NEG_TABLE = 256
 
 
 def padded_head_layout(
@@ -683,7 +682,7 @@ def optimize_layout_sharded(
     repulsion_strength: float,
     negative_sample_rate: int,
     seed: int,
-    table_size: int = 0,   # 0 = SRML_UMAP_TABLE (default 256)
+    table_size: int = 0,   # 0 = _NEG_TABLE (256)
 ) -> jax.Array:
     """Mesh-parallel SGD layout driver: reshard the layout into column-
     sharded head blocks, replicate the embedding, then launch
@@ -706,7 +705,7 @@ def optimize_layout_sharded(
     tails_T = jax.device_put(jnp.transpose(tails_pad), col_sharding(mesh))
     w_T = jax.device_put(jnp.transpose(w_pad), col_sharding(mesh))
     emb = jax.device_put(emb, replicated_sharding(mesh))
-    M = table_size or _neg_table()
+    M = table_size or _NEG_TABLE
     block = _epoch_block()
     epochs_total = jnp.float32(max(n_epochs, 1))
     scal = (

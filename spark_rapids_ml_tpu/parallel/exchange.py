@@ -558,9 +558,8 @@ def ring_shift(x, axis_name: str = None, shift: int = 1,
 def byte_totals(prefix: str = "exchange."):
     """(total_bytes, {section: bytes}) over every exchange section counter —
     host sections count per call, device sections per compiled geometry
-    (trace time).  bench.py snapshots this around each arm so the round
-    standings can print a `bytes moved` column and make the all-gather ->
-    ring traffic reduction a captured artifact.  The per-LINK rollup of
+    (trace time).  Snapshot it around a search to read the bytes each
+    route moved (all-gather against ring).  The per-LINK rollup of
     the same namespace lives in link_totals() — the `.ici_bytes`/
     `.dcn_bytes` split counters carry an underscore suffix precisely so
     this scan never double-counts them."""

@@ -18,7 +18,7 @@
 #   - PUSHED aborts and death notices: the coordinator broadcasts an abort
 #     marker / dead-rank notice the moment it learns of it, so a blocked
 #     gather wakes in ~one RTT instead of the file plane's 50 ms poll
-#     floor (benchmark/bench_control_plane.py measures both).
+#     floor (tests/test_netplane.py holds the push under it).
 #   - LEASES with session-epoch fencing replacing flock liveness: every
 #     member holds a coordinator lease refreshed by any frame (pings ride
 #     at lease/3); an expired lease — SIGKILL, OOM, network partition —

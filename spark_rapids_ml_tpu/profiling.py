@@ -58,7 +58,7 @@ import os
 import sys
 import threading
 import time
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 _log = logging.getLogger("spark_rapids_ml_tpu.profiling")
 
@@ -907,39 +907,13 @@ def collect_gauges(prefix: str = "") -> Dict[str, float]:
     return dict(sorted(out.items()))
 
 
-def spread_attribution(
-    phase_runs: List[Dict[str, float]],
-    median_s: float,
-    floor_pct: float = 1.0,
-    top: int = 5,
-) -> Dict[str, float]:
-    """Attribute a multi-repeat timing spread to phases: for each phase
-    name across `phase_runs` (one phase_times() dict per timed repeat),
-    report max−min as % of the median run `median_s` — which phase's
-    variance IS the spread.  Phases under `floor_pct` are dropped; the
-    `top` largest survive, largest first.  The ONE implementation behind
-    bench.py's per-arm spread_attribution and benchmark/base.py's
-    cross-run aggregation (both write the same artifact keys)."""
-    if len(phase_runs) < 2 or median_s <= 0:
-        return {}
-    names = set().union(*(set(p) for p in phase_runs))
-    out = {}
-    for n in names:
-        vals = [float(p.get(n, 0.0)) for p in phase_runs]
-        pct = 100.0 * (max(vals) - min(vals)) / median_s
-        if pct >= floor_pct:
-            out[n] = round(pct, 1)
-    return dict(sorted(out.items(), key=lambda kv: -kv[1])[:top])
-
-
 def export_metrics(prefix: str = "") -> Dict[str, Any]:
     """One stable JSON document of the process's observability state:
     counters, per-series duration percentile summaries, this thread's
     phase stats, and sampled gauges (memory watermarks, serving health,
     executable-cache size — whatever providers are registered), all
-    optionally prefix-filtered.  Embedded into benchmark artifacts and
-    round-trippable through json.dumps/loads (asserted by the CI
-    observability gate)."""
+    optionally prefix-filtered.  Round-trippable through json.dumps/loads
+    (tests/test_profiling.py)."""
     dur: Dict[str, Dict[str, float]] = {}
     with _durations_lock:
         series = {
@@ -1036,7 +1010,7 @@ def render_prometheus(metrics: Optional[Dict[str, Any]] = None) -> str:
     return "\n".join(lines) + "\n"
 
 
-# -- xprof capture / benchmark helpers ----------------------------------------
+# -- xprof capture -------------------------------------------------------------
 
 
 @contextlib.contextmanager
@@ -1054,17 +1028,6 @@ def maybe_trace(tag: str = "fit") -> Iterator[None]:
     with jax.profiler.trace(target):
         yield
     _log.info("xprof trace for %r written to %s", tag, target)
-
-
-def with_benchmark(name: str, fn: Callable[[], Any]) -> Tuple[Any, float]:
-    """Run fn, returning (result, elapsed_seconds) and logging the timing —
-    the reference's benchmark/utils.py:42-50 helper."""
-    t0 = time.perf_counter()
-    result = fn()
-    dt = time.perf_counter() - t0
-    _log.info("-" * 100)
-    _log.info("%s took: %s sec", name, dt)
-    return result, dt
 
 
 # -- srml-watch bootstrap ------------------------------------------------------

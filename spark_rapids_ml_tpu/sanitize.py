@@ -31,7 +31,7 @@
 # proves the graph it can SEE is acyclic; lockdep validates the orders that
 # actually execute (including through the alias/cross-module edges the AST
 # pass honestly cannot follow) whenever the chaos and serving-recovery
-# suites run with the sanitizer armed (ci/test.sh step 3p).
+# suites run with the sanitizer armed (the full pass of ci/test.sh).
 #
 # Lock names are CLASS-level (every MicroBatcher shares "serve.batcher.queue"):
 # lock ordering is a discipline of the code, not of instances, so two

@@ -57,30 +57,6 @@ from .slicepool import CapacityExhausted
 
 logger = logging.getLogger("spark_rapids_ml_tpu.serving")
 
-# knob defaults; every one overridable via SRML_AUTOSCALE_* (docs/serving.md)
-INTERVAL_ENV = "SRML_AUTOSCALE_INTERVAL_S"
-_DEFAULT_INTERVAL_S = 0.25
-MIN_ENV = "SRML_AUTOSCALE_MIN"
-_DEFAULT_MIN = 1
-MAX_ENV = "SRML_AUTOSCALE_MAX"
-_DEFAULT_MAX = 4
-WINDOW_ENV = "SRML_AUTOSCALE_WINDOW_S"
-_DEFAULT_WINDOW_S = 2.0
-DOWN_WINDOW_ENV = "SRML_AUTOSCALE_DOWN_WINDOW_S"
-_DEFAULT_DOWN_WINDOW_S = 5.0
-UP_FILL_ENV = "SRML_AUTOSCALE_UP_FILL"
-_DEFAULT_UP_FILL = 0.5
-UP_BURN_ENV = "SRML_AUTOSCALE_UP_BURN"
-_DEFAULT_UP_BURN = 0.1
-DOWN_FILL_ENV = "SRML_AUTOSCALE_DOWN_FILL"
-_DEFAULT_DOWN_FILL = 0.05
-DOWN_OCCUPANCY_ENV = "SRML_AUTOSCALE_DOWN_OCCUPANCY"
-_DEFAULT_DOWN_OCCUPANCY = 0.25
-UP_COOLDOWN_ENV = "SRML_AUTOSCALE_UP_COOLDOWN_S"
-_DEFAULT_UP_COOLDOWN_S = 1.0
-DOWN_COOLDOWN_ENV = "SRML_AUTOSCALE_DOWN_COOLDOWN_S"
-_DEFAULT_DOWN_COOLDOWN_S = 10.0
-
 # consecutive ticks a replica must read UNHEALTHY before it is replaced:
 # state() flips transient wedges to RECOVERING synchronously, but the
 # worker-death window can expose a momentary UNHEALTHY that the bounded
@@ -92,40 +68,19 @@ _TERMINAL_STREAK = 2
 
 @dataclass(frozen=True)
 class AutoscalePolicy:
-    """One model's scaling policy; from_env() reads the SRML_AUTOSCALE_*
-    knobs so deployments tune without code."""
+    """One model's scaling policy.  A deployment that wants another one
+    passes its own instance to Autoscaler(policy=...)."""
 
-    min_replicas: int = _DEFAULT_MIN
-    max_replicas: int = _DEFAULT_MAX
-    window_s: float = _DEFAULT_WINDOW_S
-    down_window_s: float = _DEFAULT_DOWN_WINDOW_S
-    up_fill: float = _DEFAULT_UP_FILL
-    up_burn: float = _DEFAULT_UP_BURN
-    down_fill: float = _DEFAULT_DOWN_FILL
-    down_occupancy: float = _DEFAULT_DOWN_OCCUPANCY
-    up_cooldown_s: float = _DEFAULT_UP_COOLDOWN_S
-    down_cooldown_s: float = _DEFAULT_DOWN_COOLDOWN_S
-
-    @classmethod
-    def from_env(cls) -> "AutoscalePolicy":
-        from ..utils import env_float
-
-        return cls(
-            min_replicas=max(1, int(env_float(MIN_ENV, _DEFAULT_MIN))),
-            max_replicas=max(1, int(env_float(MAX_ENV, _DEFAULT_MAX))),
-            window_s=env_float(WINDOW_ENV, _DEFAULT_WINDOW_S),
-            down_window_s=env_float(DOWN_WINDOW_ENV, _DEFAULT_DOWN_WINDOW_S),
-            up_fill=env_float(UP_FILL_ENV, _DEFAULT_UP_FILL),
-            up_burn=env_float(UP_BURN_ENV, _DEFAULT_UP_BURN),
-            down_fill=env_float(DOWN_FILL_ENV, _DEFAULT_DOWN_FILL),
-            down_occupancy=env_float(
-                DOWN_OCCUPANCY_ENV, _DEFAULT_DOWN_OCCUPANCY
-            ),
-            up_cooldown_s=env_float(UP_COOLDOWN_ENV, _DEFAULT_UP_COOLDOWN_S),
-            down_cooldown_s=env_float(
-                DOWN_COOLDOWN_ENV, _DEFAULT_DOWN_COOLDOWN_S
-            ),
-        )
+    min_replicas: int = 1
+    max_replicas: int = 4
+    window_s: float = 2.0
+    down_window_s: float = 5.0
+    up_fill: float = 0.5
+    up_burn: float = 0.1
+    down_fill: float = 0.05
+    down_occupancy: float = 0.25
+    up_cooldown_s: float = 1.0
+    down_cooldown_s: float = 10.0
 
 
 class _ModelScaleState:
@@ -152,18 +107,12 @@ class Autoscaler:
         self,
         router: Any,
         policy: Optional[AutoscalePolicy] = None,
-        interval_s: Optional[float] = None,
+        interval_s: float = 0.25,
         names: Optional[List[str]] = None,
     ):
-        from ..utils import env_float
-
         self._router = router
-        self._policy = policy or AutoscalePolicy.from_env()
-        self._interval_s = (
-            interval_s
-            if interval_s is not None
-            else env_float(INTERVAL_ENV, _DEFAULT_INTERVAL_S)
-        )
+        self._policy = policy or AutoscalePolicy()
+        self._interval_s = interval_s
         self._names = list(names) if names is not None else None
         self._lock = sanitize.lockdep_lock("serve.autoscale.state")
         self._states: Dict[str, _ModelScaleState] = {}

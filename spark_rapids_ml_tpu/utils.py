@@ -13,7 +13,7 @@ from __future__ import annotations
 import logging
 import os
 import sys
-from typing import Any, Iterator, List, Optional, Union
+from typing import Any, List, Optional, Union
 
 import numpy as np
 
@@ -42,22 +42,6 @@ def get_logger(cls: Union[type, str], level: int = logging.INFO) -> logging.Logg
         logger.addHandler(handler)
         logger.propagate = False
     return logger
-
-
-def dtype_to_pyspark_type(dtype: Union[np.dtype, str]) -> str:
-    """numpy dtype -> Spark SQL type name (reference utils.py:233-247)."""
-    dtype = np.dtype(dtype)
-    if dtype == np.float32:
-        return "float"
-    if dtype == np.float64:
-        return "double"
-    if dtype == np.int32:
-        return "int"
-    if dtype == np.int64:
-        return "long"
-    if dtype == np.int16:
-        return "short"
-    raise RuntimeError(f"Unsupported dtype: {dtype}")
 
 
 def _concat_and_free(array_list: List[np.ndarray], order: str = "F") -> np.ndarray:
@@ -181,8 +165,3 @@ def pad_rows(arr: np.ndarray, multiple: int) -> np.ndarray:
         return arr
     pad_shape = (rem,) + arr.shape[1:]
     return np.concatenate([arr, np.zeros(pad_shape, dtype=arr.dtype)], axis=0)
-
-
-def chunk_iter(n: int, chunk: int) -> Iterator[slice]:
-    for start in range(0, n, chunk):
-        yield slice(start, min(start + chunk, n))

@@ -588,7 +588,7 @@ def test_r7_resolves_from_import_aliases_and_timer():
 def test_r7_silent_on_named_threads_and_out_of_scope():
     assert _lint(R7_GOOD, path="spark_rapids_ml_tpu/serving/engine.py") == []
     # benchmark/test harness threads may stay anonymous
-    assert _lint(R7_BAD, path="benchmark/bench_serving.py") == []
+    assert _lint(R7_BAD, path="benchmark/audit_knn.py") == []
     assert _lint(R7_BAD, path="tests/test_x.py") == []
 
 
@@ -754,7 +754,7 @@ def test_r9_scoped_to_parallel_and_serving():
     # solver/engine modules block only on the device runtime — out of scope
     assert _lint(R9_BAD_WAITS, path="spark_rapids_ml_tpu/ops/knn.py") == []
     assert _lint(R9_BAD_SWALLOW, path="spark_rapids_ml_tpu/watch.py") == []
-    assert _lint(R9_BAD_WAITS, path="benchmark/bench_serving.py") == []
+    assert _lint(R9_BAD_WAITS, path="benchmark/audit_knn.py") == []
 
 
 def test_r9_pragma_escape():

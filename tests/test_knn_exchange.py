@@ -348,3 +348,62 @@ def test_distributed_ring_falls_back_when_any_rank_overflows(monkeypatch):
     assert ctr.get("exchange.ring.calls", 0) == 0
     assert ctr.get("exchange.alltoall.calls", 0) == 4
     profiling.reset_counters("exchange.")
+
+
+# -- the public estimator on the mesh -----------------------------------------
+
+
+@pytest.mark.parametrize(
+    "topo_env, pin",
+    [(None, False), ("2:4", False), ("2:4", True)],
+    ids=["flat", "hier_2x4", "flat_pinned_2x4"],
+)
+def test_public_kneighbors_on_the_mesh_repeats_without_compiles(
+    topo_env, pin, monkeypatch
+):
+    """`NearestNeighbors.fit(...).kneighbors(...)` over the whole 8-device mesh
+    (2000 x 16, k=10): a repeat search compiles nothing, an exchange route ran
+    and was counted, and its sections moved bytes (`exchange.byte_totals`),
+    under no topology, a simulated 2x4 and the flat pin on it.  A topology is
+    a static of the exchange kernels, so each case traces its own."""
+    from spark_rapids_ml_tpu import NearestNeighbors
+    from spark_rapids_ml_tpu.dataframe import DataFrame
+    from spark_rapids_ml_tpu.parallel import topology
+    from spark_rapids_ml_tpu.parallel.exchange import byte_totals, link_totals
+
+    monkeypatch.delenv(topology.TOPO_ENV, raising=False)
+    monkeypatch.delenv(topology.EXCHANGE_TOPO_ENV, raising=False)
+    if topo_env:
+        monkeypatch.setenv(topology.TOPO_ENV, topo_env)
+    if pin:
+        monkeypatch.setenv(topology.EXCHANGE_TOPO_ENV, "flat")
+    rng = np.random.default_rng(28)
+    centers = rng.standard_normal((8, 16)) * 6
+    X = (centers[rng.integers(0, 8, 2000)] + rng.standard_normal((2000, 16))).astype(
+        np.float32
+    )
+    df = DataFrame.from_numpy(X)
+    _, bytes0 = byte_totals()
+    links0 = link_totals()
+    routes0 = profiling.counters("knn.exchange_route")
+    model = NearestNeighbors(k=10).setInputCol("features").fit(df)
+    model.kneighbors(df)  # stages the items, compiles every geometry
+    before = profiling.counters("precompile.")
+    _, _, knn_df = model.kneighbors(df)
+    delta = profiling.counter_deltas(before, "precompile.")
+    assert delta.get("precompile.compile", 0) == 0, delta
+    assert delta.get("precompile.fallback", 0) == 0, delta
+    ids = np.concatenate(
+        [np.asarray(list(p["indices"])) for p in knn_df.partitions if len(p)]
+    )
+    np.testing.assert_array_equal(ids[:, 0], np.arange(2000))  # self-join
+    assert profiling.counter_deltas(routes0, "knn.exchange_route"), "no route ran"
+    _, bytes1 = byte_totals()
+    moved = {k: v - bytes0.get(k, 0) for k, v in bytes1.items() if v > bytes0.get(k, 0)}
+    assert any(name.startswith("knn.ring") for name in moved), moved
+    links = {k: v - links0[k] for k, v in link_totals().items()}
+    if topo_env and not pin:
+        assert links["ici"] > 0 and links["dcn"] > 0, links
+    elif pin:
+        # flat on a multi-group topology accounts everything as DCN
+        assert links["dcn"] > 0 and links["ici"] == 0, links

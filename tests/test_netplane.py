@@ -142,6 +142,7 @@ def test_pushed_abort_beats_the_poll_floor(coordinator):
     for t in threads:
         t.start()
     time.sleep(0.2)  # both are blocked in the gather wait
+    pushed0 = profiling.counter("cp.net.pushed_aborts")
     t_abort[0] = time.monotonic()
     planes[1].abort(json.dumps({
         "rank": 1, "etype": "ValueError",
@@ -158,6 +159,7 @@ def test_pushed_abort_beats_the_poll_floor(coordinator):
             "file plane's 50 ms poll floor"
         )
     assert planes[0].check_abort()["rank"] == 1  # non-blocking surface too
+    assert profiling.counter("cp.net.pushed_aborts") > pushed0
 
 
 # -- leases + fencing ---------------------------------------------------------

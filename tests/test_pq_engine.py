@@ -639,3 +639,94 @@ def test_model_hot_fraction_param_surface():
     assert res["hbm_bytes_per_item"] > 0
     assert res["host_bytes_per_item"] > 0
     assert res["items_per_device"] >= 1
+
+
+# -- the public estimator's arms, paired on one dataset ------------------------
+
+
+def _estimator_arm(X, algorithm, algo_params, k=10, raw=False):
+    """One arm through the public estimator: build, a warm probed search, a
+    repeat that may compile nothing, recall@k against the same model's exact
+    search, and where its bytes live."""
+    tier0 = profiling.counters("ann.tier")
+    model = ApproximateNearestNeighbors(
+        k=k, algorithm=algorithm, algoParams=algo_params
+    ).setInputCol("features").fit(DataFrame.from_numpy(X))
+    df = DataFrame.from_numpy(X[:512])
+
+    def ids_of(knn_df):
+        return np.concatenate(
+            [np.asarray(list(p["indices"])) for p in knn_df.partitions if len(p)]
+        )
+
+    model.kneighbors(df)  # stages the index, compiles every probe geometry
+    before = profiling.counters("precompile.")
+    _, _, knn_df = model.kneighbors(df)
+    delta = profiling.counter_deltas(before, "precompile.")
+    steady = delta.get("precompile.compile", 0) + delta.get("precompile.fallback", 0)
+    tier = profiling.counter_deltas(tier0, "ann.tier")
+    model.setExactSearch(True)
+    _, _, exact_df = model.kneighbors(df)
+    model.setExactSearch(False)
+    out = {
+        "recall": recall_at_k(ids_of(knn_df), ids_of(exact_df)),
+        "steady_compiles": steady,
+        "index_bytes_per_item": model.index_bytes_per_item(),
+        "tier": tier,
+        **model.index_residency(),
+    }
+    if raw:
+        # the raw ADC recall (refine off) beside the refined one: the gap is
+        # the quantization error the f32 re-score recovers
+        model.setAlgoParams({**algo_params, "refine_ratio": 1})
+        _, _, raw_df = model.kneighbors(df)
+        out["recall_raw"] = recall_at_k(ids_of(raw_df), ids_of(exact_df))
+    return out
+
+
+@pytest.mark.parametrize("pair", ["flat_vs_pq", "8bit_vs_4bit_opq", "tiered"])
+def test_estimator_arms_at_ci_smoke_size(pair):
+    """What the CI smokes of the removed harness asserted of the ANN tiers, at
+    their sizes (PR 28): every arm's refined recall@10 and zero compiles on a
+    repeat search; PQ's device bytes an item at most 1/8 of the flat index's;
+    4-bit + OPQ at most 0.6 of the 8-bit arm's HBM bytes at like-for-like
+    residency; and a tiered arm that really pages."""
+    if pair == "flat_vs_pq":
+        X, _ = _clustered(n=2000, d=32, n_blobs=8, seed=41)
+        flat = _estimator_arm(X, "ivfflat", {"nlist": 8, "nprobe": 4})
+        # every list probed + x8 refine: raw ADC recall at 2k x 32 is about a
+        # half, and the refine's recovery is what the bar is about; n_bits=6 so
+        # the fixed codebook bytes do not swamp the ratio at this item count
+        pq = _estimator_arm(
+            X, "ivfpq",
+            {"nlist": 8, "nprobe": 8, "M": 8, "n_bits": 6, "refine_ratio": 8},
+            raw=True,
+        )
+        assert flat["recall"] >= 0.95 and flat["steady_compiles"] == 0, flat
+        assert pq["recall"] >= 0.9 and pq["steady_compiles"] == 0, pq
+        assert pq["recall_raw"] <= pq["recall"], pq
+        ratio = flat["index_bytes_per_item"] / pq["index_bytes_per_item"]
+        assert ratio >= 8.0, (flat["index_bytes_per_item"], pq["index_bytes_per_item"])
+        return
+    X, _ = _clustered(n=2048, d=32, n_blobs=16, seed=43)
+    opq4 = {"nlist": 16, "nprobe": 16, "M": 16, "n_bits": 4, "opq": True,
+            "refine_ratio": 8}
+    if pair == "8bit_vs_4bit_opq":
+        b8 = _estimator_arm(
+            X, "ivfpq",
+            {"nlist": 16, "nprobe": 16, "M": 16, "n_bits": 8, "refine_ratio": 8},
+        )
+        b4 = _estimator_arm(X, "ivfpq", opq4)
+        for arm in (b8, b4):
+            assert arm["recall"] >= 0.9 and arm["steady_compiles"] == 0, arm
+        assert b4["hbm_bytes_per_item"] <= 0.6 * b8["hbm_bytes_per_item"], (
+            b4["hbm_bytes_per_item"], b8["hbm_bytes_per_item"],
+        )
+        return
+    # half the 16 lists pinned, 4 probed: the cold ones cycle through the pool
+    tiered = _estimator_arm(X, "ivfpq", {**opq4, "nprobe": 4, "hot_fraction": 0.5})
+    assert tiered["recall"] >= 0.9 and tiered["steady_compiles"] == 0, tiered
+    tc = tiered["tier"]
+    assert tc.get("ann.tier.hits", 0) > 0 and tc.get("ann.tier.misses", 0) > 0, tc
+    assert tc.get("ann.tier.page_bytes", 0) > 0, tc
+    assert tiered["host_bytes_per_item"] > 0, tiered

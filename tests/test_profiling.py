@@ -27,12 +27,6 @@ def test_phase_registry_accumulates():
     assert times["unit.a"] >= 0.0
 
 
-def test_with_benchmark_returns_result_and_elapsed():
-    result, elapsed = profiling.with_benchmark("unit", lambda: 42)
-    assert result == 42
-    assert elapsed >= 0.0
-
-
 def test_fit_records_phase_times():
     from spark_rapids_ml_tpu import KMeans
     from spark_rapids_ml_tpu.dataframe import DataFrame
@@ -372,6 +366,39 @@ def test_local_fit_attaches_telemetry():
     from spark_rapids_ml_tpu.core import TELEMETRY_ATTR
 
     assert TELEMETRY_ATTR not in model._get_model_attributes()
+
+
+def test_fit_and_serve_sessions_write_trace_files(tmp_path, monkeypatch):
+    """End to end through the public path: with SRML_TRACE_DIR set, a fit and
+    a serving session each leave a Chrome trace-event file of complete span
+    events, and the registry's telemetry counts the requests it served."""
+    import glob
+
+    from spark_rapids_ml_tpu import KMeans
+    from spark_rapids_ml_tpu.dataframe import DataFrame
+    from spark_rapids_ml_tpu.serving import ModelRegistry
+
+    monkeypatch.setenv(profiling.TRACE_ENV, str(tmp_path))
+    X = np.random.default_rng(0).standard_normal((512, 16)).astype(np.float32)
+    model = KMeans(k=4, maxIter=5, seed=1).fit(DataFrame.from_numpy(X))
+    assert model.fit_telemetry().phases["srml.fit"]["count"] == 1
+    with ModelRegistry(max_batch=32, max_wait_ms=2) as reg:
+        reg.register("trace_km", model)
+        for i in range(8):
+            reg.get("trace_km").predict(X[i])
+        counters = reg.telemetry().counters
+        assert counters.get("serving.trace_km.requests", 0) >= 8, counters
+    traces = glob.glob(str(tmp_path / "*.trace.json"))
+    tags = {os.path.basename(p).split("-")[0] for p in traces}
+    assert {"fit", "serve"} <= tags, traces
+    for path in traces:
+        with open(path) as f:
+            doc = json.load(f)
+        complete = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+        assert complete, f"{path}: no complete span events"
+        for e in complete:
+            assert set(e) >= {"name", "ts", "dur", "pid", "tid", "args"}, e
+    assert "srml_counter{" in profiling.render_prometheus()
 
 
 # -- export surface -----------------------------------------------------------

@@ -528,7 +528,7 @@ def test_batcher_hold_keeps_deadline_expired_batch_open():
 
 
 def test_depth2_goodput_dominates_depth1_at_equal_offered_load():
-    """THE deterministic continuous-batching gate (ci step 3k): at equal
+    """THE deterministic continuous-batching gate: at equal
     offered load against the same device-leg duration, depth-2 delivers
     at least one full batch MORE goodput than depth-1 before shedding.
 

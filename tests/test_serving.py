@@ -518,12 +518,14 @@ def test_served_knn_matches_kneighbors(model_zoo):
         srv.assert_steady_state()
 
 
-def test_steady_state_zero_new_compiles(model_zoo):
+@pytest.mark.parametrize("arm", ["kmeans", "linreg"])
+def test_steady_state_zero_new_compiles(arm, model_zoo):
     """The acceptance gate: after warmup, a mixed stream of single-row and
     small-batch requests across every bucket performs ZERO new executable
     compilations (precompile compile/fallback counters frozen)."""
-    model, X = model_zoo("kmeans")
-    srv = ModelServer("steady_km", model, max_batch=64, max_wait_ms=2)
+    model, X = model_zoo(arm)
+    name = f"steady_{arm}"
+    srv = ModelServer(name, model, max_batch=64, max_wait_ms=2)
     try:
         before = profiling.counters("precompile.")
         rng = np.random.default_rng(3)
@@ -536,7 +538,7 @@ def test_steady_state_zero_new_compiles(model_zoo):
         assert delta.get("precompile.fallback", 0) == 0, delta
         srv.drain()
         srv.assert_steady_state()
-        assert profiling.counter("serving.steady_km.steady_compiles") == 0
+        assert profiling.counter(f"serving.{name}.steady_compiles") == 0
     finally:
         srv.shutdown()
 

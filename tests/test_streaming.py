@@ -167,14 +167,22 @@ def test_streamed_logreg_metric_quality(clustered_data):
 # -- 2. zero-compile steady ingest -------------------------------------------
 
 
-def test_steady_ingest_zero_new_compiles(exact_data):
+@pytest.mark.parametrize("algo", ["linreg", "kmeans"])
+def test_steady_ingest_zero_new_compiles(algo, exact_data):
     X, y, cid = exact_data
-    eng = LinearRegression(maxIter=20).streaming()
-    eng.partial_fit(X[cid == 0], y=y[cid == 0])  # bucket's first chunk
+    if algo == "linreg":
+        eng = LinearRegression(maxIter=20).streaming()
+    else:
+        eng = KMeans(k=4, maxIter=5, seed=1).setFeaturesCol("features").streaming()
+        y = None
+
+    def ingest(m):
+        eng.partial_fit(X[m], y=None if y is None else y[m])
+
+    ingest(cid == 0)  # bucket's first chunk
     before = profiling.counters("precompile.")
     for c in range(1, int(cid.max()) + 1):
-        m = cid == c
-        eng.partial_fit(X[m], y=y[m])
+        ingest(cid == c)
     delta = profiling.counter_deltas(before, "precompile.")
     assert delta.get("precompile.compile", 0) == 0, delta
     assert delta.get("precompile.fallback", 0) == 0, delta

@@ -336,6 +336,50 @@ def test_batched_sweep_zero_new_compiles_on_repeat(monkeypatch):
     assert delta.get("precompile.aot_hit", 0) >= 2, delta  # stats + solve
 
 
+@pytest.mark.parametrize("algo", ["linreg", "logreg"])
+def test_batched_sweep_at_ci_smoke_size(algo, monkeypatch):
+    """What the CI smoke of the removed harness asserted of a batched sweep,
+    at its size (20,000 x 64, 3 folds, a grid of 8; PR 28), on both solver
+    families: every candidate goes through the sweep, its solve span is
+    recorded, and a repeat sweep at the same shapes compiles nothing.  (Its
+    third assertion, batched faster than sequential, was a timing on the CPU
+    backend and says nothing of the chip: no cell measures a sweep yet.)"""
+    rng = np.random.default_rng(42)
+    X = rng.standard_normal((20_000, 64)).astype(np.float32)
+    coef = rng.standard_normal(64).astype(np.float32)
+    if algo == "linreg":
+        y = (X @ coef + 0.1 * rng.standard_normal(20_000)).astype(np.float32)
+        est_cls, est_kwargs = LinearRegression, {"standardization": False}
+        eva = RegressionEvaluator(metricName="rmse")
+    else:
+        y = (X @ coef > 0).astype(np.float32)
+        est_cls, est_kwargs = LogisticRegression, {"maxIter": 100}
+        eva = MulticlassClassificationEvaluator(metricName="accuracy")
+    df = DataFrame.from_numpy(X, y=y, num_partitions=4)
+    grid = ParamGridBuilder().addGrid(
+        est_cls.regParam, np.geomspace(1e-3, 1.0, 8).tolist()
+    ).build()
+
+    def sweep():
+        model, _ = _run_cv(
+            df, est_cls(**est_kwargs), grid, eva, True, monkeypatch,
+            numFolds=3, seed=7, collectSubModels=True,
+        )
+        return model
+
+    c0 = profiling.counters("tuning.")
+    sweep()  # cold: compiles the sweep kernels
+    before = profiling.counters("precompile.")
+    model = sweep()
+    delta = profiling.counter_deltas(before, "precompile.")
+    assert delta.get("precompile.compile", 0) == 0, delta
+    assert delta.get("precompile.fallback", 0) == 0, delta
+    phases = model.subModels[0][0].fit_telemetry().phases
+    assert phases["tuning.sweep.solve"]["total_s"] > 0, phases.keys()
+    tuned = profiling.counter_deltas(c0, "tuning.")
+    assert tuned.get("tuning.candidates", 0) >= 2 * len(grid), tuned
+
+
 def test_batched_sweep_single_candidate_grid(monkeypatch):
     """m=1 must still route through the batched engine (tuning.candidates
     moves) and equal the sequential path exactly."""

@@ -572,6 +572,30 @@ def test_health_and_memory_round_trip_export_and_prometheus(model_zoo):
     )
 
 
+def test_fit_then_serve_leaves_memory_health_and_ring_events(monkeypatch):
+    """The health plane after real work, samplers as installed: a fit's
+    telemetry carries the host watermark, a served model reads READY with a
+    burn inside [0, 1] under a generous SLO, and the flight ring holds events."""
+    from spark_rapids_ml_tpu import KMeans
+    from spark_rapids_ml_tpu.dataframe import DataFrame
+    from spark_rapids_ml_tpu.serving import ModelRegistry
+
+    monkeypatch.setenv("SRML_SERVE_SLO_MS", "500")
+    X = np.random.default_rng(0).standard_normal((512, 16)).astype(np.float32)
+    model = KMeans(k=4, maxIter=5, seed=1).fit(DataFrame.from_numpy(X))
+    memory = model.fit_telemetry().memory
+    assert "mem.host" in memory, memory
+    with ModelRegistry(max_batch=32, max_wait_ms=2) as reg:
+        reg.register("w_smoke", model)
+        for i in range(16):
+            reg.get("w_smoke").predict(X[i])
+        h = reg.health()
+        assert h["state"] == "READY", h
+        km = h["models"]["w_smoke"]
+        assert km["attainment"] >= 0 and 0 <= km["burn"] <= 1, km
+    assert watch.ring_stats()["events"] > 0
+
+
 def test_ring_stats_self_description():
     stats = watch.ring_stats()
     assert stats["enabled"] is True

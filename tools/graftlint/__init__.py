@@ -65,7 +65,7 @@
 #                    stream/session.py, watch.py).
 #
 # Suppression: `# graftlint: disable=R1 (reason)` on the finding line or the
-# line directly above.  Granted pragmas are audited in NOTES.md.
+# line directly above; the reason in the parentheses is the audit record.
 #
 # The runtime counterpart (SRML_SANITIZE=1 transfer guard + NaN checks) lives
 # in spark_rapids_ml_tpu/sanitize.py; docs/graftlint.md documents both.
