@@ -49,6 +49,8 @@ SIZES = {
     "knn_items": 65_536, "knn_queries": 8192, "knn_k": 200, "knn_ref": 1024,
     "pq_rows": 262_144, "pq_d": 256, "pq_m": 32, "pq_queries": 1024,
     "pq_k": 10,
+    "cd_rows": 32_768, "cd_widths": (8, 100, 3000, 16_000), "cd_sweeps": 5,
+    "cd_reg": 0.012,
 }
 TOY_SIZES = {
     "rows_per_chip": 4096, "cols": 64, "k": 16, "fit_iters": 5,
@@ -59,6 +61,8 @@ TOY_SIZES = {
     "knn_items": 4096, "knn_queries": 256, "knn_k": 10, "knn_ref": 128,
     "pq_rows": 8192, "pq_d": 32, "pq_m": 8, "pq_queries": 128,
     "pq_k": 10,
+    "cd_rows": 2048, "cd_widths": (8, 100, 200), "cd_sweeps": 5,
+    "cd_reg": 0.05,
 }
 
 REHEARSAL = False
@@ -691,6 +695,103 @@ def kernel_pq(seed, n_bits):
     return {"shape": [n, d, m, n_bits], "recall": recall, "scan_matches_xla": True}
 
 
+def regression_rows(mesh, rows_per_dev, cols, seed):
+    """(X, y) made ON the mesh, row-sharded: unit normal columns, the first
+    four with coefficients 1, 2, 3, 4, unit normal noise."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+
+    rows = rows_per_dev * mesh.devices.size
+
+    def make(key):
+        kx, kn = jax.random.split(key)
+        X = jax.random.normal(kx, (rows, cols), jnp.float32)
+        y = X[:, :4] @ jnp.arange(1.0, 5.0, dtype=jnp.float32)
+        return X, y + jax.random.normal(kn, (rows,), jnp.float32)
+
+    by_rows = NamedSharding(mesh, P(DATA_AXIS))
+    return jax.jit(make, out_shardings=(by_rows, by_rows))(jax.random.key(seed))
+
+
+def enet(n_dev, reg):
+    from spark_rapids_ml_tpu import LinearRegression
+
+    return LinearRegression(
+        regParam=reg, elasticNetParam=0.5, maxIter=S["cd_sweeps"], tol=1e-30,
+        standardization=False, num_workers=n_dev,
+    )
+
+
+def labelled(X, y):
+    from spark_rapids_ml_tpu.dataframe import DataFrame
+
+    return DataFrame.from_device(X, y=np.asarray(y), n_rows=X.shape[0])
+
+
+def same_fit(a, b):
+    """Two elastic-net fits of one table: coefficients that differ by a
+    dot's order of summation, the same support but for a column whose pull
+    lies within that rounding of the threshold."""
+    ca, cb = np.asarray(a.coef_), np.asarray(b.coef_)
+    return bool(
+        np.abs(ca - cb).max() <= 1e-5
+        and ((ca == 0) == (cb == 0)).mean() >= 0.999
+    )
+
+
+def kernel_cd_sweep(seed):
+    """LinearRegression's elastic-net fit through ops/cd_sweep.py, at a small
+    width, the benchmark's and a large one, with a regParam at which the soft
+    threshold sets coefficients to exactly 0 (the benchmark cell's own, 1e-5,
+    lies below float32's rounding of a coefficient and its comparison cannot
+    see the penalty: chipbench/subjects/linreg.fit_loop.json), against the
+    fori_loop the solver runs off the chip.  A planted fault, the kernel's fit
+    with the penalty all but left out, has to read as another fit."""
+    import jax
+
+    from spark_rapids_ml_tpu.ops import cd_sweep, glm, pallas_tpu
+    from spark_rapids_ml_tpu.parallel.mesh import get_mesh
+
+    out = {}
+    for d in S["cd_widths"]:
+        X, y = regression_rows(get_mesh(1), S["cd_rows"], d, seed + 60 + d)
+        df = labelled(X, y)
+        G = jax.ShapeDtypeStruct((d, d), X.dtype)
+        require_route(
+            f"cd_sweep d={d}", "pallas" if cd_sweep.takes(G) else "xla", "pallas"
+        )
+        t0 = time.perf_counter()
+        kernel = enet(1, S["cd_reg"]).fit(df)
+        wall = time.perf_counter() - t0
+        fault = enet(1, 1e-12).fit(df)
+        os.environ[pallas_tpu.DISABLE_ENV] = "1"
+        glm.solve_elasticnet_cd.clear_cache()
+        try:
+            loop = enet(1, S["cd_reg"]).fit(df)
+        finally:
+            del os.environ[pallas_tpu.DISABLE_ENV]
+            glm.solve_elasticnet_cd.clear_cache()
+        zeros = int((np.asarray(kernel.coef_) == 0).sum())
+        log(
+            f"cd_sweep d={d}: {kernel.num_iters} sweeps (the loop "
+            f"{loop.num_iters}), {zeros} coefficients "
+            f"exactly 0 (the loop {int((np.asarray(loop.coef_) == 0).sum())}, "
+            f"no penalty {int((np.asarray(fault.coef_) == 0).sum())}), "
+            f"wall {wall:.1f}s"
+        )
+        check(2 <= kernel.num_iters <= S["cd_sweeps"], f"d={d}: {kernel.num_iters} sweeps")
+        check(d < 100 or d // 4 <= zeros <= d - 4, f"d={d}: {zeros} zeros, the penalty does not bite")
+        check(same_fit(kernel, loop), f"d={d}: the kernel's fit is not the loop's")
+        check(not same_fit(fault, loop), f"d={d}: a fit without the penalty read as sound")
+        out[str(d)] = {"zeros": zeros, "sweeps": kernel.num_iters}
+        del X, y, df
+        release()
+    return out
+
+
 def stage_kernels(seed):
     out = {"min_dist": kernel_min_dist(seed)}
     release()
@@ -700,6 +801,8 @@ def stage_kernels(seed):
     release()
     out["pq8"] = kernel_pq(seed, 8)
     out["pq4"] = kernel_pq(seed, 4)
+    release()
+    out["cd_sweep"] = kernel_cd_sweep(seed)
     return out
 
 
@@ -748,6 +851,70 @@ def mesh_knn(seed, n_dev):
     return {"overlap": overlap, "ring_dispatches": int(ring)}
 
 
+def mesh_linreg(seed, n_dev):
+    """Elastic-net LinearRegression over the mesh: the statistics are
+    replicated, so the sweep kernel runs per device under a shard_map.  One
+    fit against the one-device fit of the same rows, fitMultiple's maps on one
+    statistics pass, and a CrossValidator's sweep (ops/glm's lax.map over
+    folds and candidates) against the fold-by-fold route."""
+    import jax
+
+    from spark_rapids_ml_tpu import LinearRegression
+    from spark_rapids_ml_tpu.dataframe import DataFrame
+    from spark_rapids_ml_tpu.evaluation import RegressionEvaluator
+    from spark_rapids_ml_tpu.ops import cd_sweep
+    from spark_rapids_ml_tpu.parallel.mesh import get_mesh
+    from spark_rapids_ml_tpu.tuning import CrossValidator, ParamGridBuilder
+
+    reg = S["cd_reg"]
+    X, y = regression_rows(get_mesh(n_dev), S["cd_rows"], S["cols"], seed + 70)
+    G = jax.ShapeDtypeStruct((S["cols"], S["cols"]), X.dtype)
+    require_route("cd_sweep on the mesh", "pallas" if cd_sweep.takes(G) else "xla", "pallas")
+    df = labelled(X, y)
+    wide = enet(n_dev, reg).fit(df)
+    one = enet(1, reg).fit(labelled(jax.device_put(X, jax.devices()[0]), y))
+    zeros = int((np.asarray(wide.coef_) == 0).sum())
+    log(f"elastic net[{n_dev} dev]: {wide.num_iters} sweeps, {zeros} coefficients exactly 0")
+    check(zeros >= S["cols"] // 4, f"{zeros} zeros, the penalty does not bite")
+    check(same_fit(wide, one), "the mesh's elastic-net fit is not the one-device fit")
+    maps = [{LinearRegression.regParam: r} for r in (reg, 2 * reg)]
+    many = dict(enet(n_dev, reg).fitMultiple(df, maps))
+    check(
+        np.array_equal(many[0].coef_, wide.coef_),
+        "fitMultiple's first map is not the single fit",
+    )
+    check(
+        (np.asarray(many[1].coef_) == 0).sum() >= zeros
+        and np.abs(many[1].coef_).sum() < np.abs(wide.coef_).sum(),
+        "twice the penalty does not shrink the coefficients",
+    )
+    grid = ParamGridBuilder().addGrid(LinearRegression.regParam, [reg, 8.0]).build()
+    # a validator scores folds from host partitions: one device's rows
+    n = S["cd_rows"]
+    host = DataFrame.from_numpy(
+        np.asarray(X[:n]), y=np.asarray(y[:n]), num_partitions=S["host_parts"]
+    )
+    scores = {}
+    for batched in ("1", "0"):
+        os.environ["SRML_SWEEP_BATCH"] = batched
+        try:
+            cv = CrossValidator(
+                estimator=enet(n_dev, reg), estimatorParamMaps=grid,
+                evaluator=RegressionEvaluator(metricName="rmse"), numFolds=3,
+                seed=seed,
+            )
+            scores[batched] = cv.fit(host).avgMetrics
+        finally:
+            del os.environ["SRML_SWEEP_BATCH"]
+    log(f"tuning sweep rmse, batched {scores['1']}, fold by fold {scores['0']}")
+    check(scores["1"][0] < scores["1"][1], "the small penalty does not win")
+    check(
+        np.allclose(scores["1"], scores["0"], rtol=1e-4),
+        "the batched sweep's scores are not the fold-by-fold route's",
+    )
+    return {"zeros": zeros, "sweeps": wide.num_iters, "cv_rmse": scores["1"]}
+
+
 def mesh_replicas(made, n_dev):
     """One-chip replicas in this one process, each on its own device."""
     import jax
@@ -794,6 +961,8 @@ def stage_mesh(made, seed):
     out = {"fit": mesh_fit(made, seed, n_dev)}
     release()
     out["knn"] = mesh_knn(seed, n_dev)
+    release()
+    out["linreg"] = mesh_linreg(seed, n_dev)
     release()
     out["replicas"] = mesh_replicas(made, n_dev)
     return out

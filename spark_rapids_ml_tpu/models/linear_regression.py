@@ -15,13 +15,21 @@
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, List, Optional, Union
 
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 
-from ..core import FitInputs, _TpuEstimatorSupervised, _TpuModelWithPredictionCol
+from .. import profiling
+from ..core import (
+    FitInputs,
+    _TpuEstimatorSupervised,
+    _TpuModelWithPredictionCol,
+    fetch_fit_result,
+)
 from ..dataframe import DataFrame, as_dataframe
 from ..metrics.regression import RegressionMetrics, _SummarizerBuffer
 from ..params import (
@@ -126,6 +134,28 @@ def _host_intercept(
         np.asarray(y_mean, dtype=np.float64)
         - np.asarray(x_mean, dtype=np.float64) @ coef64
     )
+
+
+@functools.lru_cache(maxsize=256)
+def _device_scalar(value: float, dtype: str) -> jax.Array:
+    """A hyperparameter as a scalar that already lies on the device, made once
+    a value.  A Python float handed to a jitted solver goes up as one small
+    host-to-device copy a call: three a fit for coordinate descent, 0.65 ms
+    of the host's time in a fit whose whole host share is 3 ms (PERF.md,
+    PR 30)."""
+    return jnp.asarray(value, dtype)
+
+
+def _count_fit(sweeps: Optional[int], n_cols: int) -> None:
+    """Add one fit to the process-wide counters, which a fit's telemetry
+    snapshot then carries as its own deltas: linreg.fits for every solve;
+    for a coordinate-descent solve also cd.fits, cd.sweeps and
+    cd.coordinates (sweeps x columns: the solver's dependent steps)."""
+    profiling.incr_counter("linreg.fits", 1)
+    if sweeps is not None:
+        profiling.incr_counter("cd.fits", 1)
+        profiling.incr_counter("cd.sweeps", sweeps)
+        profiling.incr_counter("cd.coordinates", sweeps * int(n_cols))
 
 
 class LinearRegressionClass(_TpuParams):
@@ -264,68 +294,87 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
     def _get_tpu_fit_func(self, dataset: DataFrame, extra_params=None):
         logger = get_logger(type(self))
 
-        def _single_fit(stats, params: Dict[str, Any], inputs: FitInputs) -> Dict[str, Any]:
+        def _solve(stats, params: Dict[str, Any], mesh):
+            """Queue one param map's solve on the statistics replicated on
+            `mesh`: (coef, sweeps), both still on the device; sweeps is None
+            for the closed form."""
             alpha = float(params["alpha"])
             l1_ratio = float(params["l1_ratio"])
             fit_intercept = bool(params["fit_intercept"])
             normalize = bool(params["normalize"])
-            n_iter = None
+            dtype = str(stats.G.dtype)
             if alpha == 0.0 or l1_ratio == 0.0:
                 # OLS ("eig") or Ridge with Spark-parity alpha*n scaling —
                 # scaling handled inside solve_linear (reg = alpha * wsum)
                 coef, _ = solve_linear(
-                    stats, alpha, fit_intercept=fit_intercept, normalize=normalize
-                )
-            else:
-                # n_iter joins the batched fetch below — int() here would
-                # pay its own device round-trip
-                coef, _, n_iter = solve_elasticnet_cd(
                     stats,
-                    alpha,
-                    l1_ratio,
+                    _device_scalar(alpha, dtype),
                     fit_intercept=fit_intercept,
                     normalize=normalize,
-                    max_iter=int(params["max_iter"]),
-                    tol=float(params["tol"]),
                 )
-            # one batched device fetch (separate np.asarray/float coercions
-            # each cost a host round-trip)
-            coef_h, xm_h, ym_h, n_iter_h = jax.device_get(
-                (coef, stats.x_mean, stats.y_mean, n_iter)
+                return coef, None
+            coef, _, n_iter = solve_elasticnet_cd(
+                stats,
+                _device_scalar(alpha, dtype),
+                _device_scalar(l1_ratio, dtype),
+                fit_intercept=fit_intercept,
+                normalize=normalize,
+                max_iter=int(params["max_iter"]),
+                tol=_device_scalar(float(params["tol"]), dtype),
+                mesh=mesh,
             )
-            if n_iter_h is not None:
-                logger.info("CD sweeps: %d", int(n_iter_h))
-            coef64 = np.asarray(coef_h, dtype=np.float64)
-            return {
-                "coef_": coef64,
-                "intercept_": _host_intercept(coef64, xm_h, ym_h, fit_intercept),
-                "n_cols": inputs.n_cols,
-                "dtype": str(inputs.dtype),
-            }
+            return coef, n_iter
 
         def _fit(inputs: FitInputs, params: Dict[str, Any]):
-            assert inputs.y is not None
-            from ..ops.sparse import EllMatrix, ell_sufficient_stats
+            # the step spans below tile srml.fit (core._call_tpu_fit_func)
+            with profiling.span("srml.fit.init"):
+                assert inputs.y is not None
+                from ..ops.sparse import EllMatrix, ell_sufficient_stats
 
-            if isinstance(inputs.X, EllMatrix):
-                # CSR ingest: chunk-densify + MXU Gram pass, never the whole
-                # matrix (ops/sparse.py); downstream solves are unchanged —
-                # the sufficient statistics are dense either way
-                stats = ell_sufficient_stats(
-                    inputs.X, inputs.y, inputs.weight, mesh=inputs.mesh
-                )
-            else:
-                stats = linreg_sufficient_stats(
-                    inputs.X, inputs.y, inputs.weight, mesh=inputs.mesh
-                )
-            if extra_params:
+                maps = [dict(params, **ov) for ov in extra_params or [{}]]
+            with profiling.span("srml.fit.solve"):
+                if isinstance(inputs.X, EllMatrix):
+                    # CSR ingest: chunk-densify + MXU Gram pass, never the
+                    # whole matrix (ops/sparse.py); downstream solves are
+                    # unchanged — the sufficient statistics are dense either way
+                    stats = ell_sufficient_stats(
+                        inputs.X, inputs.y, inputs.weight, mesh=inputs.mesh
+                    )
+                else:
+                    stats = linreg_sufficient_stats(
+                        inputs.X, inputs.y, inputs.weight, mesh=inputs.mesh
+                    )
+                # one pass over the data, then every map's solve queued
+                # behind it before the one wait
+                solved = [_solve(stats, p, inputs.mesh) for p in maps]
+            # ONE batched device fetch for all maps (each np.asarray / int()
+            # alone costs a host round-trip)
+            solved_h, xm_h, ym_h = fetch_fit_result(
+                (solved, stats.x_mean, stats.y_mean)
+            )
+            with profiling.span("srml.fit.pack"):
+                # the device buffers go inside a step, not with the frame
+                # after the last one
+                del solved, stats
                 results = []
-                for override in extra_params:
-                    p = dict(params)
-                    p.update(override)
-                    results.append(_single_fit(stats, p, inputs))
-                return results
-            return _single_fit(stats, params, inputs)
+                for p, (coef_h, n_iter_h) in zip(maps, solved_h):
+                    coef64 = np.asarray(coef_h, dtype=np.float64)
+                    sweeps = None if n_iter_h is None else int(n_iter_h)
+                    _count_fit(sweeps, inputs.n_cols)
+                    if sweeps is not None:
+                        logger.info("CD sweeps: %d", sweeps)
+                    results.append(
+                        {
+                            "coef_": coef64,
+                            "intercept_": _host_intercept(
+                                coef64, xm_h, ym_h, bool(p["fit_intercept"])
+                            ),
+                            "n_cols": inputs.n_cols,
+                            "dtype": str(inputs.dtype),
+                            "num_iters": sweeps,
+                        }
+                    )
+                return results if extra_params else results[0]
 
         return _fit
 
@@ -360,7 +409,6 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
         stats pass + one stacked-lane solve dispatch per solver family over
         the ONE staged dataset (ops/glm.py sweep kernels; exact-equality
         contract in docs/tuning_engine.md)."""
-        from .. import profiling
         from ..core import _maybe_x64
         from ..ops import sweep as sweep_ops
         from ..sanitize import sanitize_scope
@@ -514,14 +562,22 @@ class LinearRegressionModel(
         intercept_: Union[float, List[float]],
         n_cols: int,
         dtype: str,
+        num_iters: Union[None, int, List[Optional[int]]] = None,
     ) -> None:
         super().__init__(
-            coef_=np.asarray(coef_), intercept_=intercept_, n_cols=int(n_cols), dtype=str(dtype)
+            coef_=np.asarray(coef_),
+            intercept_=intercept_,
+            n_cols=int(n_cols),
+            dtype=str(dtype),
+            num_iters=num_iters,
         )
         self.coef_ = np.asarray(coef_)
         self.intercept_ = intercept_
         self.n_cols = int(n_cols)
         self.dtype = str(dtype)
+        # coordinate-descent sweeps the fit ran (Spark's
+        # summary.totalIterations); None for the closed form
+        self.num_iters = num_iters
 
     @property
     def _num_models(self) -> int:
@@ -657,6 +713,7 @@ class LinearRegressionModel(
             intercept_=[float(m.intercept_) for m in models],
             n_cols=first.n_cols,
             dtype=first.dtype,
+            num_iters=[m.num_iters for m in models],
         )
         first._copyValues(combined)
         combined._tpu_params.update(first._tpu_params)

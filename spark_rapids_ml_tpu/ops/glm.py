@@ -31,6 +31,7 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import cd_sweep
 from .linalg import exact_matmul
 
 
@@ -72,10 +73,9 @@ def linreg_sufficient_stats(
 
     def per_device(X_loc, y_loc, w_loc):
         # shared chunked-moment accumulator (ops/linalg.py) with the y-terms
-        return tuple(
-            jax.lax.psum(v, DATA_AXIS)
-            for v in _local_moments(X_loc, w_loc, chunk, y_loc=y_loc)
-        )
+        with jax.named_scope("linreg.gram"):
+            local = _local_moments(X_loc, w_loc, chunk, y_loc=y_loc)
+        return tuple(jax.lax.psum(v, DATA_AXIS) for v in local)
 
     wsum, xwsum, G, ywsum, c, y2 = shard_map(
         per_device,
@@ -130,7 +130,7 @@ def solve_linear(
     return b, intercept
 
 
-@partial(jax.jit, static_argnames=("fit_intercept", "normalize", "max_iter"))
+@partial(jax.jit, static_argnames=("fit_intercept", "normalize", "max_iter", "mesh"))
 def solve_elasticnet_cd(
     stats: LinregStats,
     alpha: float,
@@ -139,6 +139,7 @@ def solve_elasticnet_cd(
     normalize: bool = False,
     max_iter: int = 1000,
     tol: float = 1e-3,
+    mesh=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Covariance-update cyclic coordinate descent on the replicated Gram
     system; data already reduced to sufficient statistics.
@@ -148,7 +149,9 @@ def solve_elasticnet_cd(
     update: rho_j = (c_j - G_j.b + G_jj b_j)/n
             b_j   = soft(rho_j, alpha*l1r) / (G_jj/n + alpha*(1-l1r))
     Converges when the largest coefficient change in a sweep <= tol.
-    Returns (coef, intercept, n_sweeps).
+    Returns (coef, intercept, n_sweeps).  `mesh` is the mesh the statistics
+    are replicated on, None for statistics on one device: on the chip the
+    sweep kernel runs per device under its shard_map (ops/cd_sweep.py).
     """
     Gc, cc = _centered_system(stats, fit_intercept)
     s = _feature_scales(Gc, stats.wsum, normalize)
@@ -156,13 +159,26 @@ def solve_elasticnet_cd(
     c = cc / s
     n = stats.wsum
     d = G.shape[0]
-    Gdiag = jnp.diag(G) / n
-    denom = Gdiag + alpha * (1.0 - l1_ratio)
+    diag = jnp.diag(G)
+    denom = diag / n + alpha * (1.0 - l1_ratio)
     denom = jnp.where(denom > 0, denom, 1.0)
     thresh = alpha * l1_ratio
 
-    def sweep(carry):
-        b, _, it = carry
+    if cd_sweep.takes(G):
+        # on the chip a sweep is one kernel (ops/cd_sweep.py): the system
+        # padded once a solve, the coefficients carried as a lane vector
+        pad = cd_sweep.padded(d) - d
+        Gp = jnp.pad(G, ((0, pad), (0, pad)))
+        cp = jnp.pad(c, (0, pad))
+        diagp = jnp.pad(diag, (0, pad))
+        denomp = jnp.pad(denom, (0, pad), constant_values=1.0)
+        b0 = jnp.zeros((1, d + pad), G.dtype)
+
+        def one_sweep(b):
+            return cd_sweep.sweep(Gp, cp, diagp, denomp, b, n, thresh, mesh)
+
+    else:
+        b0 = jnp.zeros((d,), G.dtype)
 
         def coord(j, state):
             b, max_delta = state
@@ -172,17 +188,23 @@ def solve_elasticnet_cd(
             max_delta = jnp.maximum(max_delta, jnp.abs(bj - b[j]))
             return b.at[j].set(bj), max_delta
 
-        b, max_delta = jax.lax.fori_loop(0, d, coord, (b, jnp.zeros((), b.dtype)))
+        def one_sweep(b):
+            return jax.lax.fori_loop(0, d, coord, (b, jnp.zeros((), b.dtype)))
+
+    def sweep(carry):
+        b, _, it = carry
+        with jax.named_scope("cd.sweep"):
+            b, max_delta = one_sweep(b)
         return b, max_delta, it + 1
 
     def cond(carry):
         _, max_delta, it = carry
         return (it < max_iter) & (max_delta > tol)
 
-    b0 = jnp.zeros((d,), G.dtype)
     b, _, n_iter = jax.lax.while_loop(
         cond, sweep, (b0, jnp.array(jnp.inf, G.dtype), jnp.array(0, jnp.int32))
     )
+    b = b.reshape(-1)[:d]
     b = b / s
     intercept = jnp.where(
         fit_intercept, stats.y_mean - stats.x_mean @ b, jnp.zeros((), b.dtype)
@@ -325,6 +347,7 @@ def sweep_solve_elasticnet_cd(
                 normalize=normalize,
                 max_iter=max_iter,
                 tol=tol,
+                mesh=mesh,
             ),
             (alphas, l1_ratios),
         )

@@ -330,3 +330,26 @@ def test_low_precision_features_keep_float32_labels():
     np.testing.assert_array_equal(
         np.asarray(inputs2.y)[: inputs2.n_rows], labels_b
     )
+
+
+def test_params_are_found_once_a_class_and_by_name():
+    """Params.params walks the MRO once a class (a fit resolves a hundred
+    params by name); the list is sorted by name, a caller's own copy, and a
+    subclass's further params are its own."""
+    from spark_rapids_ml_tpu import params as params_mod
+
+    class _More(_DummyParams):
+        delta = Param(_dummy(), "delta", "a subclass's own", TypeConverters.toInt)
+
+    base, more = _DummyParams(), _More()
+    names = [p.name for p in base.params]
+    assert names == sorted(names) and {"alpha", "beta", "gamma", "featuresCol", "featuresCols"} <= set(names)
+    assert [p.name for p in more.params] == sorted(names + ["delta"])
+    assert not base.hasParam("delta") and more.hasParam("delta")
+    assert base.getParam("alpha") is _DummyParams.alpha is more.getParam("alpha")
+    with pytest.raises(AttributeError, match="no param 'delta'"):
+        base.getParam("delta")
+    base.params.clear()      # the caller's copy, not the class's index
+    assert [p.name for p in base.params] == names
+    index = params_mod._PARAMS_BY_CLASS[_DummyParams]
+    assert _DummyParams().getParam("beta") is index["beta"] and params_mod._PARAMS_BY_CLASS[_DummyParams] is index

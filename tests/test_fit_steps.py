@@ -14,9 +14,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from spark_rapids_ml_tpu import KMeans, LogisticRegression, profiling
+from spark_rapids_ml_tpu import KMeans, LinearRegression, LogisticRegression, profiling
 from spark_rapids_ml_tpu.dataframe import DataFrame
-from spark_rapids_ml_tpu.ops import lbfgs
+from spark_rapids_ml_tpu.ops import glm, lbfgs
 from spark_rapids_ml_tpu.ops.kmeans import lloyd_iterations
 from spark_rapids_ml_tpu.ops.logistic import logistic_fit_kernel
 from spark_rapids_ml_tpu.parallel.mesh import get_mesh
@@ -41,7 +41,11 @@ def _case(family):
     if family == "kmeans":
         est = KMeans(k=K, maxIter=5, tol=0.0, initMode="random", seed=3, num_workers=2)
         return est, (lambda: DataFrame.from_numpy(X, num_partitions=2)), X, None
-    est = LogisticRegression(maxIter=8, tol=1e-30, regParam=1e-3, num_workers=2)
+    if family == "logreg":
+        est = LogisticRegression(maxIter=8, tol=1e-30, regParam=1e-3, num_workers=2)
+    else:       # linreg: the closed form; linreg_cd: coordinate descent
+        l1 = 0.5 if family == "linreg_cd" else 0.0
+        est = LinearRegression(maxIter=6, tol=1e-30, regParam=1e-3, elasticNetParam=l1, num_workers=2)
     return est, (lambda: DataFrame.from_numpy(X, y=y, num_partitions=2)), X, y
 
 
@@ -56,7 +60,10 @@ def _traced_fit(est, df):
     return t0, t1, mine
 
 
-@pytest.mark.parametrize("family", ["kmeans", "logreg"])
+FAMILIES = ["kmeans", "logreg", "linreg", "linreg_cd"]
+
+
+@pytest.mark.parametrize("family", FAMILIES)
 def test_step_spans_tile_the_public_fit(family):
     est, frame, _X, _y = _case(family)
     est.fit(frame())                    # compiles; the tiling is of a warm fit
@@ -87,7 +94,7 @@ def test_step_spans_tile_the_public_fit(family):
     assert puts and all(r[6] == top[1][5] and r[7]["bytes"] > 0 for r in puts)
 
 
-@pytest.mark.parametrize("family", ["kmeans", "logreg"])
+@pytest.mark.parametrize("family", FAMILIES)
 def test_h2d_and_d2h_bytes_equal_the_arrays_nbytes(family):
     est, frame, X, y = _case(family)
     model = est.fit(frame())
@@ -96,11 +103,53 @@ def test_h2d_and_d2h_bytes_equal_the_arrays_nbytes(family):
     assert moved["ingest.h2d_bytes"] == X.nbytes + vectors * N * 4
     if family == "kmeans":
         fetched = K * D * 4 + 4 + 4          # centres, n_iter, inertia
-    else:
+    elif family == "logreg":
         fetched = D * 4 + 4 + 4 + 1 + 4      # W, b, n_iter, converged, n_evals
         assert moved["lbfgs.fits"] == 1 and moved["lbfgs.iters"] == model.num_iters
         assert moved["lbfgs.evals"] >= model.num_iters + 1
+    else:
+        fetched = D * 4 + D * 4 + 4          # coefficients, column means, label mean
+        assert moved["linreg.fits"] == 1
+        if family == "linreg_cd":
+            fetched += 4                     # the sweeps
+            assert model.num_iters == 6 and moved["cd.fits"] == 1
+            assert moved["cd.sweeps"] == 6 and moved["cd.coordinates"] == 6 * D
+        else:
+            assert model.num_iters is None and "cd.fits" not in moved
     assert moved["fit.d2h_bytes"] == fetched
+
+
+def test_every_map_of_a_fit_multiple_is_solved_before_the_one_wait():
+    """One statistics pass, one wait, one fetch for all maps; the counters add up over them."""
+    X, y = _table()
+    est = LinearRegression(maxIter=6, tol=1e-30, regParam=1e-3, elasticNetParam=0.5, num_workers=2)
+    maps = [{est.regParam: 1e-3}, {est.regParam: 1e-2}, {est.elasticNetParam: 0.0}]
+    df = DataFrame.from_numpy(X, y=y, num_partitions=2)
+    me = threading.get_ident()
+    with profiling.collect_spans():
+        models = [m for _, m in est.fitMultiple(df, maps)]
+        mine = [r[0] for r in profiling.span_records() if r[3] == me]
+    assert [mine.count(s) for s in STEPS] == [1] * len(STEPS)
+    assert sorted(m.num_iters is None for m in models) == [False, False, True]
+    moved = models[0].fit_telemetry().counters
+    assert moved["linreg.fits"] == 3 and moved["cd.fits"] == 2 and moved["cd.coordinates"] == 2 * 6 * D
+    assert moved["fit.d2h_bytes"] == 3 * D * 4 + 2 * 4 + D * 4 + 4
+
+
+@pytest.mark.parametrize("l1,workers", [(0.5, 1), (0.0, 1), (0.5, 2)])
+def test_a_repeated_linreg_fit_of_a_device_frame_uploads_nothing(l1, workers):
+    """The second fit of a device-resident frame makes no host-to-device copy:
+    the frame keeps its labels and weights on the device, and a hyperparameter
+    goes to the solver as a scalar that already lies there (a Python float
+    would go up once a call, behind the statistics pass)."""
+    X, y = _table()
+    est = LinearRegression(maxIter=6, tol=1e-30, regParam=1e-3, elasticNetParam=l1, num_workers=workers)
+    frame = DataFrame.from_device(jnp.asarray(X), y=y, n_rows=N)
+    first = est.fit(frame)
+    with jax.transfer_guard_host_to_device("disallow"):
+        again = est.fit(frame)
+    assert np.array_equal(first.coef_, again.coef_) and first.intercept_ == again.intercept_
+    assert again.fit_telemetry().counters.get("ingest.h2d_bytes", 0) == 0
 
 
 # -- LbfgsResult.n_evals -------------------------------------------------------
@@ -283,3 +332,9 @@ def test_lowered_solvers_carry_the_scope_names():
     text = logistic.as_text(debug_info=True)
     for scope in ("lbfgs.eval", "lbfgs.direction"):
         assert scope in text, scope
+    y = jnp.ones((64,), jnp.float32)
+    gram = glm.linreg_sufficient_stats.lower(X, y, w, mesh=get_mesh(2))
+    assert "linreg.gram" in gram.as_text(debug_info=True)
+    stats = jax.eval_shape(lambda: glm.linreg_sufficient_stats(X, y, w, mesh=get_mesh(2)))
+    solve = glm.solve_elasticnet_cd.lower(stats, 1e-3, 0.5, max_iter=3, tol=1e-30)
+    assert "cd.sweep" in solve.as_text(debug_info=True)
