@@ -48,6 +48,7 @@ SIZES = {
     "md_n": 131_072, "md_d": 32, "md_k": 16_384,
     "rf_rows": 65_536, "rf_trees": 4, "rf_depth": 6, "rf_bins": 128,
     "reg_features": 1024,
+    "gather_rows": 401_408, "gather_subsets": (54, 1000), "gather_odd_rows": 197 * 2048,
     "knn_items": 65_536, "knn_queries": 8192, "knn_k": 200, "knn_ref": 1024,
     "pq_rows": 262_144, "pq_d": 256, "pq_m": 32, "pq_queries": 1024,
     "pq_k": 10,
@@ -62,6 +63,7 @@ TOY_SIZES = {
     "md_n": 2048, "md_d": 32, "md_k": 1024,
     "rf_rows": 4096, "rf_trees": 4, "rf_depth": 4, "rf_bins": 128,
     "reg_features": 32,
+    "gather_rows": 4096, "gather_subsets": (5, 40), "gather_odd_rows": 3 * 2048,
     "knn_items": 4096, "knn_queries": 256, "knn_k": 10, "knn_ref": 128,
     "pq_rows": 8192, "pq_d": 32, "pq_m": 8, "pq_queries": 128,
     "pq_k": 10,
@@ -665,6 +667,95 @@ def kernel_forest_reg(seed):
     return {"shape": [f_rows, n, bins], "gap": gap, "totals_gap": tot_gap, "ms": ms}
 
 
+def kernel_forest_gather(seed):
+    """The forest's subset gather (ops/forest_hist.gather_rows_matmul: a copy
+    of the rows it selects out of the binned table laid out a feature a slice,
+    tile_feature_rows) at the benchmark's two shapes, a classifier's 54 and a
+    regressor's 1000 of 3000 columns x 401,408 rows, and on a table of an odd
+    number of 2048-row tiles (the last block of the copy partial), against
+    numpy to the byte with the padding rows zero.  Timed beside it, and held
+    to the same bytes: the forms it was chosen over (PERF.md section 6,
+    PR 33): `jnp.take` on the leading axis with XLA's copy back to
+    row-interleaved tiles, and the one-hot selection product over the whole
+    table that the function was named for, in bfloat16 as it ran and in int8."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.ops import forest_hist
+
+    d = S["cols"]
+    interpret = REHEARSAL and jax.default_backend() != "tpu"
+    rng = np.random.default_rng(seed + 33)
+
+    # the forms take their arrays as arguments: a table closed over would be
+    # a constant of gigabytes in the lowered module
+    @partial(jax.jit, static_argnames=("f_pad", "n"))
+    def take(table, feats, f_pad, n):
+        rows = jnp.take(table, feats, axis=0).reshape(feats.shape[0], -1)[:, :n]
+        return jnp.pad(rows, ((0, f_pad - feats.shape[0]), (0, 0)))
+
+    @partial(jax.jit, static_argnames=("f_pad", "n", "dtype", "acc", "chunk"))
+    def product(bins, feats, f_pad, n, dtype, acc, chunk=2048):
+        sel = (feats[:, None] == jnp.arange(bins.shape[0])[None, :]).astype(dtype)
+        sel = jnp.pad(sel, ((0, f_pad - feats.shape[0]), (0, 0)))
+
+        def body(_, i):
+            blk = jax.lax.dynamic_slice_in_dim(bins, i * chunk, chunk, axis=1)
+            return 0, jnp.dot(sel, blk.astype(dtype), preferred_element_type=acc).astype(jnp.int8)
+
+        cols = jax.lax.scan(body, 0, jnp.arange(n // chunk))[1]
+        return jnp.moveaxis(cols, 0, 1).reshape(f_pad, n)
+
+    out = {}
+    for n, subsets in ((S["gather_rows"], S["gather_subsets"]), (S["gather_odd_rows"], S["gather_subsets"][:1])):
+        bins_h = rng.integers(0, S["rf_bins"], (d, n), dtype=np.int8)
+        bins = jax.device_put(bins_h)
+        jax.block_until_ready(forest_hist.tile_feature_rows(bins))    # compiles
+        t0 = time.perf_counter()
+        table = jax.block_until_ready(forest_hist.tile_feature_rows(bins))
+        tile_ms = 1e3 * (time.perf_counter() - t0)
+        for F in subsets:
+            f_pad = -(-F // forest_hist._F_BLOCK) * forest_hist._F_BLOCK
+            feats_h = rng.choice(d, F, replace=False).astype(np.int32)
+            feats = jax.device_put(feats_h)
+            want = np.zeros((f_pad, n), np.int8)
+            want[:F] = bins_h[feats_h]
+            forms = {
+                "copy": lambda: forest_hist.gather_rows_matmul(table, feats, f_pad=f_pad, n_pad=n, interpret=interpret),
+                "take": lambda: take(table, feats, f_pad=f_pad, n=n),
+                "product_bf16": lambda: product(bins, feats, f_pad=f_pad, n=n, dtype=jnp.bfloat16, acc=jnp.float32),
+                "product_int8": lambda: product(bins, feats, f_pad=f_pad, n=n, dtype=jnp.int8, acc=jnp.int32),
+            }
+            ms = {}
+            for name, fn in forms.items():
+                got = jax.block_until_ready(fn())
+                check(
+                    got.shape == want.shape and got.dtype == jnp.int8 and np.array_equal(np.asarray(got), want),
+                    f"gather {F} of {d} x {n}: the {name} form is not bins[feats] with zero padding rows",
+                )
+                del got
+                best = float("inf")
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready([fn() for _ in range(3)])
+                    best = min(best, 1e3 * (time.perf_counter() - t0) / 3)
+                ms[name] = best
+            moved = 2 * f_pad * n
+            log(
+                f"forest_gather {F} of {d} x {n} ({f_pad} rows written): the row copy {ms['copy']:.3f} ms "
+                f"({moved / ms['copy'] / 1e6:.0f} GB/s read and written), take and XLA's relayout {ms['take']:.3f}, "
+                f"the selection product in bfloat16 {ms['product_bf16']:.3f} and in int8 {ms['product_int8']:.3f}; "
+                f"the table's relayout, once a fit, {tile_ms:.2f} ms"
+            )
+            out[f"{F}_of_{d}x{n}"] = {"rows_written": f_pad, "ms": ms, "tile_ms": tile_ms, "equal": True}
+            del want
+        del bins, table, bins_h
+        release()
+    return out
+
+
 def knn_reference(items, queries, k):
     """Plain jax.numpy k nearest: full distance matrix + top_k."""
     import jax
@@ -937,6 +1028,8 @@ def stage_kernels(seed):
     out["forest"] = kernel_forest(seed)
     release()
     out["forest_reg"] = kernel_forest_reg(seed)
+    release()
+    out["forest_gather"] = kernel_forest_gather(seed)
     release()
     out["knn"] = kernel_knn(seed)
     release()

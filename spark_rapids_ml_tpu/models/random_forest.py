@@ -250,7 +250,8 @@ def _mxu_eligible(inputs, n_bins, max_features, max_depth, s_split) -> bool:
 
 def _grow_mxu_device(
     inputs,
-    bins_fm,        # (D, n_pad) int8 feature-major (bin_features_feature_major)
+    bins_rows,      # (D, tiles, 32, 128) int8: bin_features_feature_major's
+                    # (D, n_pad) table, a feature a slice (tile_feature_rows)
     stats,
     n_trees,
     bootstrap,
@@ -272,7 +273,7 @@ def _grow_mxu_device(
     depth-13 benchmark fit over HBM."""
     from ..ops import forest_mxu
 
-    n_pad = bins_fm.shape[1]
+    n_pad = _fm_rows(inputs)
 
     @partial(jax.jit, static_argnames=("n_pad",))
     def _layout(stats, weight, n_pad):
@@ -297,7 +298,7 @@ def _grow_mxu_device(
     else:
         w_trees = jnp.broadcast_to(w_pad[None, :], (n_trees, n_pad))
     return forest_mxu.grow_forest_mxu_device(
-        bins_fm, base_stats, w_trees, stats3,
+        bins_rows, base_stats, w_trees, stats3,
         max_depth=max_depth, n_bins=n_bins, kind=kind,
         max_features=int(max_features),
         min_samples_leaf=min_samples_leaf,
@@ -306,6 +307,14 @@ def _grow_mxu_device(
         # the kernels run through the interpreter anywhere but on the chip
         interpret=jax.default_backend() != "tpu",
     )
+
+
+def _fm_rows(inputs) -> int:
+    """Rows of the MXU builder's feature-major arrays: the frame's, up to
+    whole histogram row tiles."""
+    from ..ops.forest_hist import _ROW_TILE
+
+    return -(-inputs.X.shape[0] // _ROW_TILE) * _ROW_TILE
 
 
 def _bootstrap_draw(seed: int, n_trees: int, n_rows: int, tree_chunk: int) -> np.ndarray:
@@ -569,12 +578,14 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                     profiling.incr_counter("forest.reg_fits")
                 profiling.incr_counter("forest.subset_features", max_features)
                 if mxu:
-                    bins_fm = get_bins("fm", edges)
-                    n_pad_fm = bins_fm.shape[1]
+                    # the one-chip builder's subsets are copies of the rows
+                    # they select (forest_hist.gather_rows_matmul)
+                    profiling.incr_counter("forest.gather_copy_fits")
+                    bins_rows = get_bins("fm", edges)
             if mxu:
                 with profiling.span("srml.fit.solve"):
                     buf, plan = _grow_mxu_device(
-                        inputs, bins_fm, stats, n_trees, bootstrap, seed,
+                        inputs, bins_rows, stats, n_trees, bootstrap, seed,
                         is_classification, **grow_kwargs,
                     )
                 # the edges go with the forest: one read of the device a fit
@@ -583,14 +594,14 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                 with profiling.span("srml.fit.pack"):
                     from ..ops.forest_mxu import pack_forest
 
-                    del buf, bins_fm
+                    del buf, bins_rows
                     forest = pack_forest(buf_h, plan, edges_h)
                     logger.info(
                         "grew %d trees on the MXU histogram path (depth<=%d, "
                         "bins=%d)", n_trees, max_depth, n_bins,
                     )
                     draw = (
-                        _bootstrap_draw(seed, n_trees, n_pad_fm, 0)
+                        _bootstrap_draw(seed, n_trees, _fm_rows(inputs), 0)
                         if bootstrap else None
                     )
                     return _attrs(forest, inputs, max_depth, extra_attrs, draw)
@@ -682,8 +693,10 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                     edges = edges_for(n_bins)
 
             # Lazy per-route binning: the MXU route bins straight into the
-            # feature-major int8 layout (bin_features_feature_major), the
-            # scatter route row-major.  Binning eagerly row-major and
+            # feature-major int8 layout (bin_features_feature_major), a
+            # feature a slice of whole tiles (the subset gather's layout,
+            # forest_hist.tile_feature_rows), the scatter route row-major.
+            # Binning eagerly row-major and
             # re-laying-out kept TWO full bin matrices resident — the copy
             # that OOM'd the 400k x 3000 depth-13 benchmark fit.  The cache
             # holds the edges OBJECT alongside each entry (id() alone can
@@ -700,12 +713,9 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                     bins_cache.clear()  # new edges: old matrices are dead
                 with profiling.span("forest.bin"):
                     if layout == "fm":
-                        from ..ops.forest_hist import _ROW_TILE
-
-                        n = inputs.X.shape[0]
-                        n_pad = -(-n // _ROW_TILE) * _ROW_TILE
                         out = bin_features_feature_major(
-                            inputs.X, jnp.asarray(e), n_pad=n_pad
+                            inputs.X, jnp.asarray(e), n_pad=_fm_rows(inputs),
+                            tiled=True,
                         )
                     else:
                         out = bin_features(inputs.X, jnp.asarray(e))

@@ -219,21 +219,30 @@ def _bin_chunk_t(X_chunk: jax.Array, edges: jax.Array) -> jax.Array:
 
 def bin_features_feature_major(
     X: jax.Array, edges: jax.Array, chunk: int = 65536,
-    n_pad: Optional[int] = None,
+    n_pad: Optional[int] = None, tiled: bool = False,
 ) -> jax.Array:
     """(N, D) f32 -> (D, n_pad) int8 binned, row-chunked so peak temp memory
     is one (chunk, D) tile instead of a full int32 (N, D) copy (which OOMs
     at the 3000-column benchmark shape).  Requires n_bins <= 128 (int8).
     Trailing columns up to `n_pad` are zero bins (callers mask padded rows
-    through weights)."""
+    through weights).  With `tiled` the table comes as the MXU builder's
+    subset gather reads it, a feature a slice of whole tiles
+    (forest_hist.tile_feature_rows); the fused route lays it out in the
+    binning executable itself, so the table is held once."""
     n = X.shape[0]
     if bin_route(X, edges.shape[1]) == "pallas":
         from .pallas_tpu import bin_features_fm_pallas
 
         return bin_features_fm_pallas(
-            jnp.asarray(X), jnp.asarray(edges), n_pad if n_pad else n
+            jnp.asarray(X), jnp.asarray(edges), n_pad if n_pad else n,
+            tiled=tiled,
         )
-    return _bin_features_fm_xla(X, edges, chunk, n_pad)
+    out = _bin_features_fm_xla(X, edges, chunk, n_pad)
+    if tiled:
+        from .forest_hist import tile_feature_rows
+
+        out = tile_feature_rows(out)
+    return out
 
 
 def bin_route(X, n_edges: int) -> str:

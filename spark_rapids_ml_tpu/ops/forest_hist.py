@@ -16,10 +16,20 @@
 # built on the fly in VMEM from the binned features, node ids and stats, so
 # no one-hot ever touches HBM.
 #
-# Random feature subsets are materialized by `gather_rows_matmul`: XLA's
-# gather scalarizes on this backend (~30M elem/s measured), while a one-hot
-# selection matrix against the feature-major bin matrix is a single MXU
-# contraction (exact: bin values < 2^8 are representable in bfloat16).
+# Random feature subsets are materialized by `gather_rows_matmul`, which
+# copies the rows it selects and reads nothing else.  XLA keeps the (D, N)
+# int8 bin matrix in tiles of 8 features x 128 rows, four features' bytes to
+# a word, so no feature's row is a run of bytes: `jnp.take` on it scalarizes
+# (4.4 ms for 54 rows of 3000 x 401,408 on a v5e), and the one-hot selection
+# product this function was named for read the whole matrix a gather (3.4 ms
+# at 54 rows, 17.7 ms at 1000).  `tile_feature_rows` lays the matrix out once
+# a fit as (D, N / 4096, 32, 128), a feature a run of bytes (4.0 ms), and the
+# gather is one Pallas kernel: 32 selected rows a block by scalar prefetch,
+# laid in VMEM into the (32, rows) block the histogram kernels read (0.23 ms
+# at 54 rows, 1.45 ms at 1000: 570 GB/s of reads and writes).  Measured
+# beside it (PERF.md section 6, PR 33): `jnp.take` on the leading axis and
+# XLA's copy back to row-interleaved tiles 0.52 / 5.5 ms, the product with
+# int8 operands 2.5 / 10.1 ms.
 #
 # Slot packing doubles as shallow-level tree batching: at level l a tree
 # needs 2^l * S slots, so 128 // (2^l * S) lock-step trees share one scan
@@ -58,32 +68,107 @@ _ROW_TILE = 2048
 _F_BLOCK = 32
 
 
-@partial(jax.jit, static_argnames=("f_pad", "chunk"))
+# rows of one feature in one (32, 128) int8 tile, and the most such tiles of a
+# feature a gather block copies: 32 features x 16 tiles x 4 KB in and as much
+# out, each buffered twice, is 8 MB of VMEM
+_TILE_ROWS = 4096
+_GATHER_TILES = 16
+
+
+def _gather_blocks(n_pad: int):
+    """(tiles of a feature in a gather block, blocks along the rows): the
+    fewest blocks of at most _GATHER_TILES tiles, evened out."""
+    tiles = -(-n_pad // _TILE_ROWS)
+    blocks = -(-tiles // _GATHER_TILES)
+    return -(-tiles // blocks), blocks
+
+
+@jax.jit
+def tile_feature_rows(bins_fm: jax.Array) -> jax.Array:
+    """The (D, n_pad) int8 bin matrix as (D, tiles, 32, 128): a feature's
+    rows in whole tiles of its own, 4096 consecutive rows each, so that a
+    feature is a slice of the leading axis and a run of bytes.  Rows past
+    n_pad (up to whole gather blocks) are zero bins."""
+    D, n_pad = bins_fm.shape
+    per, blocks = _gather_blocks(n_pad)
+    rows = per * blocks * _TILE_ROWS
+    out = jnp.pad(bins_fm, ((0, 0), (0, rows - n_pad)))
+    return out.reshape(D, per * blocks, 32, 128)
+
+
+def _gather_kernel(feats_ref, *refs, tiles: int, rows: int):
+    """refs: _F_BLOCK blocks (tiles, 32, 128) of the tiled bin matrix, one a
+    selected feature (the index maps read feats_ref), then the output block
+    (_F_BLOCK, tiles * 4096): feature j's tiles side by side in row j."""
+    del feats_ref  # read by the index maps only
+    in_refs, out_ref = refs[:-1], refs[-1]
+    live = None
+    if rows % _F_BLOCK:  # the last block's rows past the subset are zero
+        at = pl.program_id(0) * _F_BLOCK + jax.lax.broadcasted_iota(
+            jnp.int32, (_F_BLOCK, 128), 0
+        )
+        live = at < rows
+
+    def tile(q, carry):
+        # (feature, sublane, lane) -> (sublane, feature, lane): sublane s of
+        # every feature's tile is the output's tile s.  As int32, the width
+        # at which Mosaic swaps a major axis with the sublanes
+        x = jnp.stack([r[q].astype(jnp.int32) for r in in_refs])
+        y = jnp.swapaxes(x, 0, 1)
+        for s in range(32):
+            rows_s = y[s] if live is None else jnp.where(live, y[s], 0)
+            at = pl.multiple_of(q * _TILE_ROWS + s * 128, 128)
+            out_ref[:, pl.ds(at, 128)] = rows_s.astype(jnp.int8)
+        return carry
+
+    # a loop, not `tiles` copies of its body: a process traces and lowers the
+    # kernel again at every start, and the copies cost it a second
+    jax.lax.fori_loop(0, tiles, tile, 0)
+
+
+@partial(jax.jit, static_argnames=("f_pad", "n_pad", "interpret"))
 def gather_rows_matmul(
-    bins_fm: jax.Array, feats: jax.Array, f_pad: int, chunk: int = 65536
+    bins_rows: jax.Array,  # (D, tiles, 32, 128) int8 (tile_feature_rows)
+    feats: jax.Array,      # (F,) int32 rows to select
+    f_pad: int,
+    n_pad: int,
+    interpret: bool = False,
 ) -> jax.Array:
-    """Select rows `feats` of the (D, N) int8 bin matrix as (f_pad, N) int8
-    via OneHot(feats) @ bins — MXU-fast where XLA's row gather scalarizes.
-    Exact: both operands hold small whole numbers (a 0/1 selection, bin
-    indices below 2^8), exactly representable in bf16."""
-    D, N = bins_fm.shape
-    n_chunks = N // chunk
-    assert n_chunks * chunk == N, "pad N to the gather chunk"
+    """Rows `feats` of the bin matrix as (f_pad, n_pad) int8, rows F and up
+    zero: a copy of the rows selected (the header; no product since PR 33,
+    the name is what the benchmark's configuration finds the module by)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    per, blocks = _gather_blocks(n_pad)
+    assert bins_rows.shape[1:] == (per * blocks, 32, 128), (
+        "bins_rows is not tile_feature_rows' of n_pad rows"
+    )
+    rows = feats.shape[0]
+    assert f_pad % _F_BLOCK == 0 and rows <= f_pad and n_pad % _ROW_TILE == 0
+
+    def pick(j):
+        return lambda f, c, at: (at[f * _F_BLOCK + j], c, 0, 0)
+
     with jax.named_scope("forest.gather"):
-        sel = (
-            feats[:, None] == jnp.arange(D, dtype=feats.dtype)[None, :]
-        ).astype(jnp.bfloat16)
-        sel = jnp.pad(sel, ((0, f_pad - feats.shape[0]), (0, 0)))
-
-        def body(_, i):
-            blk = jax.lax.dynamic_slice_in_dim(bins_fm, i * chunk, chunk, axis=1)
-            out = jnp.dot(
-                sel, blk.astype(jnp.bfloat16), preferred_element_type=jnp.float32
-            )
-            return 0, out.astype(jnp.int8)
-
-        _, cols = jax.lax.scan(body, 0, jnp.arange(n_chunks, dtype=jnp.int32))
-        return jnp.moveaxis(cols, 0, 1).reshape(f_pad, N)
+        # the rows past the subset copy feature 0 and the kernel zeroes them
+        at = jnp.pad(feats.astype(jnp.int32), (0, f_pad - rows))
+        return pl.pallas_call(
+            partial(_gather_kernel, tiles=per, rows=rows),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(f_pad // _F_BLOCK, blocks),
+                in_specs=[
+                    pl.BlockSpec((None, per, 32, 128), pick(j))
+                    for j in range(_F_BLOCK)
+                ],
+                out_specs=pl.BlockSpec(
+                    (_F_BLOCK, per * _TILE_ROWS), lambda f, c, at: (f, c)
+                ),
+            ),
+            out_shape=jax.ShapeDtypeStruct((f_pad, n_pad), jnp.int8),
+            interpret=interpret,
+            name="forest_gather",
+        )(at, *([bins_rows] * _F_BLOCK))
 
 
 # MXU products a label's stat row takes in a histogram: its three bfloat16

@@ -98,6 +98,7 @@ from .forest_hist import (
     label_stat_rows,
     node_histograms,
     node_histograms_segmented,
+    tile_feature_rows,
 )
 from .precompile import aval, global_precompiler
 
@@ -340,8 +341,12 @@ def _route(
 def _pack_rows(sub: jax.Array, f_pad: int) -> jax.Array:
     """(f_pad, N) int8 -> (f_pad//4, N) int32, 4 bin bytes per word, so the
     deep-phase payload sort moves 4 features per operand."""
-    v = sub.astype(jnp.int32).reshape(f_pad // 4, 4, -1)
-    return v[:, 0] | (v[:, 1] << 8) | (v[:, 2] << 16) | (v[:, 3] << 24)
+    # a byte row is widened as it is read: widening `sub` whole first makes
+    # XLA write the int32 form of every row (4.5 GB for a classifier's 50
+    # subsets of 56 rows) before it packs them
+    v = sub.reshape(f_pad // 4, 4, -1)
+    b = [v[:, k].astype(jnp.int32) for k in range(4)]
+    return b[0] | (b[1] << 8) | (b[2] << 16) | (b[3] << 24)
 
 
 
@@ -479,19 +484,29 @@ def _shallow_leaf(
     return _record(buf, _leaf_channels(tot, kind), s0, g0, nodes - 1)
 
 
-@partial(jax.jit, static_argnames=("f_pad", "P", "chunk"))
+@partial(jax.jit, static_argnames=("n_pad", "P", "interpret"))
 def _pack_all(
-    bins_fm: jax.Array, feats_all: jax.Array, f_pad: int, P: int, chunk: int
+    bins_rows: jax.Array, feats_all: jax.Array, n_pad: int, P: int,
+    interpret: bool,
 ) -> jax.Array:
     """(T, P, n_pad) int32 packed per-tree deep-subset rows (4 bins/word).
     Only ceil(F/4) words are packed — feature PADDING rows never ride the
-    payload sort; _deep_state re-pads to f_pad after the unpack."""
+    payload sort; _deep_state re-pads to f_pad after the unpack.  The T
+    subsets are ONE gather of T * 4P rows."""
+    T, F = feats_all.shape
+    # a tree's rows past its subset copy feature 0: zeroed below
+    at = jnp.pad(feats_all, ((0, 0), (0, 4 * P - F))).reshape(-1)
+    sub = gather_rows_matmul(
+        bins_rows, at, f_pad=_pack_rows_pad(T, P), n_pad=n_pad,
+        interpret=interpret,
+    )[: T * 4 * P].reshape(T, 4 * P, n_pad)
+    sub = jnp.where((jnp.arange(4 * P) < F)[None, :, None], sub, 0)
+    return jax.vmap(partial(_pack_rows, f_pad=4 * P))(sub)
 
-    def one(feats):
-        sub = gather_rows_matmul(bins_fm, feats, f_pad=f_pad, chunk=chunk)
-        return _pack_rows(sub[: 4 * P], 4 * P)
 
-    return jax.vmap(one)(feats_all)
+def _pack_rows_pad(T: int, P: int) -> int:
+    """Rows _pack_all's one gather writes: T * 4P up to whole feature blocks."""
+    return -(-T * 4 * P // _F_BLOCK) * _F_BLOCK
 
 
 @partial(jax.jit, static_argnames=("n_buckets", "n2"))
@@ -796,7 +811,8 @@ class _Dispatcher:
 
 
 def grow_forest_mxu_device(
-    bins_fm: jax.Array,     # (D, N_pad) int8 feature-major binned features
+    bins_rows: jax.Array,   # (D, tiles, 32, 128) int8 binned features, a feature
+                            # a slice (forest_hist.tile_feature_rows of (D, N_pad))
     base_stats: jax.Array,  # (S, N_pad) f32 unweighted stat rows (see below)
     w_trees: jax.Array,     # (T, N_pad) f32 per-tree bootstrap*mask weights
     stats3: jax.Array,      # (3, N_pad) f32 (1, y, y^2)*mask rows (reg) or None
@@ -823,7 +839,7 @@ def grow_forest_mxu_device(
     the segment sort.  n_rows (the frame's rows, without padding) only feeds
     the forest.hist_rows_needed counter."""
     T, n_pad = w_trees.shape
-    D = bins_fm.shape[0]
+    D = bins_rows.shape[0]
     S = base_stats.shape[0]
     V = 1 if kind == "regression" else S
     assert n_pad % _ROW_TILE == 0
@@ -848,7 +864,7 @@ def grow_forest_mxu_device(
     f32, i32, i8 = jnp.float32, jnp.int32, jnp.int8
     a_rel, a_buf = aval((T, n_pad), i32), aval((C, T, M), f32)
     a_w, a_t0 = aval((T, n_pad), f32), aval((), i32)
-    chunk = 16384 if n_pad % 16384 == 0 else _ROW_TILE
+    a_table = aval(bins_rows.shape, i8)
 
     def tpack_at(level: int) -> int:
         return _even_chunk(T, M_SLOTS // (2**level * S))
@@ -860,10 +876,10 @@ def grow_forest_mxu_device(
         return rng.choice(D, F, replace=False).astype(np.int32)
 
     # --- every geometry of the fit, submitted for parallel compilation ----
-    k_gather = ("gather_rows", D, n_pad, F, f_pad, chunk)
+    k_gather = ("gather_rows", D, n_pad, min(F, D), f_pad, interpret)
     run.submit(
-        k_gather, gather_rows_matmul, aval((D, n_pad), i8), aval((F,), i32),
-        f_pad=f_pad, chunk=chunk,
+        k_gather, gather_rows_matmul, a_table, aval((min(F, D),), i32),
+        f_pad=f_pad, n_pad=n_pad, interpret=interpret,
     )
     shallow_keys = {}
     for level in range(shallow_top + 1):
@@ -900,10 +916,10 @@ def grow_forest_mxu_device(
         a_seg = aval((T, n_tiles), i32)
         k_layout = ("deep_layout", T, n_pad, nb, n2)
         run.submit(k_layout, _deep_layout, a_rel, n_buckets=nb, n2=n2)
-        k_pack = ("pack_all", D, n_pad, T, F, f_pad_d, P, chunk)
+        k_pack = ("pack_all", D, n_pad, T, min(F, D), P, interpret)
         run.submit(
-            k_pack, _pack_all, aval((D, n_pad), i8), aval((T, F), i32),
-            f_pad=f_pad_d, P=P, chunk=chunk,
+            k_pack, _pack_all, a_table, aval((T, min(F, D)), i32),
+            n_pad=n_pad, P=P, interpret=interpret,
         )
         k_sort = {
             name: ("sort_part_" + name, T, n_pad, nb, n2)
@@ -980,8 +996,8 @@ def grow_forest_mxu_device(
                         )
                         continue
                     sub = run.call(
-                        k_gather, gather_rows_matmul, bins_fm, next(subsets),
-                        f_pad=f_pad, chunk=chunk,
+                        k_gather, gather_rows_matmul, bins_rows, next(subsets),
+                        f_pad=f_pad, n_pad=n_pad, interpret=interpret,
                     )
                     rel, buf = run.call(
                         shallow_keys[level], _shallow_step, rel, buf, w_trees,
@@ -1002,8 +1018,8 @@ def grow_forest_mxu_device(
                 k_layout, _deep_layout, rel, n_buckets=nb, n2=n2
             )
             packed = run.call(
-                k_pack, _pack_all, bins_fm, next(subsets),
-                f_pad=f_pad_d, P=P, chunk=chunk,
+                k_pack, _pack_all, bins_rows, next(subsets),
+                n_pad=n_pad, P=P, interpret=interpret,
             )
             sort = lambda name, payload: run.call(
                 k_sort[name], _sort_part, rel, dkeys, payload,
@@ -1048,7 +1064,7 @@ def grow_forest_mxu_device(
     profiling.incr_counter("forest.hist_rows_needed", hist_needed)
     profiling.incr_counter(
         "forest.gather_bytes",
-        gathers * f_pad * n_pad + (T * f_pad_d * n_pad if deep else 0),
+        gathers * f_pad * n_pad + (_pack_rows_pad(T, P) * n_pad if deep else 0),
     )
     if kind == "regression":
         profiling.incr_counter("forest.label_pieces", LABEL_PIECES)
@@ -1096,17 +1112,17 @@ def pack_forest(
 
 
 def grow_forest_mxu(
-    bins_fm: jax.Array,
+    bins_fm: jax.Array,     # (D, N_pad) int8 feature-major binned features
     base_stats: jax.Array,
     w_trees: jax.Array,
     stats3: jax.Array,
     edges: np.ndarray,      # (D, B-1) raw-space bin edges
     **kwargs,
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """grow_forest_mxu_device + a plain fetch + pack_forest: grow_forest's
-    host-array contract in one call (tests; the estimator fetches through
-    core.fetch_fit_result between the two)."""
+    """tile_feature_rows + grow_forest_mxu_device + a plain fetch +
+    pack_forest: grow_forest's host-array contract in one call (tests; the
+    estimator fetches through core.fetch_fit_result between the two)."""
     buf, plan = grow_forest_mxu_device(
-        bins_fm, base_stats, w_trees, stats3, **kwargs
+        tile_feature_rows(bins_fm), base_stats, w_trees, stats3, **kwargs
     )
     return pack_forest(np.asarray(buf), plan, np.asarray(edges))

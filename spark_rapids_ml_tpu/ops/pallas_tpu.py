@@ -237,9 +237,12 @@ def bin_features_fm_pallas(
     edges: jax.Array,      # (D, B-1) f32, B-1 <= 127
     n_pad: int,            # output row padding target (>= N)
     interpret: bool = False,
+    tiled: bool = False,
 ) -> jax.Array:
     """(D, n_pad) int8 feature-major bins — pallas drop-in for
-    ops/forest.bin_features_feature_major on TPU.
+    ops/forest.bin_features_feature_major on TPU; with `tiled`, a feature a
+    slice of whole tiles (forest_hist.tile_feature_rows), laid out by the
+    copy that cuts the kernel's padded output to size: no second table.
 
     Mesh-sharded inputs (NamedSharding, even over ONE device — what
     DataFrame.from_device / core ingest produce) are re-committed to the
@@ -255,15 +258,16 @@ def bin_features_fm_pallas(
     ):
         (dev,) = X.sharding.device_set
         X = jax.device_put(X, dev)
-    return _bin_features_fm_pallas(X, edges, n_pad, interpret)
+    return _bin_features_fm_pallas(X, edges, n_pad, interpret, tiled)
 
 
-@functools.partial(jax.jit, static_argnames=("n_pad", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_pad", "interpret", "tiled"))
 def _bin_features_fm_pallas(
     X: jax.Array,
     edges: jax.Array,
     n_pad: int,
     interpret: bool = False,
+    tiled: bool = False,
 ) -> jax.Array:
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -321,7 +325,12 @@ def _bin_features_fm_pallas(
         interpret=interpret,
         name="forest_bin",
     )(Xp, e_pad)
-    return out[:d, :n_pad]
+    out = out[:d, :n_pad]
+    if tiled:
+        from .forest_hist import tile_feature_rows
+
+        out = tile_feature_rows(out)
+    return out
 
 
 def min_dist_route(

@@ -20,6 +20,7 @@ from spark_rapids_ml_tpu.ops.forest_hist import (
     _ROW_TILE,
     gather_rows_matmul,
     node_histograms,
+    tile_feature_rows,
     node_histograms_reference,
 )
 from spark_rapids_ml_tpu.ops.forest_mxu import (
@@ -32,19 +33,62 @@ from spark_rapids_ml_tpu.ops.forest_mxu import (
 KERNEL_INTERPRET = jax.devices()[0].platform != "tpu"
 
 
-def test_gather_rows_matmul_exact():
+@pytest.mark.parametrize(
+    "D,F,n_pad",
+    [
+        (23, 7, 2 * _ROW_TILE),       # D no multiple of 32, F < 32
+        (70, 54, 3 * _ROW_TILE),      # an odd number of row tiles: half a 4096-row tile is padding
+        (3000, 1000, _ROW_TILE),      # a regressor's third of the columns, 32 whole blocks
+        (40, 40, 2 * _ROW_TILE),      # F >= D: the table in its own order, padded
+        (70, 33, 35 * _ROW_TILE),     # two feature blocks, two row blocks, the last of each partial
+    ],
+    ids=["d23_f7", "odd_tiles", "f1000_of_3000", "every_feature", "partial_blocks"],
+)
+def test_gather_rows_matmul_exact(D, F, n_pad):
+    """The subset is bins[feats] to the byte, its padding rows zero (the name
+    is the benchmark's: since PR 33 the function copies rows, no product)."""
     rng = np.random.default_rng(0)
-    N, D, F = 2 * _ROW_TILE, 23, 7
-    bins = rng.integers(0, 128, (D, N)).astype(np.int8)
-    feats = rng.choice(D, F, replace=False).astype(np.int32)
+    bins = rng.integers(0, 128, (D, n_pad)).astype(np.int8)
+    feats = (
+        np.arange(D, dtype=np.int32) if F >= D
+        else rng.choice(D, F, replace=False).astype(np.int32)
+    )
+    f_pad = -(-F // _F_BLOCK) * _F_BLOCK
+    rows = tile_feature_rows(jnp.asarray(bins))
+    assert rows.shape[0] == D and rows.shape[2:] == (32, 128)
     sub = np.asarray(
         gather_rows_matmul(
-            jnp.asarray(bins), jnp.asarray(feats), f_pad=_F_BLOCK,
-            chunk=_ROW_TILE,
+            rows, jnp.asarray(feats), f_pad=f_pad, n_pad=n_pad,
+            interpret=KERNEL_INTERPRET,
         )
     )
+    assert sub.shape == (f_pad, n_pad) and sub.dtype == np.int8
     np.testing.assert_array_equal(sub[:F], bins[feats])
     np.testing.assert_array_equal(sub[F:], 0)
+
+
+def test_pack_all_is_every_trees_subset_packed():
+    """The deep phase's T subsets ride one gather: word p of tree t holds the
+    bins of its features 4p .. 4p + 3, a byte each, and nothing of the rows
+    past its subset."""
+    from spark_rapids_ml_tpu.ops.forest_mxu import _pack_all
+
+    rng = np.random.default_rng(3)
+    D, F, T, n_pad = 19, 6, 3, 3 * _ROW_TILE
+    bins = rng.integers(0, 128, (D, n_pad)).astype(np.int8)
+    feats = np.stack([rng.choice(D, F, replace=False) for _ in range(T)]).astype(np.int32)
+    packed = np.asarray(
+        _pack_all(
+            tile_feature_rows(jnp.asarray(bins)), jnp.asarray(feats),
+            n_pad=n_pad, P=2, interpret=KERNEL_INTERPRET,
+        )
+    )
+    assert packed.shape == (T, 2, n_pad) and packed.dtype == np.int32
+    want = np.zeros((T, 8, n_pad), np.int64)
+    for t in range(T):
+        want[t, :F] = bins[feats[t]]
+    words = sum(want[:, k::4] << (8 * k) for k in range(4))
+    np.testing.assert_array_equal(packed, words.astype(np.int32))
 
 
 def test_node_histograms_matches_oracle():

@@ -215,3 +215,25 @@ def test_regression_fit_counts_its_pieces_and_its_subsets(monkeypatch):
     # three split levels, one tree group a level: three gathers of a 32-row block over the padded rows
     assert moved["forest.gather_bytes"] == 3 * 32 * 2048
     assert moved["forest.dispatches"] == 3 * 2 + 1
+
+
+@pytest.mark.parametrize("route,estimator", [
+    ("one_chip", RandomForestRegressor), ("one_chip", RandomForestClassifier), ("mesh_engine", RandomForestRegressor),
+], ids=["regressor", "classifier", "mesh_engine"])
+def test_a_fit_on_the_one_chip_builder_counts_its_subsets_as_row_copies(route, estimator, monkeypatch):
+    """forest.gather_copy_fits beside forest.fits, process-wide and in the fit's
+    telemetry: one a fit whose subsets gather_rows_matmul copied out of the tiled
+    table, none for a fit on the mesh engine, which gathers no subset."""
+    from spark_rapids_ml_tpu import profiling
+
+    if route == "one_chip":
+        _mxu_everywhere(monkeypatch)
+    X, y = _table()
+    labels = y if estimator is RandomForestRegressor else (y > jnp.median(y)).astype(jnp.float32)
+    before = profiling.counters("forest.")
+    model = estimator(numTrees=2, maxDepth=3, maxBins=BINS, seed=3, num_workers=1).fit(DataFrame.from_device(X, y=labels))
+    copies = int(route == "one_chip")
+    moved = model.fit_telemetry().counters
+    assert moved["forest.fits"] == 1 and moved.get("forest.gather_copy_fits", 0) == copies
+    process = profiling.counter_deltas(before, "forest.")
+    assert process["forest.fits"] == 1 and process.get("forest.gather_copy_fits", 0) == copies

@@ -290,8 +290,10 @@ def test_mxu_route_wiring_feature_major(monkeypatch):
     would merge green on the CPU suite (round-4 regression: the lazily
     bound feature-major binner raised NameError only on hardware).  Force
     the route (its kernels run through the Pallas interpreter off the
-    chip) and verify _grow_mxu_device receives the (D, n_pad) int8
-    feature-major bins and its result flows into the model."""
+    chip) and verify _grow_mxu_device receives the int8 feature-major bins,
+    a feature a slice of whole (32, 128) tiles (the subset gather's layout:
+    tile_feature_rows of the (D, n_pad) table), and its result flows into
+    the model."""
     import numpy as np
 
     import spark_rapids_ml_tpu.models.random_forest as rfm
@@ -320,7 +322,8 @@ def test_mxu_route_wiring_feature_major(monkeypatch):
         numTrees=3, maxDepth=3, maxBins=8, num_workers=1
     ).fit(DataFrame.from_numpy(X, y))
     n_pad = -(-X.shape[0] // _ROW_TILE) * _ROW_TILE
-    assert seen["shape"] == (7, n_pad) and seen["dtype"] == "int8"
+    assert n_pad == _ROW_TILE     # half a 4096-row tile: the other half is padding
+    assert seen["shape"] == (7, 1, 32, 128) and seen["dtype"] == "int8"
     assert model.getNumTrees == 3 and (model.features_[:, 0] >= 0).all()
     assert model.node_counts_[:, 0].max() <= 2 * 300    # padding rows weigh nothing
     pred = model.transform(DataFrame.from_numpy(X)).toPandas()["prediction"]

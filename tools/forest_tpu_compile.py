@@ -57,14 +57,22 @@ for level in (7, 10, 12):
        t_chunk=tc, level=level, bucket_level=7, s_dim=S, kind="gini", n_bins=B, F=F, msl=1.0, mid=0.0, interpret=False)
 go("deep_leaf", fm._deep_leaf, a_loc, a_st, a_seg, a_buf, level=13, bucket_level=7, kind="gini")
 go("sort_part_i32", fm._sort_part, a_rel, A((T, n2 - n_pad), i32), A((T, n_pad), i32), n_buckets=nb, n2=n2)
-go("pack_all", fm._pack_all, A((D, n_pad), i8), A((T, F), i32), f_pad=f_pad, P=P, chunk=16384)
+from spark_rapids_ml_tpu.ops.forest_hist import _gather_blocks, gather_rows_matmul, tile_feature_rows
+def table(rows):  # the binned table, a feature a slice of whole tiles (tile_feature_rows)
+    per, blocks = _gather_blocks(rows)
+    return A((D, per * blocks, 32, 128), i8)
+go("tile_feature_rows", tile_feature_rows, A((D, n_pad), i8))
+go("gather", gather_rows_matmul, table(n_pad), A((F,), i32), f_pad=f_pad, n_pad=n_pad)
+go("pack_all", fm._pack_all, table(n_pad), A((T, F), i32), n_pad=n_pad, P=P, interpret=False)
+# an odd number of 2048-row tiles (the last gather block partial) and the widest block (16 tiles a feature)
+for rows in (197 * 2048, 3 * 2048, 32 * 4096):
+    go(f"gather_rows{rows}", gather_rows_matmul, table(rows), A((F,), i32), f_pad=f_pad, n_pad=rows)
 
 # rf_reg_fit: a regressor's steps (two products a feature, 1024 subset rows) and its gather
-from spark_rapids_ml_tpu.ops.forest_hist import gather_rows_matmul
 T, F, depth, f_pad = 30, 1000, 6, 1024
 M = 2 ** (depth + 1) - 1; C = 6
 a_rel, a_buf, a_w = A((T, n_pad), i32), A((C, T, M), f32), A((T, n_pad), f32)
-go("reg_gather", gather_rows_matmul, A((D, n_pad), i8), A((F,), i32), f_pad=f_pad, chunk=2048)
+go("reg_gather", gather_rows_matmul, table(n_pad), A((F,), i32), f_pad=f_pad, n_pad=n_pad)
 for level in (0, 2, 5):
     nodes = 2 ** level; tpack = fm._even_chunk(T, 128 // (nodes * 2))
     go(f"reg_shallow_step_l{level}", fm._shallow_step, a_rel, a_buf, a_w, A((3, n_pad), f32), A((f_pad, n_pad), i8), a_t0,
