@@ -4,6 +4,7 @@
 # /root/reference), rebuilt on jax/XLA/pjit: estimators dispatch to jax.jit'd
 # solvers sharded over a device Mesh instead of cuML MG kernels over NCCL.
 #
+from . import profiling
 from .version import __version__
 
 __all__ = [
@@ -34,9 +35,30 @@ __all__ = [
 ]
 
 
-def __getattr__(name):  # lazy re-exports keep `import spark_rapids_ml_tpu` light
+# import.us: microseconds this process spent importing the package through
+# its public names — the package's own import below (from profiling's first
+# line on) and every lazy re-export's module, which is where the seconds
+# are.  A module imported by its own path is not in it.
+_importing = 0
+
+
+def _timed_import(location):
     from importlib import import_module
 
+    global _importing
+    t0 = profiling.now()
+    _importing += 1
+    try:
+        return import_module(location, __name__)
+    finally:
+        _importing -= 1
+        if not _importing:  # a re-export that imports another counts once
+            profiling.incr_counter(
+                "import.us", int(round(1e6 * (profiling.now() - t0)))
+            )
+
+
+def __getattr__(name):  # lazy re-exports keep `import spark_rapids_ml_tpu` light
     _locations = {
         "KMeans": ".models.kmeans",
         "KMeansModel": ".models.kmeans",
@@ -64,7 +86,7 @@ def __getattr__(name):  # lazy re-exports keep `import spark_rapids_ml_tpu` ligh
     }
     if name in _locations:
         try:
-            return getattr(import_module(_locations[name], __name__), name)
+            return getattr(_timed_import(_locations[name]), name)
         except ModuleNotFoundError as e:
             raise AttributeError(
                 f"module {__name__!r} has no attribute {name!r} ({e})"
@@ -74,3 +96,8 @@ def __getattr__(name):  # lazy re-exports keep `import spark_rapids_ml_tpu` ligh
 
 def __dir__():  # surface the lazy re-exports to dir()/completion
     return sorted(set(globals()) | set(__all__))
+
+
+profiling.incr_counter(
+    "import.us", int(round(1e6 * (profiling.now() - profiling._EPOCH)))
+)

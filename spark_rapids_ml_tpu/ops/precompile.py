@@ -115,6 +115,9 @@ def ensure_compile_cache() -> str:
                 "jax_persistent_cache_min_compile_time_secs",
                 _CACHE_MIN_COMPILE_SECS,
             )
+            # every fit, transform and server start passes here before it
+            # compiles: the compile account listens from now on
+            profiling.watch_compiles()
             _persist_dir = path
         return _persist_dir
 
@@ -167,13 +170,10 @@ class Precompiler:
 
     def _worker(self):
         import contextlib
-        import os
 
-        trace = os.environ.get("SRML_PRECOMPILE_LOG") == "1"
         while True:
             job, fn, avals, static_kwargs = self._q.get()
             try:
-                t0 = profiling.now() if trace else 0.0
                 # x64 is a THREAD-LOCAL scope: a float64 fit submits 64-bit
                 # avals from inside its enable_x64 context, but this worker
                 # thread is outside it — lowering here would silently
@@ -199,10 +199,6 @@ class Precompiler:
                 ):
                     job.result = fn.lower(*avals, **static_kwargs).compile()
                 profiling.incr_counter("precompile.compile")
-                if trace:
-                    logger.warning(
-                        "compiled %r in %.2fs", job.key, profiling.now() - t0
-                    )
             except BaseException as exc:  # noqa: BLE001 - relayed to waiter
                 job.error = exc
             finally:

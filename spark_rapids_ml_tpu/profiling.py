@@ -42,6 +42,10 @@
 #   - record_duration/percentiles: PROCESS-wide duration samples with
 #     p50/p95/p99 summaries (the serving SLO surface).
 #   - maybe_trace(): opt-in whole-program xprof capture (SRML_PROFILE=<dir>).
+#   - watch_compiles/compile_events/compile_summary: the compile account —
+#     what the process traced, lowered, and compiled or loaded, by
+#     executable, from jax's own monitoring events (compile.* counters and a
+#     bounded journal; always on, called only when jax builds something).
 #   - now(): the ONE monotonic clock.  Engine/serving modules must take
 #     timestamps through it (or through span()) — graftlint R6 rejects raw
 #     time.perf_counter()/time.time() outside this module, so every timing
@@ -403,6 +407,155 @@ def events(prefix: str = "") -> list:
 
 def reset_events() -> None:
     _event_log().clear()
+
+
+# -- the compile account -------------------------------------------------------
+# What a process traces, lowers, and compiles or loads, by executable: jax
+# times each of the three where it happens and reports it through
+# jax.monitoring with the function's name: its start when it opens, and its
+# start and end on time.time() when it closes.  watch_compiles() (called by
+# ops/precompile's ensure_compile_cache, which every fit, transform and
+# server start reaches before anything of the program compiles) registers
+# the listeners for the life of the process, whether or not a trace session
+# is on: jax calls them only when it traces, lowers or compiles, which a
+# warm call never does, so a steady state pays nothing and any event there
+# is itself a finding (a job that built something again).
+#
+# Nested events count once.  jax reports the trace of `matmul` or `tanh`
+# INSIDE the trace of the function that calls them (hundreds a solver), so
+# the account keeps a thread's OUTERMOST event of each kind and drops what
+# opens inside it: the seconds are the union of a thread's intervals per
+# kind, not the sum of the durations.
+#
+#   - counters (integer microseconds and counts, so they ride the counter
+#     delta into fit_telemetry() and export_metrics()): compile.trace_us,
+#     compile.lower_us, compile.backend_us, compile.executables (backend
+#     events: on a persistent-cache hit the retrieval and load, on a miss
+#     XLA's compile), compile.cache_hits, compile.cache_misses.  They are
+#     THREAD seconds: the precompile pool lowers several executables at once.
+#   - compile_summary(): the same seconds by function name and kind, which
+#     the journal's cap does not truncate.
+#   - compile_events(): a journal of (kind, fun_name, start, end, thread
+#     name), bounded like the event log (new events are dropped past the
+#     cap).  start/end are time.time() readings as jax took them: the
+#     journal can be laid over any wall-clock record of the process (a
+#     phase clock, an xprof capture) to partition WALL time, which is the
+#     reader's to do.
+
+_COMPILE_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_COMPILE_CACHE_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "compile.cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile.cache_misses",
+}
+
+_compile_journal: List[tuple] = []
+_compile_by_name: Dict[str, Dict[str, Dict[str, float]]] = {}
+_compile_watching = False
+
+
+def _bump_locked(name: str, amount: int = 1) -> None:
+    """incr_counter for a caller that holds _counters_lock (one acquisition
+    an event for its counters, roll-up and journal; no flight-ring event)."""
+    _counters[name] = _counters.get(name, 0) + amount
+
+
+def _compile_depths() -> Dict[str, int]:
+    depths = getattr(_tls, "compile_depths", None)
+    if depths is None:
+        depths = _tls.compile_depths = dict.fromkeys(_COMPILE_KINDS.values(), 0)
+    return depths
+
+
+def _on_compile_open(event: str, _start_time: float, **_kw: Any) -> None:
+    kind = _COMPILE_KINDS.get(event)
+    if kind is not None:
+        _compile_depths()[kind] += 1
+
+
+def _on_compile_span(
+    event: str, start_time: float, end_time: float, **kw: Any
+) -> None:
+    kind = _COMPILE_KINDS.get(event)
+    if kind is None:
+        return
+    depths = _compile_depths()
+    # an event whose opening was not seen (the listeners were registered
+    # inside it) closes at depth 0 and counts as outermost
+    depths[kind] = max(depths[kind] - 1, 0)
+    if depths[kind]:
+        return
+    name = str(kw.get("fun_name", ""))
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]  # lowering and backend events wrap the trace's name
+    seconds = end_time - start_time
+    with _counters_lock:
+        agg = _compile_by_name.setdefault(name, {}).setdefault(
+            kind, {"count": 0, "total_s": 0.0}
+        )
+        agg["count"] += 1
+        agg["total_s"] += seconds
+        _bump_locked("compile." + kind + "_us", int(round(1e6 * seconds)))
+        if kind == "backend":
+            _bump_locked("compile.executables")
+        if len(_compile_journal) < _EVENT_CAP:
+            _compile_journal.append(
+                (kind, name, start_time, end_time,
+                 threading.current_thread().name)
+            )
+
+
+def _on_compile_event(event: str, **_kw: Any) -> None:
+    name = _COMPILE_CACHE_COUNTERS.get(event)
+    if name is not None:
+        with _counters_lock:
+            _bump_locked(name)
+
+
+def watch_compiles() -> None:
+    """Register the compile account's jax.monitoring listeners (idempotent;
+    jax is imported here and not at this module's import)."""
+    global _compile_watching
+    with _counters_lock:
+        if _compile_watching:
+            return
+        _compile_watching = True
+    import jax.monitoring
+
+    jax.monitoring.register_scalar_listener(_on_compile_open)
+    jax.monitoring.register_event_time_span_listener(_on_compile_span)
+    jax.monitoring.register_event_listener(_on_compile_event)
+
+
+def compile_events() -> List[tuple]:
+    """Copy of the journal: (kind, fun_name, start, end, thread name) per
+    outermost event, kind one of "trace" / "lower" / "backend", start and
+    end on time.time()."""
+    with _counters_lock:
+        return list(_compile_journal)
+
+
+def compile_summary() -> Dict[str, Dict[str, Dict[str, float]]]:
+    """{fun_name: {kind: {"count", "total_s"}}} over the process's life:
+    outermost events, thread seconds."""
+    with _counters_lock:
+        return {
+            name: {kind: dict(agg) for kind, agg in kinds.items()}
+            for name, kinds in _compile_by_name.items()
+        }
+
+
+def reset_compile_account() -> None:
+    """Clear the journal, the by-name roll-up and the compile.* counters
+    (tests; the listeners stay registered)."""
+    with _counters_lock:
+        _compile_journal.clear()
+        _compile_by_name.clear()
+        for k in [k for k in _counters if k.startswith("compile.")]:
+            del _counters[k]
 
 
 # -- hierarchical spans -------------------------------------------------------
