@@ -567,6 +567,64 @@ def kernel_forest(seed):
         shapes.append(list(shape))
     out["prefix"] = {"shapes": shapes, "equal": True}
 
+    # the deep histogram kernel (forest_hist_deep) on a layout whose stray
+    # tiles hold poison, to the bit against the scatter formulation, at the
+    # slot shapes of rf_clf_fit's level 12 (64 slot rows) and level 7 (8).
+    # The window is the array's last two trees: the first keeps one tile a
+    # segment, so its stray tiles start beside its first tiles and most of
+    # its grid steps are skipped (their index maps clamp to the last kept
+    # tile); the second has no stray tile, so the array's last block is read
+    tile = forest_hist._ROW_TILE_DEEP
+    n_segs, n_tiles, f_deep = (3, 8, 32) if REHEARSAL else (8, 96, 64)
+    rng = np.random.default_rng(seed + 22)
+    spread = np.sort(rng.choice(np.arange(1, n_tiles), n_segs - 1, replace=False))
+    maps = np.stack([
+        np.minimum(np.arange(n_tiles), n_segs),                        # not in the window
+        np.minimum(np.arange(n_tiles), n_segs),                        # a tile a segment, then stray
+        np.searchsorted(spread, np.arange(n_tiles), side="right"),     # no stray tile
+    ]).astype(np.int32)
+    stray = np.repeat(maps == n_segs, tile, axis=1)                    # (3, n2)
+    n2 = n_tiles * tile
+    shapes = []
+    for local in (32, 1):
+        bins_d = rng.integers(0, bins, (3, f_deep, n2)).astype(np.int8)
+        node_d = rng.integers(0, local + 1, (3, n2)).astype(np.int32)  # == local: at no node
+        cls_d = rng.integers(0, s_dim, (3, n2))
+        stats_d = (
+            rng.poisson(1.0, (3, 1, n2)) * (cls_d[:, None, :] == np.arange(s_dim)[None, :, None])
+        ).astype(np.float32)
+        clean = np.where(stray[:, None, :], 0.0, stats_d).astype(np.float32)
+        bins_d[np.broadcast_to(stray[:, None, :], bins_d.shape)] = 127
+        stats_d[np.broadcast_to(stray[:, None, :], stats_d.shape)] = np.nan
+        node_p = np.where(stray, 0, node_d).astype(np.int32)
+        Hd = forest_hist.node_histograms_segmented(
+            jnp.asarray(bins_d), jnp.asarray(node_p)[:, None, :], jnp.asarray(stats_d),
+            jnp.asarray(maps[1:].reshape(-1)), jnp.asarray(1, jnp.int32),
+            t_chunk=2, n_segs=n_segs, nodes=local, s_dim=s_dim, n_bins=bins, f_pad=f_deep,
+            interpret=REHEARSAL and jax.default_backend() != "tpu",
+        )
+        slots = local * s_dim
+        check(Hd.shape[0] == 2 * n_segs, "the deep kernel still writes a stray block")
+        Hd = Hd.reshape((2, n_segs) + Hd.shape[1:])[:, :, :, :slots, :]
+        for t in (1, 2):
+            # a row's node among its tree's n_segs x local; stray or at no node: past them all
+            seg_row = np.repeat(maps[t], tile)
+            at = np.where((node_d[t] < local) & ~stray[t], seg_row * local + node_d[t], n_segs * local)
+            ref = hist_xla(
+                jnp.asarray(np.where(stray[t][None, :], 0, bins_d[t]).T.astype(np.int32)), jnp.asarray(clean[t].T),
+                jnp.asarray(at.astype(np.int32)), lo=0, node_batch=n_segs * local, n_bins=bins,
+            )  # (S, n_segs * local, F, B)
+            got = Hd[t - 1].reshape(n_segs, f_deep, local, s_dim, bins)  # (seg, F, local, S, B)
+            want_t = jnp.transpose(ref.reshape(s_dim, n_segs, local, f_deep, bins), (1, 3, 2, 0, 4))
+            check(
+                bool((got == want_t).all()) and float(want_t[-1].sum()) > 0,
+                f"deep histogram differs from the scatter formulation (tree {t}, {slots} slot rows)",
+            )
+        shapes.append([2 * n_segs, f_deep, max(8, slots), bins])
+    out["deep"] = {
+        "shapes": shapes, "tiles": n_tiles, "kept": [int((m < n_segs).sum()) for m in maps[1:]], "equal": True,
+    }
+
     # the public estimator on the MXU builder
     df = DataFrame.from_device(X, y=np.asarray(y), n_rows=n)
     model = RandomForestClassifier(

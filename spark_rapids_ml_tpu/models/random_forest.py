@@ -266,9 +266,9 @@ def _grow_mxu_device(
     min_impurity_decrease,
 ):
     """Grow on the MXU histogram builder: every dispatch of the growth, and
-    nothing read back.  Returns (tree_buf, plan) for core.fetch_fit_result
-    and forest_mxu.pack_forest.  Caller has already checked _mxu_eligible
-    and binned feature-major — the row-major int bin matrix this path used
+    nothing read back.  Returns ((tree_buf, kept), plan) for
+    core.fetch_fit_result and forest_mxu.pack_forest.  Caller has already
+    checked _mxu_eligible and binned feature-major — the row-major int bin matrix this path used
     to re-lay-out was a redundant 1.2-4.8 GB resident copy that tipped the
     depth-13 benchmark fit over HBM."""
     from ..ops import forest_mxu
@@ -584,18 +584,18 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
                     bins_rows = get_bins("fm", edges)
             if mxu:
                 with profiling.span("srml.fit.solve"):
-                    buf, plan = _grow_mxu_device(
+                    grown, plan = _grow_mxu_device(
                         inputs, bins_rows, stats, n_trees, bootstrap, seed,
                         is_classification, **grow_kwargs,
                     )
                 # the edges go with the forest: one read of the device a fit
                 profiling.incr_counter("forest.host_syncs")
-                buf_h, edges_h = fetch_fit_result((buf, jnp.asarray(edges)))
+                grown_h, edges_h = fetch_fit_result((grown, jnp.asarray(edges)))
                 with profiling.span("srml.fit.pack"):
                     from ..ops.forest_mxu import pack_forest
 
-                    del buf, bins_rows
-                    forest = pack_forest(buf_h, plan, edges_h)
+                    del grown, bins_rows
+                    forest = pack_forest(grown_h, plan, edges_h)
                     logger.info(
                         "grew %d trees on the MXU histogram path (depth<=%d, "
                         "bins=%d)", n_trees, max_depth, n_bins,

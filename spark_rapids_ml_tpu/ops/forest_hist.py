@@ -363,6 +363,7 @@ _ROW_TILE_DEEP = 512
 def _hist_kernel_segmented(
     t0_ref,         # (1,) int32 scalar prefetch: first tree of this dispatch
     seg_ref,        # (t_chunk * n_tiles,) int32 scalar prefetch: tile -> segment
+    last_ref,       # (t_chunk,) int32 scalar prefetch: a tree's last kept tile
     bins_ref,       # (_F_BLOCK, Kt) int8 — subset rows tile (segment-sorted)
     node_ref,       # (1, Kt) int32 segment-LOCAL node ids (>= nodes -> masked)
     stats_ref,      # (products * S, Kt) f32 stat rows
@@ -375,26 +376,34 @@ def _hist_kernel_segmented(
     n_bins: int,
     row_tile: int,
     n_tiles: int,
+    n_segs: int,
 ):
-    del t0_ref  # read by the index maps only
+    del t0_ref, last_ref  # read by the index maps only
     t = pl.program_id(0)
     k = pl.program_id(2)
     at = t * n_tiles + k
-    # a segment's tiles are contiguous: its output block stays resident
-    # while they stream, and is zeroed at the first of them
-    first = jnp.logical_or(
-        k == 0, seg_ref[at] != seg_ref[jnp.maximum(at - 1, 0)]
-    )
 
-    @pl.when(first)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    # a stray tile (segment n_segs: rows that weigh nothing) is not streamed:
+    # the index maps name the blocks already resident, and nothing here runs,
+    # the zeroing least of all: the block resident at a tree's first stray
+    # tile is its last kept segment's, not yet written back
+    @pl.when(seg_ref[at] < n_segs)
+    def _kept():
+        # a segment's tiles are contiguous: its output block stays resident
+        # while they stream, and is zeroed at the first of them
+        first = jnp.logical_or(
+            k == 0, seg_ref[at] != seg_ref[jnp.maximum(at - 1, 0)]
+        )
 
-    lhs = _slot_operands(
-        [node_ref[0, :]], stats_ref, nodes, s_dim, products, slots_pad,
-        row_tile,
-    )
-    _accumulate(bins_ref, lhs, out_ref, n_bins, row_tile)
+        @pl.when(first)
+        def _init():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        lhs = _slot_operands(
+            [node_ref[0, :]], stats_ref, nodes, s_dim, products, slots_pad,
+            row_tile,
+        )
+        _accumulate(bins_ref, lhs, out_ref, n_bins, row_tile)
 
 
 @partial(
@@ -411,7 +420,7 @@ def node_histograms_segmented(
     tile_seg: jax.Array,  # (t_chunk * n_tiles,) int32 segment of each row tile
     t0: jax.Array,        # () int32 first tree of the t_chunk window
     t_chunk: int,
-    n_segs: int,          # segments a tree (the last one takes stray tiles)
+    n_segs: int,          # segments a tree; a tile of segment n_segs is stray
     nodes: int,           # local nodes per segment at this level
     s_dim: int,
     n_bins: int,
@@ -425,8 +434,15 @@ def node_histograms_segmented(
     The kernel walks a tree's tiles in order and accumulates into the
     output block of the tile's segment, so segments of any length share one
     executable: its geometry follows from (T, n2, f_pad, nodes) alone.
-    A segment that owns no tile is never written (garbage): the layout gives
-    every kept segment at least one.  Returns
+
+    The STRAY tiles (`tile_seg` == n_segs) are a tree's LAST: the rows that
+    weigh nothing in it and the filler no segment used sort behind its
+    segments.  They are not streamed: past a tree's last kept tile the index
+    maps name the blocks already resident (no DMA is issued for a block
+    that does not change, and every index stays inside its array) and the
+    body does nothing, so what those tiles hold never enters a sum.  A
+    segment that owns no tile is never written (garbage): the layout gives
+    every segment at least one.  Returns
     (t_chunk * n_segs, f_pad, slots_pad, B) f32, under a leading axis of
     `products` planes where there is more than one (node_histograms)."""
     from jax.experimental.pallas import tpu as pltpu
@@ -441,6 +457,12 @@ def node_histograms_segmented(
     assert stats_s.shape[1] == products * s_dim
     slots_pad = max(8, -(-slots // 8) * 8)
     planes = () if products == 1 else (products,)
+    # a tree's last kept tile, from the tile map itself: the kernel's skip
+    # and its index maps cannot disagree
+    kept = (tile_seg.reshape(t_chunk, n_tiles) < n_segs).sum(
+        axis=1, dtype=jnp.int32
+    )
+    last = jnp.maximum(kept - 1, 0)
 
     kernel = partial(
         _hist_kernel_segmented,
@@ -451,24 +473,32 @@ def node_histograms_segmented(
         n_bins=n_bins,
         row_tile=_ROW_TILE_DEEP,
         n_tiles=n_tiles,
+        n_segs=n_segs,
     )
-    rows = lambda t, f, k, t0_ref, seg_ref: (t0_ref[0] + t, 0, k)
+
+    # step k of tree t names tile min(k, the tree's last kept tile)
+    def bins(t, f, k, t0_ref, seg_ref, last_ref):
+        return (t0_ref[0] + t, f, jnp.minimum(k, last_ref[t]))
+
+    def rows(t, f, k, t0_ref, seg_ref, last_ref):
+        return (t0_ref[0] + t, 0, jnp.minimum(k, last_ref[t]))
+
+    def out_block(t, f, k, t0_ref, seg_ref, last_ref):
+        seg = seg_ref[t * n_tiles + jnp.minimum(k, last_ref[t])]
+        return (0,) * len(planes) + (
+            t * n_segs + jnp.minimum(seg, n_segs - 1), f, 0, 0
+        )
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(t_chunk, f_pad // _F_BLOCK, n_tiles),
         in_specs=[
-            pl.BlockSpec(
-                (None, _F_BLOCK, _ROW_TILE_DEEP),
-                lambda t, f, k, t0_ref, seg_ref: (t0_ref[0] + t, f, k),
-            ),
+            pl.BlockSpec((None, _F_BLOCK, _ROW_TILE_DEEP), bins),
             pl.BlockSpec((None, 1, _ROW_TILE_DEEP), rows),
             pl.BlockSpec((None, stats_s.shape[1], _ROW_TILE_DEEP), rows),
         ],
         out_specs=pl.BlockSpec(
-            planes + (None, _F_BLOCK, slots_pad, n_bins),
-            lambda t, f, k, t0_ref, seg_ref: (0,) * len(planes) + (
-                t * n_segs + seg_ref[t * n_tiles + k], f, 0, 0
-            ),
+            planes + (None, _F_BLOCK, slots_pad, n_bins), out_block
         ),
     )
     return pl.pallas_call(
@@ -479,7 +509,7 @@ def node_histograms_segmented(
         ),
         interpret=interpret,
         name="forest_hist_deep",
-    )(t0.reshape(1).astype(jnp.int32), tile_seg, bins_s, node_loc, stats_s)
+    )(t0.reshape(1).astype(jnp.int32), tile_seg, last, bins_s, node_loc, stats_s)
 
 
 @partial(

@@ -37,7 +37,12 @@
 # any length accumulate inside one kernel, so there are no size classes, no
 # per-class executables and no host round-trip for the segment lengths.  All
 # of it — counts, filler keys, tile map — is computed on the device; the
-# host's only read is ONE batched fetch of the finished forest.
+# host's only read is ONE batched fetch of the finished forest.  What the
+# data DOES decide is how much of a grid the deep kernel streams: a row that
+# weighs nothing in a tree (36.8% of them under a Poisson(1) bootstrap) sorts
+# behind the tree's segments with the other stray rows, and the kernel does
+# nothing for the tiles there (a grid step that names the resident blocks and
+# runs no body).  That follows from the weights alone: no setting.
 #
 # PREFIX SUMS ARE A TRIANGULAR PRODUCT.  A candidate split's left class
 # counts are the histogram's prefix sums over the bins.  jnp.cumsum lowers to
@@ -143,6 +148,17 @@ def _even_chunk(total: int, cap: int) -> int:
     even size, so the clamped last window overlaps as little as it can."""
     cap = max(1, min(cap, total))
     return -(-total // -(-total // cap))
+
+
+def _deep_chunk(T: int, kind: str, nb: int, f_pad: int, slots: int, n_bins: int) -> int:
+    """Trees a deep level step takes: as many as keep the step's histograms
+    (nb segments a tree) UNDER _DEEP_HIST_BYTES (128 segments x 64 features x
+    64 slot rows x 128 bins are a third of it to the byte: two trees a step,
+    the windows every measurement was taken with), and at most 16: their
+    tile map is the kernel's scalar-prefetch operand."""
+    slots_pad = max(8, -(-slots // 8) * 8)
+    per_tree = _hist_products(kind) * nb * f_pad * slots_pad * n_bins * 4
+    return _even_chunk(T, min(16, max(1, (_DEEP_HIST_BYTES - 1) // per_tree)))
 
 
 def _hist_products(kind: str) -> int:
@@ -511,7 +527,7 @@ def _pack_rows_pad(T: int, P: int) -> int:
 
 @partial(jax.jit, static_argnames=("n_buckets", "n2"))
 def _sort_part(
-    rel: jax.Array,      # (T, n_pad) node ids AT the bucket level
+    keys: jax.Array,     # (T, n_pad) int32 the rows' segments (_deep_layout)
     dkeys: jax.Array,    # (T, n2 - n_pad) int32 filler keys (_deep_layout)
     payload: jax.Array,  # (T, n_pad) or (n_pad,) — ONE payload array
     n_buckets: int,
@@ -524,14 +540,14 @@ def _sort_part(
     key + P-feature-words + (w, y) sort that a cold fit used to pay ~50 s
     compiling is split into independent 2-operand sorts — one per payload —
     that the precompiler runs concurrently.  All parts sort by the same
-    UNIQUE combined key (bucket_key * n2 + column), so every part computes
-    the identical permutation with no reliance on sort stability.  n2 is a
+    UNIQUE combined key (segment * n2 + column) of the SAME keys, the
+    layout's own, so every part computes the identical permutation, the one
+    the tile map was counted from, with no reliance on sort stability.  n2 is a
     STATIC bound (_deep_width: n_pad + one tile of filler a bucket), so
     these lower at fit entry and compile while the shallow phase runs.  Uniqueness needs (n_buckets + 1) * n2 < 2^31 — 16.6 M rows
     at 128 buckets, far beyond a single chip's forest capacity."""
-    T, n_pad = rel.shape
+    T, n_pad = keys.shape
     assert (n_buckets + 1) * n2 < 2**31, "combined sort key overflows int32"
-    keys = jnp.minimum(rel, n_buckets).astype(jnp.int32)
     ck = jnp.concatenate([keys, dkeys], axis=1) * np.int32(n2) + jnp.arange(
         n2, dtype=jnp.int32
     )
@@ -550,12 +566,16 @@ def _sort_part(
 #    a batched payload sort (the only fast data-movement primitive on this
 #    backend — XLA gather/scatter scalarize).  Weight-0 filler rows ride the
 #    sort so every (tree, bucket) SEGMENT is a whole number of
-#    _ROW_TILE_DEEP tiles and owns at least one.
+#    _ROW_TILE_DEEP tiles and owns at least one.  A row that weighs nothing
+#    in a tree (out of its bag, padding, a user weight of 0) adds 0 to every
+#    sum, so it belongs to no segment there: it takes the STRAY key, the
+#    largest, with the rows whose node stopped in the shallow phase.
 # 2. The sorted width n2 = n_pad + n_buckets * _ROW_TILE_DEEP is a static
 #    bound (a segment takes less than one tile of filler, an empty one a
-#    whole tile); what the segments do not use lies behind them, with the
-#    rows whose node stopped in the shallow phase, in tiles of the stray
-#    segment, which the kernel skips.
+#    whole tile); the stray rows and the filler the segments do not use lie
+#    behind them, in whole tiles of the stray segment, which the histogram
+#    kernel does not stream (forest_hist.node_histograms_segmented): a
+#    bootstrapped tree keeps some 590 of its 912 tiles at 400,000 rows.
 # 3. Segments never move again: routing keeps rows inside their subtree, so
 #    the layout is built once and reused by every deeper level, which runs
 #    ONE histogram / split / route step per tree window.
@@ -570,14 +590,19 @@ def _deep_width(n_pad: int, n_buckets: int) -> int:
 
 
 @partial(jax.jit, static_argnames=("n_buckets", "n2"))
-def _deep_layout(rel: jax.Array, n_buckets: int, n2: int):
-    """From the routing state at the bucket level: the filler rows' sort keys
-    (T, n2 - n_pad) and each row tile's segment (T, n2 / tile), segment
-    n_buckets being the stray one.  All on the device: the segments' lengths
-    never reach the host."""
+def _deep_layout(rel: jax.Array, w_trees: jax.Array, n_buckets: int, n2: int):
+    """From the routing state at the bucket level and the trees' row weights:
+    the rows' sort keys (T, n_pad) — a row's segment, or n_buckets, the
+    stray one, where its node stopped or it weighs nothing in the tree —
+    the filler rows' keys (T, n2 - n_pad), each row tile's segment
+    (T, n2 / tile) and the tiles a tree keeps (T,): those of its segments,
+    the stray ones being its last.  Computed ONCE: the payload sorts take
+    these keys (_sort_part), so the tile map and every payload's order
+    cannot disagree.  All on the device: the segments' lengths never reach
+    the host."""
     T, n_pad = rel.shape
     tile = _ROW_TILE_DEEP
-    keys = jnp.minimum(rel, n_buckets)
+    keys = jnp.where(w_trees > 0, jnp.minimum(rel, n_buckets), n_buckets)
     ids = jnp.arange(n_buckets, dtype=keys.dtype)
     counts = (keys[:, :, None] == ids[None, None, :]).sum(
         axis=1, dtype=jnp.int32
@@ -593,7 +618,7 @@ def _deep_layout(rel: jax.Array, n_buckets: int, n2: int):
     tile_seg = (seg_end[:, None, :] <= tile0[None, :, None]).sum(
         axis=-1, dtype=jnp.int32
     )
-    return dkeys, tile_seg
+    return keys, dkeys, tile_seg, seg_end[:, -1] // tile
 
 
 @partial(jax.jit, static_argnames=("f_pad", "s_dim", "kind"))
@@ -689,14 +714,14 @@ def _deep_step(
     with jax.named_scope("forest.hist"):
         H = node_histograms_segmented(
             bins_s, rel_loc, stats_s, seg.reshape(-1), s0,
-            t_chunk=t_chunk, n_segs=nb + 1, nodes=local, s_dim=s_dim,
+            t_chunk=t_chunk, n_segs=nb, nodes=local, s_dim=s_dim,
             n_bins=n_bins, f_pad=f_pad, products=_hist_products(kind),
             interpret=interpret,
-        )  # (t_chunk * (nb + 1), f_pad, slots_pad, B)
+        )  # (t_chunk * nb, f_pad, slots_pad, B): no block for the stray tiles
         slots = local * s_dim
         if kind == "regression":
             H = fold_label_products(H, slots)
-        H = H.reshape((t_chunk, nb + 1) + H.shape[1:])[:, :nb, :, :slots, :]
+        H = H[:, :, :slots, :]
 
     with jax.named_scope("forest.split"):
         n_seg = t_chunk * nb
@@ -826,10 +851,12 @@ def grow_forest_mxu_device(
     y_vals: jax.Array = None,
     n_rows: int = None,
     interpret: bool = False,
-) -> Tuple[jax.Array, ForestPlan]:
-    """Grow T trees on the device; returns (tree_buf, plan) with nothing read
-    back: the caller fetches tree_buf (core.fetch_fit_result) and hands its
-    host copy to pack_forest.
+) -> Tuple[Tuple[jax.Array, Any], ForestPlan]:
+    """Grow T trees on the device; returns ((tree_buf, kept), plan) with
+    nothing read back: the caller fetches the pair in its one batched fetch
+    (core.fetch_fit_result) and hands the host copy to pack_forest.  kept:
+    the tiles of the deep layout each tree's segments own, (T,) int32 (the
+    rest are not streamed: forest.deep_tiles_kept); None without a deep phase.
 
     base_stats rows: regression -> (1*mask, y*mask); classification ->
     per-class one-hot rows (S = n_classes).  stats3 supplies the per-node
@@ -915,7 +942,7 @@ def grow_forest_mxu_device(
         a_st3 = aval((T, 3 if kind == "regression" else S, n2), f32)
         a_seg = aval((T, n_tiles), i32)
         k_layout = ("deep_layout", T, n_pad, nb, n2)
-        run.submit(k_layout, _deep_layout, a_rel, n_buckets=nb, n2=n2)
+        run.submit(k_layout, _deep_layout, a_rel, a_w, n_buckets=nb, n2=n2)
         k_pack = ("pack_all", D, n_pad, T, min(F, D), P, interpret)
         run.submit(
             k_pack, _pack_all, a_table, aval((T, min(F, D)), i32),
@@ -942,11 +969,9 @@ def grow_forest_mxu_device(
         )
         deep_keys, deep_chunk = {}, {}
         for level in range(bucket_level, max_depth):
-            slots_pad = max(8, -(-(2 ** (level - bucket_level) * S) // 8) * 8)
-            per_tree = _hist_products(kind) * (nb + 1) * f_pad_d * slots_pad * n_bins * 4
-            # at most 16 trees a step: their tile map is the kernel's
-            # scalar-prefetch operand
-            tc = _even_chunk(T, min(16, max(1, _DEEP_HIST_BYTES // per_tree)))
+            tc = _deep_chunk(
+                T, kind, nb, f_pad_d, 2 ** (level - bucket_level) * S, n_bins
+            )
             key = ("deep_step", T, n2, M, f_pad_d, tc, level, bucket_level, S,
                    kind, n_bins, F, msl, mid, interpret)
             run.submit(
@@ -979,6 +1004,7 @@ def grow_forest_mxu_device(
     rel = jnp.zeros((T, n_pad), jnp.int32)
     buf = jnp.zeros((C, T, M), jnp.float32)
     hist_rows = hist_needed = gathers = 0
+    kept = None
 
     with profiling.span("forest.shallow"):
         for level in range(shallow_top + 1):
@@ -1014,15 +1040,16 @@ def grow_forest_mxu_device(
     if deep:
         with profiling.span("forest.sort") as sp:
             before = run.n
-            dkeys, tile_seg = run.call(
-                k_layout, _deep_layout, rel, n_buckets=nb, n2=n2
+            keys, dkeys, tile_seg, kept = run.call(
+                k_layout, _deep_layout, rel, w_trees, n_buckets=nb, n2=n2
             )
+            del rel
             packed = run.call(
                 k_pack, _pack_all, bins_rows, next(subsets),
                 n_pad=n_pad, P=P, interpret=interpret,
             )
             sort = lambda name, payload: run.call(
-                k_sort[name], _sort_part, rel, dkeys, payload,
+                k_sort[name], _sort_part, keys, dkeys, payload,
                 n_buckets=nb, n2=n2,
             )
             packed_sorted = tuple(sort("i32", packed[:, p, :]) for p in range(P))
@@ -1033,7 +1060,7 @@ def grow_forest_mxu_device(
                 k_state, _deep_state, packed_sorted, w_sorted, y_sorted,
                 f_pad=f_pad_d, s_dim=S, kind=kind,
             )
-            del packed_sorted, w_sorted, y_sorted, rel
+            del packed_sorted, w_sorted, y_sorted, keys
             sp.set(dispatches=run.n - before)
         with profiling.span("forest.deep"):
             for level in range(bucket_level, max_depth):
@@ -1062,6 +1089,13 @@ def grow_forest_mxu_device(
     profiling.incr_counter("forest.geometries", len(run.keys))
     profiling.incr_counter("forest.hist_rows", hist_rows)
     profiling.incr_counter("forest.hist_rows_needed", hist_needed)
+    # the row tiles the deep kernel's grids cover (0 without a deep phase: the
+    # counter exists wherever this builder ran); what of them it streams is on
+    # the device until the fetch (pack_forest: forest.deep_tiles_kept)
+    profiling.incr_counter(
+        "forest.deep_tiles",
+        T * n_tiles * (max_depth - bucket_level) if deep else 0,
+    )
     profiling.incr_counter(
         "forest.gather_bytes",
         gathers * f_pad * n_pad + (_pack_rows_pad(T, P) * n_pad if deep else 0),
@@ -1071,16 +1105,23 @@ def grow_forest_mxu_device(
     plan = ForestPlan(
         max_depth, V, F, shallow, bucket_level if deep else -1, deep_feats
     )
-    return buf, plan
+    return (buf, kept), plan
 
 
 def pack_forest(
-    buf: np.ndarray, plan: ForestPlan, edges: np.ndarray
+    grown: Tuple[np.ndarray, Any], plan: ForestPlan, edges: np.ndarray
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """tree_buf's host copy -> grow_forest's dense arrays: (features (T, M),
-    thresholds, leaf_values (T, M, V), n_samples, impurities).  A split's
-    feature was recorded by its index in the subset searched; the subset is
-    the host's, and the threshold is that feature's edge at the split bin."""
+    """The host copy of grow_forest_mxu_device's (tree_buf, kept) ->
+    grow_forest's dense arrays: (features (T, M), thresholds, leaf_values
+    (T, M, V), n_samples, impurities).  A split's feature was recorded by its
+    index in the subset searched; the subset is the host's, and the threshold
+    is that feature's edge at the split bin.  kept feeds a counter alone."""
+    buf, kept = grown
+    if kept is not None:
+        profiling.incr_counter(
+            "forest.deep_tiles_kept",
+            int(np.sum(kept, dtype=np.int64)) * (plan.max_depth - plan.deep_level),
+        )
     buf = np.asarray(buf)
     T, M = buf.shape[1:]
     edges = np.asarray(edges)
@@ -1122,7 +1163,7 @@ def grow_forest_mxu(
     """tile_feature_rows + grow_forest_mxu_device + a plain fetch +
     pack_forest: grow_forest's host-array contract in one call (tests; the
     estimator fetches through core.fetch_fit_result between the two)."""
-    buf, plan = grow_forest_mxu_device(
+    grown, plan = grow_forest_mxu_device(
         tile_feature_rows(bins_fm), base_stats, w_trees, stats3, **kwargs
     )
-    return pack_forest(np.asarray(buf), plan, np.asarray(edges))
+    return pack_forest(jax.device_get(grown), plan, np.asarray(edges))

@@ -64,6 +64,17 @@ def test_forest_with_every_feature_searched_is_the_references(engine, classes, d
     np.testing.assert_allclose(model.leaf_values_ * model.node_counts_[..., None], want["leaf_values_"] * want["node_counts_"][..., None], atol=1e-3)
     held = want["node_counts_"] > 0
     np.testing.assert_allclose(model.impurities_[held], want["impurities_"][held], atol=1e-6)
+    if engine == "mxu":
+        # the deep kernel's tiles, counted: 3 trees x the sorted layout's tiles x one deep level
+        # (level 5); a tree keeps at least a tile a segment and not its out-of-bag rows' tiles;
+        # the per-tree counts came with the forest's one fetch
+        moved = model.fit_telemetry().counters
+        from spark_rapids_ml_tpu.ops import forest_hist, forest_mxu
+
+        tiles = forest_mxu._deep_width(ROWS, 32) // forest_hist._ROW_TILE_DEEP
+        assert moved["forest.deep_tiles"] == 3 * tiles
+        assert 3 * 32 <= moved["forest.deep_tiles_kept"] < moved["forest.deep_tiles"]
+        assert moved["forest.host_syncs"] <= 2               # the fetch; on the CPU the binning sample's too
 
 
 def _readings(how):
@@ -113,6 +124,7 @@ def test_step_spans_tile_the_fit_and_the_counters_add_up(engine, monkeypatch):
         assert moved["forest.levels"] == 5 and moved["forest.dispatches"] >= moved["forest.geometries"] > 0
         assert moved["forest.hist_rows"] >= moved["forest.hist_rows_needed"] == 2 * 4 * ROWS
         assert moved["forest.host_syncs"] <= 2               # the fetch; on the CPU the binning sample's too
+        assert "forest.deep_tiles" not in moved and "forest.deep_tiles_kept" not in moved   # depth 4: no deep phase
         levels = [r for r in mine if r[0] == "forest.level"]
         assert [r[7]["level"] for r in levels] == [0, 1, 2, 3, 4]
         assert sum(r[7]["dispatches"] for r in levels) == moved["forest.dispatches"]

@@ -108,11 +108,11 @@ def test_regression_histograms_are_float32_sums_and_one_piece_is_not(kernel):
                 jnp.asarray(sub), jnp.asarray(node_rel), rows, t_pack=t_pack, nodes=nodes, s_dim=2, n_bins=BINS,
                 products=products, interpret=True,
             )
-        else:       # one tree, every tile in segment 0 of 1 (+ the stray one)
+        else:       # one tree, every tile in segment 0 of 1 (none stray)
             tiles = sub.shape[1] // forest_hist._ROW_TILE_DEEP
             H = forest_hist.node_histograms_segmented(
                 jnp.asarray(sub)[None], jnp.asarray(node_rel)[:, None, :], rows[None], jnp.zeros((tiles,), jnp.int32),
-                jnp.zeros((), jnp.int32), t_chunk=1, n_segs=2, nodes=nodes, s_dim=2, n_bins=BINS,
+                jnp.zeros((), jnp.int32), t_chunk=1, n_segs=1, nodes=nodes, s_dim=2, n_bins=BINS,
                 f_pad=forest_hist._F_BLOCK, products=products, interpret=True,
             )
             H = H[:, 0] if products > 1 else H[0]

@@ -557,12 +557,13 @@ def test_netchaos_killed_coordinator_surfaces_typed_and_bounded(tmp_path):
     bare socket error."""
     procs = _spawn_netchaos(tmp_path, nranks=3, env_extra={}, rounds=0)
     # wait until the cohort is demonstrably gathering (every worker prints
-    # its join line after bootstrap), then kill the coordinator host
-    deadline = time.monotonic() + 30.0
-    while time.monotonic() < deadline:
-        if os.path.exists(tmp_path / "cp" / "coordinator.addr"):
-            break
-        time.sleep(0.05)
+    # its join line after bootstrap), then kill the coordinator host: a
+    # rank still importing when the coordinator dies was never in the
+    # cohort, and waits out its bootstrap timeout instead of a heartbeat
+    for p in procs:
+        for line in p.stdout:
+            if line.startswith("SHIELD ") and line.rstrip().endswith("joined"):
+                break
     time.sleep(1.0)  # let a few rounds complete
     os.kill(procs[0].pid, signal.SIGKILL)
     outs = _communicate_all(procs)

@@ -46,13 +46,11 @@ for level in (0, 3, 6):
     nodes = 2 ** level; tpack = fm._even_chunk(T, 128 // (nodes * S))
     go(f"shallow_step_l{level}", fm._shallow_step, a_rel, a_buf, a_w, A((S, n_pad), f32), A((f_pad, n_pad), i8), a_t0,
        tpack=tpack, nodes=nodes, s_dim=S, kind="gini", n_bins=B, F=F, msl=1.0, mid=0.0, interpret=False)
-go("deep_layout", fm._deep_layout, a_rel, n_buckets=nb, n2=n2)
+go("deep_layout", fm._deep_layout, a_rel, a_w, n_buckets=nb, n2=n2)
 go("deep_state", fm._deep_state, tuple(A((T, n2), i32) for _ in range(P)), A((T, n2), f32), A((T, n2), f32), f_pad=f_pad, s_dim=S, kind="gini")
 a_bins, a_loc, a_st, a_seg = A((T, f_pad, n2), i8), A((T, 1, n2), i32), A((T, S, n2), f32), A((T, n_tiles), i32)
 for level in (7, 10, 12):
-    slots_pad = max(8, -(-(2 ** (level - 7) * S) // 8) * 8)
-    per_tree = (nb + 1) * f_pad * slots_pad * B * 4
-    tc = fm._even_chunk(T, min(16, max(1, fm._DEEP_HIST_BYTES // per_tree)))
+    tc = fm._deep_chunk(T, "gini", nb, f_pad, 2 ** (level - 7) * S, B)
     go(f"deep_step_l{level}_tc{tc}", fm._deep_step, a_bins, a_loc, a_st, a_st, a_seg, a_buf, a_t0,
        t_chunk=tc, level=level, bucket_level=7, s_dim=S, kind="gini", n_bins=B, F=F, msl=1.0, mid=0.0, interpret=False)
 go("deep_leaf", fm._deep_leaf, a_loc, a_st, a_seg, a_buf, level=13, bucket_level=7, kind="gini")
