@@ -827,17 +827,30 @@ class _TpuEstimator(_TpuCaller):
         paramMaps: List[Dict[Param, Any]],
         n_folds: int,
         seed: int,
-    ) -> List[List[Dict[str, Any]]]:
+        evaluator: Any = None,
+    ) -> Tuple[List[List[Dict[str, Any]]], Optional[List[List[Any]]]]:
         """Fit every (fold, candidate) pair over ONE staged dataset (folds
         as weight masks, candidates as kernel lanes); returns n_folds lists
-        of per-candidate model-attribute dicts.  Only called when
-        _supportsBatchedSweep returned True."""
+        of per-candidate model-attribute dicts and, beside them, None or the
+        held-out metric partials in the same order.  CrossValidator passes
+        `evaluator` for a device-resident frame (DataFrame.from_device: its
+        rows exist nowhere else), and the estimator then scores every model
+        on its fold's rows where the staged table lies, under the fold ids
+        the training masks came from: the partials are the mergeable metric
+        objects the host route's transform-evaluate builds (metrics/).  Only
+        called when _supportsBatchedSweep returned True."""
         raise NotImplementedError
 
     def _sweep_sparse_input(self, df: DataFrame) -> bool:
-        """True when any partition carries a sparse CSR feature block —
-        the batched sweep keeps those on the legacy loop (masked-fold ELL
-        statistics are a documented non-goal, docs/tuning_engine.md)."""
+        """True when any partition carries a sparse CSR feature block, or a
+        device-resident frame's table is an EllMatrix — the batched sweep
+        keeps those off its route (masked-fold ELL statistics are a
+        documented non-goal, docs/tuning_engine.md)."""
+        dev = getattr(df, "_device_features", None)
+        if dev is not None:
+            from .ops.sparse import EllMatrix
+
+            return isinstance(dev[0], EllMatrix)
         input_col, _ = self._get_input_columns()
         if input_col is None:
             return False

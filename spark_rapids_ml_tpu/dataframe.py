@@ -144,7 +144,14 @@ class DataFrame:
 
         FIT-INPUT ONLY: transform/kneighbors need per-partition host
         features and raise on a from_device frame — run inference through
-        the host-facade or pyspark paths, or the ops-level kernels."""
+        the host-facade or pyspark paths, or the ops-level kernels.  The one
+        exception is CrossValidator.fit (tuning.py): a sweep the batched
+        engine carries (LogisticRegression on a dense table, a regParam /
+        elasticNetParam grid, MulticlassClassificationEvaluator) scores each
+        fold's models on that fold's rows where the table lies, under the
+        fold ids the training masks came from: no split frame, no toPandas,
+        no upload.  Any other sweep on such a frame is refused before it
+        fits anything."""
         n_valid = int(n_rows if n_rows is not None else X.shape[0])
         # the features column is a placeholder (readers must go through the
         # device array); keep it 1 byte/row

@@ -86,6 +86,29 @@ class MulticlassMetrics:
         ll = log_loss(labels, probs, eps) if probs is not None else -1.0
         return cls(tp, fp, cnt, len(labels), ll)
 
+    @classmethod
+    def from_confusion(
+        cls,
+        classes: np.ndarray,
+        confusion: np.ndarray,
+        log_loss_sum: Optional[float] = None,
+    ) -> "MulticlassMetrics":
+        """The partial from_arrays gives for the same rows, from their counts:
+        confusion[t, p] rows of true class classes[t] predicted classes[p]
+        (reduced where the rows lie: ops/logistic.sweep_logistic_score_kernel),
+        and their summed log-loss where the metric needs one."""
+        confusion = np.asarray(confusion, dtype=np.float64)
+        true_n, pred_n = confusion.sum(axis=1), confusion.sum(axis=0)
+        hit = np.diag(confusion)
+        seen = [i for i in range(len(classes)) if true_n[i] > 0 or pred_n[i] > 0]
+        return cls(
+            {float(classes[i]): float(hit[i]) for i in seen},
+            {float(classes[i]): float(pred_n[i] - hit[i]) for i in seen},
+            {float(classes[i]): float(true_n[i]) for i in seen if true_n[i] > 0},
+            int(confusion.sum()),
+            -1.0 if log_loss_sum is None else float(log_loss_sum),
+        )
+
     def merge(self, other: "MulticlassMetrics") -> "MulticlassMetrics":
         def _add(a: Dict[float, float], b: Dict[float, float]) -> Dict[float, float]:
             out = dict(a)

@@ -408,9 +408,13 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
             return False
         if any(set(ov) - {"alpha", "l1_ratio"} for ov in overrides):
             return False  # only the regularizer axes batch as lanes
+        if getattr(df, "_device_features", None) is not None:
+            # the masked statistics pass has no scoring where the table lies
+            # yet, and a from_device frame has no host rows to score
+            return False
         return not self._sweep_sparse_input(df)
 
-    def _fitBatchedSweep(self, df, paramMaps, n_folds, seed):
+    def _fitBatchedSweep(self, df, paramMaps, n_folds, seed, evaluator=None):
         """All n_folds x len(paramMaps) linreg fits as a fused masked-fold
         stats pass + one stacked-lane solve dispatch per solver family over
         the ONE staged dataset (ops/glm.py sweep kernels; exact-equality
@@ -556,7 +560,7 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
                         )
                         coef_h, n_iter_h = jax.device_get((coef, n_iter))
                         _collect(cd, coef_h, n_iter_h)
-        return results
+        return results, None
 
 
 class LinearRegressionModel(

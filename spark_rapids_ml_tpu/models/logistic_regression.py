@@ -60,6 +60,7 @@ from ..ops.logistic import (
     scores_to_labels,
     scores_to_probs,
     sweep_logistic_fit_kernel,
+    sweep_logistic_score_kernel,
 )
 from ..ops.softmax_ell_pass import slot_steps
 from ..utils import get_logger
@@ -434,12 +435,20 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
             return False  # only the regularizer axes batch as lanes
         return not self._sweep_sparse_input(df)
 
-    def _fitBatchedSweep(self, df, paramMaps, n_folds, seed):
+    def _fitBatchedSweep(self, df, paramMaps, n_folds, seed, evaluator=None):
         """All n_folds x len(paramMaps) logreg fits as ONE lane-batched
         L-BFGS/OWL-QN run per penalty family over the ONE staged dataset —
         folds as fold-id weight masks, candidates as traced reg/l1 lanes
         with per-lane convergence masks (ops/logistic.py,
-        ops/lbfgs.minimize_lbfgs_batched)."""
+        ops/lbfgs.minimize_lbfgs_batched).  With `evaluator` (a
+        device-resident frame) every model is scored on its fold's rows where
+        the table lies, from the solve's own device arrays
+        (ops/logistic.sweep_logistic_score_kernel): no row, no label, no
+        fold id and no coefficient goes up for it.
+
+        The step spans of a fit tile a sweep too (srml.ingest, srml.fit.init,
+        .solve, .wait, .fetch, .pack), inside tuning.sweep.solve and
+        tuning.sweep.score where they belong to one of the two."""
         from ..core import discover_label_classes
         from ..ops import sweep as sweep_ops
         from ..ops.labels import encode_labels_kernel
@@ -456,25 +465,21 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
             cand.append((reg, l1_ratio, reg > 0 and l1_ratio > 0))
         fit_intercept = bool(params["fit_intercept"])
         max_iter = int(params["max_iter"])
-        with profiling.phase("srml.ingest"):
+        with profiling.span("srml.ingest"):
             inputs = self._build_fit_inputs(df)
-        assert inputs.y is not None
-        classes = discover_label_classes(inputs)
-        if len(classes) < 2:
-            raise RuntimeError(
-                "LogisticRegression requires at least two distinct labels"
+        with profiling.span("srml.fit.init"), sanitize_scope():
+            assert inputs.y is not None
+            classes = discover_label_classes(inputs)
+            if len(classes) < 2:
+                raise RuntimeError(
+                    "LogisticRegression requires at least two distinct labels"
+                )
+            num_classes = len(classes)
+            kcls = 1 if num_classes == 2 else num_classes
+            mesh = inputs.mesh
+            fid = sweep_ops.stage_fold_ids(
+                inputs.n_rows, inputs.X.shape[0], n_folds, seed, mesh
             )
-        num_classes = len(classes)
-        kcls = 1 if num_classes == 2 else num_classes
-        mesh = inputs.mesh
-        fid = sweep_ops.stage_fold_ids(
-            inputs.n_rows, inputs.X.shape[0], n_folds, seed, mesh
-        )
-        results: List[List[Dict[str, Any]]] = [
-            [None] * len(cand) for _ in range(n_folds)  # type: ignore[list-item]
-        ]
-        logger = get_logger(type(self))
-        with sanitize_scope():
             y_enc = encode_labels_kernel(
                 inputs.y, jnp.asarray(classes.astype(inputs.y.dtype))
             )
@@ -515,14 +520,28 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                 ],
                 mesh=mesh,
             )
-            for owlqn, idxs, regs, l1s in families:
-                with profiling.span(
-                    "tuning.sweep.solve",
-                    candidates=len(idxs),
-                    folds=n_folds,
-                    owlqn=owlqn,
-                ):
-                    W, b, n_iter, conv, n_evals = sweep_ops.dispatch(
+        results: List[List[Dict[str, Any]]] = [
+            [None] * len(cand) for _ in range(n_folds)  # type: ignore[list-item]
+        ]
+        held_out: Optional[List[List[MulticlassMetrics]]] = None
+        if evaluator is not None:
+            held_out = [[None] * len(cand) for _ in range(n_folds)]  # type: ignore[list-item]
+            needs_probs = evaluator.getMetricName() == "logLoss"
+            eps = jnp.asarray(evaluator.getEps(), inputs.X.dtype)
+        logger = get_logger(type(self))
+
+        def sweep_family(owlqn, idxs, regs, l1s) -> None:
+            """One penalty family's lanes: the solve, its ONE batched fetch,
+            the scoring where the table lies, and the (fold, candidate)
+            results it fills in."""
+            with profiling.span(
+                "tuning.sweep.solve",
+                candidates=len(idxs),
+                folds=n_folds,
+                owlqn=owlqn,
+            ), sanitize_scope():
+                with profiling.span("srml.fit.solve"):
+                    solved = sweep_ops.dispatch(
                         "sweep.logreg.fit",
                         sweep_logistic_fit_kernel,
                         inputs.X,
@@ -539,14 +558,33 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                         max_iter=max_iter,
                         use_owlqn=owlqn,
                     )
-                    # graftlint: disable=R1 (one batched fetch per penalty FAMILY — at most two iterations, each a distinct compiled sweep whose results ship home together)
-                    W_h, b_h, n_iter_h, conv_h, n_evals_h = jax.device_get(
-                        (W, b, n_iter, conv, n_evals)
-                    )
+                W_h, b_h, n_iter_h, conv_h, n_evals_h, n_scans_h = fetch_fit_result(
+                    solved
+                )
+            if held_out is not None:
+                with profiling.span("tuning.sweep.score"), sanitize_scope():
+                    with profiling.span("srml.fit.solve"):
+                        scored = sweep_ops.dispatch(
+                            "sweep.logreg.score",
+                            sweep_logistic_score_kernel,
+                            inputs.X,
+                            y_enc,
+                            fid,
+                            solved[0],
+                            solved[1],
+                            eps,
+                            mesh=mesh,
+                            num_classes=num_classes,
+                        )
+                    conf_h, loss_h = fetch_fit_result(scored)
+                    del scored
+            with profiling.span("srml.fit.pack"):
+                del solved
                 # the lanes of the candidate bucket beyond the grid are not fits
                 _count_lbfgs(
                     n_iter_h[:, : len(idxs)], n_evals_h[:, : len(idxs)]
                 )
+                profiling.incr_counter("tuning.sweep.scans", int(n_scans_h))
                 logger.info(
                     "sweep L-BFGS iters (fold x candidate): %s converged: %s",
                     n_iter_h[:, : len(idxs)].tolist(),
@@ -564,7 +602,21 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                             "dtype": str(inputs.dtype),
                             "num_iters": int(n_iter_h[f, j]),
                         }
-        return results
+                        if held_out is not None:
+                            held_out[f][i] = MulticlassMetrics.from_confusion(
+                                classes,
+                                conf_h[f, j],
+                                float(loss_h[f, j]) if needs_probs else None,
+                            )
+
+        # at most two families, each a distinct compiled sweep whose results
+        # ship home together
+        for family in families:
+            sweep_family(*family)
+        if held_out is not None:
+            # every row is held out of exactly one fold, and scored there
+            profiling.incr_counter("tuning.score.rows", inputs.n_rows)
+        return results, held_out
 
 
 class LogisticRegressionModel(

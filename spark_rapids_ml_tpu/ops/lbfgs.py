@@ -23,7 +23,7 @@
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, NamedTuple, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +41,12 @@ class LbfgsResult(NamedTuple):
     # from autodiff), and the line search takes another number of them on
     # every dataset; n_iter does not say.
     n_evals: jax.Array
+    # minimize_lbfgs_batched alone: the evaluations its lanes SHARED, a scalar.
+    # Every trip of a line search evaluates all lanes at once, so one trip is one
+    # scan of the data however many lanes are still searching: what a sweep's time
+    # is made of, where the lanes' n_evals add up to what the lanes would have
+    # taken alone.  None from minimize_lbfgs (there n_evals is that number).
+    n_scans: Optional[jax.Array] = None
 
 
 def _pseudo_gradient(x, g, l1w):
@@ -84,7 +90,7 @@ def _two_loop(g, S, Y, rho, count, history):
 @partial(
     jax.jit,
     static_argnames=(
-        "value_and_grad", "max_iter", "history", "use_owlqn", "max_ls"
+        "value_and_grad", "max_iter", "history", "use_owlqn", "max_ls", "trials"
     ),
 )
 def minimize_lbfgs_batched(
@@ -96,6 +102,7 @@ def minimize_lbfgs_batched(
     history: int = 10,
     use_owlqn: bool = False,
     max_ls: int = 20,
+    trials: int = 1,
 ) -> LbfgsResult:
     """Lane-batched minimize_lbfgs for hyperparameter sweeps (srml-sweep).
 
@@ -111,19 +118,32 @@ def minimize_lbfgs_batched(
     along untouched.  Per-lane semantics mirror minimize_lbfgs; per-lane
     NUMBERS can differ from a solo run in the last bits because the fused
     contraction reduces across a different geometry (docs/tuning_engine.md
-    documents the equality contract this leaves)."""
+    documents the equality contract this leaves).
+
+    `trials` steps of the halving sequence are tried in ONE evaluation: a trip
+    of the line search evaluates t, t/2, ... t/2^(trials-1) for every lane
+    (value_and_grad then sees (trials * L, P), the trials outermost) and a
+    lane takes the first that passes, which is the step its own halving
+    search would have stopped at, so its iterates and its n_evals are the
+    solo run's whatever `trials` is.  What changes is how many trips the
+    lanes share: with many lanes some lane halves in every few iterations,
+    and every such halving was one more scan of the data for all of them.
+    A caller whose evaluation is bound by reading the data, not by the
+    lanes' columns, gets the extra trials for nothing."""
     L, P = x0.shape
+    T = trials
     dtype = x0.dtype
     l1w = l1_weight.astype(dtype)
 
     def full_objective(x):
         with jax.named_scope("lbfgs.eval"):
             f, g = value_and_grad(x)
-        if use_owlqn:
-            f = f + (l1w * jnp.abs(x)).sum(axis=-1)
+        if use_owlqn:  # x may stack several trial points a lane
+            f = f + (l1w * jnp.abs(x.reshape(-1, L, P))).sum(axis=-1).reshape(-1)
         return f, g
 
     f0, g0 = full_objective(x0)
+    halvings = 0.5 ** jnp.arange(T, dtype=dtype)
     state = (
         x0,
         f0,
@@ -135,15 +155,16 @@ def minimize_lbfgs_batched(
         jnp.zeros((L,), jnp.int32),         # per-lane iteration
         jnp.zeros((L,), bool),              # converged
         jnp.ones((L,), jnp.int32),          # per-lane evaluations (f0 is one)
+        jnp.array(1, jnp.int32),            # shared evaluations (f0 is one)
     )
     two_loop_lanes = jax.vmap(_two_loop, in_axes=(0, 0, 0, 0, 0, None))
 
     def cond(state):
-        _, _, _, _, _, _, _, it, converged, _ = state
+        _, _, _, _, _, _, _, it, converged, _, _ = state
         return jnp.any((it < max_iter) & (~converged))
 
     def body(state):
-        x, f, g, S, Y, rho, count, it, converged, n_evals = state
+        x, f, g, S, Y, rho, count, it, converged, n_evals, n_scans = state
         active = (it < max_iter) & (~converged)
         pg = _pseudo_gradient(x, g, l1w) if use_owlqn else g
         with jax.named_scope("lbfgs.direction"):
@@ -163,33 +184,55 @@ def minimize_lbfgs_batched(
         ).astype(dtype)
 
         def ls_body(ls_state):
-            t, xn, fn, gn, n_ls, ok = ls_state
+            t, xn, fn, gn, n_ls, ok, trips = ls_state
             live = active & (~ok) & (n_ls < max_ls)
-            x_try = x + t[:, None] * d
+            steps = halvings[:, None] * t[None, :]  # (T, L)
+            x_try = x[None] + steps[:, :, None] * d[None]
             if use_owlqn:
-                x_try = jnp.where(jnp.sign(x_try) == xi, x_try, 0.0)
-            f_try, g_try = full_objective(x_try)
-            ok_try = f_try <= f + 1e-4 * t * deriv
+                x_try = jnp.where(jnp.sign(x_try) == xi[None], x_try, 0.0)
+            f_try, g_try = full_objective(x_try.reshape(T * L, P))
+            f_try = f_try.reshape(T, L)
+            # a lane takes the first of its steps that passes, among those
+            # its own search would still have tried
+            may = (n_ls[None, :] + jnp.arange(T)[:, None]) < max_ls
+            ok_try = (f_try <= f[None] + 1e-4 * steps * deriv[None]) & may
+            took = jnp.argmax(ok_try, axis=0)  # 0 where none passes
+            passed = ok_try.any(axis=0)
+            tried = jnp.where(passed, took + 1, jnp.minimum(T, max_ls - n_ls))
+            pick = took[None, :]
             lv = live[:, None]
             return (
-                jnp.where(live, t * 0.5, t),
-                jnp.where(lv, x_try, xn),
-                jnp.where(live, f_try, fn),
-                jnp.where(lv, g_try, gn),
-                jnp.where(live, n_ls + 1, n_ls),
-                jnp.where(live, ok_try, ok),
+                jnp.where(live, t * 0.5**T, t),
+                jnp.where(
+                    lv, jnp.take_along_axis(x_try, pick[:, :, None], 0)[0], xn
+                ),
+                jnp.where(live, jnp.take_along_axis(f_try, pick, 0)[0], fn),
+                jnp.where(
+                    lv,
+                    jnp.take_along_axis(
+                        g_try.reshape(T, L, P), pick[:, :, None], 0
+                    )[0],
+                    gn,
+                ),
+                jnp.where(live, n_ls + tried.astype(jnp.int32), n_ls),
+                jnp.where(live, passed, ok),
+                trips + 1,
             )
 
         def ls_cond(ls_state):
-            _, _, _, _, n_ls, ok = ls_state
+            _, _, _, _, n_ls, ok, _ = ls_state
             return jnp.any(active & (~ok) & (n_ls < max_ls))
 
         # n_ls counts a lane's own trials (a frozen lane rides along
-        # uncounted), so a lane's n_evals is its solo run's
-        _, x_new, f_new, g_new, n_ls, ls_ok = jax.lax.while_loop(
+        # uncounted), so a lane's n_evals is its solo run's; trips counts
+        # the evaluations all lanes shared
+        _, x_new, f_new, g_new, n_ls, ls_ok, trips = jax.lax.while_loop(
             ls_cond,
             ls_body,
-            (t0, x, f, g, jnp.zeros((L,), jnp.int32), jnp.zeros((L,), bool)),
+            (
+                t0, x, f, g, jnp.zeros((L,), jnp.int32), jnp.zeros((L,), bool),
+                jnp.array(0, jnp.int32),
+            ),
         )
         # per-lane: on line-search exhaustion keep the current iterate
         keep = ls_ok[:, None]
@@ -231,12 +274,15 @@ def minimize_lbfgs_batched(
             it + active.astype(jnp.int32),
             jnp.where(active, converged_new, converged),
             n_evals + n_ls,
+            n_scans + trips,
         )
 
-    x, f, g, S, Y, rho, count, it, converged, n_evals = jax.lax.while_loop(
+    x, f, g, S, Y, rho, count, it, converged, n_evals, n_scans = jax.lax.while_loop(
         cond, body, state
     )
-    return LbfgsResult(x=x, f=f, n_iter=it, converged=converged, n_evals=n_evals)
+    return LbfgsResult(
+        x=x, f=f, n_iter=it, converged=converged, n_evals=n_evals, n_scans=n_scans
+    )
 
 
 @partial(jax.jit, static_argnames=("value_and_grad", "max_iter", "history", "use_owlqn", "max_ls"))

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 from ..parallel.mesh import DATA_AXIS
 from .lbfgs import minimize_lbfgs, minimize_lbfgs_batched
-from .linalg import exact_matmul
+from .linalg import SOLVER_PRECISION, exact_matmul
 from .logistic_pass import binary_block_sums, one_pass_sums, takes_table
 from .pallas_tpu import pallas_enabled
 from .softmax_ell_pass import ell_pass_sums, takes as ell_pass_takes
@@ -276,6 +276,17 @@ def logistic_warm_fit_kernel(
 # -- batched hyperparameter sweep (srml-sweep; docs/tuning_engine.md) --------
 
 
+def _line_search_trials(columns: int) -> int:
+    """How many steps of the halving sequence a sweep's line search tries in
+    one evaluation (ops/lbfgs.minimize_lbfgs_batched): as many, of 4, 2 or 1,
+    as keep the lanes' score columns within ONE 128-wide MXU tile, where the
+    product hides under the read of X and the extra trial points cost nothing.
+    24 lanes halve somewhere in a quarter of their iterations, and each
+    halving was one more scan of the table for all of them (258 scans a job
+    for 200 iterations at 400,000 x 3000; PERF.md section 6, PR 39)."""
+    return 4 if 4 * columns <= 128 else 2 if 2 * columns <= 128 else 1
+
+
 @partial(
     jax.jit,
     static_argnames=(
@@ -296,7 +307,7 @@ def sweep_logistic_fit_kernel(
     max_iter: int = 100,
     use_owlqn: bool = False,
     mesh=None,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
     """Fit a whole regularization sweep — m candidates x k folds — as ONE
     jitted L-BFGS/OWL-QN run over the one staged dataset.
 
@@ -306,12 +317,16 @@ def sweep_logistic_fit_kernel(
     reg/l1_ratio vectors are TRACED values — a different grid at the same
     shapes reuses the compiled executable.  Each optimizer iteration
     evaluates every lane's smooth objective through one fused contraction
-    (the (N, D) x (D, k*m*kcls) product XLA builds from the lane einsum);
-    per-lane convergence masks in minimize_lbfgs_batched freeze finished
-    lanes.  Returns (W (k, m, kcls, D), b (k, m, kcls), n_iter (k, m),
-    converged (k, m), n_evals (k, m)).  `mesh` only keys the AOT executable
-    cache — the
-    row-sharded reductions compile to psums via GSPMD exactly like the
+    (the (N, D) x (D, k*m*kcls) product XLA builds from the lane einsum, and
+    its transpose under autodiff: two reads of X an evaluation), both at
+    SOLVER_PRECISION: float32 products, as the single fit's data term has
+    (ops/logistic_pass.py), so a sub-model is the model its own fit would
+    give and not that fit's bfloat16 shadow.  Per-lane convergence masks in
+    minimize_lbfgs_batched freeze finished lanes.  Returns (W (k, m, kcls,
+    D), b (k, m, kcls), n_iter (k, m), converged (k, m), n_evals (k, m),
+    n_scans ()): n_scans counts the evaluations the lanes shared
+    (ops/lbfgs.LbfgsResult).  `mesh` only keys the AOT executable cache:
+    the row-sharded reductions compile to psums via GSPMD exactly like the
     single-fit kernel's."""
     n, d = X.shape
     mb = regs.shape[0]
@@ -331,27 +346,29 @@ def sweep_logistic_fit_kernel(
     y01 = y_enc.astype(dtype)
     yidx = y_enc.astype(jnp.int32)
 
-    def value_and_grad(theta):  # (lanes, P) -> ((lanes,), (lanes, P))
+    def value_and_grad(theta):  # (T * lanes, P) -> ((T * lanes,), (T * lanes, P))
         def smooth(t):
-            tf = t.reshape(k_folds, mb, n_params)
-            W = tf[..., : kcls * d].reshape(k_folds, mb, kcls, d)
-            z = jnp.einsum("nd,fmkd->fmnk", X, W)
+            # T trial points a lane (the line search's steps, outermost): all
+            # of them ride the one contraction, so the one scan of X
+            tf = t.reshape(-1, k_folds, mb, n_params)
+            W = tf[..., : kcls * d].reshape(-1, k_folds, mb, kcls, d)
+            z = jnp.einsum("nd,tfmkd->tfmnk", X, W, precision=SOLVER_PRECISION)
             if fit_intercept:
-                z = z + tf[..., kcls * d :][:, :, None, :]
+                z = z + tf[..., kcls * d :][:, :, :, None, :]
             if kcls == 1:
-                zz = z[..., 0]  # (k, m, N)
-                ll = jnp.logaddexp(0.0, zz) - y01[None, None, :] * zz
+                zz = z[..., 0]  # (T, k, m, N)
+                ll = jnp.logaddexp(0.0, zz) - y01 * zz
             else:
                 logp = z - jax.scipy.special.logsumexp(
                     z, axis=-1, keepdims=True
                 )
                 idx = jnp.broadcast_to(
-                    yidx[None, None, :, None], (k_folds, mb, n, 1)
+                    yidx[None, None, None, :, None], z.shape[:-1] + (1,)
                 )
                 ll = -jnp.take_along_axis(logp, idx, axis=-1)[..., 0]
-            data = (ll * w_folds[:, None, :]).sum(axis=-1) / wsum_f[:, None]
-            reg_term = 0.5 * l2[None, :] * ((tf * reg_mask) ** 2).sum(axis=-1)
-            per_lane = (data + reg_term).reshape(lanes)
+            data = (ll * w_folds[None, :, None, :]).sum(axis=-1) / wsum_f[None, :, None]
+            reg_term = 0.5 * l2 * ((tf * reg_mask) ** 2).sum(axis=-1)
+            per_lane = (data + reg_term).reshape(-1)
             # lanes are independent in theta, so the grad of the SUM is the
             # stack of per-lane grads — one backward pass for the sweep
             return per_lane.sum(), per_lane
@@ -370,6 +387,7 @@ def sweep_logistic_fit_kernel(
         tol=tol,
         history=10,
         use_owlqn=use_owlqn,
+        trials=_line_search_trials(lanes * kcls),
     )
     W = result.x[:, : kcls * d].reshape(k_folds, mb, kcls, d)
     if fit_intercept:
@@ -382,7 +400,59 @@ def sweep_logistic_fit_kernel(
         result.n_iter.reshape(k_folds, mb),
         result.converged.reshape(k_folds, mb),
         result.n_evals.reshape(k_folds, mb),
+        result.n_scans,
     )
+
+
+@partial(jax.jit, static_argnames=("num_classes", "mesh"))
+def sweep_logistic_score_kernel(
+    X: jax.Array,
+    y_enc: jax.Array,
+    fold_id: jax.Array,
+    W: jax.Array,
+    b: jax.Array,
+    eps: jax.Array,
+    num_classes: int = 2,
+    mesh=None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Held-out statistics of every (fold, candidate) model of a sweep, from
+    ONE read of the staged table: the scoring half of a CrossValidator whose
+    rows live on the device (tuning.py).
+
+    W (k, m, kcls, D) and b (k, m, kcls) are sweep_logistic_fit_kernel's, still
+    on the device; row r is held out of fold fold_id[r] and of no other, so it
+    is scored by that fold's m models alone (padded rows carry -1: no fold).
+    One (N, D) x (D, k*m*kcls) product at SOLVER_PRECISION gives every lane's
+    scores, as the host route's batched product does
+    (LogisticRegressionModel._get_eval_predict_func); each row keeps its own
+    fold's.  Returns what metrics.MulticlassMetrics merges, per lane:
+    (confusion counts (k, m, C, C) int32, [true class, predicted class];
+    sum of -log max(P(true class), eps) (k, m)).  `mesh` only keys the
+    executable cache: the row reductions become psums via GSPMD."""
+    k_folds, mb, kcls, _ = W.shape
+    C = num_classes
+    z_all = jnp.einsum("nd,fmkd->nfmk", X, W, precision=SOLVER_PRECISION) + b[None]
+    own = fold_id[:, None] == jnp.arange(k_folds, dtype=fold_id.dtype)[None, :]
+    z = jnp.where(own[:, :, None, None], z_all, 0.0).sum(axis=1)  # (N, m, kcls)
+    yidx = y_enc.astype(jnp.int32)
+    if kcls == 1:
+        zz = z[..., 0]
+        ll = jnp.logaddexp(0.0, zz) - yidx[:, None].astype(zz.dtype) * zz
+        pred = (zz > 0).astype(jnp.int32)
+    else:
+        logp = z - jax.scipy.special.logsumexp(z, axis=-1, keepdims=True)
+        ll = -jnp.take_along_axis(
+            logp, jnp.broadcast_to(yidx[:, None, None], (z.shape[0], mb, 1)), axis=-1
+        )[..., 0]
+        pred = jnp.argmax(z, axis=-1).astype(jnp.int32)
+    ll = jnp.minimum(ll, -jnp.log(eps).astype(ll.dtype))  # P clamped at eps
+    loss = jnp.where(own[:, :, None], ll[:, None, :], 0.0).sum(axis=0)  # (k, m)
+    # a row's cell of its fold's confusion matrix, counted in whole numbers
+    cell = fold_id[:, None] * (C * C) + yidx[:, None] * C + pred  # (N, m)
+    cell = jnp.where(fold_id[:, None] >= 0, cell, -1)
+    cells = jnp.arange(k_folds * C * C, dtype=cell.dtype)
+    conf = (cell[:, :, None] == cells[None, None, :]).sum(axis=0, dtype=jnp.int32)
+    return conf.reshape(mb, k_folds, C, C).transpose(1, 0, 2, 3), loss
 
 
 @jax.jit

@@ -21,6 +21,12 @@
 # loop; live pyspark datasets keep it too (their folds live on the
 # cluster).
 #
+# A device-resident frame (DataFrame.from_device) has no host rows to split,
+# collect or upload: its sweep takes the batched route whatever the variable
+# says, and each model is scored on its fold's rows where the staged table
+# lies, under the fold ids its training mask came from (the estimator's
+# _fitBatchedSweep, given the evaluator, returns the metric partials).
+#
 
 from __future__ import annotations
 
@@ -241,13 +247,25 @@ class CrossValidator(_ValidatorParams):
         n_folds = self.getNumFolds()
         collect_sub = self.getCollectSubModels()
         single_pass = isinstance(est, _TpuEstimator) and est._supportsTransformEvaluate(eva)
-        if (
+        # a from_device frame's rows are on the device and nowhere else
+        on_device = getattr(dataset, "_device_features", None) is not None
+        batched = (
             datasets is None  # facade path: folds are ours to formulate
             and single_pass
-            and os.environ.get("SRML_SWEEP_BATCH", "1") != "0"
             and est._supportsBatchedSweep(dataset, epm, eva)
-        ):
-            return self._fit_batched(dataset, est, eva, epm)
+        )
+        if on_device and not batched:
+            raise ValueError(
+                "CrossValidator on a DataFrame.from_device frame scores the folds "
+                "where the table lies, which takes an estimator, a grid and an "
+                "evaluator the batched sweep carries (docs/tuning_engine.md: "
+                "LogisticRegression on a dense table, regParam / elasticNetParam, "
+                "MulticlassClassificationEvaluator); "
+                f"{type(est).__name__} with this grid and {type(eva).__name__} "
+                "needs a host frame"
+            )
+        if batched and (on_device or os.environ.get("SRML_SWEEP_BATCH", "1") != "0"):
+            return self._fit_batched(dataset, est, eva, epm, on_device)
         metrics_all: List[List[float]] = [[0.0] * num_models for _ in range(n_folds)]
         sub_models: Optional[List[List[_TpuModel]]] = (
             [[None] * num_models for _ in range(n_folds)] if collect_sub else None  # type: ignore[list-item]
@@ -300,22 +318,35 @@ class CrossValidator(_ValidatorParams):
         return self._finish(dataset, est, eva, epm, metrics_all, sub_models)
 
     def _fit_batched(
-        self, df: DataFrame, est: _TpuEstimator, eva: Any, epm: List[Dict[Param, Any]]
+        self,
+        df: DataFrame,
+        est: _TpuEstimator,
+        eva: Any,
+        epm: List[Dict[Param, Any]],
+        on_device: bool = False,
     ) -> "CrossValidatorModel":
         """srml-sweep route: one staged dataset, masked folds, lane-batched
         candidate solves — no per-fold thread pool, so the CPU-backend fold
         lock never serializes this path.  Scoring reuses the sequential
         path's fold frames and mergeable metric machinery per (fold,
-        candidate), which is what the equality gates lean on."""
+        candidate), which is what the equality gates lean on; `on_device`
+        (a from_device frame) has the estimator reduce the same metric
+        partials on the device instead, under the staged fold ids.
+
+        A fit's step spans tile the sweep as they tile a fit (srml.prepare
+        and srml.finish here, the others in the estimator's
+        _fitBatchedSweep), so a profiler trace names the host's part of
+        every gap the device idles in."""
         from . import profiling, watch
 
         n_folds = self.getNumFolds()
         num_models = len(epm)
         seed = self.getOrDefault("seed")
-        counters0 = profiling.counters()
-        profiling.reset_phase_times()
         tag = f"sweep-{type(est).__name__}"
         with watch.flight_scope(tag), profiling.trace_session(tag):
+            with profiling.span("srml.prepare"):
+                counters0 = profiling.counters()
+                profiling.reset_phase_times()
             with profiling.span(
                 "tuning.sweep",
                 estimator=type(est).__name__,
@@ -324,22 +355,38 @@ class CrossValidator(_ValidatorParams):
             ):
                 profiling.incr_counter("tuning.candidates", num_models)
                 profiling.incr_counter("tuning.folds", n_folds)
-                fold_results = est._fitBatchedSweep(df, epm, n_folds, seed)
-                fold_models = _materialize_sweep_models(est, fold_results, epm)
-                with profiling.span("tuning.sweep.score"):
-                    metrics_all = []
-                    for fold, (_train, valid) in enumerate(self._kFold(df)):
-                        combined = fold_models[fold][0]._combine(
-                            fold_models[fold]
+                fold_results, held_out = est._fitBatchedSweep(
+                    df, epm, n_folds, seed, eva if on_device else None
+                )
+                with profiling.span("srml.fit.pack"):
+                    fold_models = _materialize_sweep_models(est, fold_results, epm)
+                if held_out is not None:
+                    metrics_all = [[m.evaluate(eva) for m in ms] for ms in held_out]
+                else:
+                    with profiling.span("tuning.sweep.score"):
+                        metrics_all = []
+                        for fold, (_train, valid) in enumerate(self._kFold(df)):
+                            combined = fold_models[fold][0]._combine(
+                                fold_models[fold]
+                            )
+                            metrics_all.append(
+                                combined._transformEvaluate(valid, eva)
+                            )
+                        # every row went through pandas into a fold's frame and
+                        # up to the device again, at the models' width and dtype
+                        one = fold_results[0][0]
+                        row_bytes = one["n_cols"] * np.dtype(one["dtype"]).itemsize
+                        rows = df.count()
+                        profiling.incr_counter("tuning.score.rows", rows)
+                        profiling.incr_counter(
+                            "tuning.score.h2d_bytes", rows * row_bytes
                         )
-                        metrics_all.append(
-                            combined._transformEvaluate(valid, eva)
-                        )
-        self._last_fit_phase_times = profiling.phase_times()
-        snap = profiling.TelemetrySnapshot.capture(counters0, rank=0)
-        for models in fold_models:
-            for m in models:
-                m._fit_telemetry = snap
+            with profiling.span("srml.finish"):
+                self._last_fit_phase_times = profiling.phase_times()
+                snap = profiling.TelemetrySnapshot.capture(counters0, rank=0)
+                for models in fold_models:
+                    for m in models:
+                        m._fit_telemetry = snap
         self.logger.info(
             "batched sweep: %d candidates x %d folds over one staged dataset",
             num_models,
@@ -365,7 +412,10 @@ class CrossValidator(_ValidatorParams):
         self.logger.info(
             "CV avg metrics: %s; best param map index: %d", avg.tolist(), best_index
         )
-        best_model = est.fit(dataset, epm[best_index])
+        from . import profiling
+
+        with profiling.span("tuning.refit", index=best_index):
+            best_model = est.fit(dataset, epm[best_index])
         cv_model = CrossValidatorModel(
             bestModel=best_model,
             avgMetrics=avg.tolist(),
