@@ -25,6 +25,7 @@
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import threading
@@ -72,16 +73,51 @@ def _tree_nbytes(tree: Any) -> int:
     return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
 
 
-def _device_put_counted(put: Callable[[], Any]) -> Any:
+def _device_put_counted(put: Callable[[], Any], sent: Any = None) -> Any:
     """Every host→device copy of ingest goes through here: `put()` runs
     inside a srml.device_put span that carries the bytes that went up, and
-    the process-wide counter ingest.h2d_bytes adds them up per fit."""
+    the process-wide counter ingest.h2d_bytes adds them up per fit.  The
+    bytes are the result's, or those of `sent` where the result is not what
+    this process sent (a rank's share of a global array)."""
     with profiling.span("srml.device_put") as sp:
         out = put()
-        nbytes = _tree_nbytes(out)
+        nbytes = _tree_nbytes(out if sent is None else sent)
         sp.set(bytes=nbytes)
     profiling.incr_counter("ingest.h2d_bytes", nbytes)
     return out
+
+
+def _label_dtype(dtype: Any) -> np.dtype:
+    """dtype of the O(N) label and weight vectors: the features', but at
+    least float32.  A low-precision FEATURE dtype (float32_inputs=False with
+    f16/bf16 features, a bf16 from_device table) must not round them:
+    integer class labels above the half-precision mantissa are not exact and
+    would silently corrupt label discovery and the training targets."""
+    dtype = np.dtype(dtype)
+    return np.dtype(np.float32) if dtype.itemsize < 4 else dtype
+
+
+def stage_mask_and_labels(
+    n_rows: int,
+    n_pad: int,
+    dtype: Any,
+    labels: Optional[np.ndarray],
+    weights: Optional[np.ndarray],
+    put: Callable[[np.ndarray], Any],
+) -> Tuple[Any, Any]:
+    """The tail of every ingest: the row mask (the user's weight on the
+    `n_rows` valid rows, 1 without a weightCol, 0 on the padding up to
+    `n_pad`) and the labels padded alike (None unsupervised), each sent up
+    by `put(buffer)` as a counted copy."""
+    ldtype = _label_dtype(dtype)
+
+    def staged(values: Any) -> Any:
+        buf = np.zeros(n_pad, dtype=ldtype)
+        buf[:n_rows] = values
+        return _device_put_counted(lambda: put(buf), sent=buf)
+
+    ws = staged(1 if weights is None else weights)
+    return ws, (staged(labels) if labels is not None else None)
 
 
 def fetch_fit_result(tree: Any) -> Any:
@@ -181,8 +217,6 @@ class FitInputs:
     mesh: Any
     pdesc: PartitionDescriptor
     dtype: np.dtype
-    row_id: Optional[np.ndarray] = None   # original row numbers (host, unpadded)
-    extra_cols: Dict[str, np.ndarray] = field(default_factory=dict)
     # host copies of the (unpadded) labels/weights when ingest had them —
     # single-controller label discovery reads these instead of round-
     # tripping the device label shards over the host link per fit
@@ -282,6 +316,97 @@ FitFunc = Callable[[FitInputs, Dict[str, Any]], Union[Dict[str, Any], List[Dict[
 TransformFunc = Callable[[np.ndarray], Dict[str, Any]]
 
 
+class FitJob:
+    """One fit job on the calling thread: the one place that knows what makes
+    a job observable and safe.  Every launcher enters it through fit_job():
+    the driver-local fit below, the executor's (parallel/runner) and the
+    batched sweep (tuning.CrossValidator._fit_batched).  Its step spans tile
+    the job, so a profiler trace names what the host was doing in every gap
+    the device idles in: srml.prepare, srml.ingest, srml.fit (tiled in turn
+    by the fit function's init, solve, wait, fetch, pack; a sweep runs those
+    under tuning.sweep and has no outer srml.fit) and srml.finish."""
+
+    def __init__(self, estimator: Any, rank: int) -> None:
+        self.estimator, self.rank = estimator, rank
+        self.baseline: Dict[str, int] = {}
+        # finish() leaves these for the launcher to attach to what it returns
+        self.phase_times: Dict[str, float] = {}
+        self.snapshot: Optional[profiling.TelemetrySnapshot] = None
+
+    @contextlib.contextmanager
+    def prepare(self) -> Iterator[None]:
+        """srml.prepare: the compile cache, phase times from zero, the counter
+        baseline; the launcher's own resolving (the body); then srml-shield's
+        runner.fit injection site, so a fault plan written against the site
+        name covers whichever launcher ran the fit (action=die is the chaos
+        matrix's "rank killed mid-fit", action=raise exercises the
+        abort-marker broadcast in TpuContext)."""
+        from .ops.precompile import ensure_compile_cache
+        from .parallel import faults
+
+        with profiling.span("srml.prepare"):
+            ensure_compile_cache()
+            profiling.reset_phase_times()
+            self.baseline = profiling.counters()
+            yield
+            faults.site("runner.fit", rank=self.rank)
+
+    @contextlib.contextmanager
+    def staged(self, build: Callable[[Callable[[Any], None]], FitInputs]) -> Iterator[FitInputs]:
+        """srml.ingest around `build(x64)`, then its FitInputs for the solver's
+        part of the job (the body), which runs under sanitize_scope.
+
+        float64 fits genuinely run in float64 (reference core.py:363-401
+        keeps f64 end-to-end): without x64, jax.device_put silently
+        canonicalizes f64 -> f32.  The x64 scope must cover BOTH the uploads
+        and the solver (trace-time dtypes), so the builder calls `x64(dtype)`
+        when it knows the dtype (a rank without rows learns it from the
+        others) and the scope lasts to the end of the body; it recompiles
+        the kernels for f64, which TPUs execute via (slower) emulation."""
+        from .sanitize import sanitize_scope
+
+        with contextlib.ExitStack() as scope:
+            with profiling.span("srml.ingest"):
+                inputs = build(lambda dtype: scope.enter_context(_maybe_x64(dtype)))
+                get_logger(type(self.estimator)).info(
+                    "Invoking TPU fit: %d rows x %d cols on %d-device mesh",
+                    inputs.n_rows, inputs.n_cols, inputs.mesh.devices.size,
+                )
+            with sanitize_scope():
+                yield inputs
+
+    def run(self, build: Callable[..., FitInputs], fit_func: FitFunc, params: Dict[str, Any]) -> Any:
+        """The two middle steps of a fit, in the order every launcher runs
+        them: srml.ingest, then srml.fit around the fit function."""
+        with self.staged(build) as inputs, profiling.span("srml.fit"):
+            return fit_func(inputs, params)
+
+    @contextlib.contextmanager
+    def finish(self) -> Iterator[None]:
+        """srml.finish: the job's phase seconds and its TelemetrySnapshot (the
+        counters' deltas since prepare), then the launcher's packing of what
+        it returns (the body)."""
+        with profiling.span("srml.finish"):
+            self.phase_times = profiling.phase_times()
+            self.snapshot = profiling.TelemetrySnapshot.capture(self.baseline, rank=self.rank)
+            yield
+
+
+@contextlib.contextmanager
+def fit_job(estimator: Any, kind: str = "fit", rank: Optional[int] = None) -> Iterator[FitJob]:
+    """A FitJob for `estimator`, tagged <kind>-<Estimator>[-rank<r>].
+    watch.flight_scope: an unhandled exception anywhere in the job dumps the
+    always-on flight ring (with the innermost failing span) to SRML_TRACE_DIR
+    before propagating — the crash-time counterpart of the trace session,
+    which only exports on success.  SRML_PROFILE captures <dir>/<Estimator>."""
+    from . import watch
+
+    name = type(estimator).__name__
+    tag = f"{kind}-{name}" + ("" if rank is None else f"-rank{rank}")
+    with watch.flight_scope(tag), profiling.trace_session(tag), profiling.maybe_trace(name):
+        yield FitJob(estimator, rank or 0)
+
+
 class _TpuCaller(_TpuParams):
     """Shared ingest + fit-dispatch (reference _CumlCaller core.py:327-647)."""
 
@@ -341,6 +466,12 @@ class _TpuCaller(_TpuParams):
             return self.getOrDefault("labelCol")
         return None
 
+    def _fit_weight_col(self) -> Optional[str]:
+        """Column the row weights come from, or None (unset: every row 1)."""
+        if self.hasParam("weightCol") and self.isSet("weightCol"):
+            return self.getOrDefault("weightCol")
+        return None
+
     def _pre_process_data(
         self, df: DataFrame
     ) -> Tuple[List[np.ndarray], Optional[List[np.ndarray]], Optional[List[np.ndarray]], np.dtype]:
@@ -348,23 +479,11 @@ class _TpuCaller(_TpuParams):
         casting (reference core.py:344-422 + supervised label cast :918-952)."""
         input_col, input_cols = self._get_input_columns()
         dtype = self._use_dtype(df, input_col, input_cols)
-        feats, labels, weights = [], None, None
-        label_col = self._fit_label_col()
-        weight_col = (
-            self.getOrDefault("weightCol")
-            if self.hasParam("weightCol") and self.isSet("weightCol")
-            else None
-        )
-        if label_col is not None:
-            labels = []
-        if weight_col is not None:
-            weights = []
-        # labels/weights extract at >= float32 regardless of a low-precision
-        # FEATURE dtype (float32_inputs=False + f16/bf16 features): integer
-        # class labels above the half-precision mantissa are not exact and
-        # would silently corrupt label discovery — same rule as the
-        # from_device path (_build_fit_inputs_device)
-        ldtype = np.dtype(np.float32) if np.dtype(dtype).itemsize < 4 else dtype
+        label_col, weight_col = self._fit_label_col(), self._fit_weight_col()
+        feats: List[np.ndarray] = []
+        labels = [] if label_col is not None else None
+        weights = [] if weight_col is not None else None
+        ldtype = _label_dtype(dtype)
         for part in df.partitions:
             feats.append(self._extract_partition_features(part, input_col, input_cols, dtype))
             if labels is not None:
@@ -374,12 +493,17 @@ class _TpuCaller(_TpuParams):
         return feats, labels, weights, dtype
 
     def _build_fit_inputs(
-        self, df: DataFrame, keep_row_id: bool = False
+        self, df: DataFrame, x64: Callable[[Any], None] = lambda dtype: None
     ) -> FitInputs:
+        """The frame's rows as FitInputs on this process's mesh.  `x64` is
+        FitJob.staged's: called with the dtype the fit computes in as soon
+        as it is known, before anything goes up."""
         dev = getattr(df, "_device_features", None)
         if dev is not None:
-            return self._build_fit_inputs_device(df, dev, keep_row_id)
+            x64(dev[0].dtype)
+            return self._build_fit_inputs_device(df, dev)
         feats, labels, weights, dtype = self._pre_process_data(df)
+        x64(dtype)
         partition_rows = [f.shape[0] for f in feats]
         nonempty = [f for f in feats if f.shape[0] > 0]
         if not nonempty:
@@ -449,25 +573,12 @@ class _TpuCaller(_TpuParams):
                     cache_key,
                     (Xs, n_rows, n_cols, list(nonempty)),
                 )
-        n_pad = Xs.shape[0]
-        # >= float32 for the O(N) label/weight vectors (see _pre_process_data)
-        ldtype = np.dtype(np.float32) if np.dtype(dtype).itemsize < 4 else dtype
         y_np = np.concatenate(labels) if labels is not None else None
-        w_np = (
-            np.concatenate(weights)
-            if weights is not None
-            else np.ones(n_rows, dtype=ldtype)
+        w_np = np.concatenate(weights) if weights is not None else None
+        ws, ys = stage_mask_and_labels(
+            n_rows, Xs.shape[0], dtype, y_np, w_np,
+            lambda buf: jax.device_put(buf, data_sharding(mesh)),
         )
-        mask = np.zeros(n_pad, dtype=ldtype)
-        mask[:n_rows] = w_np
-        ws = _device_put_counted(lambda: jax.device_put(mask, data_sharding(mesh)))
-        ys = None
-        if y_np is not None:
-            y_pad = np.zeros(n_pad, dtype=ldtype)
-            y_pad[:n_rows] = y_np
-            ys = _device_put_counted(
-                lambda: jax.device_put(y_pad, data_sharding(mesh))
-            )
         pdesc = PartitionDescriptor.build(partition_rows, n_cols)
         return FitInputs(
             X=Xs,
@@ -478,14 +589,11 @@ class _TpuCaller(_TpuParams):
             mesh=mesh,
             pdesc=pdesc,
             dtype=dtype,
-            row_id=np.arange(n_rows) if keep_row_id else None,
             host_y=y_np,
-            host_w=w_np if weights is not None else None,
+            host_w=w_np,
         )
 
-    def _build_fit_inputs_device(
-        self, df: DataFrame, dev: Any, keep_row_id: bool
-    ) -> FitInputs:
+    def _build_fit_inputs_device(self, df: DataFrame, dev: Any) -> FitInputs:
         """FitInputs straight from a DataFrame.from_device feature array:
         no feature extraction, no upload.  Labels/weights still come from
         the (host) partitions; padded rows are masked through the weight
@@ -496,46 +604,25 @@ class _TpuCaller(_TpuParams):
         Xs, n_rows, n_cols, _fcol = dev
         dtype = np.dtype(Xs.dtype)
         mesh = get_mesh(self.num_workers)
-        n_pad = Xs.shape[0]
-        label_col = self._fit_label_col()
-        weight_col = (
-            self.getOrDefault("weightCol")
-            if self.hasParam("weightCol") and self.isSet("weightCol")
-            else None
-        )
-        cache_key = (label_col, weight_col, id(mesh), bool(keep_row_id))
+        label_col, weight_col = self._fit_label_col(), self._fit_weight_col()
+        cache_key = (label_col, weight_col, id(mesh))
         cached = getattr(df, "_device_fit_inputs", None)
         if cached is not None and cached[0] == cache_key:
             return cached[1]
-        # labels/weights are O(N) scalars — always at least float32: a
-        # bf16 from_device FEATURE array must not round them (integer
-        # class labels above 256 are not exact in bf16, silently
-        # corrupting label discovery and training targets)
-        ldtype = np.dtype(np.float32) if dtype.itemsize < 4 else dtype
-        w_np = np.ones(n_rows, dtype=ldtype)
-        if weight_col is not None:
-            w_np = np.concatenate(
-                [
-                    np.asarray(p[weight_col].to_numpy(), dtype=ldtype)
-                    for p in df.partitions
-                ]
+        ldtype = _label_dtype(dtype)
+
+        def column(name: Optional[str]) -> Optional[np.ndarray]:
+            if name is None:
+                return None
+            return np.concatenate(
+                [np.asarray(p[name].to_numpy(), dtype=ldtype) for p in df.partitions]
             )
-        mask = np.zeros(n_pad, dtype=ldtype)
-        mask[:n_rows] = w_np
-        ws = _device_put_counted(lambda: jax.device_put(mask, data_sharding(mesh)))
-        ys = None
-        if label_col is not None:
-            y_np = np.concatenate(
-                [
-                    np.asarray(p[label_col].to_numpy(), dtype=ldtype)
-                    for p in df.partitions
-                ]
-            )
-            y_pad = np.zeros(n_pad, dtype=ldtype)
-            y_pad[:n_rows] = y_np
-            ys = _device_put_counted(
-                lambda: jax.device_put(y_pad, data_sharding(mesh))
-            )
+
+        y_np, w_np = column(label_col), column(weight_col)
+        ws, ys = stage_mask_and_labels(
+            n_rows, Xs.shape[0], dtype, y_np, w_np,
+            lambda buf: jax.device_put(buf, data_sharding(mesh)),
+        )
         inputs = FitInputs(
             X=Xs,
             weight=ws,
@@ -545,9 +632,8 @@ class _TpuCaller(_TpuParams):
             mesh=mesh,
             pdesc=PartitionDescriptor.build([n_rows], n_cols),
             dtype=dtype,
-            row_id=np.arange(n_rows) if keep_row_id else None,
-            host_y=y_np if label_col is not None else None,
-            host_w=w_np if weight_col is not None else None,
+            host_y=y_np,
+            host_w=w_np,
         )
         df._device_fit_inputs = (cache_key, inputs)
         return inputs
@@ -594,71 +680,27 @@ class _TpuCaller(_TpuParams):
                 else {}
             )
             return finish(results if paramMaps is not None else results[0])
-        from . import watch
-
-        # Driver-local path.  Four step spans tile the job on this thread,
-        # from here to the return: srml.prepare, srml.ingest, srml.fit (whose
-        # sub-spans the estimator's fit function opens: init, solve, wait,
-        # fetch, pack) and srml.finish.  A profiler trace so names what the
-        # host was doing in every gap the device idles in.
-        # watch.flight_scope: an unhandled exception anywhere in the fit
-        # dumps the always-on flight ring (with the innermost failing span)
-        # to SRML_TRACE_DIR before propagating — the crash-time counterpart
-        # of the trace session, which only exports on success
-        tag = f"fit-{type(self).__name__}"
-        with watch.flight_scope(tag), profiling.trace_session(
-            tag
-        ), contextlib.ExitStack() as scopes:
-            with profiling.span("srml.prepare"):
-                from .ops.precompile import ensure_compile_cache
-                from .parallel import faults
-                from .sanitize import sanitize_scope
-
-                ensure_compile_cache()
-                profiling.reset_phase_times()
-                counters0 = profiling.counters()
+        with fit_job(self) as job:
+            with job.prepare():
                 df = as_dataframe(dataset)
                 self._validate_parameters(df)
-                input_col, input_cols = self._get_input_columns()
                 extra_params = None
                 if paramMaps is not None:
                     extra_params = [
                         self._paramMap_to_tpu_overrides(pm) for pm in paramMaps
                     ]
                 fit_func = self._get_tpu_fit_func(df, extra_params)
-                # float64 fits genuinely run in float64 (reference
-                # core.py:363-401 keeps f64 end-to-end): without x64,
-                # jax.device_put silently canonicalizes f64 -> f32.  The x64
-                # scope must cover BOTH ingest (device_put) and the fit
-                # (trace-time dtypes); it recompiles the kernels for f64,
-                # which TPUs execute via (slower) emulation.
-                scopes.enter_context(
-                    _maybe_x64(self._use_dtype(df, input_col, input_cols))
-                )
-                scopes.enter_context(profiling.maybe_trace(type(self).__name__))
-                # srml-shield: the runner.fit injection site fires on BOTH
-                # fit paths — here (driver-local) and in parallel/runner.fit
-                # (the barrier task) — so a fault plan written against the
-                # site name covers whichever launcher ran the fit
-                faults.site("runner.fit", rank=0)
-            with profiling.span("srml.ingest"):
-                inputs = self._build_fit_inputs(df)
-                get_logger(type(self)).info(
-                    "Invoking TPU fit: %d rows x %d cols on %d-device mesh",
-                    inputs.n_rows, inputs.n_cols, inputs.mesh.devices.size,
-                )
-            with profiling.span("srml.fit"), sanitize_scope():
-                result = fit_func(inputs, dict(self._tpu_params))
-            with profiling.span("srml.finish"):
-                self._last_fit_phase_times = profiling.phase_times()
+            build = functools.partial(self._build_fit_inputs, df)
+            result = job.run(build, fit_func, dict(self._tpu_params))
+            with job.finish():
+                self._last_fit_phase_times = job.phase_times
                 # telemetry rides the SAME attribute dicts the executor path
                 # ships, so _fit_internal attaches model.fit_telemetry()
                 # uniformly (the snapshot is shared across a single-pass
                 # multi-model fit — one data load, one solver pass, one set
                 # of phase timers)
-                snap = profiling.TelemetrySnapshot.capture(counters0, rank=0)
                 for r in result if isinstance(result, list) else [result]:
-                    r[TELEMETRY_ATTR] = snap.to_dict()
+                    r[TELEMETRY_ATTR] = job.snapshot.to_dict()
                 return finish(result)
 
     def _paramMap_to_tpu_overrides(self, paramMap: Dict[Param, Any]) -> Dict[str, Any]:
@@ -823,7 +865,7 @@ class _TpuEstimator(_TpuCaller):
 
     def _fitBatchedSweep(
         self,
-        df: DataFrame,
+        inputs: FitInputs,
         paramMaps: List[Dict[Param, Any]],
         n_folds: int,
         seed: int,

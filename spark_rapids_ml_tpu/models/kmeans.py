@@ -159,7 +159,7 @@ class KMeans(_KMeansParams, _TpuEstimator):
         logger = get_logger(type(self))
 
         def _fit(inputs: FitInputs, params: Dict[str, Any]):
-            # the step spans below tile srml.fit (core._call_tpu_fit_func)
+            # the step spans below tile srml.fit (core.FitJob.run)
             with profiling.span("srml.fit.init"):
                 k = int(params["n_clusters"])
                 seed = int(params["random_state"]) & 0x7FFFFFFF

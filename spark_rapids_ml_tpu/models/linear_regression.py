@@ -331,7 +331,7 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
             return coef, n_iter
 
         def _fit(inputs: FitInputs, params: Dict[str, Any]):
-            # the step spans below tile srml.fit (core._call_tpu_fit_func)
+            # the step spans below tile srml.fit (core.FitJob.run)
             with profiling.span("srml.fit.init"):
                 assert inputs.y is not None
                 from ..ops.sparse import EllMatrix, ell_sufficient_stats
@@ -414,16 +414,13 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
             return False
         return not self._sweep_sparse_input(df)
 
-    def _fitBatchedSweep(self, df, paramMaps, n_folds, seed, evaluator=None):
+    def _fitBatchedSweep(self, inputs, paramMaps, n_folds, seed, evaluator=None):
         """All n_folds x len(paramMaps) linreg fits as a fused masked-fold
         stats pass + one stacked-lane solve dispatch per solver family over
         the ONE staged dataset (ops/glm.py sweep kernels; exact-equality
         contract in docs/tuning_engine.md)."""
-        from ..core import _maybe_x64
         from ..ops import sweep as sweep_ops
-        from ..sanitize import sanitize_scope
 
-        input_col, input_cols = self._get_input_columns()
         params = dict(self._tpu_params)
         cand = []
         for pm in paramMaps:
@@ -437,129 +434,117 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
         # form when the L1 term vanishes, covariance-update CD otherwise
         closed = [i for i, (a, l1r) in enumerate(cand) if a == 0.0 or l1r == 0.0]
         cd = [i for i in range(len(cand)) if i not in closed]
-        with _maybe_x64(self._use_dtype(df, input_col, input_cols)):
-            with profiling.phase("srml.ingest"):
-                inputs = self._build_fit_inputs(df)
-            assert inputs.y is not None
-            mesh = inputs.mesh
-            fid = sweep_ops.stage_fold_ids(
-                inputs.n_rows, inputs.X.shape[0], n_folds, seed, mesh
+        assert inputs.y is not None
+        mesh = inputs.mesh
+        fid = sweep_ops.stage_fold_ids(
+            inputs.n_rows, inputs.X.shape[0], n_folds, seed, mesh
+        )
+        # warm the solve kernels at sweep entry: their lowerings are
+        # known from shapes alone (stacked stats are mesh-replicated),
+        # so they compile on the pool WHILE the stats pass runs
+        compute_dt = np.dtype(inputs.dtype)
+        if compute_dt in (np.dtype(np.float32), np.dtype(np.float64)):
+            d = inputs.n_cols
+            aval = lambda shape: sweep_ops.replicated_aval(  # noqa: E731
+                shape, compute_dt, mesh
             )
-            # warm the solve kernels at sweep entry: their lowerings are
-            # known from shapes alone (stacked stats are mesh-replicated),
-            # so they compile on the pool WHILE the stats pass runs
-            compute_dt = np.dtype(inputs.dtype)
-            if compute_dt in (np.dtype(np.float32), np.dtype(np.float64)):
-                d = inputs.n_cols
-                aval = lambda shape: sweep_ops.replicated_aval(  # noqa: E731
-                    shape, compute_dt, mesh
+            from ..ops.glm import LinregStats
+
+            stats_avals = LinregStats(
+                wsum=aval((n_folds,)),
+                x_mean=aval((n_folds, d)),
+                y_mean=aval((n_folds,)),
+                G=aval((n_folds, d, d)),
+                c=aval((n_folds, d)),
+                y2=aval((n_folds,)),
+            )
+            entries = []
+            if closed:
+                mb = sweep_ops.candidate_bucket(len(closed))
+                entries.append(
+                    (
+                        "sweep.linreg.solve",
+                        sweep_solve_linear,
+                        (stats_avals, aval((mb,))),
+                        dict(statics),
+                    )
                 )
-                from ..ops.glm import LinregStats
-
-                stats_avals = LinregStats(
-                    wsum=aval((n_folds,)),
-                    x_mean=aval((n_folds, d)),
-                    y_mean=aval((n_folds,)),
-                    G=aval((n_folds, d, d)),
-                    c=aval((n_folds, d)),
-                    y2=aval((n_folds,)),
+            if cd:
+                mb = sweep_ops.candidate_bucket(len(cd))
+                entries.append(
+                    (
+                        "sweep.linreg.cd",
+                        sweep_solve_elasticnet_cd,
+                        (stats_avals, aval((mb,)), aval((mb,)), aval(())),
+                        dict(statics, max_iter=int(params["max_iter"])),
+                    )
                 )
-                entries = []
-                if closed:
-                    mb = sweep_ops.candidate_bucket(len(closed))
-                    entries.append(
-                        (
-                            "sweep.linreg.solve",
-                            sweep_solve_linear,
-                            (stats_avals, aval((mb,))),
-                            dict(statics),
-                        )
-                    )
-                if cd:
-                    mb = sweep_ops.candidate_bucket(len(cd))
-                    entries.append(
-                        (
-                            "sweep.linreg.cd",
-                            sweep_solve_elasticnet_cd,
-                            (stats_avals, aval((mb,)), aval((mb,)), aval(())),
-                            dict(statics, max_iter=int(params["max_iter"])),
-                        )
-                    )
-                sweep_ops.warm(entries, mesh=mesh)
-            with sanitize_scope():
-                with profiling.span(
-                    "tuning.sweep.stats", folds=n_folds, rows=inputs.n_rows
-                ):
-                    stats = sweep_ops.dispatch(
-                        "sweep.linreg.stats",
-                        sweep_linreg_fold_stats,
-                        inputs.X,
-                        inputs.y,
-                        inputs.weight,
-                        fid,
-                        mesh=mesh,
-                        k=n_folds,
-                    )
-                results: List[List[Dict[str, Any]]] = [
-                    [None] * len(cand) for _ in range(n_folds)  # type: ignore[list-item]
-                ]
-                xm_h, ym_h = jax.device_get((stats.x_mean, stats.y_mean))
+            sweep_ops.warm(entries, mesh=mesh)
+        with profiling.span("tuning.sweep.stats", folds=n_folds, rows=inputs.n_rows):
+            stats = sweep_ops.dispatch(
+                "sweep.linreg.stats",
+                sweep_linreg_fold_stats,
+                inputs.X,
+                inputs.y,
+                inputs.weight,
+                fid,
+                mesh=mesh,
+                k=n_folds,
+            )
+        results: List[List[Dict[str, Any]]] = [
+            [None] * len(cand) for _ in range(n_folds)  # type: ignore[list-item]
+        ]
+        xm_h, ym_h = jax.device_get((stats.x_mean, stats.y_mean))
 
-                def _collect(idxs, coef_h, n_iter_h=None):
-                    for j, i in enumerate(idxs):
-                        for f in range(n_folds):
-                            coef64 = np.asarray(coef_h[f, j], dtype=np.float64)
-                            results[f][i] = {
-                                "coef_": coef64,
-                                # same host float64 derivation as _single_fit
-                                # (see _host_intercept): bit-equal across the
-                                # batched and sequential routes
-                                "intercept_": _host_intercept(
-                                    coef64, xm_h[f], ym_h[f], fit_intercept
-                                ),
-                                "n_cols": inputs.n_cols,
-                                "dtype": str(inputs.dtype),
-                            }
-                    if n_iter_h is not None:
-                        get_logger(type(self)).info(
-                            "sweep CD sweeps (fold x candidate): %s",
-                            np.asarray(n_iter_h)[:, : len(idxs)].tolist(),
-                        )
+        def _collect(idxs, coef_h, n_iter_h=None):
+            for j, i in enumerate(idxs):
+                for f in range(n_folds):
+                    coef64 = np.asarray(coef_h[f, j], dtype=np.float64)
+                    results[f][i] = {
+                        "coef_": coef64,
+                        # same host float64 derivation as _single_fit
+                        # (see _host_intercept): bit-equal across the
+                        # batched and sequential routes
+                        "intercept_": _host_intercept(
+                            coef64, xm_h[f], ym_h[f], fit_intercept
+                        ),
+                        "n_cols": inputs.n_cols,
+                        "dtype": str(inputs.dtype),
+                    }
+            if n_iter_h is not None:
+                get_logger(type(self)).info(
+                    "sweep CD sweeps (fold x candidate): %s",
+                    np.asarray(n_iter_h)[:, : len(idxs)].tolist(),
+                )
 
-                with profiling.span(
-                    "tuning.sweep.solve", candidates=len(cand), folds=n_folds
-                ):
-                    if closed:
-                        _, (alphas,) = sweep_ops.pack_lane_subset(cand, closed)
-                        coef, _ = sweep_ops.dispatch(
-                            "sweep.linreg.solve",
-                            sweep_solve_linear,
-                            stats,
-                            alphas,
-                            mesh=mesh,
-                            **statics,
-                        )
-                        _collect(closed, jax.device_get(coef))
-                    if cd:
-                        _, (alphas, l1s) = sweep_ops.pack_lane_subset(
-                            cand, cd, fields=(0, 1)
-                        )
-                        tol = jax.numpy.asarray(
-                            np.float64(float(params["tol"]))
-                        )
-                        coef, _, n_iter = sweep_ops.dispatch(
-                            "sweep.linreg.cd",
-                            sweep_solve_elasticnet_cd,
-                            stats,
-                            alphas,
-                            l1s,
-                            tol,
-                            mesh=mesh,
-                            max_iter=int(params["max_iter"]),
-                            **statics,
-                        )
-                        coef_h, n_iter_h = jax.device_get((coef, n_iter))
-                        _collect(cd, coef_h, n_iter_h)
+        with profiling.span("tuning.sweep.solve", candidates=len(cand), folds=n_folds):
+            if closed:
+                _, (alphas,) = sweep_ops.pack_lane_subset(cand, closed)
+                coef, _ = sweep_ops.dispatch(
+                    "sweep.linreg.solve",
+                    sweep_solve_linear,
+                    stats,
+                    alphas,
+                    mesh=mesh,
+                    **statics,
+                )
+                _collect(closed, jax.device_get(coef))
+            if cd:
+                _, (alphas, l1s) = sweep_ops.pack_lane_subset(cand, cd, fields=(0, 1))
+                tol = jax.numpy.asarray(np.float64(float(params["tol"])))
+                coef, _, n_iter = sweep_ops.dispatch(
+                    "sweep.linreg.cd",
+                    sweep_solve_elasticnet_cd,
+                    stats,
+                    alphas,
+                    l1s,
+                    tol,
+                    mesh=mesh,
+                    max_iter=int(params["max_iter"]),
+                    **statics,
+                )
+                coef_h, n_iter_h = jax.device_get((coef, n_iter))
+                _collect(cd, coef_h, n_iter_h)
         return results, None
 
 

@@ -385,7 +385,7 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
             from ..ops.labels import encode_labels_kernel
 
             # the step spans here and in _single_fit tile srml.fit
-            # (core._call_tpu_fit_func)
+            # (core.FitJob.run)
             with profiling.span("srml.fit.init"):
                 assert inputs.y is not None
                 classes = discover_label_classes(inputs)
@@ -440,7 +440,7 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
             return False  # only the regularizer axes batch as lanes
         return not self._sweep_sparse_input(df)
 
-    def _fitBatchedSweep(self, df, paramMaps, n_folds, seed, evaluator=None):
+    def _fitBatchedSweep(self, inputs, paramMaps, n_folds, seed, evaluator=None):
         """All n_folds x len(paramMaps) logreg fits as ONE lane-batched
         L-BFGS/OWL-QN run per penalty family over the ONE staged dataset —
         folds as fold-id weight masks, candidates as traced reg/l1 lanes
@@ -451,13 +451,12 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
         (ops/logistic.sweep_logistic_score_kernel): no row, no label, no
         fold id and no coefficient goes up for it.
 
-        The step spans of a fit tile a sweep too (srml.ingest, srml.fit.init,
+        The step spans of a fit function tile a sweep too (srml.fit.init,
         .solve, .wait, .fetch, .pack), inside tuning.sweep.solve and
         tuning.sweep.score where they belong to one of the two."""
         from ..core import discover_label_classes
         from ..ops import sweep as sweep_ops
         from ..ops.labels import encode_labels_kernel
-        from ..sanitize import sanitize_scope
 
         params = dict(self._tpu_params)
         cand = []
@@ -470,9 +469,7 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
             cand.append((reg, l1_ratio, reg > 0 and l1_ratio > 0))
         fit_intercept = bool(params["fit_intercept"])
         max_iter = int(params["max_iter"])
-        with profiling.span("srml.ingest"):
-            inputs = self._build_fit_inputs(df)
-        with profiling.span("srml.fit.init"), sanitize_scope():
+        with profiling.span("srml.fit.init"):
             assert inputs.y is not None
             classes = discover_label_classes(inputs)
             if len(classes) < 2:
@@ -544,7 +541,7 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                 candidates=len(idxs),
                 folds=n_folds,
                 owlqn=owlqn,
-            ), sanitize_scope():
+            ):
                 with profiling.span("srml.fit.solve"):
                     solved = sweep_ops.dispatch(
                         "sweep.logreg.fit",
@@ -567,7 +564,7 @@ class LogisticRegression(_LogisticRegressionParams, _TpuEstimatorSupervised):
                     solved
                 )
             if held_out is not None:
-                with profiling.span("tuning.sweep.score"), sanitize_scope():
+                with profiling.span("tuning.sweep.score"):
                     with profiling.span("srml.fit.solve"):
                         scored = sweep_ops.dispatch(
                             "sweep.logreg.score",

@@ -530,7 +530,7 @@ class _RandomForestEstimator(_RandomForestParams, _TpuEstimatorSupervised):
             return attrs
 
         # The step spans of _fit and _single_fit tile srml.fit
-        # (core._call_tpu_fit_func): init (edges, label stats, binning,
+        # (core.FitJob.run): init (edges, label stats, binning,
         # bootstrap draw), solve (growth: dispatches only on the MXU
         # builder), wait + fetch (core.fetch_fit_result: ONE batched fetch
         # of the forest), pack.

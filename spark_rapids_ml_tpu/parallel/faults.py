@@ -21,9 +21,9 @@
 #                     frame, after the socket read
 #   exchange.ring_pass  exchange.ring_pass_bytes (the kNN ring hop wire)
 #   knn.ring_hop      ops/knn._distributed_ring (per ring rotation)
-#   runner.fit        the fit task body — BOTH the barrier runner
-#                     (parallel/runner.fit) and the local driver path
-#                     (core._call_tpu_fit_func)
+#   runner.fit        the fit task body: core.FitJob.prepare, which every
+#                     launcher enters (barrier runner, local driver path,
+#                     batched sweep)
 #   serving.dispatch  serving/engine.ModelServer._dispatch (tag = server name)
 #   context.init      TpuContext.__enter__ (the jax.distributed bootstrap)
 #

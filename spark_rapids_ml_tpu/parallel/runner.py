@@ -30,11 +30,12 @@ from __future__ import annotations
 
 import base64
 import contextlib
+import functools
 import json
 import os
 import random
 import time
-from typing import Any, Dict, Iterator, List, Optional, Sequence
+from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
 import pandas as pd
@@ -534,10 +535,14 @@ class DistributedFitSession:
         self.control_plane = control_plane
         self.mesh = global_mesh()
 
-    # FitInputs construction (executor-side analog of
-    # _TpuCaller._build_fit_inputs, which is single-controller)
-    def build_fit_inputs(self, estimator: Any, df: Any) -> Any:
-        from ..core import FitInputs
+    def build_fit_inputs(
+        self, estimator: Any, df: Any, x64: Callable[[Any], None] = lambda dtype: None
+    ) -> Any:
+        """FitInputs over the pod mesh: the executor-side analog of
+        _TpuCaller._build_fit_inputs, which is single-controller.  `x64` is
+        core.FitJob.staged's: called with the dtype the ranks agreed on,
+        before anything goes up."""
+        from ..core import FitInputs, _device_put_counted, stage_mask_and_labels
 
         # A rank can legitimately hold ZERO rows (fewer rows than barrier
         # tasks, skewed repartition).  It must still join every gather —
@@ -582,50 +587,33 @@ class DistributedFitSession:
         share = -(-max_rank_rows // local_dev) * local_dev
         n_pad = share * self.nranks
 
-        # labels/weights ride >= float32 buffers regardless of a low-
-        # precision FEATURE dtype — same rule as the single-controller
-        # ingest (core._pre_process_data): a bf16 buffer would round
-        # integer class labels above the half-precision mantissa
-        ldtype = np.dtype(np.float32) if np.dtype(dtype).itemsize < 4 else dtype
+        x64(dtype)
 
-        def _to_global(
-            local_cols: int, fill: Optional[np.ndarray], is_2d: bool,
-            buf_dtype=None,
-        ):
-            shape = (share, local_cols) if is_2d else (share,)
-            buf = np.zeros(shape, dtype=buf_dtype or dtype)
-            if fill is not None and fill.shape[0]:
-                buf[: fill.shape[0]] = fill
-            gshape = (n_pad, local_cols) if is_2d else (n_pad,)
+        def to_global(buf: np.ndarray):
+            """This rank's padded share of a global row-sharded array."""
             return jax.make_array_from_process_local_data(
-                NamedSharding(self.mesh, P(DATA_AXIS)), buf, global_shape=gshape
+                NamedSharding(self.mesh, P(DATA_AXIS)),
+                buf,
+                global_shape=(n_pad,) + buf.shape[1:],
             )
 
-        X_loc = (
-            np.concatenate(nonempty, axis=0)
-            if nonempty
-            else np.zeros((0, n_cols), dtype=dtype)
+        X_loc = np.zeros((share, n_cols), dtype=dtype)
+        if nonempty:
+            if nonempty[0].shape[1] != n_cols:
+                raise ValueError(
+                    f"rank {self.rank} has {nonempty[0].shape[1]} feature "
+                    f"columns, other ranks have {n_cols}"
+                )
+            np.concatenate(nonempty, axis=0, out=X_loc[:n_loc])
+        Xs = _device_put_counted(lambda: to_global(X_loc), sent=X_loc)
+        y_loc = None
+        if labels is not None:  # [] on a rank without rows: all padding
+            y_loc = np.concatenate(labels) if labels else np.zeros(0, dtype=dtype)
+        ws, ys = stage_mask_and_labels(
+            n_loc, share, dtype, y_loc,
+            np.concatenate(weights) if weights else None,  # None or []
+            to_global,
         )
-        if X_loc.shape[0] and X_loc.shape[1] != n_cols:
-            raise ValueError(
-                f"rank {self.rank} has {X_loc.shape[1]} feature columns, "
-                f"other ranks have {n_cols}"
-            )
-        Xs = _to_global(n_cols, X_loc if X_loc.shape[0] else None, is_2d=True)
-
-        w_loc = (
-            np.concatenate(weights)
-            if weights  # None or [] (empty rank) -> valid-row ones mask
-            else np.ones(n_loc, dtype=ldtype)
-        )
-        ws = _to_global(0, w_loc, is_2d=False, buf_dtype=ldtype)
-
-        ys = None
-        if labels is not None:
-            y_loc = (
-                np.concatenate(labels) if labels else np.zeros(0, dtype=ldtype)
-            )
-            ys = _to_global(0, y_loc, is_2d=False, buf_dtype=ldtype)
 
         return FitInputs(
             X=Xs,
@@ -661,42 +649,37 @@ class DistributedFitSession:
                 "or SRML_SPARK_COLLECT=1 (driver-local fit)."
             )
         df = DataFrame(list(partitions))
-        from .. import profiling, watch
-        from ..sanitize import sanitize_scope
+        from .. import watch
+        from ..core import TELEMETRY_ATTR, fit_job
 
-        profiling.reset_phase_times()
-        counters0 = profiling.counters()
-        tag = f"fit-{type(estimator).__name__}-rank{self.rank}"
         # srml-watch: every rank heartbeats through the control plane's
         # non-collective publish surface (rank 0 also runs the stall
-        # watchdog when SRML_WATCH_STALL_S > 0), and an unhandled exception
-        # inside the fit task dumps the flight ring before propagating —
-        # the two failure modes (wedge, crash) that previously died silent.
+        # watchdog when SRML_WATCH_STALL_S > 0); the job's flight scope
+        # covers the other failure mode that used to die silent, a crash
         health = watch.start_fit_health(self.control_plane, self.rank, self.nranks)
         try:
-            with watch.flight_scope(tag), profiling.trace_session(tag):
-                # srml-shield: the fit-task injection site (action=die here
-                # is the chaos matrix's "rank killed mid-fit"; action=raise
-                # exercises the abort-marker broadcast in TpuContext)
-                faults.site("runner.fit", rank=self.rank)
-                with profiling.phase("runner.build_inputs"):
-                    inputs = self.build_fit_inputs(estimator, df)
-                fit_func = estimator._get_tpu_fit_func(df, extra_params)
-                with sanitize_scope(), profiling.phase("runner.fit"):
-                    result = fit_func(inputs, dict(estimator._tpu_params))
+            with fit_job(estimator, rank=self.rank) as job:
+                with job.prepare():
+                    fit_func = estimator._get_tpu_fit_func(df, extra_params)
+                build = functools.partial(self.build_fit_inputs, estimator, df)
+                result = job.run(build, fit_func, dict(estimator._tpu_params))
+                with job.finish():
+                    encoded = [
+                        encode_attrs(r)
+                        for r in (result if isinstance(result, list) else [result])
+                    ]
         finally:
             health.stop()
-        # Telemetry snapshot at fit-task exit, merged ACROSS RANKS through
-        # the control plane before rank 0's results leave for the driver —
+        # The job's telemetry snapshot, merged ACROSS RANKS through the
+        # control plane before rank 0's results leave for the driver —
         # this is how the driver-side model sees where every executor's fit
         # spent its time (the reference's per-task NVTX/log lines die on the
         # executors; a mergeable rollup is the only thing that can ride the
         # model-attribute wire).  One extra string gather round; every rank
         # participates (collective contract).
-        snap = profiling.TelemetrySnapshot.capture(counters0, rank=self.rank)
-        merged = snap
+        merged = job.snapshot
         if self.nranks > 1:
-            gathered = self.control_plane.allGather(json.dumps(snap.to_dict()))
+            gathered = self.control_plane.allGather(json.dumps(merged.to_dict()))
             snaps = sorted(
                 (json.loads(m) for m in gathered),
                 key=lambda d: d.get("meta", {}).get("ranks", [0]),
@@ -705,10 +688,6 @@ class DistributedFitSession:
             for d in snaps[1:]:
                 merged = merged.merge(profiling.TelemetrySnapshot.from_dict(d))
         self.control_plane.barrier()
-        results = result if isinstance(result, list) else [result]
-        encoded = [encode_attrs(r) for r in results]
-        from ..core import TELEMETRY_ATTR
-
         for e in encoded:
             e[TELEMETRY_ATTR] = merged.to_dict()
         return encoded
@@ -719,13 +698,6 @@ def distributed_session(
     rank: int, nranks: int, control_plane: Optional[ControlPlane] = None
 ) -> Iterator[DistributedFitSession]:
     cp = control_plane or LocalControlPlane()
-    # every executor process of a barrier job — and every LATER job at the
-    # same kernel geometries — deserializes executables a sibling already
-    # compiled instead of recompiling them (the driver-local fit path makes
-    # the same call in core._call_tpu_fit_func)
-    from ..ops.precompile import ensure_compile_cache
-
-    ensure_compile_cache()
     try:
         with TpuContext(rank, nranks, cp):
             yield DistributedFitSession(rank, nranks, cp)

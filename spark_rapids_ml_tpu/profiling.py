@@ -781,7 +781,7 @@ def trace_session(tag: str = "session") -> Iterator[Optional[str]]:
     """Collect spans for the enclosed region and write them as ONE Chrome
     trace-event JSON file under $SRML_TRACE_DIR (yielding the target path).
     No-op — zero overhead, yields None — when the env var is unset.  Opened
-    automatically around every top-level fit (core / parallel runner),
+    automatically around every top-level fit (core.fit_job),
     kneighbors search, and serving engine lifetime; overlapping sessions
     each export their own window of the shared buffer."""
     out_dir = os.environ.get(TRACE_ENV)

@@ -2,8 +2,8 @@
 # Runtime sanitizer: the dynamic half of graftlint (tools/graftlint is the
 # static half — see docs/graftlint.md).
 #
-# SRML_SANITIZE=1 wraps every solver invocation (core._call_tpu_fit_func and
-# parallel/runner.DistributedFitSession.fit) in
+# SRML_SANITIZE=1 wraps every solver invocation (core.FitJob.staged, which the
+# driver-local fit, the executor's fit and the batched sweep all run under) in
 #
 #   - jax.transfer_guard_device_to_host("disallow"): any IMPLICIT
 #     device->host transfer inside a fit — np.asarray/float()/.item() on a

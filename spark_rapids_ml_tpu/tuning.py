@@ -31,6 +31,7 @@
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import json
 import os
@@ -43,7 +44,7 @@ import numpy as np
 # serializes parallel CV fold fits on the cpu backend (see one_fold)
 _CPU_FOLD_LOCK = threading.Lock()
 
-from .core import _TpuEstimator, _TpuModel, load as _load_any
+from .core import _TpuEstimator, _TpuModel, fit_job, load as _load_any
 from .dataframe import DataFrame, as_dataframe
 from .params import Param, Params, TypeConverters, _dummy
 from .utils import get_logger
@@ -333,20 +334,17 @@ class CrossValidator(_ValidatorParams):
         (a from_device frame) has the estimator reduce the same metric
         partials on the device instead, under the staged fold ids.
 
-        A fit's step spans tile the sweep as they tile a fit (srml.prepare
-        and srml.finish here, the others in the estimator's
-        _fitBatchedSweep), so a profiler trace names the host's part of
-        every gap the device idles in."""
-        from . import profiling, watch
+        The sweep is a fit job like any other (core.FitJob): its step spans
+        tile the sweep as they tile a fit, the estimator's _fitBatchedSweep
+        opening the fit function's (init, solve, wait, fetch, pack)."""
+        from . import profiling
 
         n_folds = self.getNumFolds()
         num_models = len(epm)
         seed = self.getOrDefault("seed")
-        tag = f"sweep-{type(est).__name__}"
-        with watch.flight_scope(tag), profiling.trace_session(tag):
-            with profiling.span("srml.prepare"):
-                counters0 = profiling.counters()
-                profiling.reset_phase_times()
+        with fit_job(est, kind="sweep") as job:
+            with job.prepare():
+                pass  # the estimator resolves its grid beside its solver
             with profiling.span(
                 "tuning.sweep",
                 estimator=type(est).__name__,
@@ -355,9 +353,10 @@ class CrossValidator(_ValidatorParams):
             ):
                 profiling.incr_counter("tuning.candidates", num_models)
                 profiling.incr_counter("tuning.folds", n_folds)
-                fold_results, held_out = est._fitBatchedSweep(
-                    df, epm, n_folds, seed, eva if on_device else None
-                )
+                with job.staged(functools.partial(est._build_fit_inputs, df)) as inputs:
+                    fold_results, held_out = est._fitBatchedSweep(
+                        inputs, epm, n_folds, seed, eva if on_device else None
+                    )
                 with profiling.span("srml.fit.pack"):
                     fold_models = _materialize_sweep_models(est, fold_results, epm)
                 if held_out is not None:
@@ -381,12 +380,11 @@ class CrossValidator(_ValidatorParams):
                         profiling.incr_counter(
                             "tuning.score.h2d_bytes", rows * row_bytes
                         )
-            with profiling.span("srml.finish"):
-                self._last_fit_phase_times = profiling.phase_times()
-                snap = profiling.TelemetrySnapshot.capture(counters0, rank=0)
+            with job.finish():
+                self._last_fit_phase_times = job.phase_times
                 for models in fold_models:
                     for m in models:
-                        m._fit_telemetry = snap
+                        m._fit_telemetry = job.snapshot
         self.logger.info(
             "batched sweep: %d candidates x %d folds over one staged dataset",
             num_models,

@@ -622,7 +622,7 @@ def flight_scope(tag: str) -> Iterator[None]:
     """Record-and-dump guard for a unit of work: an exception escaping the
     scope is ring-recorded (with the innermost failing span) and triggers a
     flight dump before propagating unchanged.  Wraps every top-level fit
-    (core / parallel runner) and the serving dispatch path."""
+    (core.fit_job) and the serving dispatch path."""
     try:
         yield
     except BaseException as exc:

@@ -5,10 +5,14 @@ thread (srml.prepare, srml.ingest, srml.fit, srml.finish), srml.fit by the
 fit function's five (init, solve, wait, fetch, pack); ingest.h2d_bytes and
 fit.d2h_bytes count what crossed the host link; LbfgsResult.n_evals counts
 the objective's evaluations; jax.named_scope names the solver loops' parts.
+
+Since PR 43 the job's own steps are opened in one place (core.FitJob), which
+the public fit, the executor's fit and the batched sweep all enter.
 """
 import threading
 
 import numpy as np
+import pandas as pd
 import pytest
 
 import jax
@@ -92,6 +96,99 @@ def test_step_spans_tile_the_public_fit(family):
     assert by_name["srml.fit.fetch"][0][7]["bytes"] > 0
     puts = by_name["srml.device_put"]
     assert puts and all(r[6] == top[1][5] and r[7]["bytes"] > 0 for r in puts)
+
+
+@pytest.mark.parametrize("launcher", ["public", "executor", "sweep"])
+def test_every_launcher_enters_the_one_job(launcher):
+    """The public fit, the executor's fit (what a live Spark DataFrame runs)
+    and the batched sweep record the job's step spans from the one place that
+    opens them: the two fits the same four in the same order, with the counted
+    uploads inside ingest; the sweep its own tiling, with no outer srml.fit."""
+    from spark_rapids_ml_tpu.core import TELEMETRY_ATTR
+    from spark_rapids_ml_tpu.evaluation import MulticlassClassificationEvaluator
+    from spark_rapids_ml_tpu.parallel.context import LocalControlPlane
+    from spark_rapids_ml_tpu.parallel.runner import run_distributed_fit
+    from spark_rapids_ml_tpu.tuning import CrossValidator, ParamGridBuilder
+
+    X, y = _table()
+    est = LogisticRegression(maxIter=8, tol=1e-30, regParam=1e-3, num_workers=2)
+    frame = DataFrame.from_numpy(X, y=y, num_partitions=2)
+    me = threading.get_ident()
+    with profiling.collect_spans():
+        if launcher == "public":
+            moved = est.fit(frame).fit_telemetry().counters
+        elif launcher == "executor":
+            pdf = pd.DataFrame({"features": list(X), "label": y})
+            (attrs,) = run_distributed_fit(est, [pdf], 0, 1, LocalControlPlane())
+            moved = profiling.TelemetrySnapshot.from_dict(attrs[TELEMETRY_ATTR]).counters
+        else:
+            grid = ParamGridBuilder().addGrid(est.regParam, [1e-3, 1e-2]).build()
+            cv = CrossValidator(estimator=est, estimatorParamMaps=grid, numFolds=2, collectSubModels=True,
+                                evaluator=MulticlassClassificationEvaluator(metricName="logLoss"))
+            moved = cv.fit(frame).subModels[0][0].fit_telemetry().counters
+        mine = sorted((r for r in profiling.span_records() if r[3] == me), key=lambda r: r[1])
+    if launcher == "sweep":         # the refit of the winner is a public fit of its own, after the sweep's job
+        refit = next(r for r in mine if r[0] == "tuning.refit")
+        mine = [r for r in mine if r[2] <= refit[1]]
+    first = next(r for r in mine if r[0] == "srml.prepare")
+    steps = [r for r in mine if r[6] == first[6]]   # its siblings: what tiles the job
+    inside = lambda outer: [r[0] for r in mine if outer[1] <= r[1] and r[2] <= outer[2] and r is not outer]  # noqa: E731
+    if launcher == "sweep":
+        assert [r[0] for r in steps] == ["srml.prepare", "tuning.sweep", "srml.finish"]
+        within = inside(steps[1])
+        assert within[0] == "srml.ingest" and "srml.fit" not in within
+        assert [n for n in within if n in STEPS][:3] == STEPS[:3] and set(STEPS) <= set(within)
+        assert [r[0] for r in mine if r[0].startswith("srml.")][-1] == "srml.finish"
+    else:
+        assert [r[0] for r in steps] == TOP
+        assert [n for n in inside(steps[2]) if n in STEPS] == STEPS
+    ingest = next(r for r in mine if r[0] == "srml.ingest")
+    puts = [r for r in mine if r[0] == "srml.device_put"]
+    assert len(puts) == 3 and all(r[6] == ingest[5] and r[7]["bytes"] > 0 for r in puts)
+    # the table, the row mask, the labels: counted wherever the job ran
+    assert moved["ingest.h2d_bytes"] == X.nbytes + 2 * N * 4 == sum(r[7]["bytes"] for r in puts)
+
+
+def test_a_float64_fit_on_the_executor_path_computes_in_float64(monkeypatch):
+    """float32_inputs=False on float64 rows through run_distributed_fit: the
+    fit function is handed float64 arrays inside the x64 scope (without it
+    jax canonicalizes the uploads to float32 and says nothing), and the
+    coefficients are the public fit's at a tolerance float32 cannot reach."""
+    from sklearn.linear_model import LinearRegression as SkLinearRegression
+
+    from spark_rapids_ml_tpu.parallel.context import LocalControlPlane
+    from spark_rapids_ml_tpu.parallel.runner import decode_attrs, run_distributed_fit
+
+    rng = np.random.default_rng(11)
+    X = rng.standard_normal((400, 7))
+    y = X @ rng.standard_normal(7) + 0.01 * rng.standard_normal(400)
+    assert X.dtype == np.float64
+    est = LinearRegression(float32_inputs=False, standardization=False)
+    handed = []
+    make = est._get_tpu_fit_func
+
+    def spying(df, extra_params=None):
+        fit = make(df, extra_params)
+
+        def fit_func(inputs, params):
+            handed.append((inputs.X.dtype, inputs.weight.dtype, inputs.y.dtype, jax.config.jax_enable_x64))
+            return fit(inputs, params)
+
+        return fit_func
+
+    monkeypatch.setattr(est, "_get_tpu_fit_func", spying)
+    public = est.fit(DataFrame.from_numpy(X, y))
+    pdf = pd.DataFrame({"features": list(X), "label": y})
+    (attrs,) = run_distributed_fit(est, [pdf], 0, 1, LocalControlPlane())
+    executor = decode_attrs({k: v for k, v in attrs.items() if not k.startswith("__")})
+    f64 = np.dtype(np.float64)
+    assert handed == [(f64, f64, f64, True)] * 2
+    assert not jax.config.jax_enable_x64           # the scope ended with the job
+    sk = SkLinearRegression().fit(X, y)
+    for got in (np.asarray(public.coef_), np.asarray(executor["coef_"])):
+        np.testing.assert_allclose(got, sk.coef_, atol=1e-10)
+    np.testing.assert_allclose(np.asarray(executor["coef_"]), np.asarray(public.coef_), atol=1e-12)
+    np.testing.assert_allclose(float(executor["intercept_"]), float(public.intercept_), atol=1e-12)
 
 
 @pytest.mark.parametrize("family", FAMILIES)

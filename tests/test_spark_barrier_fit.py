@@ -200,16 +200,16 @@ def test_barrier_fit_surfaces_merged_telemetry(fake_pyspark):
     model = KMeans(k=2, maxIter=5, seed=5).fit(_fake_sdf(X))
     t = model.fit_telemetry()
     assert t is not None, "barrier fit lost its telemetry snapshot"
-    # the executor phases (runner.*) are what must cross the wire — the
-    # driver thread never ran the fit
-    assert t.phases["runner.fit"]["count"] == 1
-    assert t.phases["runner.fit"]["total_s"] > 0.0
-    assert "runner.build_inputs" in t.phases
+    # the executor's step spans (the fit job's, core.FitJob) are what must
+    # cross the wire — the driver thread never ran the fit
+    assert t.phases["srml.fit"]["count"] == 1
+    assert t.phases["srml.fit"]["total_s"] > 0.0
+    assert "srml.ingest" in t.phases
     assert t.meta["ranks"] == [0]
     # driver-side phase view is rebuilt from the snapshot
     est = KMeans(k=2, maxIter=5, seed=5)
     est.fit(_fake_sdf(X))
-    assert est._last_fit_phase_times.get("runner.fit", 0.0) > 0.0
+    assert est._last_fit_phase_times.get("srml.fit", 0.0) > 0.0
     # and the wire key never leaks into model attributes
     assert TELEMETRY_ATTR not in model._get_model_attributes()
 
