@@ -101,6 +101,7 @@
 from __future__ import annotations
 
 from functools import lru_cache, partial
+from itertools import groupby
 from typing import Any, List, NamedTuple, Tuple
 
 import numpy as np
@@ -588,8 +589,12 @@ def _shallow_leaf(
 def _pack_all(
     bins_rows: jax.Array, feats_all: jax.Array, n_pad: int, P: int,
     interpret: bool,
-) -> jax.Array:
-    """(T, P, n_pad) int32 packed per-tree deep-subset rows (4 bins/word).
+) -> Tuple[jax.Array, ...]:
+    """P arrays (T, n_pad) int32: word p of the packed per-tree deep-subset
+    rows (4 bins/word).  An array a word: a payload sort takes its words as
+    they are, and a word's buffer goes when its sort is done (a slice of a
+    (T, P, n_pad) stack is an output of its own, allocated when the host
+    enqueues it: 14 of them stood in rf_clf_fit's in-flight set, 1.26 GB).
     Only ceil(F/4) words are packed — feature PADDING rows never ride the
     payload sort; _deep_state re-pads to f_pad after the unpack.  The T
     subsets are ONE gather of T * 4P rows."""
@@ -601,7 +606,8 @@ def _pack_all(
         interpret=interpret,
     )[: T * 4 * P].reshape(T, 4 * P, n_pad)
     sub = jnp.where((jnp.arange(4 * P) < F)[None, :, None], sub, 0)
-    return jax.vmap(partial(_pack_rows, f_pad=4 * P))(sub)
+    words = jax.vmap(partial(_pack_rows, f_pad=4))
+    return tuple(words(sub[:, 4 * p : 4 * p + 4])[:, 0] for p in range(P))
 
 
 def _pack_rows_pad(T: int, P: int) -> int:
@@ -613,34 +619,80 @@ def _pack_rows_pad(T: int, P: int) -> int:
 def _sort_part(
     keys: jax.Array,     # (T, n_pad) int32 the rows' segments (_deep_layout)
     dkeys: jax.Array,    # (T, n2 - n_pad) int32 filler keys (_deep_layout)
-    payload: jax.Array,  # (T, n_pad) or (n_pad,) — ONE payload array
+    payloads: Tuple[jax.Array, ...],  # each (T, n_pad) or (n_pad,), any dtype
     n_buckets: int,
     n2: int,
-):
-    """One payload's share of the deep phase's batched bucket sort.
+) -> Tuple[jax.Array, ...]:
+    """One group's share of the deep phase's batched bucket sort: the
+    payloads it is handed, each (T, n2), in the layout's order.
 
-    XLA's variadic-sort compile cost is ~5 s PER OPERAND on this backend
-    (measured: 7 s for 2 operands, 63 s for 12), so the single
-    key + P-feature-words + (w, y) sort that a cold fit used to pay ~50 s
-    compiling is split into independent 2-operand sorts — one per payload —
-    that the precompiler runs concurrently.  All parts sort by the same
-    UNIQUE combined key (segment * n2 + column) of the SAME keys, the
-    layout's own, so every part computes the identical permutation, the one
-    the tile map was counted from, with no reliance on sort stability.  n2 is a
-    STATIC bound (_deep_width: n_pad + one tile of filler a bucket), so
-    these lower at fit entry and compile while the shallow phase runs.  Uniqueness needs (n_buckets + 1) * n2 < 2^31 — 16.6 M rows
-    at 128 buckets, far beyond a single chip's forest capacity."""
+    Every part sorts by the same UNIQUE combined key (segment * n2 + column)
+    of the SAME keys, the layout's own, so every part computes the identical
+    permutation, the one the tile map was counted from.  The key's
+    uniqueness over a tree's n2 positions is what licenses is_stable=False:
+    a stable and an unstable sort then have exactly one correct output, and
+    XLA:TPU makes a sort stable by appending an iota and comparing
+    (key, iota): a third array moved for one payload under a comparator of
+    four compares and a select, 1.52 times this form's time on the chip.
+    Here the operand list is the key and the payloads, 1 + k arrays, under
+    a one-compare comparator.  Uniqueness needs (n_buckets + 1) * n2 < 2^31
+    — 16.6 M rows at 128 buckets, far beyond a single chip's forest
+    capacity.
+
+    How many payloads share a sort is _sort_groups' rule.  The compile grows
+    with the operands (on the chip's host at the cells' shapes: 7 s at
+    k = 1, 20-24 s at k = 4, 27-29 s at k = 5, 67 s for 9 payloads, 101 s
+    for 16; the stable form it replaced took 14-17 s at k = 1).  n2 is a
+    STATIC bound (_deep_width: n_pad + one tile of filler a bucket), so the
+    parts lower at fit entry and compile, concurrently, while the shallow
+    phase runs."""
     T, n_pad = keys.shape
     assert (n_buckets + 1) * n2 < 2**31, "combined sort key overflows int32"
     ck = jnp.concatenate([keys, dkeys], axis=1) * np.int32(n2) + jnp.arange(
         n2, dtype=jnp.int32
     )
-    if payload.ndim == 1:
-        payload = jnp.broadcast_to(payload, (T, n_pad))
-    pad = jnp.zeros((T, n2 - n_pad), payload.dtype)
-    full = jnp.concatenate([payload, pad], axis=1)
-    _, out = jax.lax.sort((ck, full), num_keys=1, dimension=1)
-    return out
+    full = tuple(
+        jnp.pad(jnp.broadcast_to(p, (T, n_pad)), ((0, 0), (0, n2 - n_pad)))
+        for p in payloads
+    )
+    return tuple(
+        jax.lax.sort((ck, *full), num_keys=1, dimension=1, is_stable=False)[1:]
+    )
+
+
+_SORT_GROUP = 5  # payloads a sort carries at most
+
+
+def _sort_groups(n_payloads: int) -> List[Tuple[int, int]]:
+    """[start, stop) of the payloads each _sort_part carries, in order: the
+    fewest sorts of at most _SORT_GROUP payloads, evenly filled (9 payloads:
+    4 + 5; 16: four of 4).  A sort of a key and k payloads moves k + 1
+    arrays where k sorts move 2k, and on the chip its time is linear in
+    what it moves: 112 + 87 k ms at (25, 2,816,000), 21 + 14.4 k ms at
+    (50, 466,944) (PERF.md section 5 has the probe's table), so a payload
+    costs 43-45% less at k = 5 than alone.  The bound is the compile's: 7 s
+    at k = 1, 27-29 s at k = 5, 67-101 s for a fit's 9 or 16 payloads in one
+    sort, on a cold fit's path; the executable's temporaries do not grow
+    with k and the outputs are kept until _deep_state whatever the groups,
+    so no bound on bytes is needed."""
+    n_groups = -(-n_payloads // _SORT_GROUP)
+    stops = [n_payloads * (g + 1) // n_groups for g in range(n_groups)]
+    return list(zip([0] + stops[:-1], stops))
+
+
+def _sort_name(group) -> str:
+    """A sort's name in its dispatch key, from the payloads it carries:
+    sort_part_ and a payload's dtype (i32 | f32), _1d where the trees share
+    it, xN where N alike follow each other (sort_part_i32x4,
+    sort_part_i32_1dx2_f32_f32_1d)."""
+    kinds = (
+        ("i32" if a.dtype == jnp.int32 else "f32") + ("_1d" if a.ndim == 1 else "")
+        for a in group
+    )
+    runs = ((kind, len(list(alike))) for kind, alike in groupby(kinds))
+    return "sort_part_" + "_".join(
+        kind + (f"x{n}" if n > 1 else "") for kind, n in runs
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -1039,6 +1091,7 @@ def grow_forest_mxu_device(
         f_pad=f_pad, n_pad=n_pad, interpret=interpret,
     )
     shallow_keys = {}
+    sort_groups: List[Tuple[int, int]] = []  # none without a deep phase
     for level in range(shallow_top + 1):
         nodes, tpack = 2**level, tpack_at(level)
         if level == max_depth:
@@ -1082,17 +1135,17 @@ def grow_forest_mxu_device(
             k_pack, _pack_all, a_table, aval((t_packed, min(F, D)), i32),
             n_pad=n_pad, P=P, interpret=interpret,
         )
-        word = "i32_1d" if whole else "i32"
-        k_sort = {
-            name: ("sort_part_" + name, T, n_pad, nb, n2)
-            for name in (word, "f32", "f32_1d")
-        }
-        for name, a_pay in (
-            (word, aval((n_pad,) if whole else (T, n_pad), i32)), ("f32", a_w),
-            ("f32_1d", aval((n_pad,), f32)),
-        ):
+        # the payloads in _deep_state's order: the packed words, w, y
+        a_pays = [aval((n_pad,) if whole else (T, n_pad), i32)] * P + [
+            a_w, aval((n_pad,), f32),
+        ]
+        sort_groups = _sort_groups(len(a_pays))
+        k_sort = []
+        for g0, g1 in sort_groups:
+            a_group = tuple(a_pays[g0:g1])
+            k_sort.append((_sort_name(a_group), T, n_pad, nb, n2))
             run.submit(
-                k_sort[name], _sort_part, a_rel, a_keys, a_pay,
+                k_sort[-1], _sort_part, a_rel, a_keys, a_group,
                 n_buckets=nb, n2=n2,
             )
         k_state = ("deep_state", T, n2, P, f_pad_d, S, kind)
@@ -1190,17 +1243,21 @@ def grow_forest_mxu_device(
                 k_pack, _pack_all, bins_rows, next(subsets),
                 n_pad=n_pad, P=P, interpret=interpret,
             )
-            sort = lambda name, payload: run.call(
-                k_sort[name], _sort_part, keys, dkeys, payload,
-                n_buckets=nb, n2=n2,
-            )
-            packed_sorted = tuple(
-                sort(word, packed[0, p] if whole else packed[:, p, :])
-                for p in range(P)
-            )
-            w_sorted = sort("f32", w_trees)
-            y_sorted = sort("f32_1d", y_vals)
+            # the payloads in _deep_state's order; the words packed once
+            # for all trees are (n_pad,) payloads
+            payloads = [word[0] if whole else word for word in packed]
+            payloads += [w_trees, y_vals]
             del packed
+            done: List[jax.Array] = []
+            for key, (g0, g1) in zip(k_sort, sort_groups):
+                done += run.call(
+                    key, _sort_part, keys, dkeys, tuple(payloads[g0:g1]),
+                    n_buckets=nb, n2=n2,
+                )
+                payloads[g0:g1] = [None] * (g1 - g0)  # a word goes with its sort
+            *packed_sorted, w_sorted, y_sorted = done
+            packed_sorted = tuple(packed_sorted)
+            del done, payloads
             bins_s, stats_s, st3, rel_loc = run.call(
                 k_state, _deep_state, packed_sorted, w_sorted, y_sorted,
                 f_pad=f_pad_d, s_dim=S, kind=kind,
@@ -1241,6 +1298,16 @@ def grow_forest_mxu_device(
     profiling.incr_counter(
         "forest.deep_tiles",
         T * n_tiles * (max_depth - bucket_level) if deep else 0,
+    )
+    # the deep phase's payload sorts, static at dispatch: the fits that ran
+    # them, those whose sorts carried no stability operand (every one: the
+    # key is unique, _sort_part), the sorts and the arrays they carry, keys
+    # counted (0 without a deep phase)
+    profiling.incr_counter("forest.sort_fits", int(deep))
+    profiling.incr_counter("forest.unique_key_sort_fits", int(deep))
+    profiling.incr_counter("forest.sort_dispatches", len(sort_groups))
+    profiling.incr_counter(
+        "forest.sort_operands", sum(1 + g1 - g0 for g0, g1 in sort_groups)
     )
     profiling.incr_counter(
         "forest.gather_bytes",

@@ -77,12 +77,12 @@ def test_pack_all_is_every_trees_subset_packed():
     D, F, T, n_pad = 19, 6, 3, 3 * _ROW_TILE
     bins = rng.integers(0, 128, (D, n_pad)).astype(np.int8)
     feats = np.stack([rng.choice(D, F, replace=False) for _ in range(T)]).astype(np.int32)
-    packed = np.asarray(
-        _pack_all(
-            tile_feature_rows(jnp.asarray(bins)), jnp.asarray(feats),
-            n_pad=n_pad, P=2, interpret=KERNEL_INTERPRET,
-        )
+    packed = _pack_all(
+        tile_feature_rows(jnp.asarray(bins)), jnp.asarray(feats),
+        n_pad=n_pad, P=2, interpret=KERNEL_INTERPRET,
     )
+    assert isinstance(packed, tuple) and len(packed) == 2           # an array a word
+    packed = np.stack([np.asarray(word) for word in packed], axis=1)
     assert packed.shape == (T, 2, n_pad) and packed.dtype == np.int32
     want = np.zeros((T, 8, n_pad), np.int64)
     for t in range(T):

@@ -168,7 +168,10 @@ def test_wider_tables_keep_their_shared_subsets_and_their_compile_keys(cols, wid
     np.testing.assert_array_equal(plan.deep_feats, np.stack([draws.choice(cols, width, replace=False) for _ in range(trees)]))
     f_pad = -(-width // 32) * 32
     names = {key[0] for key, _ in _Recorder.seen}
-    assert {"gather_rows", "shallow_step", "deep_layout", "pack_all", "sort_part_i32", "sort_part_f32", "sort_part_f32_1d", "deep_state", "deep_step", "deep_leaf"} == names
+    # the payloads (ceil(width / 4) packed words a tree, w, y) share sorts of at most five: 4 in one, 12 in three of four
+    sorts = {5: ["sort_part_i32x2_f32_f32_1d"], 40: ["sort_part_i32x4", "sort_part_i32x4", "sort_part_i32x2_f32_f32_1d"]}[width]
+    assert [key[0] for key, _ in _Recorder.seen if key[0].startswith("sort_part")] == sorts
+    assert {"gather_rows", "shallow_step", "deep_layout", "pack_all", "deep_state", "deep_step", "deep_leaf"} | set(sorts) == names
     for key, statics in _Recorder.seen:
         assert "subset" not in statics and not any(isinstance(k, tuple) for k in key)
         if key[0] == "shallow_step":
@@ -195,7 +198,9 @@ def test_a_per_node_fit_names_its_width_in_every_steps_key(monkeypatch):
     steps = [(key, statics) for key, statics in _Recorder.seen if key[0] in ("shallow_step", "deep_step")]
     assert steps and all(key[-1] == ("subset", F) and statics["subset"] == F and statics["F"] == cols for key, statics in steps)
     names = [key[0] for key, _ in _Recorder.seen]
-    assert names.count("gather_rows") == 1 and names.count("sort_part_i32_1d") == 7 and "sort_part_i32" not in names
+    # the table's 7 packed words are the same for every tree, (n_pad,) payloads: with w and y, 9 in two sorts
+    assert names.count("gather_rows") == 1 and not any(name.startswith("sort_part_i32x") for name in names)
+    assert [name for name in names if name.startswith("sort_part")] == ["sort_part_i32_1dx4", "sort_part_i32_1dx3_f32_f32_1d"]
     assert next(key for key, _ in _Recorder.seen if key[0] == "pack_all")[3] == 1      # the table's words packed once for all trees
 
 

@@ -13,6 +13,7 @@ Run it by hand after a change to ops/forest_mxu.py or ops/forest_hist.py, before
 the chip call, and ALONE: loading libtpu takes the machine-wide lock file (which
 is why it is a tool and no tier-1 test).  A compile that passes is not a chip run."""
 import os
+import re
 import sys
 import time
 
@@ -39,7 +40,12 @@ def go(name, fn, *avals, **st):
     try:
         c = fn.lower(*avals, **st).compile()
         ma = c.memory_analysis()
-        print(f"{name}: ok {time.time()-t0:.1f}s temp={ma.temp_size_in_bytes/2**20:.0f}MB out={ma.output_size_in_bytes/2**20:.0f}MB args={ma.argument_size_in_bytes/2**20:.0f}MB", flush=True)
+        # a payload sort: the arrays its `sort` carries (the key, the payloads; a stable one an iota besides)
+        sort = "".join(
+            f" sort_operands={len(ops.split(','))} stable={'is_stable=true' in rest}"
+            for ops, rest in re.findall(r" sort\(([^)]*)\)(.*)", c.as_text())
+        )
+        print(f"{name}: ok {time.time()-t0:.1f}s temp={ma.temp_size_in_bytes/2**20:.0f}MB out={ma.output_size_in_bytes/2**20:.0f}MB args={ma.argument_size_in_bytes/2**20:.0f}MB{sort}", flush=True)
     except Exception as e:
         print(f"{name}: FAILED {time.time()-t0:.1f}s {str(e)[:1500]}", flush=True)
 a_rel, a_buf, a_w, a_t0 = A((T, n_pad), i32), A((C, T, M), f32), A((T, n_pad), f32), A((), i32)
@@ -55,7 +61,12 @@ for level in (7, 10, 12):
     go(f"deep_step_l{level}_tc{tc}", fm._deep_step, a_bins, a_loc, a_st, a_st, a_seg, a_buf, a_t0,
        t_chunk=tc, level=level, bucket_level=7, s_dim=S, kind="gini", n_bins=B, F=F, msl=1.0, mid=0.0, interpret=False)
 go("deep_leaf", fm._deep_leaf, a_loc, a_st, a_seg, a_buf, level=13, bucket_level=7, kind="gini")
-go("sort_part_i32", fm._sort_part, a_rel, A((T, n2 - n_pad), i32), A((T, n_pad), i32), n_buckets=nb, n2=n2)
+def go_sorts(prefix, a_pays):
+    """The deep phase's payload sorts as the program groups them (fm._sort_groups): an executable a distinct group."""
+    groups = [tuple(a_pays[g0:g1]) for g0, g1 in fm._sort_groups(len(a_pays))]
+    for group in dict.fromkeys(groups):
+        go(prefix + fm._sort_name(group), fm._sort_part, a_rel, A((T, n2 - n_pad), i32), group, n_buckets=nb, n2=n2)
+go_sorts("", [A((T, n_pad), i32)] * P + [a_w, A((n_pad,), f32)])
 from spark_rapids_ml_tpu.ops.forest_hist import _gather_blocks, gather_rows_matmul, tile_feature_rows
 def table(rows):  # the binned table, a feature a slice of whole tiles (tile_feature_rows)
     per, blocks = _gather_blocks(rows)
@@ -97,7 +108,6 @@ for level in (7, 10, 12):
     go(f"higgs_deep_step_l{level}_tc{tc}", fm._deep_step, a_bins, a_loc, a_st, a_st, a_seg, a_buf, a_t0, a_seed,
        t_chunk=tc, level=level, bucket_level=7, s_dim=S, kind="gini", n_bins=B, F=F, msl=1.0, mid=0.0, interpret=False, subset=subset)
 go("higgs_deep_leaf", fm._deep_leaf, a_loc, a_st, a_seg, a_buf, level=13, bucket_level=7, kind="gini")
-go("higgs_sort_part_i32_1d", fm._sort_part, a_rel, A((T, n2 - n_pad), i32), A((n_pad,), i32), n_buckets=nb, n2=n2)
-go("higgs_sort_part_f32", fm._sort_part, a_rel, A((T, n2 - n_pad), i32), a_w, n_buckets=nb, n2=n2)
+go_sorts("higgs_", [A((n_pad,), i32)] * P + [a_w, A((n_pad,), f32)])
 go("higgs_gather", gather_rows_matmul, table(n_pad), A((D,), i32), f_pad=f_pad, n_pad=n_pad)
 go("higgs_pack_all", fm._pack_all, table(n_pad), A((1, D), i32), n_pad=n_pad, P=P, interpret=False)
