@@ -44,7 +44,7 @@ from .dataframe import DataFrame, FEATURE_BLOCK_ATTR, as_dataframe
 from .params import Param, Params, _TpuParams
 from .parallel.mesh import get_mesh, data_sharding
 from .parallel.partition import PartitionDescriptor
-from .utils import get_logger, materialize_feature_block
+from .utils import feature_cells_view, get_logger, materialize_feature_block
 
 
 def _is_pyspark_dataframe(dataset: Any) -> bool:
@@ -223,6 +223,38 @@ def stage_dense_batches(batches: Iterable[np.ndarray], n_rows: int, mesh: Any) -
     )
 
 
+class _DeferredProofs(threading.local):
+    """The view rule's proofs a fit job owes, on the thread the job runs on.
+    `batches` is None where nobody stands ready to settle (every extraction
+    then asks the rule at once), and inside FitJob.run the (cells, dtype) of
+    each batch that went up on admission alone (utils.admit_feature_cells);
+    `refuted` counts those the rule has since refused."""
+
+    batches: Optional[List[Tuple[np.ndarray, np.dtype]]] = None
+    refuted = 0
+
+
+_PROOFS = _DeferredProofs()
+
+
+def settle_deferred_proofs() -> None:
+    """utils.feature_cells_view, the rule itself, over every batch this
+    thread's job admitted on the cheap test: every cell of every one is
+    looked at, here, where it was not in srml.ingest.  The list is released
+    (it kept each batch's buffer alive), the refused are counted, and
+    FitJob.run throws the fit away if there was one.  16 ms a batch of 10,000
+    cells, which is why it runs where the host would otherwise be blocked on
+    the solver (fetch_fit_result) and not while the device waits for rows."""
+    owed = _PROOFS.batches
+    with profiling.span("srml.ingest.verify") as sp:
+        refuted = sum(feature_cells_view(cells, dtype) is None for cells, dtype in owed)
+        sp.set(batches=len(owed), refuted=refuted)
+    owed.clear()
+    if refuted:
+        _PROOFS.refuted += refuted
+        profiling.incr_counter("ingest.refuted_batches", refuted)
+
+
 def fetch_fit_result(tree: Any) -> Any:
     """The end of a fit function's device work, as two step spans: wait
     (srml.fit.wait: block until the solver's outputs are ready, so the
@@ -232,10 +264,14 @@ def fetch_fit_result(tree: Any) -> Any:
 
     The copies are queued behind the solver before the wait, where
     jax.device_get alone would queue them: they start when the solver ends,
-    not one host wake-up later."""
+    not one host wake-up later.  Between the two, with the solver dispatched
+    and nothing of it needed yet, the host settles the proofs the job's
+    ingest deferred (srml.ingest.verify; nothing where none is owed)."""
+    for leaf in jax.tree_util.tree_leaves(tree):
+        leaf.copy_to_host_async()
+    if _PROOFS.batches:
+        settle_deferred_proofs()
     with profiling.span("srml.fit.wait"):
-        for leaf in jax.tree_util.tree_leaves(tree):
-            leaf.copy_to_host_async()
         jax.block_until_ready(tree)
     with profiling.span("srml.fit.fetch") as sp:
         host = jax.device_get(tree)
@@ -454,6 +490,16 @@ class FitJob:
             yield
             faults.site("runner.fit", rank=self.rank)
 
+    def _ingest(self, build: Callable[[Callable[[Any], None]], FitInputs], x64: Callable[[Any], None]) -> FitInputs:
+        """srml.ingest around `build(x64)`: staged's, and run's second staging."""
+        with profiling.span("srml.ingest"):
+            inputs = build(x64)
+            get_logger(type(self.estimator)).info(
+                "Invoking TPU fit: %d rows x %d cols on %d-device mesh",
+                inputs.n_rows, inputs.n_cols, inputs.mesh.devices.size,
+            )
+        return inputs
+
     @contextlib.contextmanager
     def staged(self, build: Callable[[Callable[[Any], None]], FitInputs]) -> Iterator[FitInputs]:
         """srml.ingest around `build(x64)`, then its FitInputs for the solver's
@@ -469,20 +515,47 @@ class FitJob:
         from .sanitize import sanitize_scope
 
         with contextlib.ExitStack() as scope:
-            with profiling.span("srml.ingest"):
-                inputs = build(lambda dtype: scope.enter_context(_maybe_x64(dtype)))
-                get_logger(type(self.estimator)).info(
-                    "Invoking TPU fit: %d rows x %d cols on %d-device mesh",
-                    inputs.n_rows, inputs.n_cols, inputs.mesh.devices.size,
-                )
+            held = [self._ingest(build, lambda dtype: scope.enter_context(_maybe_x64(dtype)))]
             with sanitize_scope():
-                yield inputs
+                yield held.pop()        # the body's alone: a job that stages again frees the table first
 
     def run(self, build: Callable[..., FitInputs], fit_func: FitFunc, params: Dict[str, Any]) -> Any:
         """The two middle steps of a fit, in the order every launcher runs
-        them: srml.ingest, then srml.fit around the fit function."""
-        with self.staged(build) as inputs, profiling.span("srml.fit"):
-            return fit_func(inputs, params)
+        them: srml.ingest, then srml.fit around the fit function.
+
+        Only here do both the staging and the fit stand ready to run again,
+        so only here is the view rule's proof deferred (_PROOFS.batches is a
+        list while the job runs: _build_fit_inputs hands it to the
+        extraction).  Batches go up on admission; the proof is settled by the
+        fit function's fetch_fit_result while the solver runs, and once more
+        after it returns, for a fit function that never fetches.  A refuted
+        batch voids the fit: nothing it computed is returned, the frame is
+        staged again with the rule asked at once (the refused batches
+        stacked, as the rule's refusal always went) inside the same x64 and
+        sanitize scopes, and the fit function runs again."""
+        outer, refuted = _PROOFS.batches, _PROOFS.refuted
+        _PROOFS.batches = []
+        try:
+            with self.staged(build) as inputs:
+                with profiling.span("srml.fit"):
+                    result = fit_func(inputs, params)
+                    if _PROOFS.batches:
+                        settle_deferred_proofs()
+                if _PROOFS.refuted == refuted:
+                    return result
+                del inputs, result
+                _PROOFS.batches = None
+                profiling.incr_counter("ingest.refits")
+                get_logger(type(self.estimator)).warning(
+                    "%d feature batch(es) admitted as views of their buffers failed the view "
+                    "rule's proof; staging again cell by cell and fitting again",
+                    _PROOFS.refuted - refuted,
+                )
+                inputs = self._ingest(build, lambda dtype: None)
+                with profiling.span("srml.fit"):
+                    return fit_func(inputs, params)
+        finally:
+            _PROOFS.batches, _PROOFS.refuted = outer, refuted
 
     @contextlib.contextmanager
     def finish(self) -> Iterator[None]:
@@ -542,12 +615,18 @@ class _TpuCaller(_TpuParams):
         return np.dtype(np.float64)
 
     def _extract_partition_features(
-        self, part: pd.DataFrame, input_col: Optional[str], input_cols: Optional[List[str]], dtype: np.dtype
+        self,
+        part: pd.DataFrame,
+        input_col: Optional[str],
+        input_cols: Optional[List[str]],
+        dtype: np.dtype,
+        deferred: Optional[List[Tuple[np.ndarray, np.dtype]]] = None,
     ) -> np.ndarray:
         block = (
             _partition_feature_block(part, input_col) if input_col is not None else None
         )
-        return materialize_feature_block(
+        owed = len(deferred) if deferred is not None else 0
+        feats = materialize_feature_block(
             block,
             part,
             input_col,
@@ -561,7 +640,11 @@ class _TpuCaller(_TpuParams):
             on_cells=lambda viewed: profiling.incr_counter(
                 "ingest.view_batches" if viewed else "ingest.stacked_batches"
             ),
+            deferred=deferred,
         )
+        if deferred is not None and len(deferred) > owed:
+            profiling.incr_counter("ingest.deferred_batches")
+        return feats
 
     def _fit_label_col(self) -> Optional[str]:
         """Column to extract as ``FitInputs.y``, or None.  Supervised
@@ -579,14 +662,16 @@ class _TpuCaller(_TpuParams):
         return None
 
     def _pre_process_data(
-        self, df: DataFrame
+        self, df: DataFrame, deferred: Optional[List[Tuple[np.ndarray, np.dtype]]] = None
     ) -> Tuple[Iterator[Any], Optional[List[np.ndarray]], Optional[List[np.ndarray]], np.dtype]:
         """Per-partition (features, label, weight) numpy extraction with dtype
         casting (reference core.py:344-422 + supervised label cast :918-952).
         The labels and weights are lists, a vector a partition; the features
         are an ITERATOR over the partitions' matrices, each extracted (inside
         a srml.ingest.extract span) when the consumer asks for it, so that a
-        batch-wise consumer holds one at a time."""
+        batch-wise consumer holds one at a time.  `deferred` is
+        utils.materialize_feature_block's: None, as every caller but
+        _build_fit_inputs leaves it, asks the view rule at each batch."""
         input_col, input_cols = self._get_input_columns()
         dtype = self._use_dtype(df, input_col, input_cols)
         label_col, weight_col = self._fit_label_col(), self._fit_weight_col()
@@ -600,7 +685,7 @@ class _TpuCaller(_TpuParams):
         def features() -> Iterator[Any]:
             for part in df.partitions:
                 with profiling.span("srml.ingest.extract"):
-                    feats = self._extract_partition_features(part, input_col, input_cols, dtype)
+                    feats = self._extract_partition_features(part, input_col, input_cols, dtype, deferred)
                 yield feats
 
         return features(), column(label_col), column(weight_col), dtype
@@ -617,7 +702,8 @@ class _TpuCaller(_TpuParams):
         if dev is not None:
             x64(dev[0].dtype)
             return self._build_fit_inputs_device(df, dev)
-        feats, labels, weights, dtype = self._pre_process_data(df)
+        # inside FitJob.run the view rule's proof may wait for the solver
+        feats, labels, weights, dtype = self._pre_process_data(df, _PROOFS.batches)
         x64(dtype)
         partition_rows = [len(p) for p in df.partitions]
         n_rows = sum(partition_rows)

@@ -9,6 +9,11 @@ core.stage_dense_batches' table against chipbench/references/logreg_exec.assembl
 for bit, on one device and on eight; the models against the from_numpy route's and the
 reference's limits; the three faults a batch-wise ingest can commit in silence; the
 counters; and the host memory the staging holds.
+
+And the rule's two moments (PR 48): inside core.FitJob.run a batch goes up on
+utils.admit_feature_cells' cheap test and the rule itself runs over it while the solver
+does (core.settle_deferred_proofs, from fetch_fit_result); a refuted batch voids the fit,
+which is staged and fitted again; everyone else asks the rule at once.
 """
 import json
 import tracemalloc
@@ -22,12 +27,12 @@ import jax
 
 from chipbench.references import logreg as ref
 from chipbench.references import logreg_exec as ref_exec
-from spark_rapids_ml_tpu import KMeans, LinearRegression, LogisticRegression, core, profiling
+from spark_rapids_ml_tpu import KMeans, LinearRegression, LogisticRegression, core, profiling, utils
 from spark_rapids_ml_tpu.core import TELEMETRY_ATTR, stage_dense_batches
 from spark_rapids_ml_tpu.dataframe import DataFrame
 from spark_rapids_ml_tpu.parallel.mesh import get_mesh
 from spark_rapids_ml_tpu.parallel.runner import decode_attrs, run_distributed_fit
-from spark_rapids_ml_tpu.utils import feature_cells_view, materialize_feature_block
+from spark_rapids_ml_tpu.utils import admit_feature_cells, feature_cells_view, materialize_feature_block
 
 ROWS, COLS, BATCH = 424, 24, 64            # 6 batches of 64 and one of 40
 F32 = np.dtype(np.float32)
@@ -332,3 +337,266 @@ def test_staging_holds_a_few_extracted_batches_never_the_table():
         tracemalloc.stop()
     assert np.asarray(table).tobytes() == X.tobytes()
     assert batch_bytes <= peak < 5 * batch_bytes < X.nbytes / 3, (peak, batch_bytes)
+
+
+# -- the rule's two moments: admission in srml.ingest, settlement under the solver ---------
+
+DEFERRED = ("ingest.deferred_batches", "ingest.refuted_batches", "ingest.refits")
+
+
+def _traded(parts, batch=3):
+    """The frame with two middle rows of one batch traded, labels and all, as a pandas
+    take leaves them: every cell still a view into the batch's buffer, the first, second
+    and last where they were.  A view taken on their word pairs the two rows with each
+    other's labels, so the two are chosen to differ in theirs."""
+    part = parts[batch]
+    i = next(i for i in range(10, len(part) - 2) if part["label"][i] != part["label"][i + 1])
+    order = [*range(i), i + 1, i, *range(i + 2, len(part))]
+    return parts[:batch] + [part.iloc[order].reset_index(drop=True)] + parts[batch + 1 :]
+
+
+def _a_cell_copied(parts, batch=3):
+    """A middle cell replaced by an equal-valued copy allocated elsewhere."""
+    part = parts[batch]
+    cells = part["features"].to_numpy().copy()
+    cells[30] = cells[30].copy()
+    return parts[:batch] + [pd.DataFrame({"features": cells, "label": part["label"]})] + parts[batch + 1 :]
+
+
+def _fit(entry, est, parts):
+    """(the model's numbers, the job's counters) through the executor's entry or the public fit."""
+    if entry == "executor":
+        (attrs,) = run_distributed_fit(est, parts, 0, 1)
+        moved = profiling.TelemetrySnapshot.from_dict(attrs.pop(TELEMETRY_ATTR)).counters
+        return _vector(decode_attrs(attrs)), moved
+    model = est.fit(DataFrame(parts))
+    return _vector(model), model.fit_telemetry().counters
+
+
+@pytest.mark.parametrize("name", sorted(_refusals()))
+def test_admission_lets_through_only_what_settlement_can_refute(name):
+    """What the rule refuses, admission refuses too, but for a fault among the middle
+    cells of the one buffer (two traded, one cut short): the first, second and last cell
+    stand where they should and every cell is the buffer's, so the bytes between are
+    safe to read, and the rule catches the fault at settlement."""
+    cells = _refusals()[name]
+    admitted = admit_feature_cells(cells, F32)
+    if name in ("two_middle_rows_swapped", "a_short_cell"):
+        assert admitted is not None and admitted.shape == (BATCH, COLS) and not admitted.flags.writeable
+        assert np.shares_memory(admitted, cells[0]) and np.shares_memory(admitted, cells[-1])
+    else:
+        assert admitted is None
+
+
+def test_admission_hands_the_rules_view_of_a_sound_column():
+    X, y = _table()
+    for pdf in _arrow_batches(X, y):
+        cells = pdf["features"].to_numpy()
+        admitted, proven = admit_feature_cells(cells, F32), feature_cells_view(cells, F32)
+        assert admitted.__array_interface__ == proven.__array_interface__ and not admitted.flags.writeable
+    one = _arrow_batches(X[:1], y[:1])[0]["features"].to_numpy()       # a batch of one row
+    assert admit_feature_cells(one, F32).tobytes() == X[:1].tobytes()
+    assert admit_feature_cells(one, np.dtype(np.float64)) is None
+
+
+@pytest.mark.parametrize("entry", ["executor", "public"])
+def test_a_sound_frame_defers_every_proof_and_fits_the_eager_routes_model(entry, monkeypatch):
+    X, y = _table()
+    parts = _arrow_batches(X, y)
+    proven = []
+    rule = core.feature_cells_view
+    monkeypatch.setattr(core, "feature_cells_view", lambda cells, dtype: proven.append(len(cells)) or rule(cells, dtype))
+    theta, moved = _fit(entry, _estimator("logreg", num_workers=2), parts)
+    assert moved["ingest.deferred_batches"] == moved["ingest.view_batches"] == 7
+    assert "ingest.refits" not in moved and "ingest.refuted_batches" not in moved and "ingest.stacked_batches" not in moved
+    assert proven == [len(p) for p in parts]            # every cell of every batch was looked at, once
+    assert core._PROOFS.batches is None and core._PROOFS.refuted == 0
+    # the eager route: admission refuses everything, so the rule is asked in srml.ingest as before PR 48
+    monkeypatch.setattr(utils, "admit_feature_cells", lambda cells, dtype: None)
+    eager, moved = _fit(entry, _estimator("logreg", num_workers=2), parts)
+    assert moved["ingest.view_batches"] == 7 and not any(k in moved for k in DEFERRED)
+    assert np.array_equal(theta, eager)
+
+
+@pytest.mark.parametrize("entry", ["executor", "public"])
+@pytest.mark.parametrize("family", ["logreg", "kmeans"])
+def test_a_refuted_batch_voids_the_fit_which_is_staged_and_fitted_again(entry, family, monkeypatch, caplog):
+    X, y = _table()
+    parts = _traded(_arrow_batches(X, y))
+    est = _estimator(family, num_workers=2)
+    est.logger.propagate = True
+    handed = _spy(est, monkeypatch)
+    with caplog.at_level("WARNING"):
+        theta, moved = _fit(entry, est, parts)
+    assert moved["ingest.deferred_batches"] == 7 and moved["ingest.refuted_batches"] == 1 and moved["ingest.refits"] == 1
+    # staged twice: 7 views on admission, then 6 views the rule proved at once and the refuted batch stacked
+    assert moved["ingest.view_batches"] == 13 and moved["ingest.stacked_batches"] == 1 and moved["ingest.staged"] == 2
+    assert sum("failed the view rule's proof" in r.getMessage() for r in caplog.records) == 1
+    A, yA = ref_exec.assemble(parts)
+    first, again = (np.asarray(inputs.X)[:ROWS] for inputs in handed)
+    assert again.tobytes() == A.tobytes() and (first != A).any(axis=1).sum() == 2       # what went up unproven was wrong
+    assert core._PROOFS.batches is None and core._PROOFS.refuted == 0
+    # the model is the one the same frame gives with every batch stacked cell by cell, as before any view
+    monkeypatch.setattr(utils, "admit_feature_cells", lambda cells, dtype: None)
+    monkeypatch.setattr(utils, "feature_cells_view", lambda cells, dtype: None)
+    stacked, moved = _fit(entry, _estimator(family, num_workers=2), parts)
+    assert moved["ingest.stacked_batches"] == 7 and "ingest.view_batches" not in moved
+    assert np.array_equal(theta, stacked)
+    if family == "logreg":       # and not the one the unproven table gave: the two rows' labels differ
+        wrong = _vector(_estimator(family, num_workers=2).fit(DataFrame.from_numpy(first, y=yA)))
+        assert not np.array_equal(theta, wrong)
+
+
+def test_a_short_middle_cell_still_ends_in_the_stacks_own_error():
+    """Admitted (its neighbours stand where they should), refuted at settlement, and
+    the second staging says what the stack always said of such a column."""
+    X, y = _table()
+    parts = _arrow_batches(X, y)
+    cells = parts[2]["features"].to_numpy().copy()
+    cells[30] = cells[30][:-1]
+    parts[2] = pd.DataFrame({"features": cells, "label": parts[2]["label"]})
+    before = profiling.counters()
+    with pytest.raises(ValueError, match="same length"):
+        _estimator("logreg", num_workers=1).fit(DataFrame(parts))
+    moved = profiling.counter_deltas(before)
+    assert moved["ingest.refuted_batches"] == 1 and moved["ingest.refits"] == 1
+    assert core._PROOFS.batches is None and core._PROOFS.refuted == 0
+
+
+def test_a_cell_allocated_elsewhere_is_refused_at_admission_and_stacked_at_once():
+    X, y = _table()
+    parts = _a_cell_copied(_arrow_batches(X, y))
+    theta, moved = _fit("public", _estimator("logreg", num_workers=2), parts)
+    assert moved["ingest.deferred_batches"] == moved["ingest.view_batches"] == 6 and moved["ingest.stacked_batches"] == 1
+    assert "ingest.refits" not in moved and "ingest.refuted_batches" not in moved and moved["ingest.staged"] == 1
+    want = _estimator("logreg", num_workers=2).fit(DataFrame.from_numpy(X, y=y))
+    assert np.array_equal(theta, _vector(want))
+
+
+@pytest.mark.parametrize("route", ["staging", "extract_partition_features", "transform", "sweep"])
+def test_where_nobody_stands_ready_to_settle_the_rule_is_asked_at_once(route, monkeypatch):
+    """Outside FitJob.run (the benchmark's own staging, model-side readers, the sweep's
+    FitJob.staged) no batch is admitted on the cheap test: the traded rows are refused
+    where they are read and stacked, and nothing is left owing."""
+    X, y = _table()
+    parts = _traded(_arrow_batches(X, y))
+    A, yA = ref_exec.assemble(parts)
+    asked = []
+    monkeypatch.setattr(utils, "admit_feature_cells", lambda cells, dtype: asked.append(len(cells)))
+    before = profiling.counters()
+    if route == "staging":           # as chipbench/subjects/logreg_exec.fit_loop._staged_again does
+        feats, labels, _weights, dtype = LogisticRegression(num_workers=1)._pre_process_data(DataFrame(parts))
+        table = stage_dense_batches(feats, ROWS, get_mesh(1))
+        assert np.asarray(table).tobytes() == A.tobytes() and np.concatenate(labels).tobytes() == yA.tobytes() and dtype == F32
+    elif route == "extract_partition_features":
+        got = core.extract_partition_features(parts[3], "features", None, F32)
+        assert got.flags.owndata and got.tobytes() == A[3 * BATCH : 4 * BATCH].tobytes()
+    elif route == "transform":
+        model = _estimator("logreg", num_workers=1).fit(DataFrame.from_numpy(A, y=yA))
+        got = model.transform(DataFrame(parts)).toPandas()["prediction"].to_numpy()
+        want = model.transform(DataFrame.from_numpy(A, y=yA)).toPandas()["prediction"].to_numpy()
+        assert np.array_equal(got, want)
+    else:
+        from spark_rapids_ml_tpu.evaluation import MulticlassClassificationEvaluator
+        from spark_rapids_ml_tpu.tuning import CrossValidator, ParamGridBuilder
+
+        est = LogisticRegression(maxIter=8, tol=1e-30, regParam=1e-3, num_workers=2)
+        grid = ParamGridBuilder().addGrid(est.regParam, [1e-3, 1e-2]).build()
+        cv = CrossValidator(estimator=est, estimatorParamMaps=grid, numFolds=2, collectSubModels=True,
+                            evaluator=MulticlassClassificationEvaluator(metricName="logLoss"))
+        swept = cv.fit(DataFrame(parts)).subModels[0][0].fit_telemetry().counters
+        assert swept["ingest.view_batches"] == 6 and swept["ingest.stacked_batches"] == 1 and not any(k in swept for k in DEFERRED)
+    if route != "sweep":            # the sweep's winner is refitted by a public fit of its own, which does ask
+        assert asked == [] and not any(k in profiling.counter_deltas(before) for k in DEFERRED)
+    assert core._PROOFS.batches is None and core._PROOFS.refuted == 0
+
+
+def test_a_fit_function_that_raises_leaves_no_proof_owing_to_the_next_job(monkeypatch):
+    X, y = _table()
+    parts = _arrow_batches(X, y)
+    est = _estimator("logreg", num_workers=2)
+    make = est._get_tpu_fit_func
+
+    def failing(df, extra_params=None):
+        def fit_func(inputs, params):
+            assert len(core._PROOFS.batches) == 7           # admitted, and not yet proven
+            raise FloatingPointError("the solver gave up")
+        return fit_func
+
+    monkeypatch.setattr(est, "_get_tpu_fit_func", failing)
+    with pytest.raises(FloatingPointError, match="gave up"):
+        est.fit(DataFrame(parts))
+    assert core._PROOFS.batches is None and core._PROOFS.refuted == 0
+    monkeypatch.setattr(est, "_get_tpu_fit_func", make)
+    theta, moved = _fit("public", est, parts)
+    assert moved["ingest.deferred_batches"] == 7 and "ingest.refits" not in moved
+    assert np.array_equal(theta, _vector(_estimator("logreg", num_workers=2).fit(DataFrame.from_numpy(X, y=y))))
+
+
+def test_a_fit_function_that_never_fetches_is_settled_by_the_job(monkeypatch):
+    """FitJob.run settles what fetch_fit_result did not: here a fit function that
+    returns without calling it, on a frame the rule refutes."""
+    X, y = _table()
+    parts = _traded(_arrow_batches(X, y))
+    est = _estimator("logreg", num_workers=1)
+    calls = []
+
+    def bare(df, extra_params=None):
+        def fit_func(inputs, params):
+            calls.append(np.asarray(inputs.X)[:ROWS].tobytes())
+            return {"coef_": np.zeros(COLS), "intercept_": 0.0}
+        return fit_func
+
+    monkeypatch.setattr(est, "_get_tpu_fit_func", bare)
+    before = profiling.counters()
+    with core.fit_job(est) as job:
+        with job.prepare():
+            fit_func = est._get_tpu_fit_func(None)
+        job.run(lambda x64: est._build_fit_inputs(DataFrame(parts), x64), fit_func, {})
+    moved = profiling.counter_deltas(before)
+    assert moved["ingest.refuted_batches"] == 1 and moved["ingest.refits"] == 1
+    assert len(calls) == 2 and calls[0] != calls[1] and calls[1] == ref_exec.assemble(parts)[0].tobytes()
+
+
+def test_the_proof_runs_between_the_queued_copies_and_the_wait(monkeypatch):
+    """Inside fetch_fit_result: the leaves' copies are queued, the rule runs over the
+    admitted batches (srml.ingest.verify), and only then does the host block on the
+    solver (srml.fit.wait); in a fit, after srml.fit.solve has dispatched it."""
+    import threading
+
+    X, y = _table()
+    parts = _arrow_batches(X, y)
+    log = []
+
+    class Leaf:
+        nbytes = 12
+
+        def copy_to_host_async(self):
+            log.append("copy")
+
+        def block_until_ready(self):
+            log.append("ready")
+            return self
+
+        def __array__(self, dtype=None, copy=None):
+            return np.zeros(3, np.float32)
+
+    rule = core.feature_cells_view
+    monkeypatch.setattr(core, "feature_cells_view", lambda cells, dtype: log.append("proof") or rule(cells, dtype))
+    monkeypatch.setattr(core._PROOFS, "batches", [(p["features"].to_numpy(), F32) for p in parts[:2]])
+    me = threading.get_ident()
+    with profiling.collect_spans():
+        core.fetch_fit_result([Leaf(), Leaf()])
+        spans = [r[0] for r in sorted((r for r in profiling.span_records() if r[3] == me), key=lambda r: r[1])]
+    assert log[:6] == ["copy", "copy", "proof", "proof", "ready", "ready"]
+    assert spans == ["srml.ingest.verify", "srml.fit.wait", "srml.fit.fetch"] and core._PROOFS.batches == []
+    monkeypatch.undo()
+    with profiling.collect_spans():
+        model = _estimator("logreg", num_workers=2).fit(DataFrame(parts))
+        mine = sorted((r for r in profiling.span_records() if r[3] == me), key=lambda r: r[1])
+    fit = next(r for r in mine if r[0] == "srml.fit")
+    steps = [r for r in mine if r[6] == fit[5]]
+    assert [r[0] for r in steps] == ["srml.fit.init", "srml.fit.solve", "srml.ingest.verify", "srml.fit.wait", "srml.fit.fetch", "srml.fit.pack"]
+    assert steps[2][7] == {"batches": 7, "refuted": 0}
+    phases = model.fit_telemetry().phase_seconds()
+    assert "srml.ingest.verify" in phases
