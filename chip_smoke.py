@@ -338,6 +338,7 @@ def ingest_batches(seed):
           f"Arrow-made batches were not taken as views: {moved}")
     check(moved.get("ingest.rows") == rows and moved.get("ingest.h2d_bytes") == X.nbytes,
           f"staging counted {moved}, sent {rows} rows of {X.nbytes} bytes")
+    check(not any(k.startswith("ingest.link_") for k in moved), f"a staging outside a fit job kept a landing journal: {moved}")
     got = np.asarray(table)
     check(got.shape == X.shape and got.tobytes() == X.tobytes(), "the staged table is not the batches' rows in order")
     log(f"ingest: 4 Arrow-made batches, {rows} x {cols}, staged in {wall:.3f} s ({X.nbytes / 1e9 / wall:.2f} GB/s)")

@@ -145,6 +145,34 @@ def _pieces(lo: int, hi: int, share: int, row_bytes: int) -> Iterator[Tuple[int,
         lo = cut
 
 
+class _LinkScope(threading.local):
+    """Whether the thread is inside a fit job's srml.ingest (FitJob._ingest):
+    only there does stage_dense_batches keep a landing journal of the copies
+    it sends.  Anyone else's staging records nothing and starts no thread."""
+
+    open = False
+
+
+_LINK = _LinkScope()
+
+
+def _count_landings(pieces: Optional[List[Sequence[Any]]]) -> None:
+    """One journaled staging's pieces, (opened, landed, bytes) each, reduced
+    once: the time at least one copy was on its way (fed), the rest of first
+    enqueue to last landing (starved: nothing was), and the copies' own times
+    added up (flight; over fed, how many were under way at once).  Nothing
+    where the journal is void (LandingJournal.close gave none)."""
+    if not pieces:
+        return
+    fed, starved, flight = profiling.interval_measures([(o, l) for o, l, _ in pieces])
+    profiling.incr_counter("ingest.link_stagings")
+    profiling.incr_counter("ingest.link_pieces", len(pieces))
+    profiling.incr_counter("ingest.link_bytes", sum(nbytes for _, _, nbytes in pieces))
+    profiling.incr_counter("ingest.link_fed_us", round(1e6 * fed))
+    profiling.incr_counter("ingest.link_starved_us", round(1e6 * starved))
+    profiling.incr_counter("ingest.link_flight_us", round(1e6 * flight))
+
+
 def stage_dense_batches(batches: Iterable[np.ndarray], n_rows: int, mesh: Any) -> jax.Array:
     """The fit's dense table, built on the device batch by batch: `n_rows`
     rows (zero-padded to a multiple of the mesh's devices, the padding masked
@@ -171,13 +199,21 @@ def stage_dense_batches(batches: Iterable[np.ndarray], n_rows: int, mesh: Any) -
     device copy, one piece behind the newest, under srml.ingest.wait), which
     also bounds the device to the table and two pieces.  The function
     returns when every shard is whole, so srml.ingest ends with the table on
-    the device."""
+    the device.
+
+    When a piece LANDS the host's spans cannot say (the wait ends when this
+    thread next looks), so inside a fit job's srml.ingest each piece is also
+    a record of a landing journal (profiling.LandingJournal: `opened` here,
+    `landed` by the watcher thread that blocks on nothing else, span
+    srml.link.h2d), reduced once when the table is whole (_count_landings:
+    the counters ingest.link_*).  Outside a job nothing is journaled."""
     import jax.numpy as jnp
 
     devices = list(mesh.devices.flat)
     share = -(-n_rows // len(devices))
     shards: List[Any] = [None] * len(devices)
     in_flight: collections.deque = collections.deque()
+    journal = profiling.LandingJournal("srml.link.h2d") if _LINK.open else None
     row, n_cols, dtype = 0, 0, None
     for batch in batches:
         rows = batch.shape[0]
@@ -198,7 +234,10 @@ def stage_dense_batches(batches: Iterable[np.ndarray], n_rows: int, mesh: Any) -
             whole = at == 0 and hi - lo == filled       # all of this device's rows
             if whole and filled < share:                # which end in padding
                 host = np.concatenate([host, np.zeros((share - filled, n_cols), dtype)])
+            opened = profiling.now()
             up = _device_put_counted(lambda: jax.device_put(host, devices[s]))
+            if journal is not None:
+                journal.sent(opened, up.nbytes, up)
             if whole:
                 shards[s] = up
             else:
@@ -218,6 +257,8 @@ def stage_dense_batches(batches: Iterable[np.ndarray], n_rows: int, mesh: Any) -
             shards[s] = jnp.zeros((share, n_cols), dtype, device=dev)
     with profiling.span("srml.ingest.wait"):
         jax.block_until_ready(shards)
+    if journal is not None:
+        _count_landings(journal.close())
     return jax.make_array_from_single_device_arrays(
         (share * len(devices), n_cols), data_sharding(mesh), shards
     )
@@ -491,13 +532,19 @@ class FitJob:
             faults.site("runner.fit", rank=self.rank)
 
     def _ingest(self, build: Callable[[Callable[[Any], None]], FitInputs], x64: Callable[[Any], None]) -> FitInputs:
-        """srml.ingest around `build(x64)`: staged's, and run's second staging."""
-        with profiling.span("srml.ingest"):
-            inputs = build(x64)
-            get_logger(type(self.estimator)).info(
-                "Invoking TPU fit: %d rows x %d cols on %d-device mesh",
-                inputs.n_rows, inputs.n_cols, inputs.mesh.devices.size,
-            )
+        """srml.ingest around `build(x64)`: staged's, and run's second staging.
+        While it lasts the job keeps the landing journal of the pieces its
+        staging sends (_LINK; stage_dense_batches)."""
+        outer, _LINK.open = _LINK.open, True
+        try:
+            with profiling.span("srml.ingest"):
+                inputs = build(x64)
+                get_logger(type(self.estimator)).info(
+                    "Invoking TPU fit: %d rows x %d cols on %d-device mesh",
+                    inputs.n_rows, inputs.n_cols, inputs.mesh.devices.size,
+                )
+        finally:
+            _LINK.open = outer
         return inputs
 
     @contextlib.contextmanager

@@ -46,6 +46,10 @@
 #     what the process traced, lowered, and compiled or loaded, by
 #     executable, from jax's own monitoring events (compile.* counters and a
 #     bounded journal; always on, called only when jax builds something).
+#   - LandingJournal: [opened, landed] of asynchronous host→device copies,
+#     the landing stamped by the process's one watcher thread (srml-link-watch)
+#     and not where the sender next looks; core.stage_dense_batches keeps one a
+#     staging inside a fit job (counters ingest.link_*, span srml.link.h2d).
 #   - now(): the ONE monotonic clock.  Engine/serving modules must take
 #     timestamps through it (or through span()) — graftlint R6 rejects raw
 #     time.perf_counter()/time.time() outside this module, so every timing
@@ -59,10 +63,11 @@ import itertools
 import json
 import logging
 import os
+import queue
 import sys
 import threading
 import time
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 _log = logging.getLogger("spark_rapids_ml_tpu.profiling")
 
@@ -828,6 +833,113 @@ def trace_session(tag: str = "session") -> Iterator[Optional[str]]:
             # around successful fits/searches and must never replace their
             # result with a telemetry crash
             _log.warning("trace export for %r failed: %s", tag, exc)
+
+
+# -- the landing journal -------------------------------------------------------
+# jax's host→device copies are asynchronous, and the thread that sends one sees
+# it land only when it next blocks on it: on time while it has nothing else to
+# do (the link is fed, the sender waits), late while it works (the link stands
+# still behind it), which is the case a measurement of the link is for.  So a
+# landing is stamped by a thread that does nothing else: the process's one
+# watcher takes the journaled copies in the order they were sent, blocks on each
+# (block_until_ready releases the interpreter lock) and stamps now() when it
+# returns.  The stamp waits for the interpreter lock like any other line of
+# Python: at most one switch interval (5 ms) late while another thread runs
+# bytecode without a pause, and at once while the others are blocked themselves.
+
+# how long a journal's close waits for the watcher to reach its end; the sender
+# has seen every copy land by then, so the watcher is one wake-up behind
+_LANDING_DRAIN_S = 10.0
+
+_landing_queue: "queue.SimpleQueue[tuple]" = queue.SimpleQueue()
+_landing_lock = threading.Lock()
+_landing_thread: Optional[threading.Thread] = None
+
+
+def interval_measures(intervals: List[Tuple[float, float]]) -> Tuple[float, float, float]:
+    """(fed, starved, flight) of the closed intervals [(start, end), ...]: the
+    measure of their union (at least one under way), what is missing of it from
+    the first start to the last end (none under way), and the sum of their
+    lengths (flight / fed is how many were under way at once while any was)."""
+    fed, reach = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > reach:
+            fed += end - max(start, reach)
+            reach = end
+    first = min(start for start, _ in intervals)
+    return fed, (reach - first) - fed, sum(end - start for start, end in intervals)
+
+
+def _stamp_landing(journal: "LandingJournal", piece: int, array: Any) -> None:
+    """The watcher's one step, a function of its own so that the thread holds no
+    reference to a copy while it waits for the next."""
+    if array is None:       # a journal's end: every copy before it has been stamped
+        journal._drained.set()
+        return
+    try:
+        with (_annotation or _resolve_annotation())(journal.name):
+            array.block_until_ready()
+        journal.pieces[piece][1] = time.perf_counter()
+    except Exception as exc:  # noqa: BLE001 - the sender's own wait raises it; the journal is void
+        _log.debug("landing %d of %s was not seen: %s", piece, journal.name, exc)
+
+
+def _watch_landings() -> None:
+    while True:
+        _stamp_landing(*_landing_queue.get())
+
+
+class LandingJournal:
+    """One sender's record of the asynchronous copies it enqueues, a piece a
+    copy: [opened, landed, bytes], `opened` the sender's stamp and `landed` the
+    watcher's.  sent() a copy, close() when the sender has seen the last one
+    land.  A sender that raises simply lets go of it: the watcher stamps what is
+    still under way (nothing blocks on a copy for longer than the copy takes),
+    drops each array as it lands, and the journal goes with the last."""
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self.pieces: List[list] = []
+        self._drained = threading.Event()
+
+    def sent(self, opened: float, nbytes: int, array: Any) -> None:
+        """`array` (anything with block_until_ready) was enqueued at `opened`."""
+        global _landing_thread
+        if _landing_thread is None:
+            with _landing_lock:
+                if _landing_thread is None:
+                    _landing_thread = threading.Thread(
+                        target=_watch_landings, name="srml-link-watch", daemon=True
+                    )
+                    _landing_thread.start()
+        self.pieces.append([opened, None, int(nbytes)])
+        _landing_queue.put((self, len(self.pieces) - 1, array))
+
+    def close(self) -> Optional[List[list]]:
+        """The pieces as [opened, landed, bytes], once the watcher has stamped
+        the last; while a trace session collects, each is also one span record
+        on the watcher's thread (a lane of its own in the Chrome-trace export)
+        under the caller's open span.  None where nothing was sent, or where a
+        landing went unseen (a copy that raised): no journal rather than one
+        with a guess in it."""
+        if not self.pieces:
+            return None
+        _landing_queue.put((self, -1, None))
+        if not self._drained.wait(_LANDING_DRAIN_S) or any(p[1] is None for p in self.pieces):
+            _log.warning("the landing journal %s is void: a copy's landing was not seen", self.name)
+            return None
+        if _collect_depth > 0:
+            stack = _span_stack()
+            parent = stack[-1] if stack else 0
+            th = _landing_thread
+            with _trace_lock:
+                for i, (t0, t1, nbytes) in enumerate(self.pieces):
+                    if len(_trace_records) < _TRACE_CAP:
+                        _trace_records.append(
+                            (self.name, t0, t1, th.ident, th.name, next(_span_ids), parent,
+                             {"bytes": nbytes, "piece": i})
+                        )
+        return self.pieces
 
 
 # -- mergeable telemetry snapshots -------------------------------------------

@@ -410,6 +410,7 @@ def test_a_sound_frame_defers_every_proof_and_fits_the_eager_routes_model(entry,
     assert moved["ingest.deferred_batches"] == moved["ingest.view_batches"] == 7
     assert "ingest.refits" not in moved and "ingest.refuted_batches" not in moved and "ingest.stacked_batches" not in moved
     assert proven == [len(p) for p in parts]            # every cell of every batch was looked at, once
+    assert moved["ingest.link_stagings"] == 1 and moved["ingest.link_bytes"] == X.nbytes      # the landing journal (PR 49)
     assert core._PROOFS.batches is None and core._PROOFS.refuted == 0
     # the eager route: admission refuses everything, so the rule is asked in srml.ingest as before PR 48
     monkeypatch.setattr(utils, "admit_feature_cells", lambda cells, dtype: None)
@@ -431,6 +432,8 @@ def test_a_refuted_batch_voids_the_fit_which_is_staged_and_fitted_again(entry, f
     assert moved["ingest.deferred_batches"] == 7 and moved["ingest.refuted_batches"] == 1 and moved["ingest.refits"] == 1
     # staged twice: 7 views on admission, then 6 views the rule proved at once and the refuted batch stacked
     assert moved["ingest.view_batches"] == 13 and moved["ingest.stacked_batches"] == 1 and moved["ingest.staged"] == 2
+    # and journaled twice (PR 49): the second staging sends the same pieces and the same bytes again
+    assert moved["ingest.link_stagings"] == 2 and moved["ingest.link_bytes"] == 2 * X.nbytes and moved["ingest.link_pieces"] % 2 == 0
     assert sum("failed the view rule's proof" in r.getMessage() for r in caplog.records) == 1
     A, yA = ref_exec.assemble(parts)
     first, again = (np.asarray(inputs.X)[:ROWS] for inputs in handed)
@@ -440,7 +443,7 @@ def test_a_refuted_batch_voids_the_fit_which_is_staged_and_fitted_again(entry, f
     monkeypatch.setattr(utils, "admit_feature_cells", lambda cells, dtype: None)
     monkeypatch.setattr(utils, "feature_cells_view", lambda cells, dtype: None)
     stacked, moved = _fit(entry, _estimator(family, num_workers=2), parts)
-    assert moved["ingest.stacked_batches"] == 7 and "ingest.view_batches" not in moved
+    assert moved["ingest.stacked_batches"] == 7 and "ingest.view_batches" not in moved and moved["ingest.link_stagings"] == 1
     assert np.array_equal(theta, stacked)
     if family == "logreg":       # and not the one the unproven table gave: the two rows' labels differ
         wrong = _vector(_estimator(family, num_workers=2).fit(DataFrame.from_numpy(first, y=yA)))
@@ -506,6 +509,13 @@ def test_where_nobody_stands_ready_to_settle_the_rule_is_asked_at_once(route, mo
                             evaluator=MulticlassClassificationEvaluator(metricName="logLoss"))
         swept = cv.fit(DataFrame(parts)).subModels[0][0].fit_telemetry().counters
         assert swept["ingest.view_batches"] == 6 and swept["ingest.stacked_batches"] == 1 and not any(k in swept for k in DEFERRED)
+    # the landing journal (PR 49) is a job's: the bare staging and the reader keep none, transform neither (its
+    # model's fit kept one), and a sweep is a job, whose one staging is journaled like any other's
+    link = {k: v for k, v in profiling.counter_deltas(before).items() if k.startswith("ingest.link_")}
+    if route in ("staging", "extract_partition_features"):
+        assert link == {}
+    else:
+        assert (link if route == "transform" else swept)["ingest.link_stagings"] == 1
     if route != "sweep":            # the sweep's winner is refitted by a public fit of its own, which does ask
         assert asked == [] and not any(k in profiling.counter_deltas(before) for k in DEFERRED)
     assert core._PROOFS.batches is None and core._PROOFS.refuted == 0

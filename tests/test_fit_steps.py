@@ -142,6 +142,14 @@ def test_every_launcher_enters_the_one_job(launcher):
     else:
         assert [r[0] for r in steps] == TOP
         assert [n for n in inside(steps[2]) if n in STEPS] == STEPS
+    # the executor's launcher alone encodes what it returns (PR 49): one srml.finish.encode inside srml.finish,
+    # with the base64 characters it made; the public fit and the sweep hand back objects and have no such span
+    encodes = [r for r in mine if r[0] == "srml.finish.encode"]
+    if launcher == "executor":
+        finish = next(r for r in mine if r[0] == "srml.finish")
+        assert len(encodes) == 1 and encodes[0][6] == finish[5] and encodes[0][7]["bytes"] >= 4 * (X.shape[1] + 1) * 4 // 3
+    else:
+        assert encodes == []
     ingest = next(r for r in mine if r[0] == "srml.ingest")
     puts = [r for r in mine if r[0] == "srml.device_put"]
     # the table goes up a device's rows at a time (core.stage_dense_batches): the
