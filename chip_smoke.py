@@ -32,6 +32,7 @@
 
 import argparse
 import gc
+import inspect
 import json
 import os
 import sys
@@ -1166,31 +1167,39 @@ def kernel_gram_triangle(seed):
     """The statistics pass of LinearRegression, PCA and the tuning engine
     (ops/linalg._local_moments): one triangle of X'WX through the MXU as
     column panels and the other mirrored in, at the benchmark's width and at a
-    ragged one, on rows that are no multiple of the chunk and weights that
-    hold zeros.  Held against the float64 scatter on three slices of columns
+    ragged one, on rows that are no multiple of the chunk (40,000 under a
+    chunk of 32,768: one whole chunk through the loop and 7,232 rows left
+    over as a block of their own, both halves of the walk since PR 50) and
+    weights that hold zeros; the same rows walked as ONE block beside it.
+    Held against the float64 scatter on three slices of columns
     (all rows of the matrix, so both triangles), not against another float32
     product: on the chip the panels read 2.1e-6 to 2.8e-6 of the largest
     entry from it, the scan at one panel 2.9e-6, the whole product in one
     contraction (the pass without a mesh, timed here beside the panels)
     6.6e-6 to 7.1e-6 at these 40,000 rows and 1.9e-5 at 120,000 (PERF.md,
-    PR 31).  The scatter equals its transpose to the bit."""
+    PR 31); the loop and the left-over block together 2.9e-6 and 3.2e-6, the
+    one block 6.8e-6 and 7.1e-6 (one contraction over all 40,000 rows rounds
+    as the whole product does: PERF.md, PR 50).  The scatter equals its
+    transpose to the bit."""
     import jax
     import jax.numpy as jnp
 
     from spark_rapids_ml_tpu.ops import glm
-    from spark_rapids_ml_tpu.ops.linalg import gram_panels
+    from spark_rapids_ml_tpu.ops.linalg import gram_panels, scan_rows
     from spark_rapids_ml_tpu.parallel.mesh import get_mesh
 
     out = {}
+    chunk = inspect.signature(glm.linreg_sufficient_stats).parameters["chunk"].default
     for d in S["gram_widths"]:
         X, y = regression_rows(get_mesh(1), S["gram_rows"], d, seed + 80 + d)
         i = jnp.arange(X.shape[0])
         w = ((i % 7 != 0) * (1.0 + 0.25 * (i % 5))).astype(X.dtype)
         G, ms = {}, {}
-        for form, mesh in (("panels", get_mesh(1)), ("whole", None)):
-            jax.block_until_ready(glm.linreg_sufficient_stats(X, y, w, mesh=mesh))
+        forms = (("panels", get_mesh(1), chunk), ("one_block", get_mesh(1), X.shape[0]), ("whole", None, chunk))
+        for form, mesh, rows in forms:
+            jax.block_until_ready(glm.linreg_sufficient_stats(X, y, w, mesh=mesh, chunk=rows))
             t0 = time.perf_counter()
-            stats = jax.block_until_ready(glm.linreg_sufficient_stats(X, y, w, mesh=mesh))
+            stats = jax.block_until_ready(glm.linreg_sufficient_stats(X, y, w, mesh=mesh, chunk=rows))
             ms[form] = 1e3 * (time.perf_counter() - t0)
             G[form] = np.asarray(stats.G)
         Xh, wh = np.asarray(X, np.float64), np.asarray(w, np.float64)
@@ -1202,17 +1211,20 @@ def kernel_gram_triangle(seed):
                 off = np.abs(G[form][:, c0:c0 + span] - exact).max() / np.abs(exact).max()
                 gap[form] = max(gap[form], float(off))
         panels = gram_panels(d)
+        n_full, tail = scan_rows(X.shape[0], chunk)
         log(
-            f"gram d={d}: {panels} panels {ms['panels']:.2f} ms, {gap['panels']:.2e} "
-            f"of the largest entry from float64; the whole product "
-            f"{ms['whole']:.2f} ms, {gap['whole']:.2e}"
+            f"gram d={d}: {panels} panels over {n_full} whole chunks and {tail} rows left over "
+            f"{ms['panels']:.2f} ms, {gap['panels']:.2e} of the largest entry from float64; "
+            f"the same rows as one block {ms['one_block']:.2f} ms, {gap['one_block']:.2e}; "
+            f"the whole product {ms['whole']:.2f} ms, {gap['whole']:.2e}"
         )
         check(gap["panels"] <= 1e-5, f"d={d}: the panel scatter is {gap['panels']:.2e} off float64")
+        check(gap["one_block"] <= 1e-5, f"d={d}: the one-block scatter is {gap['one_block']:.2e} off float64")
         check(
             panels == 1 or np.array_equal(G["panels"], G["panels"].T),
             f"d={d}: the panel scatter is not its own transpose",
         )
-        out[str(d)] = {"panels": panels, "gap": gap, "ms": ms}
+        out[str(d)] = {"panels": panels, "walk": [n_full, tail], "gap": gap, "ms": ms}
         del X, y, w, stats, Xh, wh
         release()
     return out

@@ -16,6 +16,7 @@
 from __future__ import annotations
 
 import functools
+import inspect
 from typing import Any, Callable, Dict, List, Optional, Union
 
 import numpy as np
@@ -60,7 +61,7 @@ from ..ops.glm import (
     sweep_solve_elasticnet_cd,
     sweep_solve_linear,
 )
-from ..ops.linalg import gram_panels
+from ..ops.linalg import gram_panels, scan_rows
 from ..utils import get_logger
 
 
@@ -147,20 +148,41 @@ def _device_scalar(value: float, dtype: str) -> jax.Array:
     return jnp.asarray(value, dtype)
 
 
-def _count_fit(sweeps: Optional[int], n_cols: int, dense: bool) -> None:
+@functools.cache
+def _stats_chunk() -> int:
+    """The chunk _fit's statistics pass walks by: linreg_sufficient_stats'
+    own default (_fit passes none), read from the function and not restated."""
+    return inspect.signature(linreg_sufficient_stats).parameters["chunk"].default
+
+
+def _count_fit(sweeps: Optional[int], inputs: FitInputs, dense: bool) -> None:
     """Add one fit to the process-wide counters, which a fit's telemetry
     snapshot then carries as its own deltas: linreg.fits for every solve;
-    linreg.gram_triangle_fits for a dense fit wide enough that its Gram scan
-    computes one triangle (ops/linalg.gram_panels: a choice static at
-    dispatch); for a coordinate-descent solve also cd.fits, cd.sweeps and
-    cd.coordinates (sweeps x columns: the solver's dependent steps)."""
+    for a dense fit what its Gram scan was handed, static at dispatch:
+    linreg.gram_triangle_fits if the table is wide enough that the scan
+    computes one triangle (ops/linalg.gram_panels), linreg.gram_rows (the
+    staged table's rows, over all shards) and linreg.gram_rows_multiplied
+    (the rows the scan's blocks hold, by the plan the scan itself walks by:
+    ops/linalg.scan_rows; a fit of several param maps shares one scan and
+    counts it once a map, as linreg.fits counts the maps); for a
+    coordinate-descent solve also cd.fits, cd.sweeps and cd.coordinates
+    (sweeps x columns: the solver's dependent steps)."""
+    n_cols = int(inputs.n_cols)
     profiling.incr_counter("linreg.fits", 1)
-    if dense and gram_panels(int(n_cols)) > 1:
-        profiling.incr_counter("linreg.gram_triangle_fits", 1)
+    if dense:
+        if gram_panels(n_cols) > 1:
+            profiling.incr_counter("linreg.gram_triangle_fits", 1)
+        rows = multiplied = int(inputs.X.shape[0])
+        if inputs.mesh is not None:  # no mesh: one contraction over the table, no scan
+            shards, chunk = inputs.mesh.devices.size, _stats_chunk()
+            n_full, tail = scan_rows(rows // shards, chunk)
+            multiplied = shards * (n_full * chunk + tail)
+        profiling.incr_counter("linreg.gram_rows", rows)
+        profiling.incr_counter("linreg.gram_rows_multiplied", multiplied)
     if sweeps is not None:
         profiling.incr_counter("cd.fits", 1)
         profiling.incr_counter("cd.sweeps", sweeps)
-        profiling.incr_counter("cd.coordinates", sweeps * int(n_cols))
+        profiling.incr_counter("cd.coordinates", sweeps * n_cols)
 
 
 class LinearRegressionClass(_TpuParams):
@@ -366,7 +388,7 @@ class LinearRegression(_LinearRegressionParams, _TpuEstimatorSupervised):
                 for p, (coef_h, n_iter_h) in zip(maps, solved_h):
                     coef64 = np.asarray(coef_h, dtype=np.float64)
                     sweeps = None if n_iter_h is None else int(n_iter_h)
-                    _count_fit(sweeps, inputs.n_cols, dense=not sparse)
+                    _count_fit(sweeps, inputs, dense=not sparse)
                     if sweeps is not None:
                         logger.info("CD sweeps: %d", sweeps)
                     results.append(

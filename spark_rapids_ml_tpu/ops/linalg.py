@@ -111,17 +111,31 @@ def _mirror_block_rows(rows, d: int) -> jax.Array:
     return jnp.where(i[:, None] <= i[None, :], upper, upper.T)
 
 
+def scan_rows(n_loc: int, chunk: int) -> Tuple[int, int]:
+    """How the chunk scan (_local_moments) walks a shard of n_loc rows: (whole
+    chunks, rows left over).  The whole chunks go through a loop over the
+    chunk index, the rows left over are one block of their own shape, so the
+    products are handed whole * chunk + left over = n_loc rows, each once.
+    The scan plans by this and the fit's counters count by it
+    (models/linear_regression._count_fit)."""
+    return divmod(n_loc, chunk)
+
+
 def _local_moments(
     X_loc: jax.Array, w_loc: jax.Array, chunk: int, y_loc: jax.Array = None
 ):
-    """Per-shard weighted moments via a dynamic-slice scan over row chunks:
-    compile time is independent of the shard's row count and no padded copy
-    of the shard is materialized.  The clamped last chunk masks re-visited
-    rows through `fresh` (same pattern as ops/knn.py).
+    """Per-shard weighted moments from a walk over row chunks: compile time is
+    independent of the shard's row count and no padded copy of the shard is
+    materialized.  Rows are read where they lie (as ops/kmeans.py's
+    _chunked_assign_stats reads them): scan_rows' whole chunks are sliced out
+    of the shard by a loop over the chunk index, and the rows left over are
+    one more block of their own (smaller, static) shape, so no row goes
+    through the products twice.  Either half drops out when it is empty (a
+    shard no longer than a chunk is that one block and no loop).
 
-    The scatter X'WX is symmetric, so a chunk's product is taken as
+    The scatter X'WX is symmetric, so a block's product is taken as
     gram_panels(d) column panels, each against the columns from its own
-    start on; the scan carries those block rows and the lower triangle is
+    start on; the walk carries those block rows and the lower triangle is
     mirrored in once after it (_mirror_block_rows).
 
     Returns (wsum, xwsum, scatter) — plus (ywsum, Xty, y2) when `y_loc` is
@@ -145,18 +159,13 @@ def _local_moments(
         ]
     if n_loc == 0:
         # empty shard (possible under uneven mesh layouts / direct callers):
-        # zero moments, no scan — min(chunk, 0) would divide by zero below
+        # zero moments, nothing to walk
         init[2] = jnp.zeros((d, d), X_loc.dtype)
         return tuple(init)
-    chunk = min(chunk, n_loc)
-    n_chunks = -(-n_loc // chunk)
+    n_full, tail = scan_rows(n_loc, chunk)
+    tables = (X_loc, w_loc) + ((y_loc,) if with_y else ())
 
-    def body(carry, i):
-        start = jnp.minimum(i * chunk, n_loc - chunk)
-        xb = jax.lax.dynamic_slice_in_dim(X_loc, start, chunk)
-        wb = jax.lax.dynamic_slice_in_dim(w_loc, start, chunk)
-        fresh = (start + jnp.arange(chunk)) >= i * chunk
-        wb = wb * fresh
+    def block(carry, xb, wb, yb=None):
         xw = xb * wb[:, None]
         out = [
             carry[0] + wb.sum(),
@@ -173,17 +182,25 @@ def _local_moments(
             ),
         ]
         if with_y:
-            yb = jax.lax.dynamic_slice_in_dim(y_loc, start, chunk)
             out += [
                 carry[3] + (yb * wb).sum(),
                 carry[4] + exact_matmul(xw.T, yb),
                 carry[5] + (yb * yb * wb).sum(),
             ]
-        return tuple(out), None
+        return tuple(out)
 
-    out, _ = jax.lax.scan(
-        body, tuple(init), jnp.arange(n_chunks, dtype=jnp.int32)
-    )
+    def rows(start, size):
+        return (jax.lax.dynamic_slice_in_dim(a, start, size) for a in tables)
+
+    out = tuple(init)
+    if n_full:
+        out, _ = jax.lax.scan(
+            lambda carry, i: (block(carry, *rows(i * chunk, chunk)), None),
+            out,
+            jnp.arange(n_full, dtype=jnp.int32),
+        )
+    if tail:
+        out = block(out, *rows(n_full * chunk, tail))
     return out[:2] + (_mirror_block_rows(out[2], d),) + out[3:]
 
 

@@ -265,6 +265,33 @@ def test_gram_triangle_fits_counts_the_dense_fits_of_more_than_one_panel(case):
     assert moved.get("linreg.gram_triangle_fits", 0) == int(case == "dense_wide")
 
 
+@pytest.mark.parametrize("case", ["dense_whole_chunks_and_more", "dense_two_short_shards", "ell"])
+def test_gram_rows_counts_what_the_dense_scan_was_handed(case):
+    """linreg.gram_rows (the staged table's rows) and linreg.gram_rows_multiplied
+    (the rows of the scan's blocks, by ops/linalg.scan_rows: the plan the scan
+    itself walks by) are equal for a dense fit whatever its rows leave over a
+    chunk: no row goes through the products twice (PR 49's clamped last chunk
+    would have handed 2 x 32768 for 33,000).  The ELL pass has a scan of its own."""
+    from scipy import sparse
+
+    from spark_rapids_ml_tpu.ops.linalg import scan_rows
+
+    rows, workers = (33_000, 1) if case == "dense_whole_chunks_and_more" else (192, 2)
+    rng = np.random.default_rng(12)
+    X = rng.standard_normal((rows, 8)).astype(np.float32)
+    y = (X[:, :4] @ np.arange(1.0, 5.0)).astype(np.float32)
+    if case == "ell":
+        X = sparse.csr_matrix(np.where(rng.random(X.shape) < 0.2, X, 0.0).astype(np.float32))
+    model = LinearRegression(regParam=1e-3, num_workers=workers).fit(DataFrame.from_numpy(X, y=y, num_partitions=2))
+    moved = model.fit_telemetry().counters
+    assert moved["linreg.fits"] == 1
+    if case == "ell":
+        assert "linreg.gram_rows" not in moved and "linreg.gram_rows_multiplied" not in moved
+    else:
+        assert scan_rows(rows // workers, 32768) == ((1, 232) if workers == 1 else (0, 96))
+        assert moved["linreg.gram_rows"] == moved["linreg.gram_rows_multiplied"] == rows
+
+
 @pytest.mark.parametrize("l1,workers", [(0.5, 1), (0.0, 1), (0.5, 2)])
 def test_a_repeated_linreg_fit_of_a_device_frame_uploads_nothing(l1, workers):
     """The second fit of a device-resident frame makes no host-to-device copy:

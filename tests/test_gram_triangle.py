@@ -24,7 +24,7 @@ from spark_rapids_ml_tpu.ops import glm, linalg
 from spark_rapids_ml_tpu.ops.linalg import GRAM_PANEL_WIDTH as WIDTH, gram_panels
 from spark_rapids_ml_tpu.parallel.mesh import get_mesh
 
-ROWS, CHUNK = 300, 128          # three chunks, the last one clamped: 84 of its rows were seen before
+ROWS, CHUNK = 300, 128          # two whole chunks and 44 rows left over (before PR 50: a third chunk, clamped, 84 of its rows seen before)
 WIDTHS = [8, WIDTH, WIDTH + 1, 2 * WIDTH + 37, 3000]
 # of the whole product's d^2 outputs, what the panels may compute at d = 3000 (0.584 at 512, 0.667 at 1024)
 AREA_SHARE = 0.60 if WIDTH <= 512 else 0.67
@@ -39,18 +39,22 @@ def _rows(d, seed=0):
     return X, w, y
 
 
-@jax.jit
 def _whole_product_scan(X, w):
-    """The scatter as the scan had it before the panels: one product a chunk."""
-    n = X.shape[0]
+    """The scatter as the walk had it before the panels: one product a block, over the blocks
+    the walk takes since PR 50 (the whole chunks where they lie, then the rows left over alone).
+    Not jitted: the case calls _local_moments eagerly, and the left-over block's product
+    compiled alone and compiled inside a larger module differ in the last bit on the CPU."""
+    n_full, tail = divmod(X.shape[0], CHUNK)
 
-    def body(G, i):
-        start = jnp.minimum(i * CHUNK, n - CHUNK)
-        xb = jax.lax.dynamic_slice_in_dim(X, start, CHUNK)
-        wb = jax.lax.dynamic_slice_in_dim(w, start, CHUNK) * ((start + jnp.arange(CHUNK)) >= i * CHUNK)
-        return G + linalg.exact_matmul((xb * wb[:, None]).T, xb), None
+    def block(G, start, size):
+        xb = jax.lax.dynamic_slice_in_dim(X, start, size)
+        wb = jax.lax.dynamic_slice_in_dim(w, start, size)
+        return G + linalg.exact_matmul((xb * wb[:, None]).T, xb)
 
-    return jax.lax.scan(body, jnp.zeros((X.shape[1],) * 2, X.dtype), jnp.arange(-(-n // CHUNK), dtype=jnp.int32))[0]
+    G = jnp.zeros((X.shape[1],) * 2, X.dtype)
+    if n_full:
+        G = jax.lax.scan(lambda G, i: (block(G, i * CHUNK, CHUNK), None), G, jnp.arange(n_full, dtype=jnp.int32))[0]
+    return block(G, n_full * CHUNK, tail) if tail else G
 
 
 def test_the_panel_rule():
