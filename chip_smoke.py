@@ -59,6 +59,7 @@ SIZES = {
     "gram_rows": 40_000, "gram_widths": (3000, 700),
     "ell_rows": 1_000_000, "ell_kernel_rows": 20 * 2048 + 1,
     "tall_rows_per_chip": 6_250_000, "tall_cols": 30, "tall_k": 20,
+    "tall_kernel_rows": 3_000_000 + 77,
 }
 TOY_SIZES = {
     "rows_per_chip": 4096, "cols": 64, "k": 16, "fit_iters": 5,
@@ -76,6 +77,7 @@ TOY_SIZES = {
     "gram_rows": 1000, "gram_widths": (700, 40),
     "ell_rows": 4096, "ell_kernel_rows": 2 * 256 + 1,
     "tall_rows_per_chip": 5000, "tall_cols": 30, "tall_k": 20,
+    "tall_kernel_rows": 3 * 1024 + 77,
 }
 
 REHEARSAL = False
@@ -1302,6 +1304,55 @@ def kernel_softmax_ell(seed):
     return out
 
 
+def kernel_lloyd_tall(seed):
+    """Lloyd's one-read update pass (ops/lloyd_tall_pass.py, through Mosaic)
+    against its XLA twin ops/kmeans._tall_assign_stats over the same rows of a
+    feature-major table of a few million rows in blobs, at two values of k
+    (20 in 24 centre rows, thirty-two lane tiles a trip; 130 in 136, four) with the
+    tile the rule picks, float32 weights with zeros, and rows left over behind
+    the whole tiles (the twin's own block takes them in the solver).  The
+    counts say which centre every row chose: whole weights, so a row that
+    changed sides moves them by 0.25 or more; |x|^2, which the kernel leaves
+    out of the argmin, may turn a tie of the last bit, so a few in a million
+    may."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_ml_tpu.ops import lloyd_tall_pass as lp
+    from spark_rapids_ml_tpu.ops.kmeans import _tall_assign_stats
+    from spark_rapids_ml_tpu.ops.tall import padded_features
+
+    rows, cols, chunk = S["tall_kernel_rows"], S["tall_cols"], 1024 if REHEARSAL else 32768
+    d_pad = padded_features(cols)
+    out = {}
+    for k in (20, 130):
+        key = jax.random.key(seed + 700 + k)
+        kc, ka, kn, km = jax.random.split(key, 4)
+        true = jax.random.uniform(kc, (k, cols), jnp.float32, -10.0, 10.0)
+        xt = true.T[:, jax.random.randint(ka, (rows,), 0, k)] + jax.random.normal(kn, (cols, rows), jnp.float32)
+        xt = jnp.pad(xt, ((0, d_pad - cols), (0, 0)))
+        w = jnp.where(jnp.arange(rows) % 7 == 0, 0.0, 0.5 + 0.25 * (jnp.arange(rows) % 5)).astype(jnp.float32)
+        centres = jnp.pad(true + 0.3 * jax.random.normal(km, (k, cols), jnp.float32), ((0, 0), (0, d_pad - cols)))
+        tile = lp.row_tile(k, d_pad, rows, chunk)
+        done = rows // tile * tile
+        check(0 < done < rows, f"k={k}: {rows} rows in tiles of {tile} leave no rows over: no test of the whole tiles' extent")
+        got = jax.block_until_ready(lp.pass_sums(xt, lp.weight_tiles(w, tile), centres, interpret=REHEARSAL))
+        x_norm = (xt * xt).sum(axis=0)
+        want = jax.jit(lambda xt, w, c, xn: _tall_assign_stats(xt, w, c, chunk, xn)[:2])(
+            xt[:, :done], w[:done], centres, x_norm[:done])
+        sums, counts = (np.asarray(a, np.float64) for a in got)
+        tsums, tcounts = (np.asarray(a, np.float64) for a in want)
+        moved = float(np.abs(counts - tcounts).max())
+        gap = float(np.abs(sums - tsums).max() / np.abs(tsums).max())
+        check(moved <= 2e-5 * done, f"k={k}: the counts lie {moved} from the twin's over {done} rows: rows changed sides")
+        check(gap <= 2e-5, f"k={k}: the kernel's sums lie {gap:.2e} of the largest from the twin's")
+        check(not sums[:, cols:].any(), f"k={k}: a padding feature row took a sum")
+        log(f"lloyd_tall k={k}: {done} of {rows} rows in tiles of {tile}, sums {gap:.2e} of the largest from the twin, "
+            f"counts within {moved:g}")
+        out[str(k)] = {"tile": tile, "sums_gap": gap, "counts_moved": moved}
+    return out
+
+
 def fit_sparse(seed):
     """The public multinomial fit of a device-resident ELL frame
     (DataFrame.from_device(EllMatrix)) through the one-pass kernel: the table
@@ -1368,6 +1419,8 @@ def stage_kernels(seed):
     release()
     out["softmax_ell"] = kernel_softmax_ell(seed)
     out["fit_sparse"] = fit_sparse(seed)
+    release()
+    out["lloyd_tall"] = kernel_lloyd_tall(seed)
     return out
 
 
@@ -1426,7 +1479,9 @@ def mesh_tall_fit(seed, n_dev):
     def fit(table, workers):
         est = KMeans(k=k, initMode="random", tol=0.0, maxIter=S["fit_iters"], seed=seed, num_workers=workers)
         model = est.fit(DataFrame.from_device(table))
-        check(model.fit_telemetry().counters.get("lloyd.tall_fits") == 1, "the fit did not take Lloyd's tall pass")
+        counters = model.fit_telemetry().counters
+        check(counters.get("lloyd.tall_fits") == 1, "the fit did not take Lloyd's tall pass")
+        check(counters.get("lloyd.tall_kernel_fits") == 1, "the fit's update passes did not take the kernel")
         return model
 
     wide = fit(TallMatrix(xt, cols), n_dev)

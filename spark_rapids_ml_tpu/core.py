@@ -29,6 +29,7 @@ import contextlib
 import functools
 import json
 import os
+import sys
 import threading
 from abc import abstractmethod
 from dataclasses import dataclass, field
@@ -45,6 +46,15 @@ from .params import Param, Params, _TpuParams
 from .parallel.mesh import get_mesh, data_sharding
 from .parallel.partition import PartitionDescriptor
 from .utils import feature_cells_view, get_logger, materialize_feature_block
+
+
+def is_tall_table(X: Any) -> bool:
+    """Whether X is an ops.tall.TallMatrix, asked without importing that
+    module: a table of the type exists only where somebody imported it, and
+    its import starts a thread (ops/tall.py says why) that a fit of any other
+    table should not pay for."""
+    tall = sys.modules.get(f"{__package__}.ops.tall")
+    return tall is not None and isinstance(X, tall.TallMatrix)
 
 
 def _is_pyspark_dataframe(dataset: Any) -> bool:
@@ -843,9 +853,7 @@ class _TpuCaller(_TpuParams):
         so repeated fits skip the per-fit label/mask device_puts the way
         the host path's input cache does."""
         Xs, n_rows, n_cols, _fcol = dev
-        from .ops.tall import TallMatrix
-
-        if isinstance(Xs, TallMatrix) and not self._supports_tall_input:
+        if is_tall_table(Xs) and not self._supports_tall_input:
             raise TypeError(
                 f"{type(self).__name__} has no pass over a feature-major table "
                 "(ops.tall.TallMatrix), and nothing densifies or transposes a whole "

@@ -24,6 +24,7 @@ from ..core import (
     _TpuEstimator,
     _TpuModelWithPredictionCol,
     fetch_fit_result,
+    is_tall_table,
 )
 from ..dataframe import DataFrame
 from ..params import (
@@ -48,7 +49,7 @@ from ..ops.kmeans import (
     random_init_tall,
     scalable_kmeans_pp_init,
 )
-from ..ops.tall import TallMatrix
+from ..ops import lloyd_tall_pass
 from ..utils import get_logger
 
 
@@ -167,7 +168,7 @@ class KMeans(_KMeansParams, _TpuEstimator):
         def _fit(inputs: FitInputs, params: Dict[str, Any]):
             # the table's type picks the pass: a feature-major TallMatrix takes
             # Lloyd's tall form, a row-major array the one it always took
-            tall = isinstance(inputs.X, TallMatrix)
+            tall = is_tall_table(inputs.X)
             # the step spans below tile srml.fit (core.FitJob.run)
             with profiling.span("srml.fit.init"):
                 k = int(params["n_clusters"])
@@ -181,6 +182,9 @@ class KMeans(_KMeansParams, _TpuEstimator):
                         )
                     centers0 = random_init_tall(inputs.X, inputs.weight, k, seed, inputs.mesh)
                     profiling.incr_counter("lloyd.tall_fits")
+                    if lloyd_tall_pass.takes(inputs.X, k, chunk, inputs.mesh.devices.size):
+                        # the update passes' whole tiles go through the kernel
+                        profiling.incr_counter("lloyd.tall_kernel_fits")
                     profiling.incr_counter("lloyd.table_bytes", inputs.X.table_bytes)
                     profiling.incr_counter("lloyd.table_resident_bytes", inputs.X.resident_bytes)
                 elif params["init"] == "random":

@@ -36,6 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
 from ..parallel.mesh import DATA_AXIS
+from . import lloyd_tall_pass
 
 
 def _chunked_assign_stats(X_loc, w_loc, centers, chunk, x_norm_loc, exact_inertia=False):
@@ -164,7 +165,11 @@ def lloyd_iterations(
 
 def _tall_assign_stats(xt_loc, w_loc, centers, chunk, x_norm_loc, exact_inertia=False):
     """_chunked_assign_stats over a device's columns of a feature-major table
-    (ops/tall.TallMatrix): `xt_loc` (D_pad, n_loc) with the rows on the lanes,
+    (ops/tall.TallMatrix), in plain jnp: lloyd_tall's exact-inertia pass, the
+    block of rows its update passes leave over behind the kernel's whole tiles
+    (ops/lloyd_tall_pass.py), the whole update pass where the kernel does not
+    take the table (float64, fewer than 128 rows a device), and the kernel's
+    twin in tests.  `xt_loc` (D_pad, n_loc) with the rows on the lanes,
     `centers` (k, D_pad) with the padding features zero.  The same equations
     with the rows as the minor axis of everything a block builds, so that at
     k = 20 nothing is a (rows, k) tile of 20 lanes in 128: for a block b of C
@@ -184,7 +189,10 @@ def _tall_assign_stats(xt_loc, w_loc, centers, chunk, x_norm_loc, exact_inertia=
     block's rows, and at blocks of 524,288 rows the centres that have stopped
     lay 6.9e-6 of their norm from the exact means of their rows, ten times
     what they do at 32,768 and twice what bfloat16 operands cost (a pass
-    was 15.9 ms for 21.1: PERF.md section 6, PR 52).
+    was 15.9 ms for 21.1: PERF.md section 6, PR 52).  The kernel's chains
+    are a lane tile's 128 rows, its lane tiles and tiles added on the vector
+    unit (the tiles compensated), which is why its tile may be as large as
+    VMEM holds and this block may not.
 
     The walk is _chunked_assign_stats' own: the n_loc // chunk whole chunks
     where they lie by a loop over the chunk index, the rows left over one
@@ -255,8 +263,18 @@ def lloyd_tall(
     rule, the same guarantees (ties to the lowest index, an emptied centre
     keeps its place, exactly max_iter updates at tol 0, the inertia in the
     difference form) and the same named scopes; its own name, so that a
-    profile tells the two passes apart.  Returns (centers (k, D), n_iter,
-    inertia)."""
+    profile tells the two passes apart.
+
+    An update pass of a float32 table is the Pallas call `lloyd_tall_pass`
+    (ops/lloyd_tall_pass.py) over each device's whole tiles: a tile read once,
+    cut into its bfloat16 pieces once, distances, argmin and sums from them in
+    VMEM, both products the six partial products Precision.HIGHEST computes.
+    Its tile follows from k, D_pad, the device's rows and `chunk` (row_tile:
+    never more than `chunk`); it leaves |x|^2 out of the argmin, so there a
+    tie is a tie of |m|^2 - 2 m.x.  The rows behind the whole tiles, the
+    exact-inertia pass, and every pass of a float64 table or of fewer than
+    128 rows a device are _tall_assign_stats'.  Off the chip the call runs
+    through Pallas's interpreter.  Returns (centers (k, D), n_iter, inertia)."""
     d = X.n_cols
 
     def per_device(xt_loc, w_loc, centers0):
@@ -267,9 +285,22 @@ def lloyd_tall(
             _, prev_shift, it = state
             return (it < max_iter) & (prev_shift > tol)
 
+        # the update passes' whole tiles go through the kernel, the rows left
+        # over (fewer than a tile, so than a chunk) through XLA's block
+        d_pad, n_loc = xt_loc.shape
+        tile = lloyd_tall_pass.row_tile(centers0.shape[0], d_pad, n_loc, chunk) if xt_loc.dtype == jnp.float32 else 0
+        done = n_loc // tile * tile if tile else 0
+        w_tiles = lloyd_tall_pass.weight_tiles(w_loc, tile) if done else None
+        xt_left, w_left, norm_left = xt_loc[:, done:], w_loc[done:], x_norm_loc[done:]
+
         def body(state):
             centers, _, it = state
-            sums, counts, _ = _tall_assign_stats(xt_loc, w_loc, centers, chunk, x_norm_loc)
+            parts = []
+            if done:
+                parts.append(lloyd_tall_pass.pass_sums(xt_loc, w_tiles, centers, interpret=lloyd_tall_pass.interpreted()))
+            if done < n_loc:
+                parts.append(_tall_assign_stats(xt_left, w_left, centers, chunk, norm_left)[:2])
+            sums, counts = (sum(p) for p in zip(*parts))
             with jax.named_scope("lloyd.update"):
                 sums = jax.lax.psum(sums, DATA_AXIS)
                 counts = jax.lax.psum(counts, DATA_AXIS)
@@ -280,7 +311,7 @@ def lloyd_tall(
             return (new_centers, shift, it + 1)
 
         # the padding features of the table are zero: so are the centres'
-        padded = jnp.pad(centers0, ((0, 0), (0, xt_loc.shape[0] - d)))
+        padded = jnp.pad(centers0, ((0, 0), (0, d_pad - d)))
         init = (padded, jnp.array(jnp.inf, xt_loc.dtype), jnp.array(0, jnp.int32))
         centers, _, n_iter = jax.lax.while_loop(cond, body, init)
         with jax.named_scope("lloyd.inertia"):

@@ -11,13 +11,19 @@
 # sibling of ops/sparse.py's slot-major EllMatrix, and it reaches an estimator
 # the same way: DataFrame.from_device(TallMatrix(...)), FitInputs.X carries it,
 # and the estimators with a pass written for it (KMeans: ops/kmeans.py's
-# lloyd_tall) fit it where it lies.  Every other estimator refuses it at entry
+# lloyd_tall, whose update passes read a tile of it once through the Pallas
+# kernel of ops/lloyd_tall_pass.py) fit it where it lies.  Every other
+# estimator refuses it at entry
 # (core._build_fit_inputs_device): nothing densifies or transposes a whole
-# table behind the user's back.
+# table behind the user's back.  The package does not import this module
+# (core.is_tall_table asks for the type without it): whoever does is about to
+# build such a table, and the module imports Pallas ahead of the first fit on a
+# thread of its own (_import_pallas_ahead).
 #
 
 from __future__ import annotations
 
+import threading
 from functools import partial
 from typing import Any, Iterable, Tuple
 
@@ -28,6 +34,23 @@ import jax.numpy as jnp
 
 # a table's feature rows are rounded up to a multiple of this: a sublane group
 FEATURE_MULTIPLE = 8
+
+
+def _import_pallas_ahead() -> None:
+    """Whoever imports this module is about to build a feature-major table, and
+    the passes over one are Pallas kernels (ops/lloyd_tall_pass.py).  Importing
+    Pallas takes 0.4 s of the chip's host, a seventh of a tall fit's whole
+    set-up, and nothing needs it before the first fit is traced: so it is
+    imported here on a thread of its own, which ends with the import, while the
+    caller builds or uploads the table and the host waits for the device.  (The
+    package does not import this module: core.is_tall_table asks for the type
+    without it.)"""
+    from .lloyd_tall_pass import _pallas
+
+    threading.Thread(target=_pallas, name="srml-pallas-import", daemon=True).start()
+
+
+_import_pallas_ahead()
 
 
 def padded_features(n_cols: int) -> int:

@@ -5,7 +5,11 @@ benchmark's size, read here, without a chip:
 
 Compiles `random_init_tall` and `lloyd_tall` at 25,000,000 x 30 a chip (xt of
 (32, rows)), k=20, chunk 32768 for a DESCRIBED v5e (one chip, then the 2x2 mesh,
-both in this one process), prints one JSON line per executable and exits 1 if
+both in this one process), the update pass's Pallas kernel `lloyd_tall_pass`
+compiled by Mosaic as the chip runs it (this process's backend is the CPU, where
+the solver would take the interpreter: the tool steers
+`ops.lloyd_tall_pass.interpreted`), prints one JSON line per executable with its
+temporaries and exits 1 if
   - the compiler keeps more than half the table beside it (on the mesh the seeded
     draw's top_k gathers the rows' 100M keys, 0.8 GB a chip; both traps PR 52
     fell into show here as temporaries of the table's size or four times it: a
@@ -14,7 +18,10 @@ both in this one process), prints one JSON line per executable and exits 1 if
     device, where the compile then fails for memory);
   - a loop body of the solver holds a `copy`, `transpose` or `pad` of a chunk's
     width on its own (inside a fusion nothing is written to HBM; the line also
-    counts the module's products, two a chunk in an update's body);
+    counts the module's products: the left-over block's two and the inertia
+    pass's, the update's whole tiles being the kernel's);
+  - the solver holds no call of the kernel (the line counts them: one, in the
+    update's loop body);
   - on the mesh the solver has no all-reduce.
 Nothing runs: no result and no time comes from here.  Run it by hand after a
 change to ops/tall.py or to the tall half of ops/kmeans.py, before the chip call
@@ -66,6 +73,7 @@ def main() -> int:
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from spark_rapids_ml_tpu.ops import lloyd_tall_pass
     from spark_rapids_ml_tpu.ops.kmeans import lloyd_tall, random_init_tall
     from spark_rapids_ml_tpu.ops.tall import TallMatrix, padded_features
     from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
@@ -75,6 +83,8 @@ def main() -> int:
     except (RuntimeError, ValueError) as e:  # the plugin or its lock, not a fault of the solver
         print(f"no v5e:2x2 topology can be described here: {e}", file=sys.stderr)
         return 2
+    # this process's backend is the CPU: the kernel is compiled as the chip runs it
+    lloyd_tall_pass.interpreted = lambda: False
     bad = False
     for chips in (1, 4):
         mesh = Mesh(np.array(topo.devices[:chips]), (DATA_AXIS,))
@@ -101,13 +111,16 @@ def main() -> int:
             faults = []
             if mem.temp_size_in_bytes > table // 2:
                 faults.append(f"temporaries of {mem.temp_size_in_bytes} bytes beside a table of {table}")
+            kernels = len(re.findall(r"^\s*(?:ROOT )?%?lloyd_tall_pass[\w.\-]* = .* custom-call\(.*tpu_custom_call", text, re.M))
             if name == "lloyd_tall":
                 faults += loop_faults(text)
+                if not kernels:
+                    faults.append("no call of the kernel lloyd_tall_pass")
                 if chips > 1 and "all-reduce" not in text:
                     faults.append("no all-reduce on the mesh")
             print(json.dumps({"what": name, "chips": chips, "ok": not faults, "faults": faults,
                               "temp_bytes": mem.temp_size_in_bytes, "table_bytes_a_chip": table,
-                              "products": text.count(" convolution(")}))
+                              "products": text.count(" convolution("), "kernel_calls": kernels}))
             bad = bad or bool(faults)
     return 1 if bad else 0
 
